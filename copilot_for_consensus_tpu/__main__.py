@@ -46,10 +46,24 @@ def _cmd_serve(args: argparse.Namespace) -> int:
             initialize_multihost,
         )
         initialize_multihost(mh)
+    # Host-only deployments (mock/openai drivers) never import jax; a
+    # tpu driver names the device it serves on and keeps its compiled
+    # programs across restarts (parallel/mesh.py).
+    on_chip = any(dict(cfg.get(k) or {}).get("driver") == "tpu"
+                  for k in ("embedding", "vector_store", "llm"))
+    device: dict = {}
+    if on_chip:
+        from copilot_for_consensus_tpu.parallel.mesh import (
+            enable_compile_cache,
+            require_accelerator,
+        )
+        device["compile_cache"] = enable_compile_cache()
+        dev = require_accelerator("serve with a tpu driver")
+        device.update(platform=dev.platform, device_kind=dev.device_kind)
     server = serve_pipeline(cfg, host=args.host, port=args.port)
     server.start()
     print(json.dumps({"event": "serving", "host": args.host,
-                      "port": server.port}), flush=True)
+                      "port": server.port, **device}), flush=True)
     stop = threading.Event()
     for sig in (signal.SIGINT, signal.SIGTERM):
         signal.signal(sig, lambda *_: stop.set())

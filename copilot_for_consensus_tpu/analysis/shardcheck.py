@@ -248,18 +248,46 @@ def _check_logical(case: ContractCase) -> list[tuple[str, str]]:
     return out
 
 
+def _unbound_vma_axes(exc) -> tuple:
+    """Under ``check_vma`` a psum/pmax/ppermute/pcast over an axis no
+    enclosing shard_map binds dies in ``jax.core.pvary`` on a bare
+    ``assert set(new_axes) == set(axes)`` — no message, no axis name.
+    The innermost frame still holds both sets; their difference is the
+    unbound axes. Needs unfiltered tracebacks (jax strips its own
+    frames by default), which ``_check_trace`` arranges."""
+    if not isinstance(exc, AssertionError) or exc.__traceback__ is None:
+        return ()
+    tb = exc.__traceback__
+    while tb.tb_next is not None:
+        tb = tb.tb_next
+    if tb.tb_frame.f_code.co_name != "pvary":
+        return ()
+    loc = tb.tb_frame.f_locals
+    bound = set(loc.get("new_axes") or ())
+    return tuple(a for a in (loc.get("axes") or ()) if a not in bound)
+
+
 def _check_trace(case: ContractCase):
     """eval_shape the program; returns (findings, out_avals | None)."""
     if case.fn is None:
         return [], None
     import jax
 
+    filtering = jax.config.jax_traceback_filtering
+    jax.config.update("jax_traceback_filtering", "off")
     try:
         out = jax.eval_shape(case.fn, *case.args, **dict(case.kwargs))
         return [], out
     except ContractSkip:
         raise
     except Exception as exc:
+        unbound = _unbound_vma_axes(exc)
+        if unbound:
+            return [("shard-collective",
+                     f"tracing under the declared mesh failed: a "
+                     f"collective names unbound axis "
+                     f"{', '.join(map(repr, unbound))} (jax.core.pvary "
+                     f"assertion under check_vma)")], None
         msg = f"{type(exc).__name__}: {_oneline(exc)}"
         text = str(exc).lower()
         # Classify narrowly: axis-binding failures surface as jax's
@@ -276,6 +304,8 @@ def _check_trace(case: ContractCase):
                      f"tracing under the declared mesh failed: {msg}")], \
                 None
         return [("shard-contract", f"tracing failed: {msg}")], None
+    finally:
+        jax.config.update("jax_traceback_filtering", filtering)
 
 
 def _check_donation(case: ContractCase, out_avals) -> list[tuple[str, str]]:

@@ -30,6 +30,10 @@ def create_embedding_provider(config: Any = None) -> EmbeddingProvider:
         return MockEmbeddingProvider(
             dimension=int(_cfg_get(config, "dimension", 32)))
     if driver == "tpu":
+        from copilot_for_consensus_tpu.parallel.mesh import (
+            require_accelerator,
+        )
+        require_accelerator("embedding driver 'tpu'")
         return TPUEmbeddingProvider(
             model=_cfg_get(config, "model", "minilm-l6"),
             checkpoint=_cfg_get(config, "checkpoint"),

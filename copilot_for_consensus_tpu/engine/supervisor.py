@@ -134,8 +134,8 @@ def is_resource_exhaustion(exc: BaseException) -> bool:
 @dataclass(frozen=True)
 class SupervisorConfig:
     """Policy knobs. Deadlines are generous by default — the watchdog
-    exists to catch a WEDGED tunnel/device (minutes of silence), not a
-    slow compile; chaos tests tighten them to milliseconds."""
+    exists to catch a WEDGED device (minutes of silence), not a slow
+    compile; chaos tests tighten them to milliseconds."""
 
     #: per-dispatch-kind wall-time deadline; ``step`` covers the
     #: runner's whole ``eng.step()`` frame (compile included, hence

@@ -44,15 +44,11 @@ import functools
 import jax
 import jax.numpy as jnp
 
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
 from copilot_for_consensus_tpu.analysis.contracts import checkable
 from copilot_for_consensus_tpu.ops.attention import decode_attention
-
-try:  # Pallas TPU lowering — import-light so host-only tools survive
-    from jax.experimental import pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
-    HAS_PALLAS = True
-except Exception:  # pragma: no cover - pallas ships with jax on tpu
-    HAS_PALLAS = False
 
 # TPU lane width the kernel's block axis packs against: pool blocks
 # must divide it so a block never straddles a lane boundary. The pool
@@ -309,8 +305,7 @@ def paged_decode_attention(
     ``impl="pallas"`` reads the pool by pointer instead of gathering
     (TPU serving route; parity-tested against the reference)."""
     if impl == "auto":
-        impl = "pallas" if (jax.default_backend() == "tpu"
-                            and HAS_PALLAS) else "xla"
+        impl = "pallas" if jax.default_backend() == "tpu" else "xla"
     if impl == "pallas":
         return paged_decode_attention_pallas(
             q, pool_k_l, pool_v_l, tables, lengths, window=window)

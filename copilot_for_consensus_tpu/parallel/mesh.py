@@ -2,6 +2,7 @@
 
 from __future__ import annotations
 
+import pathlib
 from dataclasses import dataclass
 
 import jax
@@ -11,6 +12,58 @@ from jax.sharding import Mesh
 from copilot_for_consensus_tpu.analysis.contracts import checkable
 
 MESH_AXES = ("dp", "pp", "sp", "ep", "tp")
+
+#: Where compiled programs persist when nothing outside the process
+#: placed the cache: one fixed directory at the root of the checkout.
+#: The path is part of JAX's cache key, so it must never vary by run
+#: (no temp name, pid or timestamp).
+DEFAULT_COMPILE_CACHE_DIR = str(
+    pathlib.Path(__file__).resolve().parents[2] / ".jax_cache")
+
+
+def enable_compile_cache() -> str:
+    """Turn on JAX's persistent compilation cache; returns its directory.
+
+    Entry points (``serve``, ``bench.py``, ``chip_smoke.py``, the chip
+    benches under ``scripts/``) call this before their first compile.
+    A directory placed from outside — ``JAX_COMPILATION_CACHE_DIR``,
+    which JAX reads into ``jax_compilation_cache_dir`` itself — is left
+    alone and nothing else is set: whoever placed it owns its policy
+    (the persistence thresholds ride the same env). Otherwise the cache
+    goes to :data:`DEFAULT_COMPILE_CACHE_DIR` and keeps EVERY program:
+    under JAX's default a compile is only persisted when it took over a
+    second, so a sub-second program would recompile on every start and
+    one that straddles the second would be written on some runs only."""
+    placed = jax.config.jax_compilation_cache_dir
+    if placed:
+        return placed
+    jax.config.update("jax_compilation_cache_dir",
+                      DEFAULT_COMPILE_CACHE_DIR)
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
+    jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
+    return DEFAULT_COMPILE_CACHE_DIR
+
+
+def require_accelerator(what: str) -> "jax.Device":
+    """Refuse to run ``what`` on a backend nobody asked for.
+
+    When TPU initialisation fails JAX drops to the CPU with a warning,
+    and everything downstream — ``attn_impl="auto"``, ``kv_kernel=
+    "auto"``, the Pallas kernels' interpret switch — would quietly
+    serve a 7B model through XLA:CPU and interpreted kernels. A process
+    that builds a ``tpu`` driver or runs a chip bench therefore needs
+    ``jax.default_backend() == "tpu"`` unless the caller named another
+    platform explicitly (``JAX_PLATFORMS=cpu``, which JAX reads into
+    ``jax_platforms`` — the CPU test lanes set it). Returns the first
+    device so callers can name it in their output."""
+    dev = jax.devices()[0]
+    if dev.platform != "tpu" and not jax.config.jax_platforms:
+        raise RuntimeError(
+            f"{what} needs a TPU, but JAX came up on {dev.platform!r} "
+            f"({dev.device_kind}) and no platform was requested: the "
+            f"TPU runtime failed to initialise or no chip is attached. "
+            f"Set JAX_PLATFORMS=cpu to run on the CPU on purpose.")
+    return dev
 
 
 @dataclass(frozen=True)

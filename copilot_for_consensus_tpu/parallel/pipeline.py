@@ -32,10 +32,7 @@ from typing import Any
 
 import jax
 import jax.numpy as jnp
-try:
-    from jax import shard_map
-except ImportError:   # jax < 0.5 exports it under experimental only
-    from jax.experimental.shard_map import shard_map
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 from copilot_for_consensus_tpu.analysis.contracts import checkable
@@ -88,11 +85,8 @@ def _pp_shard(layers_local, x_mb, lengths, *, axis, cfg, impl,
     steps = m + pp - 1
     perm = [(i, i + 1) for i in range(pp - 1)]       # no wraparound
 
-    if hasattr(jax.lax, "pcast"):
-        vary = lambda t: jax.lax.pcast(
-            t, (axis,), to="varying")  # noqa: E731
-    else:   # jax < 0.7: no varying/unvarying type system
-        vary = lambda t: t  # noqa: E731
+    vary = lambda t: jax.lax.pcast(
+        t, (axis,), to="varying")  # noqa: E731
 
     if tp_axis is not None:
         import dataclasses

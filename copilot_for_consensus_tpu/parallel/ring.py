@@ -24,10 +24,7 @@ import functools
 
 import jax
 import jax.numpy as jnp
-try:
-    from jax import shard_map
-except ImportError:   # jax < 0.5 exports it under experimental only
-    from jax.experimental.shard_map import shard_map
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 from copilot_for_consensus_tpu.analysis.contracts import checkable
@@ -48,11 +45,8 @@ def _ring_shard(q, k, v, kv_lengths, *, axis_name: str, causal: bool,
 
     # pcast: constants are "unvarying" over the mesh axis; the loop carry
     # becomes varying after the first ppermute, so types must match.
-    if hasattr(jax.lax, "pcast"):
-        vary = lambda x: jax.lax.pcast(
-            x, (axis_name,), to="varying")  # noqa: E731
-    else:   # jax < 0.7: no varying/unvarying type system
-        vary = lambda x: x  # noqa: E731
+    vary = lambda x: jax.lax.pcast(
+        x, (axis_name,), to="varying")  # noqa: E731
     m0 = vary(jnp.full((b, h, s_loc, 1), NEG_INF, jnp.float32))
     l0 = vary(jnp.zeros((b, h, s_loc, 1), jnp.float32))
     acc0 = vary(jnp.zeros((b, h, s_loc, d), jnp.float32))

@@ -19,10 +19,7 @@ from __future__ import annotations
 import functools
 
 import jax
-try:
-    from jax import shard_map
-except ImportError:   # jax < 0.5 exports it under experimental only
-    from jax.experimental.shard_map import shard_map
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 from copilot_for_consensus_tpu.analysis.contracts import checkable

@@ -30,9 +30,13 @@ def create_summarizer(config: Any = None, **kwargs: Any) -> Summarizer:
         return MockSummarizer(
             max_sentences=int(_cfg_get(config, "max_sentences", 3)))
     if driver == "tpu":
+        from copilot_for_consensus_tpu.parallel.mesh import (
+            require_accelerator,
+        )
         from copilot_for_consensus_tpu.summarization.tpu_summarizer import (
             TPUSummarizer,
         )
+        require_accelerator("llm driver 'tpu'")
         return TPUSummarizer(
             model=_cfg_get(config, "model", "mistral-7b"),
             max_new_tokens=int(_cfg_get(config, "max_new_tokens", 256)),

@@ -14,8 +14,12 @@ def create_vector_store(config: Any = None):
     if driver == "memory":
         return InMemoryVectorStore(cfg)
     if driver == "tpu":
+        from copilot_for_consensus_tpu.parallel.mesh import (
+            require_accelerator,
+        )
         from copilot_for_consensus_tpu.vectorstore.tpu import TPUVectorStore
 
+        require_accelerator("vector_store driver 'tpu'")
         return TPUVectorStore(cfg)
     if driver == "native":
         from copilot_for_consensus_tpu.vectorstore.native import NativeFlatVectorStore

@@ -468,10 +468,7 @@ class IVFIndex:
         else:
             import functools
 
-            try:  # jax >= 0.5
-                from jax import shard_map
-            except ImportError:  # this toolchain
-                from jax.experimental.shard_map import shard_map
+            from jax import shard_map
             from jax.sharding import PartitionSpec as P
             mesh = self.mesh
 
@@ -480,11 +477,11 @@ class IVFIndex:
                 body = functools.partial(self._search_body,
                                          nprobe=nprobe, k=k)
                 sm = shard_map(
-                    body, mesh,
+                    body, mesh=mesh,
                     in_specs=(P(None, None), P("dp", None),
                               P("dp", None), P("dp"), P(None, None)),
                     out_specs=(P(None, "dp"), P(None, "dp")),
-                    check_rep=False)
+                    check_vma=False)
                 return sm(matrix, cents, rowids, spill, q)
 
             self._search_fn = jax.jit(mesh_search,

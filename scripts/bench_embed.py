@@ -35,16 +35,19 @@ _WORDS = ("consensus rough draft review thread mail archive protocol "
 
 
 def main() -> None:
-    import jax
-
     n_texts = int(os.environ.get("BENCH_TEXTS", "4096"))
     words = int(os.environ.get("BENCH_WORDS", "90"))
     batch = int(os.environ.get("BENCH_BATCH", "2048"))
 
     from copilot_for_consensus_tpu.engine.embedding import EmbeddingEngine
     from copilot_for_consensus_tpu.models import encoder_config
+    from copilot_for_consensus_tpu.parallel.mesh import (
+        enable_compile_cache,
+        require_accelerator,
+    )
 
-    dev = jax.devices()[0]
+    dev = require_accelerator("scripts/bench_embed.py")
+    enable_compile_cache()
     cfg = encoder_config("minilm-l6")
     log(f"device: {dev.device_kind} ({dev.platform}), encoder: {cfg.name} "
         f"d={cfg.d_model} L={cfg.n_layers}")
@@ -67,6 +70,8 @@ def main() -> None:
                   f"(1 chip, batch {batch}, ~{words}-word texts)",
         "value": round(n_texts / elapsed, 1),
         "unit": "texts/s",
+        "platform": dev.platform,
+        "device_kind": dev.device_kind,
     }))
 
 

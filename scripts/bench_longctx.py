@@ -92,6 +92,10 @@ def main() -> int:
     from copilot_for_consensus_tpu.engine.longctx import LongContextEngine
     from copilot_for_consensus_tpu.models import decoder_config
     from copilot_for_consensus_tpu.parallel import MeshConfig, build_mesh
+    from copilot_for_consensus_tpu.parallel.mesh import (
+        enable_compile_cache,
+        require_accelerator,
+    )
     from copilot_for_consensus_tpu.engine.tokenizer import ByteTokenizer
     from copilot_for_consensus_tpu.summarization.base import (
         Summary,
@@ -104,9 +108,11 @@ def main() -> int:
     tokenizer = ByteTokenizer(max(259, decoder_config(args.model)
                                   .vocab_size))
 
+    dev = require_accelerator("scripts/bench_longctx.py")
+    enable_compile_cache()
     cfg = decoder_config(args.model)
     print(f"building long-context engine ({args.model}, "
-          f"{jax.devices()[0].platform})...", file=sys.stderr)
+          f"{dev.device_kind} {dev.platform})...", file=sys.stderr)
     t0 = time.monotonic()
     dtype = jnp.bfloat16 if args.model != "tiny" else jnp.float32
     params = None
@@ -208,6 +214,8 @@ def main() -> int:
                   "summarization (sp path, no truncation, "
                   f"{args.weight_dtype if params is not None else 'fp32'}"
                   " weights)",
+        "platform": dev.platform,
+        "device_kind": dev.device_kind,
         "threads": len(rows),
         "elapsed_s": round(elapsed, 1),
         "warmup_s_excluded": round(warmup_s, 1),

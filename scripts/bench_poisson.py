@@ -35,6 +35,10 @@ def main() -> None:
     )
     from copilot_for_consensus_tpu.engine.generation import GenerationEngine
     from copilot_for_consensus_tpu.models import decoder_config
+    from copilot_for_consensus_tpu.parallel.mesh import (
+        enable_compile_cache,
+        require_accelerator,
+    )
 
     ap = argparse.ArgumentParser(description=__doc__)
     ap.add_argument("--rate", type=float, default=0.0,
@@ -62,8 +66,11 @@ def main() -> None:
     new_tokens = int(os.environ.get("BENCH_NEW_TOKENS", "96"))
     window = int(os.environ.get("BENCH_DECODE_WINDOW", "32"))
 
+    dev = require_accelerator("scripts/bench_poisson.py")
+    enable_compile_cache()
     cfg = decoder_config(model)
-    print(f"building {model} engine ({slots} slots)...", file=sys.stderr)
+    print(f"building {model} engine ({slots} slots) on "
+          f"{dev.device_kind} ({dev.platform})...", file=sys.stderr)
     eng = GenerationEngine(
         cfg, num_slots=slots, max_len=max_len,
         prefill_buckets=(prompt_len,), dtype=jnp.bfloat16,
@@ -214,6 +221,8 @@ def main() -> None:
                   f"({slots} slots, {rate:.1f} req/s offered)",
         "value": round(tok_s, 1),
         "unit": "tok/s",
+        "platform": dev.platform,
+        "device_kind": dev.device_kind,
         "fraction_of_batch": round(frac, 3),
         "p50_latency_s": round(float(lat_arr[len(lat_arr) // 2]), 2),
         "p95_latency_s": round(float(lat_arr[int(len(lat_arr) * 0.95)

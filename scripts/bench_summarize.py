@@ -37,8 +37,14 @@ def main() -> int:
     ap.add_argument("--num-slots", type=int, default=64)
     args = ap.parse_args()
 
+    from copilot_for_consensus_tpu.parallel.mesh import (
+        enable_compile_cache,
+        require_accelerator,
+    )
     from copilot_for_consensus_tpu.services.runner import build_pipeline
 
+    dev = require_accelerator("scripts/bench_summarize.py")
+    enable_compile_cache()
     t0 = time.monotonic()
     p = build_pipeline({
         "embedding": {"driver": "tpu", "model": "minilm-l6"},
@@ -90,6 +96,8 @@ def main() -> int:
                   f"({n} threads, TPU embed+generate)",
         "value": round(n / wall * 60, 2),
         "unit": "threads/min",
+        "platform": dev.platform,
+        "device_kind": dev.device_kind,
         "p50_summary_latency_s": round(pct(0.50), 2),
         "p95_summary_latency_s": round(pct(0.95), 2),
         "pipeline_wall_s": round(wall, 1),

@@ -12,10 +12,7 @@ from copilot_for_consensus_tpu.analysis.contracts import (
 def _case(axis_name):
     import jax
     import jax.numpy as jnp
-    try:
-        from jax import shard_map
-    except ImportError:   # jax < 0.5 exports it under experimental only
-        from jax.experimental.shard_map import shard_map
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
 
     from copilot_for_consensus_tpu.parallel.mesh import (

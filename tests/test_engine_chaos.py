@@ -883,34 +883,6 @@ def _baseline_outputs(max_new=8):
     return {i: c.tokens for i, c in enumerate(comps)}
 
 
-def _copy_cycle_setup(period=7):
-    """The spec-decode acceptance fixture (test_engine_spec_decode):
-    zeroed attention/FFN outputs + one-hot embeddings/lm_head make
-    greedy generation the exact cycle t -> 3 + ((t - 3 + 1) % period),
-    so prompt-lookup drafts ALWAYS hit — which guarantees the verify
-    dispatch fires, the thing the persistent verify fault targets."""
-    import jax
-    import jax.numpy as jnp
-
-    from copilot_for_consensus_tpu.models import decoder
-    from copilot_for_consensus_tpu.models.configs import decoder_config
-
-    cfg = decoder_config("tiny")
-    params = decoder.init_params(jax.random.PRNGKey(7), cfg,
-                                 dtype=jnp.float32)
-    params["layers"]["wo"] = jnp.zeros_like(params["layers"]["wo"])
-    params["layers"]["w_down"] = jnp.zeros_like(
-        params["layers"]["w_down"])
-    emb = np.zeros((cfg.vocab_size, cfg.d_model), np.float32)
-    head = np.zeros((cfg.d_model, cfg.vocab_size), np.float32)
-    for i in range(period):
-        emb[3 + i, i] = 1.0
-        head[i, 3 + (i + 1) % period] = 1.0
-    params["tok_emb"] = jnp.asarray(emb)
-    params["lm_head"] = jnp.asarray(head)
-    return cfg, params
-
-
 def _cycle_engine(cfg, params, **kw):
     import jax.numpy as jnp
 
@@ -965,13 +937,13 @@ def test_chaos_gate_transient_faults_bit_identical_recovery():
         runner.stop()
 
 
-def test_chaos_gate_persistent_verify_fault_flips_spec_breaker():
+def test_chaos_gate_persistent_verify_fault_flips_spec_breaker(copy_cycle):
     """Acceptance: persistent verify faults flip the engine to plain
     decode (served traffic keeps completing, bit-identical), the
     breaker opens, and the half-open probe restores speculation once
     the faults clear. Copy-cycle fixture: drafts ALWAYS hit, so the
     verify dispatch — the fault's target — reliably fires."""
-    cfg_m, params = _copy_cycle_setup()
+    cfg_m, params, _prompt = copy_cycle
     prompts = [_cycle_prompt(i, 14) for i in range(4)]
     base_eng = _cycle_engine(cfg_m, params)
     base = {i: c.tokens for i, c in enumerate(
@@ -1063,14 +1035,14 @@ def test_tokenize_fault_point_fires_in_generate_text():
 
 
 @pytest.mark.slow
-def test_chaos_long_storm_zero_lost_handles():
+def test_chaos_long_storm_zero_lost_handles(copy_cycle):
     """The storm: seeded-random dispatch faults, a real-engine hang
     past the watchdog deadline, and a persistent verify fault, over a
     bigger scripted workload. The gate: EVERY handle resolves — a
     Completion (bit-identical to fault-free) or a structured error
     carrying a correlation id — and the recovery counters are sane."""
     rng = np.random.default_rng(0)
-    cfg_m, params = _copy_cycle_setup()
+    cfg_m, params, _prompt = copy_cycle
     prompts = [_cycle_prompt(int(rng.integers(0, 7)),
                              int(rng.integers(8, 20)))
                for _ in range(24)]

@@ -270,20 +270,9 @@ def test_kernel_route_prefix_zero_copy_tokens_match_reference():
 
 
 @pytest.mark.slow
-def test_kernel_route_spec_decode_tokens_match_reference():
-    params = _params()
-    rng = np.random.default_rng(0)
-    half = 60
-
-    def copy_prompt():
-        head = rng.integers(3, CFG.vocab_size, size=half).tolist()
-        tail = []
-        while len(tail) < half:
-            s0 = int(rng.integers(0, max(1, half - 16)))
-            tail.extend(head[s0:s0 + 16])
-        return head + tail[:half]
-
-    prompts = [copy_prompt() for _ in range(4)]
+def test_kernel_route_spec_decode_tokens_match_reference(copy_cycle):
+    _cfg, params, prompt = copy_cycle     # drafts always hit
+    prompts = [prompt, prompt[3:] + prompt[:3]]
     ref = _engine(params, "reference", kv_pool_blocks=16,
                   spec_decode=True)
     ker = _engine(params, "pallas", kv_pool_blocks=16,

@@ -94,24 +94,10 @@ def test_sharded_paged_prefix_hit_zero_copy_bit_identity():
 
 
 @pytest.mark.slow
-def test_sharded_paged_spec_decode_bit_identity():
-    # copy-cycle weights (test_engine_spec_decode.py): greedy
-    # generation is a deterministic token cycle, so prompt-lookup
-    # drafts always hit and the verify dispatch really runs sharded
-    period = 7
-    params = decoder.init_params(jax.random.PRNGKey(7), CFG,
-                                 dtype=jnp.float32)
-    params["layers"]["wo"] = jnp.zeros_like(params["layers"]["wo"])
-    params["layers"]["w_down"] = jnp.zeros_like(
-        params["layers"]["w_down"])
-    emb = np.zeros((CFG.vocab_size, CFG.d_model), np.float32)
-    head = np.zeros((CFG.d_model, CFG.vocab_size), np.float32)
-    for i in range(period):
-        emb[3 + i, i] = 1.0
-        head[i, 3 + (i + 1) % period] = 1.0
-    params["tok_emb"] = jnp.asarray(emb)
-    params["lm_head"] = jnp.asarray(head)
-    prompt = [3 + (i % period) for i in range(2 * period)]
+def test_sharded_paged_spec_decode_bit_identity(copy_cycle):
+    # copy-cycle weights: prompt-lookup drafts always hit, so the
+    # verify dispatch really runs sharded
+    _cfg, params, prompt = copy_cycle
     kw = dict(params=params, decode_window=4, spec_decode=True,
               spec_draft_lens=(0, 2, 4))
     want = _engine(kv_pool_blocks=20, **kw).generate(

@@ -440,20 +440,9 @@ def test_write_maps_drop_columns_past_max_len():
 
 
 @pytest.mark.slow
-def test_paged_spec_decode_bit_identical():
-    params = _params()
-    rng = np.random.default_rng(0)   # a seed whose drafts actually hit
-    half = 60
-
-    def copy_prompt():
-        head = rng.integers(3, CFG.vocab_size, size=half).tolist()
-        tail = []
-        while len(tail) < half:
-            s0 = int(rng.integers(0, max(1, half - 16)))
-            tail.extend(head[s0:s0 + 16])
-        return head + tail[:half]
-
-    prompts = [copy_prompt() for _ in range(4)]
+def test_paged_spec_decode_bit_identical(copy_cycle):
+    _cfg, params, prompt = copy_cycle     # drafts always hit
+    prompts = [prompt, prompt[3:] + prompt[:3]]
     plain = _engine(params, spec_decode=True)
     paged = _engine(params, paged_blocks=16, spec_decode=True)
     want = plain.generate(prompts, max_new_tokens=16)

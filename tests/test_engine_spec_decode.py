@@ -109,30 +109,6 @@ class TestSpecDecodeEndToEnd:
                                      dtype=jnp.float32)
         return cfg, params
 
-    def _copy_cycle_setup(self, period=7):
-        """Zero the attention/FFN outputs and craft one-hot embeddings
-        + lm_head so greedy generation is the deterministic cycle
-        t -> 3 + ((t - 3 + 1) % period): the model 'copies' forever,
-        which is the best case prompt-lookup drafting targets."""
-        from copilot_for_consensus_tpu.models import decoder
-        from copilot_for_consensus_tpu.models.configs import decoder_config
-
-        cfg = decoder_config("tiny")
-        params = decoder.init_params(jax.random.PRNGKey(7), cfg,
-                                     dtype=jnp.float32)
-        params["layers"]["wo"] = jnp.zeros_like(params["layers"]["wo"])
-        params["layers"]["w_down"] = jnp.zeros_like(
-            params["layers"]["w_down"])
-        emb = np.zeros((cfg.vocab_size, cfg.d_model), np.float32)
-        head = np.zeros((cfg.d_model, cfg.vocab_size), np.float32)
-        for i in range(period):
-            emb[3 + i, i] = 1.0
-            head[i, 3 + (i + 1) % period] = 1.0
-        params["tok_emb"] = jnp.asarray(emb)
-        params["lm_head"] = jnp.asarray(head)
-        prompt = [3 + (i % period) for i in range(2 * period)]
-        return cfg, params, prompt
-
     def test_greedy_bit_identical_on_random_weights(self):
         cfg, params = self._random_setup()
         base, spec = self._engines(params, cfg)
@@ -145,12 +121,12 @@ class TestSpecDecodeEndToEnd:
             assert g.tokens == w.tokens
             assert g.finish_reason == w.finish_reason
 
-    def test_copy_heavy_fixture_bit_identical_and_amortized(self):
+    def test_copy_heavy_fixture_bit_identical_and_amortized(self, copy_cycle):
         """The acceptance fixture: greedy speculation-on output equals
         speculation-off bit for bit, AND the measured per-stream
         tokens_per_weight_pass clears 2.0 — the decode bandwidth wall
         actually moved."""
-        cfg, params, prompt = self._copy_cycle_setup()
+        cfg, params, prompt = copy_cycle
         base, spec = self._engines(params, cfg)
         want = base.generate([prompt], max_new_tokens=64)[0]
         got = spec.generate([prompt], max_new_tokens=64)[0]
@@ -163,10 +139,10 @@ class TestSpecDecodeEndToEnd:
         assert st["mean_accepted_per_step"] >= 2.0
         assert st["tokens_per_weight_pass"] >= 2.0, st
 
-    def test_mixed_wave_hit_and_miss_slots_stay_exact(self):
+    def test_mixed_wave_hit_and_miss_slots_stay_exact(self, copy_cycle):
         """Streams with and without draft hits share verify dispatches
         (the k=0 lane); nobody's tokens may change."""
-        cfg, params, prompt = self._copy_cycle_setup()
+        cfg, params, prompt = copy_cycle
         base, spec = self._engines(params, cfg)
         prompts = [prompt, [200, 201, 202, 203]]   # cycle + no-repeat
         want = base.generate(prompts, max_new_tokens=32)
@@ -174,7 +150,7 @@ class TestSpecDecodeEndToEnd:
         for w, g in zip(want, got):
             assert g.tokens == w.tokens
 
-    def test_sampled_speculation_reproducible_and_in_vocab(self):
+    def test_sampled_speculation_reproducible_and_in_vocab(self, copy_cycle):
         """The sampled verify path (rejection rule) is seed-stable and
         emits valid tokens; distribution-exactness itself is proven at
         the verify_draft level (test_engine_sampling.py)."""
@@ -182,7 +158,7 @@ class TestSpecDecodeEndToEnd:
             SamplingConfig,
         )
 
-        cfg, params, prompt = self._copy_cycle_setup()
+        cfg, params, prompt = copy_cycle
         outs = []
         for _ in range(2):
             _, spec = self._engines(

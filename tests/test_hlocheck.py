@@ -202,8 +202,6 @@ def test_bench_hlo_preflight_blocks_on_violation():
         cwd=ROOT, capture_output=True, text=True, timeout=600,
         env={**os.environ,
              "BENCH_PREFLIGHT": "1",
-             "BENCH_NO_PROBE": "1",
-             "BENCH_EXTRA": "0",
              "BENCH_PRESET": "pipeline_chaos",
              "BENCH_HLOCHECK_MODULES":
                  str(FIXTURES / "donation_alias.py")})

@@ -378,8 +378,6 @@ def test_bench_preflight_blocks_on_contract_violation():
         cwd=ROOT, capture_output=True, text=True, timeout=600,
         env={**__import__("os").environ,
              "BENCH_PREFLIGHT": "1",
-             "BENCH_NO_PROBE": "1",
-             "BENCH_EXTRA": "0",
              "BENCH_PRESET": "",
              "BENCH_SHARDCHECK_MODULES":
                  str(FIXTURES / "donation.py")})
@@ -403,8 +401,6 @@ def test_bench_dura_preflight_blocks_on_violation():
         cwd=ROOT, capture_output=True, text=True, timeout=600,
         env={**__import__("os").environ,
              "BENCH_PREFLIGHT": "1",
-             "BENCH_NO_PROBE": "1",
-             "BENCH_EXTRA": "0",
              "BENCH_PRESET": "pipeline_chaos",
              "BENCH_DURACHECK_PATHS": "tests/fixtures/duracheck"})
     assert proc.returncode == 2, proc.stdout + proc.stderr

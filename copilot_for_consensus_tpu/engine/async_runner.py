@@ -47,6 +47,7 @@ dies (SURVEY §0).
 
 from __future__ import annotations
 
+import contextlib
 import threading
 import time
 from dataclasses import dataclass, field
@@ -193,7 +194,6 @@ class AsyncEngineRunner:
         self._step_t0: float | None = None
         #: dispatcher-loop stats for benches/metrics
         self.completed = 0
-        self.decode_busy_s = 0.0
         #: resilience counters (recovery_stats())
         self.replayed = 0          # continuation resubmissions
         self.recovered = 0         # completions that needed >=1 replay
@@ -468,11 +468,19 @@ class AsyncEngineRunner:
     def _loop(self) -> None:
         eng = self.engine
         sup = self.supervisor
+        # Host phases of the loop (obs/profile.py:HOST_PHASES) go to
+        # the engine's telemetry; a stand-in engine without one gets
+        # spans that cost nothing and count nowhere.
+        span = getattr(getattr(eng, "telemetry", None), "host_span",
+                       lambda _name: contextlib.nullcontext())
         while True:
             with self._work:
-                while (not self._stop and not self._pending
-                       and self._engine_idle(eng)):
-                    self._work.wait(timeout=0.1)
+                if (not self._stop and not self._pending
+                        and self._engine_idle(eng)):
+                    with span("wait_work"):
+                        while (not self._stop and not self._pending
+                               and self._engine_idle(eng)):
+                            self._work.wait(timeout=0.1)
                 if self._stop:
                     stopping = True
                 else:
@@ -498,22 +506,25 @@ class AsyncEngineRunner:
             # same contained way: it is an ADMISSION outcome, so it
             # must not trip the engine-failure path below (no flight-
             # recorder dump, no error_reporter post-mortem).
-            for prompt, mnt, kw, h in fresh:
-                try:
-                    # kwargs only when set: duck-typed engine stands-in
-                    # (tests, shims) keep their 2-arg submit signature
-                    rid = eng.submit(prompt, mnt, **kw)
-                except Exception as exc:
-                    h._fail(exc)
+            with span("enqueue") if fresh else contextlib.nullcontext():
+                for prompt, mnt, kw, h in fresh:
+                    try:
+                        # kwargs only when set: duck-typed engine
+                        # stands-in (tests, shims) keep their 2-arg
+                        # submit signature
+                        rid = eng.submit(prompt, mnt, **kw)
+                    except Exception as exc:
+                        h._fail(exc)
+                        with self._work:
+                            self._admitting -= 1
+                        continue
+                    h.request_id = rid
+                    # _handles/_replays are shared with the watchdog
+                    # thread's _on_suspect — every mutation holds the
+                    # lock
                     with self._work:
+                        self._handles[rid] = h
                         self._admitting -= 1
-                    continue
-                h.request_id = rid
-                # _handles/_replays are shared with the watchdog
-                # thread's _on_suspect — every mutation holds the lock
-                with self._work:
-                    self._handles[rid] = h
-                    self._admitting -= 1
             t0 = time.monotonic()
             self._step_t0 = t0
             if sup is not None:
@@ -551,31 +562,32 @@ class AsyncEngineRunner:
                 if sup is not None:
                     sup.end_dispatch("step")
                 self._step_t0 = None
-                self.decode_busy_s += time.monotonic() - t0
             if sup is not None:
                 sup.on_step_ok()
-            for c in comps:
-                self.completed += 1
-                # pop under the lock (shared with the watchdog's
-                # _on_suspect); resolve OUTSIDE it — done-callbacks may
-                # re-enter submit(), which takes the same lock
-                with self._work:
-                    h = self._handles.pop(c.request_id, None)
-                    meta = self._replays.pop(c.request_id, None)
-                if h is None:
-                    continue   # watchdog failed this handle mid-hang
-                if meta is not None:
-                    # Stitch the continuation onto the original
-                    # identity: the caller sees ONE completion with its
-                    # own prompt length and the full token stream.
-                    c = Completion(
-                        request_id=c.request_id,
-                        prompt_len=meta.prompt_len,
-                        tokens=meta.tokens + c.tokens,
-                        finish_reason=c.finish_reason,
-                        prefill_s=c.prefill_s, decode_s=c.decode_s)
-                    self.recovered += 1
-                h._resolve(c)
+            with span("resolve") if comps else contextlib.nullcontext():
+                for c in comps:
+                    self.completed += 1
+                    # pop under the lock (shared with the watchdog's
+                    # _on_suspect); resolve OUTSIDE it — done-callbacks
+                    # may re-enter submit(), which takes the same lock
+                    with self._work:
+                        h = self._handles.pop(c.request_id, None)
+                        meta = self._replays.pop(c.request_id, None)
+                    if h is None:
+                        continue  # watchdog failed this handle mid-hang
+                    if meta is not None:
+                        # Stitch the continuation onto the original
+                        # identity: the caller sees ONE completion with
+                        # its own prompt length and the full token
+                        # stream.
+                        c = Completion(
+                            request_id=c.request_id,
+                            prompt_len=meta.prompt_len,
+                            tokens=meta.tokens + c.tokens,
+                            finish_reason=c.finish_reason,
+                            prefill_s=c.prefill_s, decode_s=c.decode_s)
+                        self.recovered += 1
+                    h._resolve(c)
             if sup is not None and sup.take_suspect():
                 # The watchdog tripped during a step that then returned
                 # on its own: the in-engine waiters were failed by the

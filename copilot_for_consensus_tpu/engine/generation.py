@@ -73,7 +73,7 @@ from copilot_for_consensus_tpu.engine.tokenizer import (
     NgramDraftIndex,
     Tokenizer,
 )
-from copilot_for_consensus_tpu.obs.profile import step_annotation
+from copilot_for_consensus_tpu.obs.profile import scope, step_annotation
 from copilot_for_consensus_tpu.models import decoder, quant
 from copilot_for_consensus_tpu.models.configs import DecoderConfig
 from copilot_for_consensus_tpu.parallel.sharding import (
@@ -118,6 +118,14 @@ class Request:
     #: computed — queued requests at step start, active slots at
     #: harvest. inf = no deadline.
     deadline_at: float = float("inf")
+    #: where the time after the first token goes, summed as the work
+    #: happens and copied to the RequestTrace at retire
+    #: (engine/telemetry.py): the dispatches that advanced this
+    #: request, and other requests' admission dispatches it sat
+    #: through in its slot
+    decode_s_own: float = 0.0
+    decode_dispatches: int = 0
+    stalled_s: float = 0.0
 
 
 @dataclass
@@ -294,6 +302,10 @@ class GenerationEngine:
         #: contained prefix-publish failures (completion still
         #: delivered; only the cache contribution was lost)
         self.prefix_publish_failures = 0
+        #: (kind, *static shape key) of every program dispatched so
+        #: far: what makes a StepRecord's ``first_use``
+        self.programs_seen: set[tuple] = set()
+        self._phase_span = None       # the open host phase (_phase)
         # Flight recorder + request-lifecycle spans + Prometheus export
         # (engine/telemetry.py). Default ON: pure host-side bookkeeping
         # around dispatches the engine already syncs on (<1% measured —
@@ -616,10 +628,11 @@ class GenerationEngine:
             program. ``slots`` may contain out-of-range ids for padded
             prefill rows — 'drop' mode discards those updates."""
             s = pref["k"].shape[3]
-            k = cache["k"].at[:, slots, :, :s, :].set(
-                pref["k"].astype(cache["k"].dtype), mode="drop")
-            v = cache["v"].at[:, slots, :, :s, :].set(
-                pref["v"].astype(cache["v"].dtype), mode="drop")
+            with scope("kv_write"):
+                k = cache["k"].at[:, slots, :, :s, :].set(
+                    pref["k"].astype(cache["k"].dtype), mode="drop")
+                v = cache["v"].at[:, slots, :, :s, :].set(
+                    pref["v"].astype(cache["v"].dtype), mode="drop")
             return {"k": k, "v": v}
 
         def _admit_fused(params, tokens, lengths, cache, slots, key):
@@ -704,12 +717,17 @@ class GenerationEngine:
             n, sbuc = tokens.shape
             nb = bids_flat.shape[0] // n
             blk = pool_k.shape[3]
-            pk_flat = pool_k[:, bids_flat]     # [L, N*NB, Hkv, B, Dh]
-            pv_flat = pool_v[:, bids_flat]
-            pk = pk_flat.reshape(n_l, n, nb, hkv, blk, dh).transpose(
-                0, 1, 3, 2, 4, 5).reshape(n_l, n, hkv, nb * blk, dh)
-            pv = pv_flat.reshape(n_l, n, nb, hkv, blk, dh).transpose(
-                0, 1, 3, 2, 4, 5).reshape(n_l, n, hkv, nb * blk, dh)
+            with scope("kv_prefix"):
+                pk_flat = pool_k[:, bids_flat]  # [L, N*NB, Hkv, B, Dh]
+                pv_flat = pool_v[:, bids_flat]
+                pk = pk_flat.reshape(
+                    n_l, n, nb, hkv, blk, dh).transpose(
+                    0, 1, 3, 2, 4, 5).reshape(
+                    n_l, n, hkv, nb * blk, dh)
+                pv = pv_flat.reshape(
+                    n_l, n, nb, hkv, blk, dh).transpose(
+                    0, 1, 3, 2, 4, 5).reshape(
+                    n_l, n, hkv, nb * blk, dh)
             scratch = decoder.init_cache(cfg, n, sbuc,
                                          dtype=self.kv_dtype)
             logits, scratch = decoder.prefill_seeded(
@@ -725,21 +743,23 @@ class GenerationEngine:
             sidx_b = jnp.broadcast_to(sidx_b[:, None], (m, blk))
             pidx_b = (jnp.tile(jnp.arange(nb), n) * blk)[:, None] \
                 + jnp.arange(blk)[None, :]
-            upd_k = pk_flat.transpose(1, 3, 0, 2, 4)  # [M, B, L, H, D]
-            upd_v = pv_flat.transpose(1, 3, 0, 2, 4)
-            ck = cache["k"].at[:, sidx_b, :, pidx_b, :].set(
-                upd_k.astype(cache["k"].dtype), mode="drop")
-            cv = cache["v"].at[:, sidx_b, :, pidx_b, :].set(
-                upd_v.astype(cache["v"].dtype), mode="drop")
-            # insert the fresh suffix KV at the per-row prefix offset
-            sidx_s = jnp.broadcast_to(slots[:, None], (n, sbuc))
-            pidx_s = pref_lens[:, None] + jnp.arange(sbuc)[None, :]
-            ck = ck.at[:, sidx_s, :, pidx_s, :].set(
-                scratch["k"].transpose(1, 3, 0, 2, 4).astype(ck.dtype),
-                mode="drop")
-            cv = cv.at[:, sidx_s, :, pidx_s, :].set(
-                scratch["v"].transpose(1, 3, 0, 2, 4).astype(cv.dtype),
-                mode="drop")
+            with scope("kv_write"):
+                upd_k = pk_flat.transpose(1, 3, 0, 2, 4)  # [M,B,L,H,D]
+                upd_v = pv_flat.transpose(1, 3, 0, 2, 4)
+                ck = cache["k"].at[:, sidx_b, :, pidx_b, :].set(
+                    upd_k.astype(cache["k"].dtype), mode="drop")
+                cv = cache["v"].at[:, sidx_b, :, pidx_b, :].set(
+                    upd_v.astype(cache["v"].dtype), mode="drop")
+                # insert the fresh suffix KV at the per-row prefix
+                # offset
+                sidx_s = jnp.broadcast_to(slots[:, None], (n, sbuc))
+                pidx_s = pref_lens[:, None] + jnp.arange(sbuc)[None, :]
+                ck = ck.at[:, sidx_s, :, pidx_s, :].set(
+                    scratch["k"].transpose(1, 3, 0, 2, 4).astype(
+                        ck.dtype), mode="drop")
+                cv = cv.at[:, sidx_s, :, pidx_s, :].set(
+                    scratch["v"].transpose(1, 3, 0, 2, 4).astype(
+                        cv.dtype), mode="drop")
             first = sample(logits, key, self.sampling)
             return first, {"k": ck, "v": cv}
 
@@ -783,13 +803,8 @@ class GenerationEngine:
                         params, tok, positions, w, cfg, cache, k_win,
                         v_win, kv_len=kv_len, k_done=k_done,
                         v_done=v_done)
-                    # k_cols: [L, B, H, D] → window col [L, B, H, 1, D]
-                    k_win = jax.lax.dynamic_update_slice_in_dim(
-                        k_win, k_cols[:, :, :, None].astype(k_win.dtype),
-                        w, axis=3)
-                    v_win = jax.lax.dynamic_update_slice_in_dim(
-                        v_win, v_cols[:, :, :, None].astype(v_win.dtype),
-                        w, axis=3)
+                    k_win = decoder.put_window_column(k_win, k_cols, w)
+                    v_win = decoder.put_window_column(v_win, v_cols, w)
                     nxt = sample(logits, sub, self.sampling)
                     return (nxt, k_win, v_win, key), nxt
 
@@ -859,16 +874,15 @@ class GenerationEngine:
                     params, tok, positions, w, cfg, cache, k_win,
                     v_win, pre_tok_w, rope_b, kv_b, kv_l, sel_r,
                     kbuf, vbuf, kv_len=kv_len)
-                k_win = jax.lax.dynamic_update_slice_in_dim(
-                    k_win, k_cols[:, :, :, None].astype(k_win.dtype),
-                    w, axis=3)
-                v_win = jax.lax.dynamic_update_slice_in_dim(
-                    v_win, v_cols[:, :, :, None].astype(v_win.dtype),
-                    w, axis=3)
-                kbuf = jax.lax.dynamic_update_slice_in_dim(
-                    kbuf, pre_k.astype(kbuf.dtype), w * chunk, axis=3)
-                vbuf = jax.lax.dynamic_update_slice_in_dim(
-                    vbuf, pre_v.astype(vbuf.dtype), w * chunk, axis=3)
+                k_win = decoder.put_window_column(k_win, k_cols, w)
+                v_win = decoder.put_window_column(v_win, v_cols, w)
+                with scope("kv_write"):
+                    kbuf = jax.lax.dynamic_update_slice_in_dim(
+                        kbuf, pre_k.astype(kbuf.dtype), w * chunk,
+                        axis=3)
+                    vbuf = jax.lax.dynamic_update_slice_in_dim(
+                        vbuf, pre_v.astype(vbuf.dtype), w * chunk,
+                        axis=3)
                 nxt = sample(logits, sub, self.sampling)
                 return (nxt, k_win, v_win, kbuf, vbuf, key), (nxt,
                                                               h_step)
@@ -1069,6 +1083,7 @@ class GenerationEngine:
                 paged_gather_kv,
             )
 
+            @scope("kv_write")
             def _pool_scatter(pool_k, pool_v, k_new, v_new, sbids,
                               soffs):
                 """Scatter fresh KV [L, R, Hkv, S, Dh] into the pool at
@@ -1083,6 +1098,7 @@ class GenerationEngine:
                     v_upd.astype(pool_v.dtype), mode="drop")
                 return pk, pv
 
+            @scope("kv_prefix")
             def _view_take(view, positions, steps):
                 """Read the dispatch's freshly merged columns back out
                 of the view: [L, B, Hkv, W, Dh]-shaped gather at
@@ -1430,14 +1446,10 @@ class GenerationEngine:
                                     params, tok, positions, w, cfg,
                                     partial_fn, k_win, v_win,
                                     k_done=k_done, v_done=v_done)
-                            k_win = \
-                                jax.lax.dynamic_update_slice_in_dim(
-                                    k_win, k_cols[:, :, :, None]
-                                    .astype(k_win.dtype), w, axis=3)
-                            v_win = \
-                                jax.lax.dynamic_update_slice_in_dim(
-                                    v_win, v_cols[:, :, :, None]
-                                    .astype(v_win.dtype), w, axis=3)
+                            k_win = decoder.put_window_column(
+                                k_win, k_cols, w)
+                            v_win = decoder.put_window_column(
+                                v_win, v_cols, w)
                             nxt = sample(logits, sub, self.sampling)
                             return (nxt, k_win, v_win, key), nxt
 
@@ -1783,34 +1795,70 @@ class GenerationEngine:
         long prompts advance by ONE chunk dispatch, and only then does
         the decode window run — so the per-step prefill work, and with
         it ITL, stays bounded regardless of prompt mix."""
-        self._expire_deadlines()
-        if self._sched is not None:
-            self._sched_pump()
-        self._admit()
-        if self._chunk_pending or self._chunking:
-            self._chunk_step()
-        if self.paged:
-            self.peak_active = max(self.peak_active, self._occupied)
-        if self._active or self._prefilling:
-            self._decode_once()
-        if self.journal is not None:
-            self._journal_tick()
-        if self.telemetry is not None:
-            self.telemetry.gauge_queue(self.queue_depth,
-                                       len(self._active))
-            if self.role != "both":
-                self.telemetry.gauge_role_occupancy(
-                    self.role, self._occupied / self.num_slots
-                    if self.num_slots else 0.0)
+        # Host phases (obs/profile.py:HOST_PHASES): "plan" runs up to
+        # each dispatch annotation, the dispatch helpers switch to
+        # "commit"/"harvest" after their host fetch, "upkeep" closes
+        # the step. One phase is open at a time, none during a dispatch.
+        try:
+            self._phase("plan", ahead=True)
+            self._expire_deadlines()
+            if self._sched is not None:
+                self._sched_pump()
+            self._admit()
+            if self._chunk_pending or self._chunking:
+                self._phase("plan", ahead=True)
+                self._chunk_step()
             if self.paged:
-                # gauges straight off the pool counters — the full
-                # kv_pool_stats() (headroom walk over active slots +
-                # trie) is a stats/bench API, too heavy for every step
-                self.telemetry.gauge_kv_pool(
-                    self._pool.free_blocks, self._pool.pinned_blocks,
-                    round(self._pool.fragmentation(
-                        self._used_tokens()), 4))
-        return self._drain_done()
+                self.peak_active = max(self.peak_active, self._occupied)
+            if self._active or self._prefilling:
+                self._phase("plan", ahead=True)
+                self._decode_once()
+            self._phase("upkeep")
+            if self.journal is not None:
+                self._journal_tick()
+            if self.telemetry is not None:
+                self.telemetry.gauge_queue(self.queue_depth,
+                                           len(self._active))
+                if self.role != "both":
+                    self.telemetry.gauge_role_occupancy(
+                        self.role, self._occupied / self.num_slots
+                        if self.num_slots else 0.0)
+                if self.paged:
+                    # gauges straight off the pool counters — the full
+                    # kv_pool_stats() (headroom walk over active slots
+                    # + trie) is a stats/bench API, too heavy for
+                    # every step
+                    self.telemetry.gauge_kv_pool(
+                        self._pool.free_blocks,
+                        self._pool.pinned_blocks,
+                        round(self._pool.fragmentation(
+                            self._used_tokens()), 4))
+            return self._drain_done()
+        finally:
+            self._phase(None)
+
+    def _phase(self, name: str | None, *, ahead: bool = False) -> None:
+        """Close the open host phase and open ``name`` (None: only
+        close; the phase already open: keep it). ``ahead`` tags the
+        span with the id the NEXT dispatch will take, else the last
+        one's."""
+        cur = self._phase_span
+        if cur is not None:
+            if cur.name == name:
+                return
+            cur.__exit__(None, None, None)
+            self._phase_span = None
+        if name is not None and self.telemetry is not None:
+            self._phase_span = self.telemetry.host_span(name, ahead=ahead)
+            self._phase_span.__enter__()
+
+    def _first_use(self, *key) -> bool:
+        """True the first time a dispatch kind runs with this static
+        shape key: that step traced, compiled or loaded a program."""
+        if key in self.programs_seen:
+            return False
+        self.programs_seen.add(key)
+        return True
 
     def generate(self, prompts: list[list[int]],
                  max_new_tokens: int = 256, *,
@@ -2290,6 +2338,7 @@ class GenerationEngine:
                         tbl.extend(self._alloc_blocks(
                             need, self._slot_shard(slot)))
                     self._tables[slot] = tbl
+            self._phase(None)
             with step_annotation(wave_kind, seq), \
                     self._dispatch_boundary(wave_kind):
                 if seeded:
@@ -2388,12 +2437,19 @@ class GenerationEngine:
             self._queue[0:0] = [req for _slot, req in batch]
             raise
         prefill_s = time.monotonic() - t0
+        self._phase("commit")
         self.admitted_s += prefill_s
+        for req in self._active.values():
+            req.stalled_s += prefill_s   # sat through this wave
         if self.telemetry is not None:
             self.telemetry.record_step(
                 wave_kind, prefill_s, seq=seq, rows=len(batch),
                 batch=n, tokens=sum(suffix_lens),
-                padded_tokens=n * bucket, route=self._kv_route)
+                padded_tokens=n * bucket, route=self._kv_route,
+                t_start=t0, new_tokens=len(batch),
+                prompt_tokens=sum(suffix_lens),
+                first_use=self._first_use(
+                    wave_kind, bucket, n, nb if seeded else 0))
         self.prefill_tokens += sum(suffix_lens)
         self.prefill_tokens_saved += sum(
             m.tokens for m in matches if m is not None)
@@ -2973,12 +3029,13 @@ class GenerationEngine:
         # fault retries the same chunk next step; a real device failure
         # is evacuated by the supervisor, which restarts chunking
         # requests from token zero (their partial fill is not trusted).
+        kv_len = self._kv_extent(hi)
+        self._phase(None)
         with step_annotation("prefill_chunk", seq), \
                 self._dispatch_boundary("prefill_chunk"):
             with quant.pallas_qmatmul_override(
                     self._decode_pallas_override):
                 if self.paged:
-                    kv_len = self._kv_extent(hi)
                     for slot, n in fed.items():
                         self._ensure_blocks(
                             slot, self._chunking[slot][1] + n)
@@ -3008,14 +3065,18 @@ class GenerationEngine:
                         jnp.asarray(positions),
                         self._cache,
                         sub,
-                        kv_len=self._kv_extent(hi),
+                        kv_len=kv_len,
                     )
             first = _host_fetch(first_dev)
         step_s = time.monotonic() - t0
+        self._phase("commit")
         self.chunk_s += step_s
         self.chunk_dispatches += 1
+        for req in self._active.values():
+            req.stalled_s += step_s      # sat through this chunk
         now = time.monotonic()
         rows = len(fed)
+        first_tokens = 0
         for slot in list(self._chunking):
             entry = self._chunking[slot]
             req, _filled, started = entry
@@ -3026,6 +3087,7 @@ class GenerationEngine:
                 continue
             del self._chunking[slot]
             tok = int(first[slot])
+            first_tokens += 1
             if self.telemetry is not None:
                 self.telemetry.on_admit(req.request_id,
                                         wave_start=started,
@@ -3051,7 +3113,11 @@ class GenerationEngine:
                 "prefill_chunk", step_s, seq=seq, rows=rows,
                 batch=self.num_slots, tokens=sum(fed.values()),
                 padded_tokens=self.num_slots * width,
-                route=self._kv_route)
+                route=self._kv_route, t_start=t0,
+                new_tokens=first_tokens,
+                prompt_tokens=sum(fed.values()),
+                first_use=self._first_use("prefill_chunk", kv_len,
+                                          width))
             self.telemetry.on_prefill_chunks(rows)
 
     def _decode_once(self) -> None:
@@ -3083,7 +3149,9 @@ class GenerationEngine:
         step_kind = "piggyback" if piggy else "decode"
         seq = self.telemetry.next_step() if self.telemetry is not None \
             else None
-        piggy_tok0 = self.piggy_tokens
+        piggy_tok0, piggy_rows0 = self.piggy_tokens, self.piggy_rows
+        kv_len = self._kv_bucket()
+        self._phase(None)
         with step_annotation(step_kind, seq), \
                 self._dispatch_boundary(step_kind):
             if piggy:
@@ -3097,7 +3165,6 @@ class GenerationEngine:
                 with quant.pallas_qmatmul_override(
                         self._decode_pallas_override):
                     if self.paged:
-                        kv_len = self._kv_bucket()
                         for slot in self._active:
                             self._ensure_blocks(
                                 slot, int(self._positions[slot])
@@ -3127,15 +3194,18 @@ class GenerationEngine:
                             jnp.asarray(self._positions),
                             self._cache,
                             sub,
-                            kv_len=self._kv_bucket(),
+                            kv_len=kv_len,
                             n_windows=self.windows_per_dispatch,
                         )
                 toks = _host_fetch(toks)                 # [steps, slots]
                 self.plain_s += time.monotonic() - t0
                 self.plain_dispatches += 1
         step_s = time.monotonic() - t0
+        self._phase("harvest")
         harvested_total = 0
         for slot, req in active_before:
+            req.decode_s_own += step_s
+            req.decode_dispatches += 1
             gen = self._generated[slot]
             harvested0 = len(gen)
             finished = None
@@ -3176,7 +3246,12 @@ class GenerationEngine:
                 tokens=harvested_total
                 + (self.piggy_tokens - piggy_tok0),
                 padded_tokens=window * self.num_slots,
-                route=self._kv_route)
+                route=self._kv_route, t_start=t0,
+                new_tokens=harvested_total
+                + (self.piggy_rows - piggy_rows0),
+                prompt_tokens=self.piggy_tokens - piggy_tok0,
+                first_use=self._first_use(
+                    step_kind, kv_len, self.windows_per_dispatch))
 
     def _spec_allowed(self) -> bool:
         """Spec-decode degraded-mode gate: the supervisor's
@@ -3259,12 +3334,13 @@ class GenerationEngine:
         t0 = time.monotonic()
         seq = self.telemetry.next_step() if self.telemetry is not None \
             else None
+        kv_len = self._kv_bucket()
+        self._phase(None)
         with step_annotation("verify", seq), \
                 self._dispatch_boundary("verify"):
             with quant.pallas_qmatmul_override(
                     self._decode_pallas_override):
                 if self.paged:
-                    kv_len = self._kv_bucket()
                     # The dispatch width s is global; near-cap rows'
                     # columns past max_len are dead padding (the
                     # contiguous merge drops them OOB) — cap the table
@@ -3300,16 +3376,19 @@ class GenerationEngine:
                         jnp.asarray(self._positions),
                         self._cache,
                         sub,
-                        kv_len=self._kv_bucket(),
+                        kv_len=kv_len,
                     )
             out = _host_fetch(out_dev)                     # [slots, S]
             acc = _host_fetch(acc_dev)                     # [slots]
         step_s = time.monotonic() - t0
+        self._phase("harvest")
         self.spec_s += step_s
         self.spec_dispatches += 1
         accepted0 = self.spec_accepted_tokens
         emitted0 = self.spec_emitted_tokens
         for slot, req in active_before:
+            req.decode_s_own += step_s
+            req.decode_dispatches += 1
             m = int(acc[slot]) + 1        # emitted: accepts + 1 sample
             self.spec_accepted_tokens += m - 1
             self.spec_rows += 1
@@ -3350,7 +3429,9 @@ class GenerationEngine:
                 padded_tokens=s * self.num_slots,
                 draft_tokens=sum(len(d) for d in drafts.values()),
                 accepted_tokens=self.spec_accepted_tokens - accepted0,
-                route=self._kv_route)
+                route=self._kv_route, t_start=t0,
+                new_tokens=self.spec_emitted_tokens - emitted0,
+                first_use=self._first_use("verify", kv_len, s))
 
     def _pack_prefill(self):
         """Pack whole pending prompts into the W×P chunk grid.
@@ -3534,9 +3615,11 @@ class GenerationEngine:
             decode_s=time.monotonic() - req.decode_started_at,
         )
         if self.telemetry is not None:
-            self.telemetry.on_retire(req.request_id,
-                                     new_tokens=len(gen),
-                                     finish_reason=reason)
+            self.telemetry.on_retire(
+                req.request_id, new_tokens=len(gen),
+                finish_reason=reason, decode_s_own=req.decode_s_own,
+                decode_dispatches=req.decode_dispatches,
+                stalled_s=req.stalled_s)
             # ledger gauges at retire cadence: the stats are cumulative
             # engine-wide counters, so per-step export buys nothing
             self.telemetry.update_ledgers(

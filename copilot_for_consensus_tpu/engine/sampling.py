@@ -22,6 +22,8 @@ from dataclasses import dataclass
 import jax
 import jax.numpy as jnp
 
+from copilot_for_consensus_tpu.obs.profile import scope
+
 
 @dataclass(frozen=True)
 class SamplingConfig:
@@ -59,6 +61,7 @@ def _filter_logits(logits: jax.Array, cfg: SamplingConfig) -> jax.Array:
     return logits
 
 
+@scope("sample")
 def sample(logits: jax.Array, key: jax.Array,
            cfg: SamplingConfig) -> jax.Array:
     """logits: [B, V] fp32 → [B] int32 token ids."""
@@ -68,6 +71,7 @@ def sample(logits: jax.Array, key: jax.Array,
         key, _filter_logits(logits, cfg), axis=-1).astype(jnp.int32)
 
 
+@scope("sample")
 def verify_draft(logits: jax.Array, draft: jax.Array,
                  draft_lens: jax.Array, key: jax.Array,
                  cfg: SamplingConfig) -> tuple[jax.Array, jax.Array]:

@@ -56,6 +56,7 @@ from copilot_for_consensus_tpu.obs.metrics import (
     MetricsCollector,
     check_registry_labels,
 )
+from copilot_for_consensus_tpu.obs.profile import HOST_PHASES, host_span
 
 # ---------------------------------------------------------------------------
 # Metric registry — the single source of truth for what the telemetry
@@ -100,6 +101,12 @@ METRICS: dict[str, tuple[str, tuple[str, ...], str]] = {
         "histogram", ("engine", "kind"),
         "Host wall time per device dispatch, by wave kind (prefill|"
         "prefill_seeded|decode|verify|piggyback|embed)."),
+    "engine_host_phase_seconds_total": (
+        "counter", ("engine", "phase"),
+        "Host seconds of the serving loop outside device dispatches, "
+        "by phase (obs/profile.py:HOST_PHASES: wait_work|enqueue|plan|"
+        "commit|harvest|upkeep|resolve). wait_work is the traffic's; "
+        "the rest is time the chip waits on host work."),
     "engine_queue_depth": (
         "gauge", ("engine",),
         "Requests waiting for a slot (queued + piggyback-prefilling)."),
@@ -244,6 +251,9 @@ METRICS: dict[str, tuple[str, tuple[str, ...], str]] = {
 # loudly, not at scrape time when the aggregator stamps them.
 check_registry_labels(METRICS, owner="ENGINE_METRICS")
 
+#: how many of the newest step records a flight-recorder dump carries
+DUMP_STEPS = 512
+
 #: step-record kinds the engines emit (doc + test anchor)
 STEP_KINDS = ("prefill", "prefill_seeded", "prefill_chunk", "decode",
               "verify", "piggyback", "embed")
@@ -283,6 +293,13 @@ class RequestTrace:
     ttft_s: float = 0.0
     itl_s: float = 0.0
     e2e_s: float = 0.0
+    # where the time after the first token went (copied from the
+    # engine's Request at retire): finished_at - first_token_at ==
+    # decode_s_own + stalled_s + host_s
+    decode_s_own: float = 0.0       # dispatches that advanced it
+    decode_dispatches: int = 0
+    stalled_s: float = 0.0          # other requests' admission waves
+    host_s: float = 0.0             # what no dispatch covers
 
     def as_dict(self) -> dict:
         return asdict(self)
@@ -306,6 +323,15 @@ class StepRecord:
     accepted_tokens: int = 0  # verify waves: accepted
     route: str = ""           # paged dispatch route: kernel |
     #                           reference ("" = contiguous layout)
+    t_start: float = 0.0      # time.monotonic(): t_end - duration_s
+    t_end: float = 0.0        # time.monotonic() at the host fetch
+    new_tokens: int = 0       # tokens handed to requests: harvested
+    #                           decode tokens + one first token per
+    #                           admitted row (a piggyback step: both)
+    prompt_tokens: int = 0    # prompt tokens prefilled
+    first_use: bool = False   # first dispatch of its kind with its
+    #                           static shape key: it loaded (or
+    #                           compiled) a program
 
     @property
     def occupancy(self) -> float:
@@ -328,9 +354,15 @@ class StepRecord:
 class FlightRecorder:
     """Bounded ring of ``StepRecord``s. Append is one deque op under
     the GIL (the deque's maxlen does the eviction) — cheap enough to
-    stay on by default in the serving loop."""
+    stay on by default in the serving loop.
 
-    def __init__(self, capacity: int = 512):
+    The default capacity holds an hour of serving: a 7B engine on one
+    chip makes about 8 dispatches a second (PERF_LEDGER, PR 25), 8 x
+    3600 = 28,800 records, rounded up to 32,768; a record is 16 small
+    fields, about 200 bytes, so a full ring is 6-7 MB. A run's warm-up
+    steps (``first_use``) are then still there when it ends."""
+
+    def __init__(self, capacity: int = 32768):
         self.capacity = capacity
         self._ring: "collections.deque[StepRecord]" = collections.deque(
             maxlen=capacity)
@@ -345,15 +377,18 @@ class FlightRecorder:
             self._seq += 1
             return self._seq
 
+    @property
+    def last_seq(self) -> int:
+        """The newest step id handed out (0 before the first)."""
+        with self._lock:
+            return self._seq
+
     def record(self, rec: StepRecord) -> StepRecord:
         self._ring.append(rec)
         return rec
 
     def records(self) -> list[StepRecord]:
         return list(self._ring)
-
-    def as_dicts(self) -> list[dict]:
-        return [r.as_dict() for r in self.records()]
 
 
 # ---------------------------------------------------------------------------
@@ -367,6 +402,13 @@ _default_dump_dir: str | None = None
 #: live telemetry instances, so a test-failure hook can dump every
 #: engine that existed when the failure happened
 _live: "weakref.WeakSet[EngineTelemetry]" = weakref.WeakSet()
+
+
+def live() -> "list[EngineTelemetry]":
+    """The telemetry instances alive in this process (one per engine):
+    how a benchmark reader or an operator's console reaches the
+    recorders without holding the engines."""
+    return list(_live)
 
 
 def set_default_dump_dir(path: str | None) -> None:
@@ -414,7 +456,7 @@ class EngineTelemetry:
     def __init__(self, *, engine: str = "generation",
                  num_slots: int = 0,
                  metrics: MetricsCollector | None = None,
-                 recorder_capacity: int = 512,
+                 recorder_capacity: int = 32768,
                  completed_capacity: int = 4096,
                  dump_dir: str | None = None):
         self.engine_label = engine
@@ -429,8 +471,28 @@ class EngineTelemetry:
             collections.deque(maxlen=completed_capacity)
         self.created_wall = time.time()
         self.errors = 0
+        #: host seconds per phase (obs/profile.py:HOST_PHASES), what
+        #: ``engine_host_phase_seconds_total`` exports
+        self.phase_seconds: dict[str, float] = dict.fromkeys(
+            HOST_PHASES, 0.0)
+        self._phase_labels = {p: {**self._labels, "phase": p}
+                              for p in HOST_PHASES}
         self._dump_seq = 0
         _live.add(self)
+
+    # -- host phases ----------------------------------------------------
+
+    def host_span(self, name: str, *, ahead: bool = False) -> host_span:
+        """A host-phase span (``obs/profile.py:host_span``) tagged with
+        the step it belongs to: the last dispatch's id, or with
+        ``ahead`` the id the next dispatch will take."""
+        return host_span(name, self.recorder.last_seq + bool(ahead),
+                         self._on_phase)
+
+    def _on_phase(self, name: str, seconds: float) -> None:
+        self.phase_seconds[name] += seconds
+        self.metrics.increment("engine_host_phase_seconds_total",
+                               seconds, self._phase_labels[name])
 
     # -- lifecycle ------------------------------------------------------
 
@@ -465,7 +527,9 @@ class EngineTelemetry:
         m.observe("engine_ttft_seconds", tr.ttft_s, lb)
 
     def on_retire(self, request_id: int, *, new_tokens: int,
-                  finish_reason: str) -> RequestTrace | None:
+                  finish_reason: str, decode_s_own: float = 0.0,
+                  decode_dispatches: int = 0,
+                  stalled_s: float = 0.0) -> RequestTrace | None:
         tr = self._traces.pop(request_id, None)
         if tr is None:
             return None
@@ -475,6 +539,10 @@ class EngineTelemetry:
         tr.finish_reason = finish_reason
         tr.e2e_s = now - tr.enqueued_at
         decode_s = now - (tr.first_token_at or now)
+        tr.decode_s_own = decode_s_own
+        tr.decode_dispatches = decode_dispatches
+        tr.stalled_s = stalled_s
+        tr.host_s = decode_s - decode_s_own - stalled_s
         tr.itl_s = decode_s / (new_tokens - 1) if new_tokens > 1 else 0.0
         self.completed.append(tr)
         m, lb = self.metrics, self._labels
@@ -504,13 +572,22 @@ class EngineTelemetry:
                     batch: int = 0, tokens: int = 0,
                     padded_tokens: int = 0, draft_tokens: int = 0,
                     accepted_tokens: int = 0,
-                    route: str = "") -> StepRecord:
+                    route: str = "", t_start: float | None = None,
+                    new_tokens: int = 0, prompt_tokens: int = 0,
+                    first_use: bool = False) -> StepRecord:
+        """``t_start`` is the dispatch's ``time.monotonic()`` start
+        (default: now less ``duration_s``)."""
+        if t_start is None:
+            t_start = time.monotonic() - duration_s
         rec = StepRecord(
             seq=self.recorder.next_seq() if seq is None else seq,
             kind=kind, t_wall=time.time(), duration_s=duration_s,
             rows=rows, batch=batch, tokens=tokens,
             padded_tokens=padded_tokens, draft_tokens=draft_tokens,
-            accepted_tokens=accepted_tokens, route=route)
+            accepted_tokens=accepted_tokens, route=route,
+            t_start=t_start, t_end=t_start + duration_s,
+            new_tokens=new_tokens, prompt_tokens=prompt_tokens,
+            first_use=first_use)
         self.recorder.record(rec)
         m, lb = self.metrics, self._labels
         m.observe("engine_step_seconds", duration_s,
@@ -726,7 +803,10 @@ class EngineTelemetry:
             "correlation_ids": self.correlation_ids(),
             "completed_tail": [t.as_dict()
                                for t in list(self.completed)[-64:]],
-            "steps": self.recorder.as_dicts(),
+            # the newest steps only: a post-mortem reads the last
+            # seconds, and the ring now holds an hour
+            "steps": [r.as_dict()
+                      for r in self.recorder.records()[-DUMP_STEPS:]],
             "summary": self.latency_summary(),
         }
         if error is not None:

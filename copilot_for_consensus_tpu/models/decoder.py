@@ -25,6 +25,7 @@ import jax.numpy as jnp
 from copilot_for_consensus_tpu.models.configs import DecoderConfig
 from copilot_for_consensus_tpu.models import layers as L
 from copilot_for_consensus_tpu.models.moe import moe_ffn
+from copilot_for_consensus_tpu.obs.profile import scope
 
 Params = dict[str, Any]
 
@@ -119,10 +120,19 @@ def param_count(params: Params) -> int:
 # ---------------------------------------------------------------------------
 
 
+@scope("embed")
+def _embed(params: Params, tokens: jax.Array) -> jax.Array:
+    return params["tok_emb"][tokens]
+
+
 def _ffn(x: jax.Array, layer: Params, cfg: DecoderConfig) -> jax.Array:
-    return moe_ffn(x, layer, cfg) if cfg.is_moe else L.swiglu(x, layer)
+    if cfg.is_moe:
+        with scope("ffn"):
+            return moe_ffn(x, layer, cfg)
+    return L.swiglu(x, layer)
 
 
+@scope("unembed")
 def _unembed(x: jax.Array, params: Params, cfg: DecoderConfig) -> jax.Array:
     x = L.rms_norm(x, params["final_norm"], cfg.norm_eps)
     if cfg.tie_embeddings:
@@ -152,7 +162,7 @@ def forward(params: Params, tokens: jax.Array, cfg: DecoderConfig,
             lengths: jax.Array | None = None,
             attn_impl: str = "auto") -> jax.Array:
     """Scoring/training pass: [B, S] int tokens → [B, S, V] fp32 logits."""
-    x = params["tok_emb"][tokens]
+    x = _embed(params, tokens)
 
     def body(x, layer):
         return block(x, layer, cfg, lengths, attn_impl), None
@@ -179,7 +189,7 @@ def prefill(params: Params, tokens: jax.Array, lengths: jax.Array,
     positions [0, S) into the cache and returns (last-valid-position logits
     [B, V] fp32, cache)."""
     b, s = tokens.shape
-    x = params["tok_emb"][tokens]
+    x = _embed(params, tokens)
 
     def body(x, scanned):
         layer, k_cache, v_cache = scanned
@@ -189,10 +199,11 @@ def prefill(params: Params, tokens: jax.Array, lengths: jax.Array,
         x = x + h
         x = x + _ffn(L.rms_norm(x, layer["ffn_norm"], cfg.norm_eps),
                      layer, cfg)
-        k_cache = jax.lax.dynamic_update_slice_in_dim(
-            k_cache, k.astype(k_cache.dtype), 0, axis=2)
-        v_cache = jax.lax.dynamic_update_slice_in_dim(
-            v_cache, v.astype(v_cache.dtype), 0, axis=2)
+        with scope("kv_write"):
+            k_cache = jax.lax.dynamic_update_slice_in_dim(
+                k_cache, k.astype(k_cache.dtype), 0, axis=2)
+            v_cache = jax.lax.dynamic_update_slice_in_dim(
+                v_cache, v.astype(v_cache.dtype), 0, axis=2)
         return x, (k_cache, v_cache)
 
     x, (k_new, v_new) = jax.lax.scan(
@@ -222,7 +233,7 @@ def prefill_seeded(params: Params, tokens: jax.Array, lengths: jax.Array,
     fp32, scratch). Rows with prefix_lens 0 compute exactly what
     ``prefill`` computes — mixed hit/miss admission waves run as one
     program."""
-    x = params["tok_emb"][tokens]
+    x = _embed(params, tokens)
 
     def body(x, scanned):
         layer, k_pref_l, v_pref_l, k_cache, v_cache = scanned
@@ -233,10 +244,11 @@ def prefill_seeded(params: Params, tokens: jax.Array, lengths: jax.Array,
         x = x + h
         x = x + _ffn(L.rms_norm(x, layer["ffn_norm"], cfg.norm_eps),
                      layer, cfg)
-        k_cache = jax.lax.dynamic_update_slice_in_dim(
-            k_cache, k.astype(k_cache.dtype), 0, axis=2)
-        v_cache = jax.lax.dynamic_update_slice_in_dim(
-            v_cache, v.astype(v_cache.dtype), 0, axis=2)
+        with scope("kv_write"):
+            k_cache = jax.lax.dynamic_update_slice_in_dim(
+                k_cache, k.astype(k_cache.dtype), 0, axis=2)
+            v_cache = jax.lax.dynamic_update_slice_in_dim(
+                v_cache, v.astype(v_cache.dtype), 0, axis=2)
         return x, (k_cache, v_cache)
 
     x, (k_new, v_new) = jax.lax.scan(
@@ -276,11 +288,12 @@ def verify_seeded(params: Params, tokens: jax.Array, lengths: jax.Array,
     scatter drops). Returns (logits [B, S, V] fp32, k_new, v_new
     [L, B, Hkv, S, Dh] — ``merge_window`` layout, for the engine's
     single end-of-dispatch scatter at the per-row offset)."""
-    x = params["tok_emb"][tokens]
+    x = _embed(params, tokens)
     k_pref, v_pref = cache["k"], cache["v"]
     if kv_len is not None and kv_len < k_pref.shape[3]:
-        k_pref = k_pref[:, :, :, :kv_len]
-        v_pref = v_pref[:, :, :, :kv_len]
+        with scope("kv_prefix"):
+            k_pref = k_pref[:, :, :, :kv_len]
+            v_pref = v_pref[:, :, :, :kv_len]
 
     def body(x, scanned):
         layer, k_pref_l, v_pref_l = scanned
@@ -306,7 +319,7 @@ def decode_step(params: Params, tokens: jax.Array, positions: jax.Array,
     [B] — the cache index each token occupies; ``kv_len`` (static) bounds
     the cache prefix attention reads. Returns ([B, V] fp32 logits,
     updated cache)."""
-    x = params["tok_emb"][tokens][:, None, :]               # [B, 1, D]
+    x = _embed(params, tokens)[:, None, :]               # [B, 1, D]
 
     # The stacked cache rides the scan CARRY with per-column scatter
     # writes (attn_decode_stacked): as scan xs/ys it would be fully
@@ -356,7 +369,7 @@ def decode_step_windowed(params: Params, tokens: jax.Array,
     where k_cols/v_cols [L, B, Hkv, Dh] are this step's new KV columns
     for the caller to slot into the window buffers at index ``w``.
     """
-    x = params["tok_emb"][tokens][:, None, :]               # [B, 1, D]
+    x = _embed(params, tokens)[:, None, :]               # [B, 1, D]
     # Static prefix slice BEFORE the layer scan, streamed per layer as
     # scan xs (read-only, never in ys): attention reads exactly the
     # occupied [0, kv_len) columns per layer and nothing writes back.
@@ -365,8 +378,9 @@ def decode_step_windowed(params: Params, tokens: jax.Array,
     # max_len 256→512 with identical kv_len cost ~12 ms/step).
     k_pref, v_pref = cache["k"], cache["v"]
     if kv_len is not None and kv_len < k_pref.shape[3]:
-        k_pref = k_pref[:, :, :, :kv_len]
-        v_pref = v_pref[:, :, :, :kv_len]
+        with scope("kv_prefix"):
+            k_pref = k_pref[:, :, :, :kv_len]
+            v_pref = v_pref[:, :, :, :kv_len]
     have_done = k_done is not None
     xs = (params["layers"], jnp.arange(cfg.n_layers), k_pref, v_pref)
     if have_done:
@@ -416,7 +430,7 @@ def decode_step_windowed_paged(params: Params, tokens: jax.Array,
     tokens: [B]; positions0: [B] dispatch-start positions; ``w``:
     traced in-window step index. Returns ([B, V] fp32 logits, k_cols,
     v_cols [L, B, Hkv, Dh]) exactly like the reference twin."""
-    x = params["tok_emb"][tokens][:, None, :]               # [B, 1, D]
+    x = _embed(params, tokens)[:, None, :]               # [B, 1, D]
     have_done = k_done is not None
     xs = (params["layers"], jnp.arange(cfg.n_layers))
     if have_done:
@@ -466,7 +480,7 @@ def prefill_seeded_paged(params: Params, tokens: jax.Array,
     ``all_logits`` else the last-valid-position [B, V] (selected
     BEFORE the lm_head — the same admission OOM guard as
     ``prefill``)."""
-    x = params["tok_emb"][tokens]
+    x = _embed(params, tokens)
 
     def body(x, scanned):
         layer, li = scanned
@@ -556,8 +570,8 @@ def decode_step_piggyback(params: Params, tokens: jax.Array,
     b = tokens.shape[0]
     p, c = pre_tok.shape
     dh, hq, hkv = cfg.head_dim, cfg.n_heads, cfg.n_kv_heads
-    x_dec = params["tok_emb"][tokens]                      # [B, D]
-    x_pre = params["tok_emb"][pre_tok]                     # [P, C, D]
+    x_dec = _embed(params, tokens)                      # [B, D]
+    x_pre = _embed(params, pre_tok)                     # [P, C, D]
     d_model = x_dec.shape[-1]
     x = jnp.concatenate([x_dec, x_pre.reshape(p * c, d_model)], axis=0)
 
@@ -566,8 +580,9 @@ def decode_step_piggyback(params: Params, tokens: jax.Array,
 
     k_pref, v_pref = cache["k"], cache["v"]
     if kv_len is not None and kv_len < k_pref.shape[3]:
-        k_pref = k_pref[:, :, :, :kv_len]
-        v_pref = v_pref[:, :, :, :kv_len]
+        with scope("kv_prefix"):
+            k_pref = k_pref[:, :, :, :kv_len]
+            v_pref = v_pref[:, :, :, :kv_len]
     inv_freq = L.rope_frequencies(dh, cfg.rope_theta)
     xs = (params["layers"], jnp.arange(cfg.n_layers), k_pref, v_pref)
 
@@ -576,15 +591,17 @@ def decode_step_piggyback(params: Params, tokens: jax.Array,
         xa = L.rms_norm(x, layer["attn_norm"], cfg.norm_eps)
         # ONE projection matmul over decode+prefill rows: the weight
         # stream is shared — this is the piggyback.
-        if "wqkv" in layer:
-            nq, nkv = hq * dh, hkv * dh
-            qkv = L.qmatmul(xa, layer["wqkv"])
-            q_all, k_all, v_all = (qkv[..., :nq], qkv[..., nq:nq + nkv],
-                                   qkv[..., nq + nkv:])
-        else:
-            q_all = L.qmatmul(xa, layer["wq"])
-            k_all = L.qmatmul(xa, layer["wk"])
-            v_all = L.qmatmul(xa, layer["wv"])
+        with scope("qkv"):
+            if "wqkv" in layer:
+                nq, nkv = hq * dh, hkv * dh
+                qkv = L.qmatmul(xa, layer["wqkv"])
+                q_all, k_all, v_all = (qkv[..., :nq],
+                                       qkv[..., nq:nq + nkv],
+                                       qkv[..., nq + nkv:])
+            else:
+                q_all = L.qmatmul(xa, layer["wq"])
+                k_all = L.qmatmul(xa, layer["wk"])
+                v_all = L.qmatmul(xa, layer["wv"])
 
         def split_heads(z, n_heads):
             zd = z[:b].reshape(b, 1, n_heads, dh).transpose(0, 2, 1, 3)
@@ -616,21 +633,23 @@ def decode_step_piggyback(params: Params, tokens: jax.Array,
                                               keepdims=False)
         vbuf_l = jax.lax.dynamic_index_in_dim(pre_vbuf, li, 0,
                                               keepdims=False)
-        kbuf_cur = jax.lax.dynamic_update_slice_in_dim(
-            kbuf_l, kp.astype(kbuf_l.dtype), w * c, axis=2)
-        vbuf_cur = jax.lax.dynamic_update_slice_in_dim(
-            vbuf_l, vp.astype(vbuf_l.dtype), w * c, axis=2)
-        o_pre = flash_attention(
-            qp, kbuf_cur.astype(qp.dtype), vbuf_cur.astype(qp.dtype),
-            causal=True, kv_lengths=pre_kv_len,
-            q_offsets=jnp.broadcast_to(w * c, (p,)),
-            kv_begins=pre_kv_begin)                 # [P, Hq, C, Dh]
+        with scope("kv_write"):
+            kbuf_cur = jax.lax.dynamic_update_slice_in_dim(
+                kbuf_l, kp.astype(kbuf_l.dtype), w * c, axis=2)
+            vbuf_cur = jax.lax.dynamic_update_slice_in_dim(
+                vbuf_l, vp.astype(vbuf_l.dtype), w * c, axis=2)
+        with scope("attn"):
+            o_pre = flash_attention(
+                qp, kbuf_cur.astype(qp.dtype), vbuf_cur.astype(qp.dtype),
+                causal=True, kv_lengths=pre_kv_len,
+                q_offsets=jnp.broadcast_to(w * c, (p,)),
+                kv_begins=pre_kv_begin)             # [P, Hq, C, Dh]
 
         o = jnp.concatenate([
             o_dec.reshape(b, hq * dh),
             o_pre.transpose(0, 2, 1, 3).reshape(p * c, hq * dh),
         ], axis=0)
-        x = x + L.qmatmul(o, layer["wo"])           # one wo matmul
+        x = x + L.attn_out(o, layer)               # one wo matmul
         x = x + _ffn(L.rms_norm(x, layer["ffn_norm"], cfg.norm_eps),
                      layer, cfg)                    # one FFN pass
         return x, (kd[:, :, 0, :], vd[:, :, 0, :], kp, vp)
@@ -644,6 +663,16 @@ def decode_step_piggyback(params: Params, tokens: jax.Array,
     return logits, k_cols, v_cols, pre_k, pre_v, h_step
 
 
+@scope("kv_write")
+def put_window_column(win: jax.Array, cols: jax.Array,
+                      w: jax.Array) -> jax.Array:
+    """One decode step's new KV columns ``cols`` [L, B, Hkv, Dh] into
+    column ``w`` of the window buffer ``win`` [L, B, Hkv, W, Dh]."""
+    return jax.lax.dynamic_update_slice_in_dim(
+        win, cols[:, :, :, None].astype(win.dtype), w, axis=3)
+
+
+@scope("kv_write")
 def merge_prefill(cache: Params, k_buf: jax.Array, v_buf: jax.Array,
                   sidx: jax.Array, pidx: jax.Array) -> Params:
     """Scatter a dispatch's prefill-chunk buffers into the cache.
@@ -663,6 +692,7 @@ def merge_prefill(cache: Params, k_buf: jax.Array, v_buf: jax.Array,
     return {"k": k, "v": v}
 
 
+@scope("kv_write")
 def merge_window(cache: Params, k_win: jax.Array, v_win: jax.Array,
                  positions0: jax.Array, steps: int) -> Params:
     """Scatter a decode window's KV into the big cache, once.

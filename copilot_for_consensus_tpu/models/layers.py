@@ -13,6 +13,7 @@ import jax
 import jax.numpy as jnp
 
 from copilot_for_consensus_tpu.models.configs import DecoderConfig
+from copilot_for_consensus_tpu.obs.profile import scope
 from copilot_for_consensus_tpu.ops.attention import attention, decode_attention
 
 # ---------------------------------------------------------------------------
@@ -83,6 +84,7 @@ def qmatmul(x: jax.Array, w) -> jax.Array:
 # ---------------------------------------------------------------------------
 
 
+@scope("norm_rope")
 def rms_norm(x: jax.Array, weight: jax.Array, eps: float) -> jax.Array:
     xf = x.astype(jnp.float32)
     var = jnp.mean(xf * xf, axis=-1, keepdims=True)
@@ -111,6 +113,7 @@ def rope_frequencies(head_dim: int, theta: float) -> jax.Array:
     return 1.0 / (theta ** exponents)                     # [head_dim/2]
 
 
+@scope("norm_rope")
 def apply_rope(x: jax.Array, positions: jax.Array,
                inv_freq: jax.Array) -> jax.Array:
     """x: [B, H, S, D]; positions: [B, S] (int) → same shape, rotated."""
@@ -132,24 +135,30 @@ def _project_qkv(x: jax.Array, layer: dict, cfg: DecoderConfig,
                  positions: jax.Array):
     b, s, _ = x.shape
     dh = cfg.head_dim
-    if "wqkv" in layer:
-        # Fused int4 projection (quant.fuse_int4_projections): one
-        # kernel call; split the product by column.
-        nq, nkv = cfg.n_heads * dh, cfg.n_kv_heads * dh
-        qkv = qmatmul(x, layer["wqkv"])
-        q, k, v = (qkv[..., :nq], qkv[..., nq:nq + nkv],
-                   qkv[..., nq + nkv:])
-    else:
-        q = qmatmul(x, layer["wq"])
-        k = qmatmul(x, layer["wk"])
-        v = qmatmul(x, layer["wv"])
-    q = q.reshape(b, s, cfg.n_heads, dh).transpose(0, 2, 1, 3)
-    k = k.reshape(b, s, cfg.n_kv_heads, dh).transpose(0, 2, 1, 3)
-    v = v.reshape(b, s, cfg.n_kv_heads, dh).transpose(0, 2, 1, 3)
+    with scope("qkv"):
+        if "wqkv" in layer:
+            # Fused int4 projection (quant.fuse_int4_projections): one
+            # kernel call; split the product by column.
+            nq, nkv = cfg.n_heads * dh, cfg.n_kv_heads * dh
+            qkv = qmatmul(x, layer["wqkv"])
+            q, k, v = (qkv[..., :nq], qkv[..., nq:nq + nkv],
+                       qkv[..., nq + nkv:])
+        else:
+            q = qmatmul(x, layer["wq"])
+            k = qmatmul(x, layer["wk"])
+            v = qmatmul(x, layer["wv"])
+        q = q.reshape(b, s, cfg.n_heads, dh).transpose(0, 2, 1, 3)
+        k = k.reshape(b, s, cfg.n_kv_heads, dh).transpose(0, 2, 1, 3)
+        v = v.reshape(b, s, cfg.n_kv_heads, dh).transpose(0, 2, 1, 3)
     inv_freq = rope_frequencies(dh, cfg.rope_theta)
     q = apply_rope(q, positions, inv_freq)
     k = apply_rope(k, positions, inv_freq)
     return q, k, v
+
+
+@scope("attn_out")
+def attn_out(o: jax.Array, layer: dict) -> jax.Array:
+    return qmatmul(o, layer["wo"])
 
 
 def attn_prefill(x: jax.Array, layer: dict, cfg: DecoderConfig,
@@ -162,7 +171,7 @@ def attn_prefill(x: jax.Array, layer: dict, cfg: DecoderConfig,
     o = attention(q, k, v, causal=True, window=cfg.sliding_window,
                   kv_lengths=lengths, impl=impl)
     o = o.transpose(0, 2, 1, 3).reshape(b, s, cfg.n_heads * cfg.head_dim)
-    return qmatmul(o, layer["wo"]), k, v
+    return attn_out(o, layer), k, v
 
 
 def attn_prefill_seeded(x: jax.Array, layer: dict, cfg: DecoderConfig,
@@ -191,7 +200,7 @@ def attn_prefill_seeded(x: jax.Array, layer: dict, cfg: DecoderConfig,
     o = prefill_attention_seeded(q, k, v, k_pref, v_pref,
                                  prefix_lens, kv_lengths=lengths)
     o = o.transpose(0, 2, 1, 3).reshape(b, s, cfg.n_heads * cfg.head_dim)
-    return qmatmul(o, layer["wo"]), k, v
+    return attn_out(o, layer), k, v
 
 
 def attn_decode_stacked(x: jax.Array, layer: dict, cfg: DecoderConfig,
@@ -209,10 +218,11 @@ def attn_decode_stacked(x: jax.Array, layer: dict, cfg: DecoderConfig,
     b = x.shape[0]
     q, k, v = _project_qkv(x, layer, cfg, positions[:, None])
     bidx = jnp.arange(b)
-    k_cache = k_cache.at[li, bidx, :, positions, :].set(
-        k[:, :, 0, :].astype(k_cache.dtype), mode="drop")
-    v_cache = v_cache.at[li, bidx, :, positions, :].set(
-        v[:, :, 0, :].astype(v_cache.dtype), mode="drop")
+    with scope("kv_write"):
+        k_cache = k_cache.at[li, bidx, :, positions, :].set(
+            k[:, :, 0, :].astype(k_cache.dtype), mode="drop")
+        v_cache = v_cache.at[li, bidx, :, positions, :].set(
+            v[:, :, 0, :].astype(v_cache.dtype), mode="drop")
     k_l = jax.lax.dynamic_index_in_dim(k_cache, li, 0, keepdims=False)
     v_l = jax.lax.dynamic_index_in_dim(v_cache, li, 0, keepdims=False)
     o = decode_attention(q[:, :, 0, :], k_l, v_l,
@@ -220,7 +230,7 @@ def attn_decode_stacked(x: jax.Array, layer: dict, cfg: DecoderConfig,
                          window=cfg.sliding_window,
                          kv_len=kv_len)                   # [B, Hq, Dh]
     o = o.reshape(b, 1, cfg.n_heads * cfg.head_dim)
-    return qmatmul(o, layer["wo"]), k_cache, v_cache
+    return attn_out(o, layer), k_cache, v_cache
 
 
 def attn_decode_windowed(x: jax.Array, layer: dict, cfg: DecoderConfig,
@@ -258,7 +268,7 @@ def attn_decode_windowed(x: jax.Array, layer: dict, cfg: DecoderConfig,
         window=cfg.sliding_window, kv_len=kv_len,
         k_done=k_done_l, v_done=v_done_l)                   # [B, Hq, Dh]
     o = o.reshape(b, 1, cfg.n_heads * cfg.head_dim)
-    return qmatmul(o, layer["wo"]), k_cur, v_cur
+    return attn_out(o, layer), k_cur, v_cur
 
 
 def attn_decode_windowed_paged(x: jax.Array, layer: dict,
@@ -290,13 +300,14 @@ def attn_decode_windowed_paged(x: jax.Array, layer: dict,
     k_cur = k[:, :, 0, :]
     v_cur = v[:, :, 0, :]
     qg = q[:, :, 0, :].reshape(b, hkv, cfg.n_heads // hkv, dh)
-    pool_part = partial_fn(qg, positions0, q_pos)
+    with scope("attn"):
+        pool_part = partial_fn(qg, positions0, q_pos)
     local_part = decode_window_partial(
         qg, k_win_l, v_win_l, k_cur, v_cur, positions0, w,
         window=cfg.sliding_window, k_done=k_done_l, v_done=v_done_l)
     o = combine_partials([pool_part, local_part], x.dtype)
     o = o.reshape(b, 1, cfg.n_heads * dh)
-    return qmatmul(o, layer["wo"]), k_cur, v_cur
+    return attn_out(o, layer), k_cur, v_cur
 
 
 def attn_prefill_seeded_paged(x: jax.Array, layer: dict,
@@ -322,12 +333,13 @@ def attn_prefill_seeded_paged(x: jax.Array, layer: dict,
     q, k, v = _project_qkv(x, layer, cfg, positions)
     q_rows = q.reshape(b, hkv, hq // hkv, s, dh).reshape(
         b, hkv, (hq // hkv) * s, dh)
-    pool_part = partial_fn(q_rows, prefix_lens, prefix_lens)
+    with scope("attn"):
+        pool_part = partial_fn(q_rows, prefix_lens, prefix_lens)
     suffix_part = causal_suffix_partial(q, k, v, kv_lengths=lengths)
     o = combine_partials([pool_part, suffix_part], x.dtype)
     o = o.reshape(b, hq, s, dh).transpose(0, 2, 1, 3).reshape(
         b, s, hq * dh)
-    return qmatmul(o, layer["wo"]), k, v
+    return attn_out(o, layer), k, v
 
 
 # ---------------------------------------------------------------------------
@@ -335,6 +347,7 @@ def attn_prefill_seeded_paged(x: jax.Array, layer: dict,
 # ---------------------------------------------------------------------------
 
 
+@scope("ffn")
 def swiglu(x: jax.Array, layer: dict) -> jax.Array:
     """SwiGLU MLP: silu(x·Wg) ⊙ (x·Wu) · Wd — Llama/Mistral family FFN."""
     if "w_gu" in layer:
@@ -349,6 +362,7 @@ def swiglu(x: jax.Array, layer: dict) -> jax.Array:
     return qmatmul((gate * up).astype(x.dtype), layer["w_down"])
 
 
+@scope("ffn")
 def gelu_mlp(x: jax.Array, layer: dict) -> jax.Array:
     """BERT-style 2-layer GELU MLP (encoder FFN). Exact (erf) GELU —
     the BERT family's ``hidden_act="gelu"``; tanh-approximate would

@@ -1,31 +1,79 @@
-"""jax.profiler integration: flag-gated trace capture on the engines.
+"""jax.profiler integration: what the chip and the host are doing, by name.
 
 SURVEY.md §5 assigns the tracing/profiling subsystem to the TPU build
 (the reference's per-event correlation_id covers the host side; device
-time needs the XLA profiler). Usage:
+time needs the XLA profiler). A trace is captured with
 
     with maybe_profile("var/traces"):            # or None → no-op
         engine.generate(...)
 
-Traces are Perfetto/TensorBoard-compatible (``jax.profiler.trace``).
-Enable on the serving engines via config ``llm.profile_dir``
-(``GenerationEngine(profile_dir=...)``); the flag defaults off so
-production pays zero overhead.
+(config ``llm.profile_dir`` → ``GenerationEngine(profile_dir=...)``;
+off by default) and is Perfetto/TensorBoard-compatible. Three kinds of
+name go into it, each declared here and nowhere else:
 
-``step_annotation`` wraps each engine dispatch in a
-``jax.profiler.StepTraceAnnotation`` whose ``step_num`` is the flight
-recorder's step id (``engine/telemetry.py``) — a Perfetto device-trace
-row and a host-side ``StepRecord`` then name the SAME step, which is
-what makes "slow device step 1234" and "step 1234 was a 2-row padded
-prefill wave" one investigation. The annotation is a TraceMe that is
-near-free when no profiler session is active, so the engines keep it
-on unconditionally.
+* **Dispatch steps** — ``step_annotation(kind, seq)``: a
+  ``StepTraceAnnotation`` around each engine dispatch whose
+  ``step_num`` is the flight recorder's step id
+  (``engine/telemetry.py:StepRecord.seq``), so a device-trace row and
+  a host-side ``StepRecord`` name the SAME step. Read by
+  ``benchmark/harness/trace_reduce.py`` (device time per step, idle
+  gaps inside a dispatch).
+* **Device scopes** — ``SCOPES`` / ``scope(name)``: ``jax.named_scope``
+  around the code that does each thing inside the jitted programs. The
+  name lands in every HLO instruction's ``op_name`` metadata
+  (``jit(_decode)/while/body/ffn/dot_general``) and from there in the
+  trace's ``tf_op`` event-metadata stat; it costs nothing at run time
+  and changes no HLO but its metadata. Read by
+  ``benchmark/harness/scope_reduce.py`` (device self time by scope:
+  the ``decode_*_share`` / ``prefill_*_share`` metrics), and by an
+  operator in Perfetto/XProf as the op's "tf_op" / framework name.
+* **Host phases** — ``HOST_PHASES`` / ``host_span(name, step_num)``: a
+  ``TraceAnnotation`` around each stretch of host work between
+  dispatches, on the profiler's clock, whose duration also goes to
+  ``EngineTelemetry.phase_seconds`` and the
+  ``engine_host_phase_seconds_total{phase}`` counter. Phases never
+  nest and never overlap a dispatch step: the trace reducer gives an
+  idle gap of the device to the one span that holds its midpoint
+  (``breakdown.idle_gaps``, the ``idle_*_share`` metrics); the exact
+  split among phases is the counter's.
+
+All three are TraceMe-based and near-free while no profiler session is
+active, so the engines keep them on unconditionally.
 """
 
 from __future__ import annotations
 
 import contextlib
 import pathlib
+import time
+
+#: device scopes, innermost wins (``unembed`` holds a ``norm_rope``)
+SCOPES = (
+    "embed",      # token embedding lookup
+    "kv_prefix",  # static prefix slice / paged gather of the KV cache
+    "qkv",        # q, k, v projections
+    "attn",       # attention proper (XLA decode pieces, flash, paged)
+    "attn_out",   # the wo projection
+    "ffn",        # swiglu / gelu MLP / MoE
+    "norm_rope",  # rms_norm, apply_rope
+    "unembed",    # final norm + lm_head
+    "sample",     # engine/sampling.py:sample
+    "kv_write",   # window-buffer updates, merge_window/merge_prefill,
+    #               the admit program's cache scatter
+)
+
+#: host phases of the serving loop (with the dispatch kinds ``decode``
+#: and ``prefill`` and ``_no_annotation_`` they fit the trace
+#: reducer's ten gap owners)
+HOST_PHASES = (
+    "wait_work",  # runner blocked with nothing pending, engine idle
+    "enqueue",    # runner handing fresh arrivals to eng.submit
+    "plan",       # step() host work leading up to a dispatch
+    "commit",     # after an admission's host fetch: records, slots
+    "harvest",    # after a decode's host fetch: tokens, retire
+    "upkeep",     # journal tick, gauges, draining completions
+    "resolve",    # runner resolving handles and done-callbacks
+)
 
 
 @contextlib.contextmanager
@@ -59,3 +107,47 @@ def step_annotation(name: str, step_num: int | None = None):
         return jax.profiler.StepTraceAnnotation(name, step_num=step_num)
     except Exception:  # pragma: no cover - profiler API missing
         return contextlib.nullcontext()
+
+
+def scope(name: str):
+    """``jax.named_scope`` for one of ``SCOPES`` (trace time only)."""
+    import jax
+
+    if name not in SCOPES:
+        raise ValueError(f"unknown device scope {name!r}; obs/profile.py "
+                         f"SCOPES has {SCOPES}")
+    return jax.named_scope(name)
+
+
+class host_span:
+    """One host phase: a ``TraceAnnotation(name, step_num=...)`` on the
+    profiler's clock (a phase is not a step, so not a
+    ``StepTraceAnnotation``) whose ``time.monotonic()`` duration is
+    handed to ``sink(name, seconds)`` on exit. ``step_num`` is the id
+    of the dispatch the phase follows or leads to — the trace reducer
+    keeps only host events that carry one."""
+
+    __slots__ = ("name", "_note", "_sink", "_t0")
+
+    def __init__(self, name: str, step_num: int | None = None,
+                 sink=None):
+        import jax
+
+        if name not in HOST_PHASES:
+            raise ValueError(f"unknown host phase {name!r}; "
+                             f"obs/profile.py HOST_PHASES has "
+                             f"{HOST_PHASES}")
+        self.name, self._sink = name, sink
+        kw = {} if step_num is None else {"step_num": step_num}
+        self._note = jax.profiler.TraceAnnotation(name, **kw)
+
+    def __enter__(self):
+        self._t0 = time.monotonic()
+        self._note.__enter__()
+        return self
+
+    def __exit__(self, *exc):
+        self._note.__exit__(*exc)
+        if self._sink is not None:
+            self._sink(self.name, time.monotonic() - self._t0)
+        return False

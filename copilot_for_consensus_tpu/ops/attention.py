@@ -16,6 +16,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from copilot_for_consensus_tpu.obs.profile import scope
+
 
 def _gqa_expand(k: jax.Array, hq: int) -> jax.Array:
     """[B, Hkv, S, D] → [B, Hq, S, D] by repeating each kv head."""
@@ -84,6 +86,7 @@ def attention_xla(
     return jnp.einsum("bhqk,bhkd->bhqd", probs.astype(v.dtype), v)
 
 
+@scope("attn")
 def attention(
     q: jax.Array,
     k: jax.Array,
@@ -125,6 +128,7 @@ def attention(
     )
 
 
+@scope("attn")
 def prefill_attention_seeded(
     q: jax.Array,
     k: jax.Array,
@@ -236,6 +240,7 @@ def _joint_probs(pieces_logits: list[jax.Array]) -> list[jax.Array]:
     return jnp.split(probs, splits, axis=-1)
 
 
+@scope("attn")
 def combine_partials(parts: list[tuple[jax.Array, jax.Array, jax.Array]],
                      dtype) -> jax.Array:
     """Fold flash-style (acc, m, l) partials from independent KV pieces
@@ -276,6 +281,7 @@ def _masked_partial(logits: jax.Array, v_pieces: list[jax.Array]
     return acc, m, l
 
 
+@scope("attn")
 def decode_window_partial(
     qg: jax.Array,
     k_win: jax.Array,
@@ -326,6 +332,7 @@ def decode_window_partial(
     return _masked_partial(jnp.concatenate(pieces_l, axis=-1), pieces_v)
 
 
+@scope("attn")
 def causal_suffix_partial(
     q: jax.Array,
     k: jax.Array,
@@ -359,6 +366,7 @@ def causal_suffix_partial(
 
 
 @functools.partial(jax.jit, static_argnames=("window", "kv_len"))
+@scope("attn")
 def decode_attention(
     q: jax.Array,
     k_cache: jax.Array,
@@ -405,6 +413,7 @@ def decode_attention(
     return out.reshape(b, hq, d)
 
 
+@scope("attn")
 def decode_attention_prefix_window(
     q: jax.Array,
     k_pref: jax.Array,

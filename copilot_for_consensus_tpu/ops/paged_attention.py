@@ -48,6 +48,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from copilot_for_consensus_tpu.analysis.contracts import checkable
+from copilot_for_consensus_tpu.obs.profile import scope
 from copilot_for_consensus_tpu.ops.attention import decode_attention
 
 # TPU lane width the kernel's block axis packs against: pool blocks
@@ -58,6 +59,7 @@ from copilot_for_consensus_tpu.ops.attention import decode_attention
 KERNEL_BLOCK_PACK = 128
 
 
+@scope("kv_prefix")
 def paged_gather_layer(pool_k_l: jax.Array, pool_v_l: jax.Array,
                        tables: jax.Array
                        ) -> tuple[jax.Array, jax.Array]:
@@ -75,6 +77,7 @@ def paged_gather_layer(pool_k_l: jax.Array, pool_v_l: jax.Array,
     return k, v
 
 
+@scope("kv_prefix")
 def paged_gather_kv(pool_k: jax.Array, pool_v: jax.Array,
                     tables: jax.Array
                     ) -> tuple[jax.Array, jax.Array]:

@@ -3,6 +3,7 @@
 from __future__ import annotations
 
 import pathlib
+import re
 from dataclasses import dataclass
 
 import jax
@@ -17,8 +18,8 @@ MESH_AXES = ("dp", "pp", "sp", "ep", "tp")
 #: placed the cache: one fixed directory at the root of the checkout.
 #: The path is part of JAX's cache key, so it must never vary by run
 #: (no temp name, pid or timestamp).
-DEFAULT_COMPILE_CACHE_DIR = str(
-    pathlib.Path(__file__).resolve().parents[2] / ".jax_cache")
+_CHECKOUT = pathlib.Path(__file__).resolve().parents[2]
+DEFAULT_COMPILE_CACHE_DIR = str(_CHECKOUT / ".jax_cache")
 
 
 def enable_compile_cache() -> str:
@@ -33,7 +34,21 @@ def enable_compile_cache() -> str:
     goes to :data:`DEFAULT_COMPILE_CACHE_DIR` and keeps EVERY program:
     under JAX's default a compile is only persisted when it took over a
     second, so a sub-second program would recompile on every start and
-    one that straddles the second would be written on some runs only."""
+    one that straddles the second would be written on some runs only.
+
+    Either way the cache key takes in the programs' metadata (``op_name``
+    with its ``named_scope`` path, source lines), with this checkout's
+    root cut off the file names so that a copy elsewhere still hits. By
+    JAX's default the key leaves metadata out, and a hit then hands back
+    an executable carrying the names of WHATEVER code compiled it first:
+    a trace of this code read ``obs/profile.py:SCOPES`` nowhere because
+    its decode programs came from a cache that an older checkout had
+    filled (PERF.md, PR 26). The price is one compile per program after
+    an edit that moves lines under a jitted function."""
+    jax.config.update("jax_compilation_cache_include_metadata_in_key",
+                      True)
+    jax.config.update("jax_hlo_source_file_canonicalization_regex",
+                      re.escape(str(_CHECKOUT) + "/"))
     placed = jax.config.jax_compilation_cache_dir
     if placed:
         return placed

@@ -40,13 +40,61 @@ def test_compile_cache_leaves_a_placed_directory_alone(tmp_path):
     )
 
     placed = str(tmp_path / "placed-cache")
-    before = jax.config.jax_compilation_cache_dir
+    keys = ("jax_compilation_cache_dir",
+            "jax_compilation_cache_include_metadata_in_key",
+            "jax_hlo_source_file_canonicalization_regex")
+    before = {k: getattr(jax.config, k) for k in keys}
     jax.config.update("jax_compilation_cache_dir", placed)
     try:
         assert enable_compile_cache() == placed
         assert jax.config.jax_compilation_cache_dir == placed
     finally:
-        jax.config.update("jax_compilation_cache_dir", before)
+        for k, v in before.items():
+            jax.config.update(k, v)
+
+
+def test_a_cache_hit_never_hands_back_another_codes_names(tmp_path):
+    """JAX leaves metadata out of the cache key by default, so a program
+    that differs from a cached one only in its ``named_scope``s would
+    load the cached executable, old names and all, and a device trace
+    would show no scope (PR 26 met this on the chip). With
+    ``enable_compile_cache`` the names are the running code's, and
+    the same code still hits."""
+    body = (
+        "import re, sys, jax, jax.numpy as jnp\n"
+        "from copilot_for_consensus_tpu.parallel.mesh import "
+        "enable_compile_cache\n"
+        "enable_compile_cache()\n"
+        "def f(x):\n"
+        "    if SCOPED:\n"
+        "        with jax.named_scope('ffn'):\n"
+        "            return (x @ x) * 2.0\n"
+        "    return (x @ x) * 2.0\n"
+        "text = jax.jit(f).lower(jnp.ones((64, 64))).compile().as_text()\n"
+        "print(sorted(set(re.findall(r'op_name=\"([^\"]*)\"', text))))\n")
+    cache = tmp_path / "cache"
+    env = _env(JAX_PLATFORMS="cpu", PYTHONPATH=str(REPO),
+               JAX_COMPILATION_CACHE_DIR=str(cache),
+               JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS="0",
+               JAX_PERSISTENT_CACHE_MIN_ENTRY_SIZE_BYTES="-1")
+
+    def run(scoped, cwd):
+        cwd.mkdir(exist_ok=True)
+        (cwd / "prog.py").write_text(f"SCOPED = {scoped}\n" + body)
+        out = subprocess.run([sys.executable, "prog.py"], cwd=cwd,
+                             env=env, text=True, capture_output=True,
+                             timeout=120)
+        assert out.returncode == 0, out.stderr[-2000:]
+        return out.stdout.strip().splitlines()[-1], \
+            len(list(cache.iterdir()))
+
+    plain, n0 = run(False, tmp_path / "a")
+    assert "ffn" not in plain and n0 >= 1
+    scoped, n1 = run(True, tmp_path / "a")
+    assert "jit(f)/ffn/dot_general" in scoped and n1 > n0
+    # the same code: a hit, nothing new is written
+    again, n2 = run(True, tmp_path / "a")
+    assert again == scoped and n2 == n1
 
 
 def test_compile_cache_default_is_one_fixed_in_checkout_path():

@@ -260,6 +260,9 @@ def test_telemetry_registry_matches_actual_emission():
                      padded_tokens=16, draft_tokens=4,
                      accepted_tokens=2)
     tele.gauge_queue(3, active=1)
+    # host phases of the serving loop (obs/profile.py:HOST_PHASES)
+    with tele.host_span("plan", ahead=True):
+        pass
     # scheduler series (engine/scheduler.py): per-tenant gauges, the
     # shed counter, and the chunked-prefill counter
     tele.sched_gauges({"tenant-a": 2, "": 1},

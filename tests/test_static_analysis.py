@@ -512,15 +512,16 @@ def test_done_callback_under_runner_lock_fails_the_lane(tmp_path):
     ``_work`` lock (shared with the watchdog; done-callbacks may
     re-enter submit()) must flag race-callback-under-lock."""
     src = _RUNNER.read_text()
-    needle = ("                with self._work:\n"
-              "                    h = self._handles.pop(c.request_id, None)\n"
-              "                    meta = self._replays.pop(c.request_id, None)\n")
+    needle = (
+        "                    with self._work:\n"
+        "                        h = self._handles.pop(c.request_id, None)\n"
+        "                        meta = self._replays.pop(c.request_id, None)\n")
     assert needle in src, "dispatcher harvest block moved; update the test"
     mutated = tmp_path / "async_runner_mutated.py"
     mutated.write_text(src.replace(
         needle,
-        needle + "                    if h is not None:\n"
-                 "                        h._resolve(c)\n", 1))
+        needle + "                        if h is not None:\n"
+                 "                            h._resolve(c)\n", 1))
     found = [f for f in analyze_files([mutated])
              if f.rule == "race-callback-under-lock"]
     assert any("_resolve" in f.message for f in found), found

@@ -776,20 +776,23 @@ class GenerationEngine:
             for the same reason: chaining windows IN-program amortizes
             the sync without growing the window buffers.
 
-            The big KV cache stays OUT of the inner scan carry: a
+            The big KV cache stays OUT of the token loop altogether: a
             per-step carried cache is re-materialized by XLA every token
             (~2× cache bytes — measured 2778→1841 tok/s going max_len
             256→512 with identical attended work, before this design).
-            Fresh KV accumulates in small [L, B, Hkv, W, Dh] window
-            buffers and merges into the cache once per window; only the
-            OUTER per-window scan carries the cache, so its
-            re-materialization amortizes over ``decode_window`` steps.
-            ``kv_len`` (static, bucketed by the caller) bounds the cache
-            prefix attention reads and must cover all n_windows."""
+            The program touches it twice per dispatch: the prefix
+            attention reads is cut out once, here, before the token
+            scan (a slice taken inside the scan's body is a cache-sized
+            copy per token — XLA does not hoist it), and fresh KV, which
+            accumulates in small [L, B, Hkv, W, Dh] window buffers,
+            merges in place once at the end. ``kv_len`` (static,
+            bucketed by the caller) bounds that prefix and must cover
+            all n_windows."""
             w_sz = self.decode_window
             n_l = cfg.n_layers
             b = tokens.shape[0]
             shape = (n_l, b, cfg.n_kv_heads, w_sz, cfg.head_dim)
+            prefix = decoder.cache_prefix(cache, kv_len)
 
             def run_window(tok, key, done):
                 k_win = jnp.zeros(shape, self.kv_dtype)
@@ -800,9 +803,8 @@ class GenerationEngine:
                     tok, k_win, v_win, key = carry
                     key, sub = jax.random.split(key)
                     logits, k_cols, v_cols = decoder.decode_step_windowed(
-                        params, tok, positions, w, cfg, cache, k_win,
-                        v_win, kv_len=kv_len, k_done=k_done,
-                        v_done=v_done)
+                        params, tok, positions, w, cfg, prefix, k_win,
+                        v_win, k_done=k_done, v_done=v_done)
                     k_win = decoder.put_window_column(k_win, k_cols, w)
                     v_win = decoder.put_window_column(v_win, v_cols, w)
                     nxt = sample(logits, sub, self.sampling)
@@ -2518,9 +2520,10 @@ class GenerationEngine:
         bucket = min(-(-(hi + 1) // 128) * 128, self.max_len)
         # A bucket below the full extent makes the decode program slice
         # the cache's sequence axis — a STRIDED slice XLA materializes
-        # as a full prefix copy (4.3 GB at 32x2304 — the rag2k OOM).
-        # Near the extent the read saving cannot pay for that copy, so
-        # snap to the full cache (slice = identity, zero-copy).
+        # as a full prefix copy, once per dispatch (4.3 GB of scratch at
+        # 32x2304 — the rag2k OOM). Near the extent the read saving
+        # cannot pay for that copy, so snap to the full cache (slice =
+        # identity, zero-copy).
         if bucket * 8 >= self.max_len * 7:
             return self.max_len
         return bucket
@@ -4269,9 +4272,13 @@ def _paged_mesh_contract_cases(cfg, group):
                   tbl(b, w), tbl(b, w), key),
             donate_argnums=(3, 4), kv_group=group,
             kv_caches=(("kv-pool-mesh", pool),),
+            # the view is dp-sharded over slots; merge_window's slab
+            # scatter batches over that axis, so it partitions with no
+            # collective (the per-column scatter it replaced all-gathered
+            # updates and indices: 6 all-gathers, 8 permutes then)
             hlo=HloSpec(
-                collectives={"all-reduce": 4, "all-gather": 6,
-                             "collective-permute": 8},
+                collectives={"all-reduce": 4, "all-gather": 2,
+                             "collective-permute": 4},
                 peak_bytes=240_000)),
         ContractCase(
             label="decode-paged-mesh-table", kv_group=tgroup,

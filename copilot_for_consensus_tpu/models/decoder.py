@@ -182,6 +182,19 @@ def cache_logical_axes() -> Params:
             "v": (None, "batch", "kv_heads", None, None)}
 
 
+def cache_prefix(cache: Params, kv_len: int | None) -> Params:
+    """The cache cut to its first ``kv_len`` (static) columns: what a
+    decode-side program attends to. Cut short of the full extent, each
+    half is a strided COPY of ``kv_len`` columns of every slot —
+    cache-sized work, so a program takes it once per dispatch, outside
+    any loop over tokens (the compiler does not hoist it)."""
+    if kv_len is None or kv_len >= cache["k"].shape[3]:
+        return cache
+    with scope("kv_prefix"):
+        return {"k": cache["k"][:, :, :, :kv_len],
+                "v": cache["v"][:, :, :, :kv_len]}
+
+
 def prefill(params: Params, tokens: jax.Array, lengths: jax.Array,
             cfg: DecoderConfig, cache: Params,
             attn_impl: str = "auto") -> tuple[jax.Array, Params]:
@@ -289,11 +302,7 @@ def verify_seeded(params: Params, tokens: jax.Array, lengths: jax.Array,
     [L, B, Hkv, S, Dh] — ``merge_window`` layout, for the engine's
     single end-of-dispatch scatter at the per-row offset)."""
     x = _embed(params, tokens)
-    k_pref, v_pref = cache["k"], cache["v"]
-    if kv_len is not None and kv_len < k_pref.shape[3]:
-        with scope("kv_prefix"):
-            k_pref = k_pref[:, :, :, :kv_len]
-            v_pref = v_pref[:, :, :, :kv_len]
+    pref = cache_prefix(cache, kv_len)
 
     def body(x, scanned):
         layer, k_pref_l, v_pref_l = scanned
@@ -307,7 +316,7 @@ def verify_seeded(params: Params, tokens: jax.Array, lengths: jax.Array,
         return x, (k, v)
 
     x, (k_new, v_new) = jax.lax.scan(
-        body, x, (params["layers"], k_pref, v_pref))
+        body, x, (params["layers"], pref["k"], pref["v"]))
     return _unembed(x, params, cfg), k_new, v_new
 
 
@@ -365,24 +374,25 @@ def decode_step_windowed(params: Params, tokens: jax.Array,
     allocation (the r2 OOM at kv extents > 256).
 
     tokens: [B]; positions0: [B] dispatch-start positions; ``w``: traced
-    in-window step index. Returns ([B, V] fp32 logits, k_cols, v_cols)
+    in-window step index. ``cache``: the halves attention reads, every
+    column of them. The engine calls this from the body of its token
+    scan with the prefix it cut before the scan (``cache_prefix``) and
+    no ``kv_len``; given a ``kv_len`` the cut is made here, on every
+    call — for a caller that holds a full cache and runs no loop over
+    tokens around this. Returns ([B, V] fp32 logits, k_cols, v_cols)
     where k_cols/v_cols [L, B, Hkv, Dh] are this step's new KV columns
     for the caller to slot into the window buffers at index ``w``.
     """
     x = _embed(params, tokens)[:, None, :]               # [B, 1, D]
-    # Static prefix slice BEFORE the layer scan, streamed per layer as
-    # scan xs (read-only, never in ys): attention reads exactly the
-    # occupied [0, kv_len) columns per layer and nothing writes back.
-    # A dynamic per-layer index into the full-extent cache instead
-    # materializes max_len-proportional layer copies (measured: going
-    # max_len 256→512 with identical kv_len cost ~12 ms/step).
-    k_pref, v_pref = cache["k"], cache["v"]
-    if kv_len is not None and kv_len < k_pref.shape[3]:
-        with scope("kv_prefix"):
-            k_pref = k_pref[:, :, :, :kv_len]
-            v_pref = v_pref[:, :, :, :kv_len]
+    # The prefix is streamed per layer as scan xs (read-only, never in
+    # ys): attention reads exactly the occupied [0, kv_len) columns per
+    # layer and nothing writes back. A dynamic per-layer index into the
+    # full-extent cache instead materializes max_len-proportional layer
+    # copies (measured: going max_len 256→512 with identical kv_len
+    # cost ~12 ms/step).
+    pref = cache_prefix(cache, kv_len)
     have_done = k_done is not None
-    xs = (params["layers"], jnp.arange(cfg.n_layers), k_pref, v_pref)
+    xs = (params["layers"], jnp.arange(cfg.n_layers), pref["k"], pref["v"])
     if have_done:
         xs = xs + (k_done, v_done)
 
@@ -578,13 +588,9 @@ def decode_step_piggyback(params: Params, tokens: jax.Array,
     pos_dec = (positions0 + w)[:, None]                    # [B, 1]
     pos_pre = pre_rope_base[:, None] + jnp.arange(c)[None, :]  # [P, C]
 
-    k_pref, v_pref = cache["k"], cache["v"]
-    if kv_len is not None and kv_len < k_pref.shape[3]:
-        with scope("kv_prefix"):
-            k_pref = k_pref[:, :, :, :kv_len]
-            v_pref = v_pref[:, :, :, :kv_len]
+    pref = cache_prefix(cache, kv_len)
     inv_freq = L.rope_frequencies(dh, cfg.rope_theta)
-    xs = (params["layers"], jnp.arange(cfg.n_layers), k_pref, v_pref)
+    xs = (params["layers"], jnp.arange(cfg.n_layers), pref["k"], pref["v"])
 
     def body(x, scanned):
         layer, li, k_pref_l, v_pref_l = scanned
@@ -695,28 +701,51 @@ def merge_prefill(cache: Params, k_buf: jax.Array, v_buf: jax.Array,
 @scope("kv_write")
 def merge_window(cache: Params, k_win: jax.Array, v_win: jax.Array,
                  positions0: jax.Array, steps: int) -> Params:
-    """Scatter a decode window's KV into the big cache, once.
+    """Write a dispatch's fresh KV into the big cache, once, in place.
 
-    k_win/v_win: [L, B, Hkv, W, Dh]; slot b's window columns land at
-    cache positions ``positions0[b] + [0, steps)``. Out-of-range columns
-    drop (same semantics as the per-step scatter this replaces). One
-    transpose per window puts W in front of the head axis to match the
-    advanced-indexing update shape [B, W, L, H, D].
+    k_win/v_win: [L, B, Hkv, W, Dh]; slot b's first ``steps`` window
+    columns land at cache positions ``positions0[b] + [0, steps)``, and
+    a column whose position is at or past the cache extent is DROPPED
+    (free and prefilling slots park there; a live slot near the end
+    loses only what does not fit).
+
+    Each slot's columns go in as ONE slab [L, Hkv, W, Dh] at
+    ``(b, start[b])``: a scatter whose only index is the slab's first
+    column and whose batch axis is the cache's own, which XLA:TPU
+    expands to a loop of in-place ``dynamic-update-slice`` on the
+    donated buffer and GSPMD partitions over a sharded slot axis with
+    no collective. (A scatter with one index per COLUMN,
+    ``.at[:, bidx, :, pidx, :]``, wants its operand with the sequence
+    axis ahead of the heads: XLA copied each half into that layout and
+    back, four cache-sized copies a dispatch to write W columns a
+    slot.) A slab cannot drop single columns, so one that would run
+    past the extent is laid over the last W columns instead, its
+    window columns shifted to their positions and the columns before
+    them filled with what the cache holds there. Layout assignment is
+    touchy here — the same slabs written by a vmapped
+    ``dynamic_update_slice``, or their old columns read by a gather,
+    bring cache-sized copies back — so tests/test_decode_structure.py
+    compiles the decode program for the chip and holds it to none.
     """
-    b = k_win.shape[1]
-    w = k_win.shape[3]
-    bidx = jnp.broadcast_to(jnp.arange(b)[:, None], (b, w))
-    pidx = positions0[:, None] + jnp.arange(w)[None, :]
-    if steps < w:
-        k_win = k_win[:, :, :, :steps]
-        v_win = v_win[:, :, :, :steps]
-        bidx, pidx = bidx[:, :steps], pidx[:, :steps]
-    k_upd = k_win.transpose(1, 3, 0, 2, 4)     # [B, W, L, H, D]
-    v_upd = v_win.transpose(1, 3, 0, 2, 4)
-    # cache axes [L, B, H, S, D]; advanced indices on axes 1 and 3 put
-    # the [B, W] index shape in front: update shape [B, W, L, H, D].
-    k = cache["k"].at[:, bidx, :, pidx, :].set(
-        k_upd.astype(cache["k"].dtype), mode="drop")
-    v = cache["v"].at[:, bidx, :, pidx, :].set(
-        v_upd.astype(cache["v"].dtype), mode="drop")
-    return {"k": k, "v": v}
+    s_max = cache["k"].shape[3]
+    # a window column at index >= s_max can land nowhere (positions >= 0)
+    w = min(k_win.shape[3], steps, s_max)
+    start = jnp.clip(positions0, 0, s_max - w)     # [B] first slab column
+    shift = positions0 - start       # > 0 only where start is s_max - w
+    fresh = (jnp.arange(w)[None, :] >= shift[:, None])[None, :, None, :, None]
+    roll = jax.vmap(lambda win_b, n: jnp.roll(win_b, n, axis=2),
+                    in_axes=(1, 0), out_axes=1)
+    dnums = jax.lax.ScatterDimensionNumbers(
+        update_window_dims=(1, 2, 3, 4), inserted_window_dims=(),
+        scatter_dims_to_operand_dims=(3,), operand_batching_dims=(1,),
+        scatter_indices_batching_dims=(0,))
+
+    def merge(half, win):
+        new = roll(win[:, :, :, :w], shift).astype(half.dtype)
+        slab = jnp.where(fresh, new, half[:, :, :, s_max - w:])
+        return jax.lax.scatter(
+            half, start[:, None], slab.transpose(1, 0, 2, 3, 4), dnums,
+            unique_indices=True,
+            mode=jax.lax.GatherScatterMode.PROMISE_IN_BOUNDS)
+
+    return {"k": merge(cache["k"], k_win), "v": merge(cache["v"], v_win)}

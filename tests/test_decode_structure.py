@@ -1,0 +1,313 @@
+# The decode program's handling of the slot cache (engine/generation.py
+# `_decode`, models/decoder.py `cache_prefix` / `merge_window`): the
+# prefix is cut once per dispatch, fresh KV goes in as one slab per slot,
+# and the compiled program holds no cache-sized copy. Three kinds of
+# check: the merge against the per-column scatter it replaced, served
+# tokens pinned from the commit before the change, and the structure of
+# the traced and of the compiled program (the latter for a described
+# v5e, no chip needed), each shown to trip on the old formulation.
+import functools
+import re
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from copilot_for_consensus_tpu.engine.generation import GenerationEngine
+from copilot_for_consensus_tpu.models import decoder
+from copilot_for_consensus_tpu.models.configs import (
+    DecoderConfig,
+    decoder_config,
+)
+
+
+def merge_window_by_column(cache, k_win, v_win, positions0, steps):
+    """The reference: `merge_window` as it was through PR 26, a scatter
+    with one index per (slot, column); out-of-range columns drop."""
+    b = k_win.shape[1]
+    w = k_win.shape[3]
+    bidx = jnp.broadcast_to(jnp.arange(b)[:, None], (b, w))
+    pidx = positions0[:, None] + jnp.arange(w)[None, :]
+    if steps < w:
+        k_win = k_win[:, :, :, :steps]
+        v_win = v_win[:, :, :, :steps]
+        bidx, pidx = bidx[:, :steps], pidx[:, :steps]
+    k_upd = k_win.transpose(1, 3, 0, 2, 4)     # [B, W, L, H, D]
+    v_upd = v_win.transpose(1, 3, 0, 2, 4)
+    k = cache["k"].at[:, bidx, :, pidx, :].set(
+        k_upd.astype(cache["k"].dtype), mode="drop")
+    v = cache["v"].at[:, bidx, :, pidx, :].set(
+        v_upd.astype(cache["v"].dtype), mode="drop")
+    return {"k": k, "v": v}
+
+
+# ---------------------------------------------------------------------------
+# merge_window == the per-column scatter
+# ---------------------------------------------------------------------------
+
+S_MAX = 48
+
+
+@pytest.mark.parametrize("kv_dtype", [jnp.bfloat16, jnp.float8_e4m3fn],
+                         ids=["bf16", "fp8"])
+@pytest.mark.parametrize("slots", [4, 64])
+@pytest.mark.parametrize("w,steps", [(8, 8), (8, 5), (24, 24), (5, 5)],
+                         ids=["window8", "steps5of8", "chunk24",
+                              "verify5"])
+def test_merge_window_equals_column_scatter(kv_dtype, slots, w, steps):
+    n_l, h, d = 2, 2, 8
+    rng = np.random.default_rng(slots * 100 + w)
+
+    def rand(shape, dtype):
+        return jnp.asarray(rng.normal(size=shape), jnp.float32).astype(dtype)
+
+    cache = {n: rand((n_l, slots, h, S_MAX, d), kv_dtype) for n in "kv"}
+    k_win = rand((n_l, slots, h, w, d), jnp.bfloat16)
+    v_win = rand((n_l, slots, h, w, d), jnp.bfloat16)
+    pos = rng.integers(0, S_MAX - w, size=slots)       # in range
+    pos[0] = S_MAX                  # parked: a free or prefilling slot
+    pos[1] = S_MAX - 3              # live, within w of the extent
+    pos[2] = S_MAX - w              # the last slab that fits whole
+    pos[3] = 0
+    pos = jnp.asarray(pos, jnp.int32)
+    want = jax.jit(merge_window_by_column, static_argnames="steps")(
+        cache, k_win, v_win, pos, steps=steps)
+    got = jax.jit(decoder.merge_window, static_argnames="steps")(
+        cache, k_win, v_win, pos, steps=steps)
+    for n in "kv":
+        assert got[n].dtype == kv_dtype
+        np.testing.assert_array_equal(
+            np.asarray(got[n].astype(jnp.float32)),
+            np.asarray(want[n].astype(jnp.float32)))
+    # and something was written: slot 3's first column is the window's
+    assert np.array_equal(
+        np.asarray(got["k"][:, 3, :, 0].astype(jnp.float32)),
+        np.asarray(k_win[:, 3, :, 0].astype(kv_dtype).astype(jnp.float32)))
+
+
+def test_merge_window_wider_than_the_cache_keeps_what_fits():
+    """A view narrower than the window (the paged reference route hands
+    `_prefill_chunk` such views): columns past the extent drop."""
+    n_l, slots, h, d, s_max, w = 1, 4, 1, 4, 6, 8
+    cache = {n: jnp.zeros((n_l, slots, h, s_max, d), jnp.float32)
+             for n in "kv"}
+    win = jnp.arange(1, w + 1, dtype=jnp.float32)[None, None, None, :, None] \
+        * jnp.ones((n_l, slots, h, w, d), jnp.float32)
+    pos = jnp.asarray([0, 2, 6, 5], jnp.int32)
+    want = merge_window_by_column(cache, win, win, pos, w)
+    got = decoder.merge_window(cache, win, win, pos, w)
+    np.testing.assert_array_equal(np.asarray(got["k"]),
+                                  np.asarray(want["k"]))
+    assert np.asarray(got["v"][0, 1, 0, :, 0]).tolist() == [0, 0, 1, 2, 3, 4]
+
+
+# ---------------------------------------------------------------------------
+# served tokens, pinned from the parent commit
+# ---------------------------------------------------------------------------
+
+# Greedy tokens of the engine below at commit 7d9e1fb (PR 26), where the
+# prefix was cut inside the token scan and the merge was the per-column
+# scatter. Four decode dispatches of 8 steps; the longer request's cache
+# prefix passes 128 after the second, so the program's kv_len goes
+# 128, 128, 256, 256. The smallest gap between the best and the second
+# logit along both paths is 0.009 (naive f32 forward), far above what a
+# different CPU's rounding moves.
+PINNED = [
+    [9, 477, 209, 430, 450, 291, 56, 465, 9, 477, 209, 103, 353, 65, 407,
+     398, 291, 257, 291, 257, 9, 136, 108, 9, 477, 371, 313, 494, 53, 477],
+    [113, 123, 303, 412, 9, 477, 97, 110, 123, 478, 9, 414, 325, 477, 123,
+     60, 97, 65, 477, 293, 291, 497, 65, 477, 313, 65, 477, 65, 477, 65],
+]
+
+
+def test_greedy_tokens_across_a_kv_len_boundary_match_the_parent():
+    cfg = decoder_config("tiny")
+    params = decoder.init_params(jax.random.PRNGKey(11), cfg,
+                                 dtype=jnp.float32)
+    eng = GenerationEngine(cfg, params, num_slots=4, max_len=512,
+                           prefill_buckets=(64, 128), dtype=jnp.float32,
+                           attn_impl="xla", eos_id=-1, decode_window=8)
+    kv_lens = []
+    decode_fn = eng._decode_fn
+
+    def spy(*args, **kw):
+        kv_lens.append(kw["kv_len"])
+        return decode_fn(*args, **kw)
+
+    eng._decode_fn = spy
+    prompts = [[3 + (i * 7) % 50 for i in range(118)],
+               [5 + (i * 3) % 40 for i in range(41)]]
+    comps = eng.generate(prompts, max_new_tokens=30)
+    assert kv_lens == [128, 128, 256, 256]
+    assert [c.tokens for c in comps] == PINNED
+
+
+# ---------------------------------------------------------------------------
+# structure of the decode program
+# ---------------------------------------------------------------------------
+
+# large enough that the chip's compiler treats the cache as it does the
+# served one (a half under ~20 MB is prefetched whole into fast memory,
+# which reads as a cache-sized copy): 2 x 8 x 2 x 4096 x 128 bf16 = 33.5 MB
+STRUCT_CFG = DecoderConfig(name="structure", vocab_size=512, d_model=256,
+                           n_layers=2, n_heads=2, n_kv_heads=2, d_ff=512,
+                           max_seq_len=8192)
+SLOTS, MAX_LEN, KV_LEN = 8, 4096, 512
+
+
+@pytest.fixture(scope="module")
+def struct_engine():
+    return GenerationEngine(STRUCT_CFG, num_slots=SLOTS, max_len=MAX_LEN,
+                            prefill_buckets=(64,), dtype=jnp.bfloat16,
+                            attn_impl="xla", eos_id=-1)
+
+
+@pytest.fixture
+def old_program(monkeypatch):
+    """The decode program as it was: the prefix cut inside the step
+    function, which the token scan calls, and the per-column merge."""
+    step, cut = decoder.decode_step_windowed, decoder.cache_prefix
+
+    def cutting_step(params, tok, pos, w, cfg, cache, k_win, v_win, **kw):
+        return step(params, tok, pos, w, cfg, cut(cache, KV_LEN), k_win,
+                    v_win, **kw)
+
+    monkeypatch.setattr(decoder, "decode_step_windowed", cutting_step)
+    monkeypatch.setattr(decoder, "cache_prefix",
+                        lambda cache, kv_len: cache)
+    monkeypatch.setattr(decoder, "merge_window", merge_window_by_column)
+    # the engine's jit has the new program's trace cached, under the
+    # function it wraps: trace through a function of this test's own,
+    # which also leaves nothing of the old program cached behind
+    def rejit(eng):
+        def _decode(*args, **kw):
+            return eng._decode_fn.__wrapped__(*args, **kw)
+
+        return jax.jit(_decode, donate_argnums=(3,),
+                       static_argnames=("kv_len", "n_windows"))
+
+    return rejit
+
+
+def _decode_args(eng, wrap):
+    i32 = wrap(jax.ShapeDtypeStruct((SLOTS,), jnp.int32))
+    return (jax.tree.map(wrap, eng.params), i32, i32,
+            jax.tree.map(wrap, eng._cache), wrap(jax.random.PRNGKey(0)))
+
+
+def _is_cache_like(shape) -> bool:
+    """A cache half, or its prefix: [L, B, Hkv, >= kv_len, Dh]."""
+    cfg = STRUCT_CFG
+    return (len(shape) == 5
+            and tuple(shape[:3]) == (cfg.n_layers, SLOTS, cfg.n_kv_heads)
+            and shape[3] >= KV_LEN and shape[4] == cfg.head_dim)
+
+
+def _sub_jaxprs(params):
+    for v in params.values():
+        for x in (v if isinstance(v, (list, tuple)) else [v]):
+            x = getattr(x, "jaxpr", x)
+            if hasattr(x, "eqns"):
+                yield x
+
+
+def cache_traffic_faults(jaxpr, in_loop=False):
+    """What the traced decode program may not do with a cache-sized
+    array: read a part of it inside a loop (a copy per iteration that
+    XLA does not hoist), or scatter into it by column (the sequence axis
+    must be inside the update window, or XLA:TPU relayouts the operand
+    for the scatter and back)."""
+    faults = []
+    for eqn in jaxpr.eqns:
+        name = eqn.primitive.name
+        operand = eqn.invars[0].aval.shape if eqn.invars else ()
+        if (in_loop and name in ("slice", "dynamic_slice", "gather")
+                and _is_cache_like(operand)):
+            faults.append(f"{name} of {operand} inside a loop body")
+        if name.startswith("scatter") and _is_cache_like(operand):
+            dn = eqn.params["dimension_numbers"]
+            if 3 in dn.inserted_window_dims + dn.operand_batching_dims:
+                faults.append(f"{name} into {operand} by column")
+        loops = in_loop or name in ("scan", "while")
+        for sub in _sub_jaxprs(eqn.params):
+            faults += cache_traffic_faults(sub, loops)
+    return faults
+
+
+def _traced(eng, decode_fn=None):
+    fn = functools.partial(decode_fn or eng._decode_fn, kv_len=KV_LEN,
+                           n_windows=1)
+    return jax.make_jaxpr(fn)(*_decode_args(eng, lambda a: a)).jaxpr
+
+
+def test_traced_decode_keeps_the_cache_out_of_the_token_loop(struct_engine):
+    assert cache_traffic_faults(_traced(struct_engine)) == []
+
+
+def test_traced_guard_trips_on_the_old_program(struct_engine, old_program):
+    faults = cache_traffic_faults(
+        _traced(struct_engine, old_program(struct_engine)))
+    assert sum("slice" in f and "inside a loop" in f for f in faults) == 2
+    assert sum("by column" in f for f in faults) == 2
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    from jax.experimental import topologies
+    from jax.sharding import SingleDeviceSharding
+
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    return SingleDeviceSharding(topo.devices[0])
+
+
+def compiled_cache_ops(eng, sharding, decode_fn=None):
+    """Every op of the decode program compiled for the chip whose result
+    is a cache half or a prefix of one, as (opcode, is it inside a while
+    loop, is it the whole extent)."""
+    def wrap(a):
+        return jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=sharding)
+
+    text = (decode_fn or eng._decode_fn).lower(
+        *_decode_args(eng, wrap), kv_len=KV_LEN,
+        n_windows=1).compile().as_text()
+    calls, found, comp = {}, [], None
+    for line in text.splitlines():
+        if line and not line[0].isspace() and line.rstrip().endswith("{"):
+            comp = line.split()[1 if line.startswith("ENTRY") else 0]
+            comp = comp.lstrip("%")
+        calls.setdefault(comp, set()).update(re.findall(
+            r"(?:calls|body|condition|to_apply)=%?([\w.\-]+)", line))
+        m = re.match(r"\s*(?:ROOT )?%?[\w.\-]+ = \w+\[([\d,]+)\]\S* "
+                     r"([\w\-]+)\(", line)
+        if m and m.group(2) not in ("parameter", "get-tuple-element",
+                                    "bitcast"):
+            shape = tuple(int(x) for x in m.group(1).split(","))
+            if _is_cache_like(shape):
+                found.append((m.group(2), comp, shape[3] == MAX_LEN))
+    # what runs inside some while loop: the bodies, and whatever they call
+    looped = frontier = set(re.findall(r"body=%?([\w.\-]+)", text))
+    while frontier:
+        frontier = {c for f in frontier for c in calls.get(f, ())} - looped
+        looped = looped | frontier
+    return [(op, comp in looped, whole) for op, comp, whole in found]
+
+
+def test_compiled_decode_holds_no_cache_sized_copy(struct_engine, one_chip):
+    ops = compiled_cache_ops(struct_engine, one_chip)
+    # the prefix: one slice a half, once per dispatch
+    assert sorted(o for o in ops if not o[2]) == [("slice", False, False)] * 2
+    # the cache itself: only updated where it lies (the expanded scatter)
+    assert {o[0] for o in ops if o[2]} == {"dynamic-update-slice"}
+
+
+def test_compiled_guard_trips_on_the_old_program(struct_engine, one_chip,
+                                                 old_program):
+    ops = compiled_cache_ops(struct_engine, one_chip,
+                             old_program(struct_engine))
+    assert ("slice", True, False) in ops        # re-cut every token
+    assert sum(o == ("copy", False, True) for o in ops) == 4

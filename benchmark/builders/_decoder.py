@@ -7,9 +7,18 @@ from __future__ import annotations
 import numpy as np
 
 
+#: what a configuration file says about itself, its serving and its
+#: checks: every other key is the model's, as its source publishes it
+FILE_KEYS = frozenset((
+    "name", "source", "builder", "reference", "serving", "engine",
+    "reduced", "assumed", "correct", "rehearsal", "why"))
+
+
 def dims_of(cfg_data: dict, rehearse: bool) -> dict:
-    dims = {k: v for k, v in cfg_data.items()
-            if isinstance(v, (int, float)) and not isinstance(v, bool)}
+    """The model's keys, numbers or not: a layer pattern, a list of
+    layer types or a nested ``rope_scaling`` reaches the builder and
+    the reference as the file has it."""
+    dims = {k: v for k, v in cfg_data.items() if k not in FILE_KEYS}
     if rehearse:
         dims.update(cfg_data["rehearsal"]["model"])
     return dims

@@ -1,12 +1,13 @@
 """What a builder reads from a GenerationEngine: a tap on its public
 ``submit``/``step`` (prompt and served tokens per request id), its
 request traces and its step records, read incrementally because the
-flight recorder is a ring."""
+flight recorder is a ring. A step record is copied as the program
+wrote it: how many tokens a dispatch handed to requests is the
+program's own ``new_tokens``, whatever the dispatch's kind."""
 
 from __future__ import annotations
 
 import threading
-import time
 
 
 class EngineTap:
@@ -16,8 +17,6 @@ class EngineTap:
         self.completions: dict[int, object] = {}
         self.steps: dict[int, dict] = {}
         self.traces: dict[int, object] = {}
-        self.piggy: dict[int, tuple[int, int]] = {}
-        self.wall_minus_mono = time.time() - time.monotonic()
         self._stop = threading.Event()
         self._thread = None
         submit, step = engine.submit, engine.step
@@ -28,19 +27,7 @@ class EngineTap:
             return rid
 
         def tapped_step():
-            before = engine.piggy_tokens, engine.piggy_rows
             comps = step()
-            if engine.piggy_tokens != before[0]:
-                # this call's piggyback dispatch prefilled whole
-                # prompts: its record, the newest of that kind, counts
-                # their tokens among its own and leaves out the first
-                # token it sampled for each
-                for r in reversed(engine.telemetry.recorder.records()):
-                    if r.kind == "piggyback":
-                        self.piggy[r.seq] = (
-                            engine.piggy_tokens - before[0],
-                            engine.piggy_rows - before[1])
-                        break
             for c in comps:
                 self.completions[c.request_id] = c
             return comps
@@ -51,12 +38,17 @@ class EngineTap:
         tel = self.engine.telemetry
         for r in tel.recorder.records():
             if r.seq not in self.steps:
+                # a record without these fields is an error here, not
+                # a zero in a rate later
                 self.steps[r.seq] = {
                     "seq": r.seq, "kind": r.kind,
-                    "t_end": r.t_wall - self.wall_minus_mono,
+                    "t_start": r.t_start, "t_end": r.t_end,
                     "duration_s": r.duration_s, "rows": r.rows,
                     "batch": r.batch, "tokens": r.tokens,
-                    "padded_tokens": r.padded_tokens}
+                    "padded_tokens": r.padded_tokens,
+                    "new_tokens": r.new_tokens,
+                    "prompt_tokens": r.prompt_tokens,
+                    "first_use": r.first_use}
         for tr in list(tel.completed):
             self.traces[tr.request_id] = tr
 
@@ -75,14 +67,7 @@ class EngineTap:
         self.poll()
 
     def step_list(self) -> list[dict]:
-        out = []
-        for k in sorted(self.steps):
-            s = self.steps[k]
-            if s["kind"] == "piggyback":
-                prompt, first = self.piggy.get(k, (0, 0))
-                s = dict(s, prompt_tokens=prompt, first_tokens=first)
-            out.append(s)
-        return out
+        return [self.steps[k] for k in sorted(self.steps)]
 
     def engine_requests(self) -> list[dict]:
         """One record per request the engine retired: for the layer
@@ -100,6 +85,7 @@ class EngineTap:
                 "finished_at": tr.finished_at,
                 "new_tokens": tr.new_tokens,
                 "finish_reason": tr.finish_reason,
+                "stalled_s": tr.stalled_s, "host_s": tr.host_s,
                 "prompt": (prompt[-tr.prompt_len:]
                            if prompt is not None else None),
                 "tokens": list(comp.tokens) if comp is not None else None,

@@ -1,6 +1,12 @@
 """Builder: a bare GenerationEngine behind an AsyncEngineRunner, with
 the constructor arguments of the configuration file and the benchmark's
-own seeded weights."""
+own seeded weights.
+
+What is shaped by the model is in three methods, ``make_dims``,
+``make_weights`` and ``program_config``. The builder of another
+architecture that ``GenerationEngine`` serves is a file with a
+subclass named ``System`` that overrides them; the tap, the runner,
+the load, the records and the warm-up are inherited."""
 
 from __future__ import annotations
 
@@ -33,18 +39,18 @@ class System:
 
         self.log, self.seed, self.parts = log, seed, {}
         data = cell["config_data"]
-        self.dims = dims_of(data, rehearse)
+        self.dims = self.make_dims(data, rehearse)
         eng_args = dict(data["engine"])
         if rehearse:
             eng_args.update(data["rehearsal"]["engine"])
         eng_args["prefill_buckets"] = tuple(eng_args["prefill_buckets"])
         t0 = time.monotonic()
-        self.weights = W.decoder_weights(self.dims, seed)
+        self.weights = self.make_weights(seed)
         jax.block_until_ready(self.weights)
         self.parts["weights_s"] = time.monotonic() - t0
         t0 = time.monotonic()
         self.engine = GenerationEngine(
-            decoder_config(self.dims, cell["config"]), self.weights,
+            self.program_config(cell["config"]), self.weights,
             dtype=jnp.bfloat16, seed=seed & 0x7FFFFFFF, **eng_args)
         self.parts["build_s"] = time.monotonic() - t0
         self.tap = EngineTap(self.engine)
@@ -57,6 +63,23 @@ class System:
         self._cv = threading.Condition()
         self._stopping = False
         self._tag = "b"
+
+    # -- the model's shape -------------------------------------------------
+
+    def make_dims(self, data: dict, rehearse: bool) -> dict:
+        """The model's own keys of the configuration file: what the
+        weights, the program's config, the reference and the roofline
+        functions read."""
+        return dims_of(data, rehearse)
+
+    def make_weights(self, seed: int):
+        """Seeded weights on the device, in the types and the layout
+        the program serves; the same arrays go to the reference."""
+        return W.decoder_weights(self.dims, seed)
+
+    def program_config(self, name: str):
+        """The program's own config object for ``self.dims``."""
+        return decoder_config(self.dims, name)
 
     # -- set-up ----------------------------------------------------------
 
@@ -141,7 +164,7 @@ class System:
             for leaf in jax.tree.leaves(old):
                 leaf.delete()
             self.seed = seed
-            self.weights = W.decoder_weights(self.dims, seed)
+            self.weights = self.make_weights(seed)
             self.engine.params = self.weights
             self._rng = np.random.default_rng(seed)
 
@@ -162,6 +185,8 @@ class System:
                 "finished_at": tr["finished_at"] if tr else None,
                 "admitted_at": tr["admitted_at"] if tr else None,
                 "new_tokens": tr["new_tokens"] if tr else 0,
+                "stalled_s": tr["stalled_s"] if tr else None,
+                "host_s": tr["host_s"] if tr else None,
             })
         return {"requests": requests, "steps": self.tap.step_list(),
                 "engine_requests": list(by_corr.values()),

@@ -1,9 +1,13 @@
 """Closed loop over ONE fixed cycle of request shapes: the queue is kept
-non-empty, the cycle's order is dealt once in stratified blocks from
-``pair_key``, and ``--seed`` decides where in the cycle the run starts
-(and, in the builder, weights and token ids), nothing else. (Dealt from
-the seed, six seeds spread `out_tok_s` by 2.0% where one seed repeated
-to 0.2%: my chip runs, PR 25.)"""
+non-empty, and the cycle's order is dealt once in stratified blocks from
+``pair_key``. Every run starts at the head of the cycle: ``--seed``
+decides weights and token ids (in the builder) and nothing of the
+sizes or their order. (Dealt from the seed, six seeds spread
+`out_tok_s` by 2.0% where one seed repeated to 0.2%: my chip runs,
+PR 25. Until PR 28 the seed picked where in the cycle a run started;
+once a window held 1.3 cycles instead of 1.1 that too was the work:
+six seeds ranged 2.7% where one seed repeated to 0.01-0.4%, and the
+driver's check read 2.5%: PERF.md section 6, PR 28.)"""
 
 from __future__ import annotations
 
@@ -33,12 +37,11 @@ def shapes(t: dict) -> list[dict]:
 def plan(traffic: dict, seed: int, seconds: float) -> dict:
     t = traffic
     ring = shapes(t)
-    offset = random.Random(seed).randrange(len(ring))
     need = int(seconds * float(t["max_requests_per_s"])) + len(ring)
     need = -(-need // len(ring)) * len(ring)        # whole cycles
     items = [{"phase": "closed", "due": None,
-              "prompt_len": ring[(offset + k) % len(ring)]["prompt_len"],
-              "new_tokens": ring[(offset + k) % len(ring)]["new_tokens"]}
+              "prompt_len": ring[k % len(ring)]["prompt_len"],
+              "new_tokens": ring[k % len(ring)]["new_tokens"]}
              for k in range(need)]
     lead_s = float(t["lead_in_share"]) * seconds
     return {"mode": "closed", "items": items,

@@ -2,15 +2,34 @@
 requests the window finished, the longest among them, is replayed once
 through the plain reference (prompt plus served tokens), and at every
 served position the served token's logit is held against the
-reference's best. Greedy serving only."""
+reference's best. Greedy serving only.
+
+The reference is the module ``benchmark/reference/<name>.py`` that the
+cell's configuration file names under ``"reference"``; this file
+imports none. What such a module offers:
+
+``logits_at(weights, dims, tokens, positions, lower=None, pad_to=0)``
+    float32 ``[len(positions), vocab]``: the logits that follow
+    ``tokens[: p + 1]`` for each p, from a forward pass in float32 at
+    ``highest`` matmul precision with no cache, batching or kernels,
+    over the weights and dims that the configuration's builder made
+    (``System.weights``, ``System.dims``). It imports nothing of the
+    program. ``pad_to`` lets a run compile one length for all its
+    passes.
+``padded_len(n)``
+    the length a sequence of ``n`` tokens is padded to.
+``LOWERS``
+    the lower-precision controls it can compute, as values of
+    ``lower``: each the same pass in a precision below the one the
+    configuration states. The tests hold every limit of ``correct``
+    against them.
+"""
 
 from __future__ import annotations
 
 import random
 
 import numpy as np
-
-from benchmark.reference import decoder as ref
 
 
 def sample_requests(engine_requests, since: float, seed: int, k: int):
@@ -27,9 +46,10 @@ def sample_requests(engine_requests, since: float, seed: int, k: int):
     return [longest] + rest[:max(0, k - 1)]
 
 
-def logit_gaps(weights, dims, sample, lowers=()) -> dict:
+def logit_gaps(ref, weights, dims, sample, lowers=()) -> dict:
     """gap = reference's best logit minus the reference's logit of the
-    served token, per served position. For each lower precision in
+    served token, per served position; ``ref`` is the reference module
+    the configuration names. For each lower precision in
     ``lowers`` (the control): the same gap for the token that the
     reference computed in that precision puts first. ``rows`` keeps
     the gaps request by request, for the tools that set the limits."""

@@ -26,9 +26,8 @@ to the innermost scope name in its path, or to ``_unscoped_``.
 from __future__ import annotations
 
 import bisect
-import glob
 
-from benchmark.harness import spec, trace_reduce
+from benchmark.harness import trace_reduce
 
 UNSCOPED = "_unscoped_"
 OUTSIDE = "_no_program_"
@@ -197,6 +196,29 @@ def scope_of(path: str | None, scopes) -> str:
     return UNSCOPED
 
 
+def scope_of_ops(planes: list[dict], scopes) -> dict:
+    """HLO name -> the scope under which the events of that name spent
+    most of their time. One name is one op of one program, so as a rule
+    it has one scope; two programs can each have a ``fusion.7``, and
+    the per-name sums of ``trace_reduce`` add them as they always did."""
+    seconds: dict[str, dict] = {}
+    for plane in planes:
+        for start, end, name, path in plane["ops"]:
+            by = seconds.setdefault(name, {})
+            scope = scope_of(path, scopes)
+            by[scope] = by.get(scope, 0) + end - start
+    return {name: max(by, key=by.get) for name, by in seconds.items()}
+
+
+def program_scopes():
+    """The scope names the program declares, or None."""
+    try:
+        from copilot_for_consensus_tpu.obs.profile import SCOPES
+    except ImportError:
+        return None
+    return SCOPES
+
+
 def reduce_planes(planes: list[dict], scopes) -> dict:
     """{program: {"device_s": seconds of its XLA Modules events,
     "self_s": {scope: seconds}}}, averaged over the device planes."""
@@ -234,18 +256,11 @@ def for_run(run: dict) -> dict | None:
     if "scope_table" in run:
         return run["scope_table"]
     run["scope_table"] = None
-    if not run.get("trace"):
+    planes = (run.get("trace") or {}).get("device_planes")
+    scopes = program_scopes()
+    if not planes or scopes is None:
         return None
-    try:
-        from copilot_for_consensus_tpu.obs.profile import SCOPES
-    except ImportError:
-        return None
-    files = glob.glob(str(spec.BENCH / ".cache" / "trace"
-                          / run["cell"]["name"] / "**" / "*.xplane.pb"),
-                      recursive=True)
-    if not files:
-        return None
-    table = reduce_file(files[0], SCOPES)
+    table = reduce_planes(planes, scopes)
     for name, prog in sorted(table.items()):
         total = sum(prog["self_s"].values())
         if total > 0:

@@ -1,6 +1,6 @@
 """Finds a cell's files by the names in BENCHMARK.json. A later PR adds
-a configuration, a traffic mix, a metric or a reader as new files; no
-file here lists them."""
+a configuration, its builder and its plain reference, a traffic mix, a
+metric or a reader as new files; no file here lists them."""
 
 from __future__ import annotations
 
@@ -27,6 +27,12 @@ def load_cell(workload: str) -> dict:
     configs = {c["name"]: c for c in bench["configs"]}
     cell["config_file"] = configs[cell["config"]]["file"]
     cell["config_data"] = _json(REPO / cell["config_file"])
+    # no default for either: a cell is never built by, or held
+    # against, a module that its configuration did not name
+    for key, group in (("builder", "builders"), ("reference", "reference")):
+        if not cell["config_data"].get(key):
+            raise SystemExit(f"{cell['config_file']} has no {key!r}: it "
+                             f"names a module of benchmark/{group}/")
     cell["traffic_data"] = _json(
         BENCH / "traffic" / f"{cell['traffic']}.json")
     return cell

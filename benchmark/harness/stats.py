@@ -69,29 +69,13 @@ def e2e_values(requests, window) -> list[float]:
     return good + [worst] * (len(rows) - len(good))
 
 
-def generated_tokens(step) -> int:
-    """Tokens a step handed to requests. A decode dispatch emits
-    ``tokens``. An admission wave samples one first token per row (its
-    ``tokens`` field counts PROMPT tokens). A piggyback dispatch's
-    ``tokens`` is decoded tokens PLUS the prompt tokens its chunk grid
-    prefilled, and leaves out the first token it sampled for each
-    prompt it finished; the tap reads both from the engine's counters
-    into ``prompt_tokens`` and ``first_tokens``, and a record without
-    them is an error, not a larger rate."""
-    if step["kind"] in ("decode", "verify"):
-        return int(step["tokens"])
-    if step["kind"] == "piggyback":
-        return (int(step["tokens"]) - int(step["prompt_tokens"])
-                + int(step["first_tokens"]))
-    if step["kind"].startswith("prefill"):
-        return int(step["rows"])
-    return 0
-
-
 def out_tok_s(steps, window) -> float | None:
-    """Generated tokens of the steps whose host fetch ended inside the
-    counted interval, the first such step left out, over the time from
-    that first fetch to the last. Continuous in every timestamp."""
+    """Tokens handed to requests by the steps whose host fetch ended
+    inside the counted interval, the first such step left out, over the
+    time from that first fetch to the last. Continuous in every
+    timestamp. The count is the program's own, ``new_tokens`` of each
+    step record, so a dispatch of any kind is counted for what it
+    delivered; a record without it is an error, not a zero."""
     t0, t1 = window
     inside = sorted((s for s in steps if t0 <= s["t_end"] < t1),
                     key=lambda s: s["t_end"])
@@ -100,7 +84,7 @@ def out_tok_s(steps, window) -> float | None:
     span = inside[-1]["t_end"] - inside[0]["t_end"]
     if span <= 0:
         return None
-    return sum(generated_tokens(s) for s in inside[1:]) / span
+    return sum(int(s["new_tokens"]) for s in inside[1:]) / span
 
 
 def spread(values) -> float:

@@ -109,10 +109,14 @@ def read_planes(path: str) -> dict:
             "compiles": compiles, "lo": lo, "hi": hi}
 
 
-def reduce_planes(planes: dict, top: int = 10) -> dict:
+def reduce_planes(planes: dict, top: int = 10,
+                  scope_of_op: dict | None = None) -> dict:
     """busy / window / idle gaps by host annotation / per-op sums /
     device time of each annotated step. Busy seconds are averaged over
-    the device planes."""
+    the device planes. The sums are per HLO name; with ``scope_of_op``
+    (HLO name -> the program's scope it ran under) an op is printed as
+    ``ffn/fusion.231``, which says what it is where a bare
+    ``fusion.231`` does not."""
     devices = planes["devices"]
     if not devices:
         # a CPU rehearsal: no device plane, nothing to reduce
@@ -152,14 +156,27 @@ def reduce_planes(planes: dict, top: int = 10) -> dict:
                       "host_s": n["end"] - n["start"], "modules": mine})
     n_dev = len(devices)
     rank = lambda d: sorted(d.items(), key=lambda kv: -kv[1])[:top]  # noqa: E731
+    named = scope_of_op or {}
     return {
         "busy_s": sum(busy) / n_dev, "window_s": hi - lo,
         "devices": n_dev,
-        "device_ops": [[k, v / n_dev] for k, v in rank(op_sums)],
+        "device_ops": [[f"{named[k]}/{k}" if k in named else k, v / n_dev]
+                       for k, v in rank(op_sums)],
         "idle_gaps": [[k, v / n_dev] for k, v in rank(gap_sums)],
         "steps": steps, "compiles": planes["compiles"],
     }
 
 
 def reduce_file(path: str) -> dict:
-    return reduce_planes(read_planes(path))
+    """The reduction of one trace file, its ops named by scope where
+    the program declares scopes. ``device_planes`` keeps what the scope
+    reader decoded, so that the file is decoded once a run."""
+    from benchmark.harness import scope_reduce    # it imports this file
+
+    device_planes = scope_reduce.read_device_planes(path)
+    scopes = scope_reduce.program_scopes()
+    named = scope_reduce.scope_of_ops(device_planes, scopes) \
+        if scopes else None
+    out = reduce_planes(read_planes(path), scope_of_op=named)
+    out["device_planes"] = device_planes
+    return out

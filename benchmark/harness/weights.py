@@ -78,7 +78,3 @@ def decoder_weights(dims: dict, seed: int, dtype=jnp.bfloat16) -> dict:
                 "lm_head": quantized(next(keys), (d, v))}
 
     return jax.jit(build)(seed_key(seed))
-
-
-def weight_bytes(params: dict) -> int:
-    return sum(x.size * x.dtype.itemsize for x in jax.tree.leaves(params))

@@ -1,4 +1,5 @@
-"""out_tok_s from step records and their end times."""
+"""out_tok_s from the program's step records: their own count of
+tokens handed to requests, and their end times."""
 from benchmark.harness import stats
 
 

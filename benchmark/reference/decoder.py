@@ -30,6 +30,8 @@ import numpy as np
 #: layer for one length (2304 = prompt 2048 + 256 served)
 PAD_TO = 768
 HEAD_ROWS = 256
+#: the controls ``lower`` can compute (``harness/correct.py``)
+LOWERS = ("int4", "fp8kv")
 
 
 def _deq(leaf, lower):

@@ -4,10 +4,13 @@
 
 Runs one cell of BENCHMARK.json on the machine it is started on and
 prints, as the last line of stdout, one JSON object with ``correct``,
-``attempted``, ``failed``, ``metrics`` and ``device``. Everything a cell
-is made of is found by name: ``configs/<config>.json`` (which names its
-builder), ``traffic/<traffic>.json`` (which names its generator) and
-every ``metrics/*.json`` that lists the cell (each names its reader).
+``attempted``, ``failed``, ``metrics`` and ``device`` and, last, under
+``compared`` each number ``correct`` was decided by beside its limit
+(the same lines end standard error). Everything a cell is made of is
+found by name: ``configs/<config>.json`` (which names its builder and
+its plain reference), ``traffic/<traffic>.json`` (which names its
+generator) and every ``metrics/*.json`` that lists the cell (each names
+its reader).
 
 ``--rehearse`` runs the same builders and generators at the ``tiny``
 sizes wherever JAX was told to run, prints ``platform: cpu`` (or
@@ -160,7 +163,9 @@ def execute(args) -> dict:
         records["engine_requests"], window[0] - plan["window"][0],
         args.seed, int(limits["sample_requests"]))
     freed = correct.free_device_memory(system.weights)
-    gaps = correct.logit_gaps(system.weights, system.dims, sample)
+    gaps = correct.logit_gaps(
+        spec.module("reference", cell["config_data"]["reference"]),
+        system.weights, system.dims, sample)
     for name in ("logit_gap_max", "logit_gap_mean", "not_best_share"):
         if name in limits:
             checks.append({"name": name, "value": gaps.get(name),
@@ -182,9 +187,6 @@ def execute(args) -> dict:
         else:
             c["ok"] = c["value"] <= c["limit"]
         ok = ok and c["ok"]
-        log(f"compared {c['name']}: {c['value']} "
-            f"({'at least' if c.get('at_least') else 'limit'} "
-            f"{c['limit']}) {'ok' if c['ok'] else 'NOT OK'}")
     log(f"reference over {gaps['requests']} requests / {gaps['tokens']} "
         f"served tokens took {time.monotonic() - t0:.1f}s after freeing "
         f"{freed} device arrays ({gaps.get('seconds_each')} s each); "
@@ -198,6 +200,16 @@ def execute(args) -> dict:
         device["window_s"] = trace["window_s"]
         result["breakdown"] = {"device_ops": trace["device_ops"],
                                "idle_gaps": trace["idle_gaps"]}
+    # what the driver's record keeps of a run that is not correct: the
+    # end of standard error and the end of the result line
+    result["compared"] = {
+        c["name"]: {"value": c["value"], "limit": c["limit"]}
+        for c in checks}
+    for c in checks:
+        print(f"compared {c['name']} {c['value']} "
+              f"{'at least' if c.get('at_least') else 'limit'} "
+              f"{c['limit']}{'' if c['ok'] else ' NOT OK'}",
+              file=sys.stderr, flush=True)
     return result
 
 

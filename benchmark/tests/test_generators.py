@@ -1,7 +1,7 @@
 """The traffic generators are pure functions of (file, seed, seconds):
 the count, the multiset of lengths and the multiset of gaps are the
 same for every seed, two calls with one seed agree, and the order
-differs between seeds."""
+differs between seeds in the open loop and not in the closed one."""
 import collections
 import json
 import pathlib
@@ -114,8 +114,7 @@ def test_closed_every_cycle_is_the_same_multiset():
             assert cyc == base
         orders.append([(i["prompt_len"], i["new_tokens"])
                        for i in items[:n]])
-    assert orders[0] != orders[1]
-    # another seed is another rotation of the one cycle
-    assert any(orders[1][j:] + orders[1][:j] == orders[0]
-               for j in range(n) if orders[1][j] == orders[0][0])
+    # every seed offers the cycle from its head: the seed is not the work
+    assert orders[0] == orders[1] == orders[2] == [
+        (s["prompt_len"], s["new_tokens"]) for s in ring]
     assert closed_shapes.plan(t, 5, 51.0) == closed_shapes.plan(t, 5, 51.0)

@@ -90,3 +90,33 @@ def test_recorded_trace_names_the_idle_gaps():
                                               "_no_annotation_"}
     assert owners.get("_no_annotation_", 0.0) < \
         0.10 * sum(owners.values())
+
+
+def test_device_ops_are_named_by_scope_with_totals_and_order_kept():
+    """``breakdown.device_ops`` is what the writer of the next issue
+    reads of a trace: an op is ``<scope>/<HLO name>``, and the seconds
+    and their order are those of the bare names."""
+    bare = tr.reduce_planes(tr.read_planes(str(TRACE)))["device_ops"]
+    named = tr.reduce_file(str(TRACE))["device_ops"]
+    assert [v for _k, v in named] == [v for _k, v in bare]
+    assert [k.split("/", 1)[1] for k, _v in named] == [k for k, _v in bare]
+    scopes = {k.split("/", 1)[0] for k, _v in named}
+    assert scopes <= set(SCOPES) | {sr.UNSCOPED}
+    assert scopes & set(SCOPES)
+    # the layer scan's own loop has no scope; the matmul fusions have
+    assert named[0][0].startswith(f"{sr.UNSCOPED}/while.")
+    assert any(k.startswith("ffn/fusion.") for k, _v in named)
+
+
+def test_an_op_name_takes_the_scope_it_spent_most_time_under():
+    plane = {"ops": [(0, 10, "fusion.7", "jit(_decode)/ffn/dot_general:"),
+                     (20, 23, "fusion.7", "jit(_admit_fused)/attn/exp:"),
+                     (30, 31, "copy.1", None)]}
+    assert sr.scope_of_ops([plane], ("ffn", "attn")) == {
+        "fusion.7": "ffn", "copy.1": sr.UNSCOPED}
+    reduced = tr.reduce_planes(
+        {"devices": [{"name": "/device:TPU:0", "modules": [],
+                      "ops": [("fusion.7", 0.0, 1.0), ("copy.1", 2.0, 2.5)]}],
+         "annotations": [], "compiles": 0, "lo": 0.0, "hi": 3.0},
+        scope_of_op={"fusion.7": "ffn"})
+    assert reduced["device_ops"] == [["ffn/fusion.7", 1.0], ["copy.1", 0.5]]

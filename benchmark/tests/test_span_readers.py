@@ -2,8 +2,6 @@
 idle gaps by host phase, a request's time after its first token, and
 set-up's program loads; and the rehearsal, which lists the new
 metrics' names and still gives no result."""
-import types
-
 import pytest
 
 from benchmark import run as bench_run
@@ -29,80 +27,52 @@ def test_gap_share_by_owner_and_by_exception():
     assert gap_share.read({"trace": None}, {"owners": ["x"]}) is None
 
 
-def _tele(traces=(), records=()):
-    return types.SimpleNamespace(
-        completed=list(traces),
-        recorder=types.SimpleNamespace(records=lambda: list(records)))
-
-
-def _trace(rid, first, finish, stalled, host, corr=None):
-    return types.SimpleNamespace(
-        request_id=rid, correlation_id=corr or f"b{rid}",
-        first_token_at=first, finished_at=finish, stalled_s=stalled,
-        host_s=host)
-
-
-def _run(n=3):
-    reqs, eng = [], []
-    for i in range(n):
-        first, finish = 10.0 + i, 12.0 + i
+def _run(parts):
+    """Counted requests 2 s each from first token to finish, with the
+    program's (stalled_s, host_s) or None where the trace is gone."""
+    reqs = []
+    for i, part in enumerate(parts):
+        stalled, host = part or (None, None)
         reqs.append({"phase": "counted", "due": 9.5 + i,
-                     "first_token_at": first, "finished_at": finish})
-        eng.append({"rid": 100 + i, "correlation_id": f"b{100 + i}",
-                    "first_token_at": first, "finished_at": finish})
+                     "first_token_at": 10.0 + i, "finished_at": 12.0 + i,
+                     "stalled_s": stalled, "host_s": host})
     # a lead-in request: offered, never counted
     reqs.append({"phase": "lead_in", "due": 1.0, "first_token_at": 2.0,
-                 "finished_at": 3.0})
-    return {"window": (9.0, 20.0),
-            "records": {"requests": reqs, "engine_requests": eng}}
+                 "finished_at": 3.0, "stalled_s": 0.9, "host_s": 0.9})
+    return {"window": (9.0, 20.0), "records": {"requests": reqs}}
 
 
-def test_request_share_sums_over_counted_requests(monkeypatch):
-    from copilot_for_consensus_tpu.engine import telemetry
-
-    traces = [_trace(100, 10.0, 12.0, 0.8, 0.02),
-              _trace(101, 11.0, 13.0, 0.4, 0.04),
-              _trace(102, 12.0, 14.0, 0.0, 0.06),
-              _trace(7, 2.0, 3.0, 0.9, 0.9)]           # the lead-in's
-    monkeypatch.setattr(telemetry, "live", lambda: [_tele(traces)])
-    assert request_share.read(_run(), {"part": "stalled"}) == \
+def test_request_share_sums_over_counted_requests():
+    run = _run([(0.8, 0.02), (0.4, 0.04), (0.0, 0.06)])
+    assert request_share.read(run, {"part": "stalled"}) == \
         pytest.approx(100 * 1.2 / 6.0)
-    assert request_share.read(_run(), {"part": "host"}) == \
+    assert request_share.read(run, {"part": "host"}) == \
         pytest.approx(100 * 0.12 / 6.0)
     # one counted request's trace is gone: no value, not a smaller sum
-    monkeypatch.setattr(telemetry, "live", lambda: [_tele(traces[1:])])
-    assert request_share.read(_run(), {"part": "stalled"}) is None
-    # a program whose traces carry no such part (the parent commit)
-    bare = [types.SimpleNamespace(
-        request_id=100 + i, correlation_id=f"b{100 + i}",
-        first_token_at=10.0 + i, finished_at=12.0 + i) for i in range(3)]
-    monkeypatch.setattr(telemetry, "live", lambda: [_tele(bare)])
-    assert request_share.read(_run(), {"part": "stalled"}) is None
-    monkeypatch.delattr(telemetry, "live")
-    assert request_share.read(_run(), {"part": "stalled"}) is None
+    gone = _run([None, (0.4, 0.04), (0.0, 0.06)])
+    assert request_share.read(gone, {"part": "stalled"}) is None
+    assert request_share.read(_run([]), {"part": "stalled"}) is None
 
 
-def test_first_use_steps_counts_set_up_only(monkeypatch):
-    from copilot_for_consensus_tpu.engine import telemetry
-
+def test_first_use_steps_counts_set_up_only():
     def rec(t_end, dur, first):
-        return types.SimpleNamespace(t_end=t_end, duration_s=dur,
-                                     first_use=first)
+        return {"t_end": t_end, "duration_s": dur, "first_use": first}
 
-    records = [rec(1.0, 0.9, True), rec(2.0, 0.1, False),
-               rec(3.0, 1.5, True), rec(9.5, 0.2, True)]  # in the window
-    monkeypatch.setattr(telemetry, "live",
-                        lambda: [_tele(records=records)])
-    run = {"window": (9.0, 20.0)}
+    steps = [rec(1.0, 0.9, True), rec(2.0, 0.1, False),
+             rec(3.0, 1.5, True), rec(9.5, 0.2, True)]  # in the window
+    run = {"window": (9.0, 20.0), "records": {"steps": steps}}
     assert first_use_steps.read(run, {"value": "count"}) == 2.0
     assert first_use_steps.read(run, {"value": "seconds"}) == \
         pytest.approx(2.4)
     with pytest.raises(ValueError):
         first_use_steps.read(run, {"value": "mean"})
-    # records without the field (the parent commit): nothing to read
-    old = [types.SimpleNamespace(t_end=1.0, duration_s=0.9)]
-    monkeypatch.setattr(telemetry, "live", lambda: [_tele(records=old)])
+    # no program was loaded in set-up: nothing to read
+    run["records"]["steps"] = steps[1:2]
     assert first_use_steps.read(run, {"value": "count"}) is None
+    # a record without the field is an error, as in the tap
+    run["records"]["steps"] = [{"t_end": 1.0, "duration_s": 0.9}]
+    with pytest.raises(KeyError):
+        first_use_steps.read(run, {"value": "count"})
 
 
 NEW_ON_ANY_PLATFORM = {

@@ -4,8 +4,27 @@ import pytest
 from benchmark.harness import stats
 
 
-def step(t_end, kind="decode", tokens=8, rows=1):
-    return {"t_end": t_end, "kind": kind, "tokens": tokens, "rows": rows}
+def old_generated_tokens(step, first_tokens=None) -> int:
+    """How ``out_tok_s`` counted a step until PR 28, by its kind; kept
+    here as the reference the program's own count is held to. A
+    piggyback dispatch's ``tokens`` holds its prompt tokens too and
+    leaves out the first token of each prompt it finished, which the
+    tap then read from the engine's counters. Any other kind: 0."""
+    if step["kind"] in ("decode", "verify"):
+        return int(step["tokens"])
+    if step["kind"] == "piggyback":
+        return (int(step["tokens"]) - int(step["prompt_tokens"])
+                + int(first_tokens))
+    if step["kind"].startswith("prefill"):
+        return int(step["rows"])
+    return 0
+
+
+def step(t_end, kind="decode", tokens=8, rows=1, **more):
+    s = {"t_end": t_end, "kind": kind, "tokens": tokens, "rows": rows,
+         **more}
+    s.setdefault("new_tokens", old_generated_tokens(s, 0))
+    return s
 
 
 def test_out_tok_s_is_continuous_in_every_timestamp():
@@ -28,19 +47,25 @@ def test_out_tok_s_counts_first_tokens_of_waves_not_prompt_tokens():
         pytest.approx(11 / 2.0)
 
 
-def test_piggyback_step_counts_decoded_tokens_not_prompt_tokens():
-    """A piggyback dispatch's record counts the prompt tokens its
-    chunk grid prefilled among its tokens (engine/generation.py,
-    record_step in _decode_once) and not the first token of each prompt
-    it finished; the rate counts what requests were handed."""
-    piggy = dict(step(2.0, "piggyback", tokens=8 + 640, rows=1),
-                 prompt_tokens=640, first_tokens=2)
-    steps = [step(1.0), piggy, step(3.0)]
-    assert stats.generated_tokens(piggy) == 10
-    assert stats.out_tok_s(steps, (0.0, 5.0)) == pytest.approx(18 / 2.0)
-    # a record whose prompt tokens nobody read is an error, not a rate
+def test_the_count_is_the_records_own_whatever_the_kind():
+    """A piggyback dispatch hands over decoded tokens and first tokens
+    and no prompt token; a kind this benchmark has never heard of (a
+    step that yields several tokens a sequence) is counted for what
+    its record says, where the count by kind gave it 0; and a record
+    that says nothing is an error, not a larger or a smaller rate."""
+    piggy = step(2.0, "piggyback", tokens=8 + 640, rows=1,
+                 prompt_tokens=640, new_tokens=10)
+    assert old_generated_tokens(piggy, first_tokens=2) == 10
+    many = step(2.5, "multi_token_decode", tokens=40, rows=8,
+                new_tokens=5)
+    assert old_generated_tokens(many) == 0
+    steps = [step(1.0), piggy, many, step(3.0)]
+    assert stats.out_tok_s(steps, (0.0, 5.0)) == \
+        pytest.approx((10 + 5 + 8) / 2.0)
+    silent = dict(step(2.0))
+    del silent["new_tokens"]
     with pytest.raises(KeyError):
-        stats.generated_tokens(step(2.0, "piggyback", tokens=648))
+        stats.out_tok_s([step(1.0), silent, step(3.0)], (0.0, 5.0))
 
 
 def req(due, first=None, finish=None, new=10, ok=True, phase="counted"):
@@ -116,18 +141,12 @@ def test_every_metric_of_the_manifest_has_its_reader_file():
     assert files == listed
 
 
-def test_tap_counts_every_delivered_token_with_piggyback_on():
-    """On a tiny engine with chunked-prefill piggybacking switched on,
-    the tokens the step records hand to the rate are exactly the
-    tokens the requests received."""
+def _tiny_engine(**more):
     import json
 
     import jax.numpy as jnp
-    import numpy as np
 
-    from benchmark.builders._decoder import (
-        decoder_config, dims_of, token_ids)
-    from benchmark.builders._tap import EngineTap
+    from benchmark.builders._decoder import decoder_config, dims_of
     from benchmark.harness import spec, weights
     from copilot_for_consensus_tpu.engine.generation import (
         GenerationEngine)
@@ -137,9 +156,28 @@ def test_tap_counts_every_delivered_token_with_piggyback_on():
     dims = dims_of(cfg, True)
     args = dict(cfg["engine"], **cfg["rehearsal"]["engine"])
     args["prefill_buckets"] = tuple(args["prefill_buckets"])
-    engine = GenerationEngine(
+    return dims, GenerationEngine(
         decoder_config(dims, "tiny"), weights.decoder_weights(dims, 5),
-        dtype=jnp.bfloat16, seed=5, piggyback_min_prompt=40, **args)
+        dtype=jnp.bfloat16, seed=5, **args, **more)
+
+
+@pytest.mark.parametrize("piggyback", [False, True],
+                         ids=["waves", "piggyback-on"])
+def test_recorded_steps_count_every_delivered_token(piggyback):
+    """Step lists recorded from a tiny engine through the builder's
+    tap, admission waves only and with chunked-prefill piggybacking
+    switched on: the records' own count is the count by kind wherever
+    that was defined from a record alone, a piggyback step's is the old
+    arithmetic with the first tokens the engine counted, their sum is
+    exactly the tokens the requests received, and so the rate by
+    either count is the same number."""
+    import numpy as np
+
+    from benchmark.builders._decoder import token_ids
+    from benchmark.builders._tap import EngineTap
+
+    dims, engine = _tiny_engine(
+        **({"piggyback_min_prompt": 40} if piggyback else {}))
     tap = EngineTap(engine)
     rng = np.random.default_rng(0)
     for n in (20, 100, 60, 24, 90):
@@ -149,7 +187,47 @@ def test_tap_counts_every_delivered_token_with_piggyback_on():
         done += engine.step()
     tap.poll()
     steps = tap.step_list()
-    assert any(s["kind"] == "piggyback" and s["prompt_tokens"] > 0
-               for s in steps)
-    assert sum(stats.generated_tokens(s) for s in steps) == \
+    piggy = [s for s in steps if s["kind"] == "piggyback"]
+    assert bool(piggy) == piggyback
+    assert any(s["kind"].startswith("prefill") for s in steps)
+    for s in steps:
+        assert s["t_end"] - s["t_start"] == pytest.approx(s["duration_s"])
+        if s["kind"] != "piggyback":
+            assert s["new_tokens"] == old_generated_tokens(s)
+    if piggyback:
+        assert any(s["prompt_tokens"] > 0 for s in piggy)
+        assert sum(s["new_tokens"] - s["tokens"] + s["prompt_tokens"]
+                   for s in piggy) == engine.piggy_rows
+    assert sum(s["new_tokens"] for s in steps) == \
         sum(len(c.tokens) for c in done) == 60
+    window = (steps[0]["t_end"] - 1.0, steps[-1]["t_end"] + 1.0)
+    span = steps[-1]["t_end"] - steps[0]["t_end"]
+    assert stats.out_tok_s(steps, window) == pytest.approx(
+        (60 - steps[0]["new_tokens"]) / span)
+
+
+def test_model_keys_that_are_not_numbers_reach_the_builder():
+    """``dims_of`` keeps the model's keys as the file has them and
+    drops the file's own sections; for ``mistral-7b-int8`` the numbers
+    are the ones the old rule (numbers only) kept."""
+    import json
+
+    from benchmark.builders._decoder import FILE_KEYS, dims_of
+    from benchmark.harness import spec
+
+    cfg = json.loads((spec.BENCH / "configs"
+                      / "mistral-7b-int8.json").read_text())
+    numbers = {k: v for k, v in cfg.items()
+               if isinstance(v, (int, float)) and not isinstance(v, bool)}
+    dims = dims_of(cfg, False)
+    assert {k: v for k, v in dims.items() if k in numbers} == numbers
+    assert set(dims) - set(numbers) == {"hidden_act", "model_type",
+                                        "tie_word_embeddings"}
+    assert not set(dims) & FILE_KEYS
+    more = dict(cfg, layer_types=["a", "b"], rope_scaling={"factor": 2.0})
+    more["rehearsal"] = dict(cfg["rehearsal"], model={
+        **cfg["rehearsal"]["model"], "layer_types": ["a"]})
+    assert dims_of(more, False)["layer_types"] == ["a", "b"]
+    assert dims_of(more, False)["rope_scaling"] == {"factor": 2.0}
+    assert dims_of(more, True)["layer_types"] == ["a"]
+    assert dims_of(more, True)["hidden_size"] == 128

@@ -144,6 +144,7 @@ def main() -> int:
                                          seed, k_sample)
         t1 = time.monotonic()
         gaps = correct.logit_gaps(
+            spec.module("reference", cell["config_data"]["reference"]),
             system.weights, system.dims, sample,
             lowers if k < args.control_seeds else ())
         for kind, rows in gaps.pop("rows", {}).items():

@@ -32,6 +32,17 @@ DOMINANT LATENCY" in SURVEY.md §3.2). Design:
   (greedy bit-identical; sampled via the rejection rule in
   ``sampling.verify_draft``). Design: ``docs/SPEC_DECODE.md``.
 
+* **A second kind of sequence state** (``cfg.attention == "eva"``,
+  EvaByte class; ``models/eva.py``): per slot an exact window of
+  ``window_size`` positions beside one summary key and value per
+  ``chunk_size`` positions of everything before it, in one cache
+  manager. Admission goes piece by piece (``_admit_pieces``: a prompt of
+  four windows is four waves, the first three leave only summaries),
+  decode compacts a window that fills into its summaries on the
+  device, mid-dispatch, for exactly the slots concerned. The options
+  that assume one column of keys and values per position refuse it at
+  construction (``_check_eva``).
+
 The engine is synchronous and single-owner: services drive it through
 ``submit()`` + ``step()`` (or ``generate()`` for batch use) from their
 consumer thread, mirroring how the reference's summarization service owns
@@ -74,7 +85,7 @@ from copilot_for_consensus_tpu.engine.tokenizer import (
     Tokenizer,
 )
 from copilot_for_consensus_tpu.obs.profile import scope, step_annotation
-from copilot_for_consensus_tpu.models import decoder, quant
+from copilot_for_consensus_tpu.models import decoder, eva, quant
 from copilot_for_consensus_tpu.models.configs import DecoderConfig
 from copilot_for_consensus_tpu.parallel.sharding import (
     DEFAULT_RULES,
@@ -321,6 +332,22 @@ class GenerationEngine:
             b for b in sorted(set(min(b, self.max_len)
                                   for b in prefill_buckets)))
         self.sampling = sampling
+        #: EVA attention (models/eva.py): a slot's state is an exact
+        #: window beside chunk summaries, admission goes piece by piece
+        #: (``_admit_pieces``), decode compacts on the device
+        self._eva = cfg.is_eva
+        if self._eva:
+            self._check_eva(dict(
+                mesh=mesh, prefix_cache_blocks=prefix_cache_blocks,
+                kv_pool_blocks=kv_pool_blocks, spec_decode=spec_decode,
+                kv_dtype=kv_dtype, quantize=quantize,
+                windows_per_dispatch=windows_per_dispatch,
+                piggyback_min_prompt=piggyback_min_prompt))
+            # a piece of a prompt fills a bucket and ends at or before
+            # its window's edge: buckets that divide the window
+            w = cfg.window_size
+            self.buckets = tuple(b for b in self.buckets
+                                 if w % b == 0) or (w,)
         # eos_id may be a list (Llama-3.1-style multi-EOS checkpoints).
         eos_list = list(eos_id) if isinstance(eos_id, (list, tuple)) \
             else [int(eos_id)]
@@ -373,6 +400,7 @@ class GenerationEngine:
         self.piggyback_min_prompt = piggyback_min_prompt
         self._piggyback_ok = (
             self.windows_per_dispatch == 1 and not cfg.is_moe
+            and not self._eva
             and (cfg.sliding_window == 0
                  or cfg.sliding_window >= self.max_len))
         self._prefilling: list[tuple[Request, float]] = []  # packer feed
@@ -392,6 +420,9 @@ class GenerationEngine:
             raise ValueError(f"unknown quantize mode {qmode!r}")
         self.quant_mode = qmode
         axes = decoder.logical_axes(cfg)
+        if params is None and self._eva:
+            params = eva.init_params(jax.random.PRNGKey(seed), cfg,
+                                     dtype=dtype)
         if params is None:
             if qmode:
                 params = quant.init_random_quantized(
@@ -604,6 +635,14 @@ class GenerationEngine:
             # chunked prefill, so the (default-off) path stays off.
             self._piggyback_ok = False
             self._cache = None
+        elif self._eva:
+            # one manager for both kinds of state: per slot a window of
+            # exact keys and values and a store of summaries, two
+            # halves each, donated to every program and updated in
+            # place (models/eva.py)
+            self._cache = eva.init_cache(cfg, num_slots, self.max_len,
+                                         dtype=self.kv_dtype,
+                                         margin=self._dispatch_steps)
         else:
             cache = decoder.init_cache(cfg, num_slots, self.max_len,
                                        dtype=self.kv_dtype)
@@ -1001,8 +1040,9 @@ class GenerationEngine:
                                         telemetry=self.telemetry)
         # Chunking rides prefill_attention_seeded, which (like spec
         # decode) does not implement absolute-timeline window masking.
-        self._chunk_ok = (cfg.sliding_window == 0
-                          or cfg.sliding_window >= self.max_len)
+        self._chunk_ok = not self._eva and (
+            cfg.sliding_window == 0
+            or cfg.sliding_window >= self.max_len)
         ct = self.prompt_limit
         if self._sched is not None:
             ct = max(1, min(self._sched.cfg.chunk_tokens,
@@ -1044,6 +1084,40 @@ class GenerationEngine:
 
         self._chunk_fn = jax.jit(_prefill_chunk, donate_argnums=(4,),
                                  static_argnames=("kv_len",))
+
+        # ---- EVA programs (cfg.attention == "eva"; models/eva.py) ------
+        # Two programs stand where the dense decoder has admission,
+        # chunk continuation and decode: a piece of each admitted
+        # prompt per row, and the decode dispatch. Static keys: rows x
+        # bucket; whether a window can fill within the dispatch.
+
+        def _admit_eva(params, tokens, lens, pos0, slots, cache, key):
+            """One admission wave: each row's next piece (at most a
+            bucket, never across a window edge) into its slot's window,
+            the window compacted where the piece ends on its edge, and
+            a candidate first token from each row's last position (the
+            host keeps it for rows whose prompt ended)."""
+            logits, cache = eva.prefill_piece(
+                params, tokens, lens, pos0, slots, cfg, cache,
+                attn_impl=impl)
+            first = sample(logits[:, :cfg.vocab_size], key, self.sampling)
+            return first, cache
+
+        def _decode_eva(params, tokens, positions, cache, key, *,
+                        may_close):
+            """``decode_window`` steps for every slot against window
+            and summaries, compaction included (eva.decode_tokens)."""
+            return eva.decode_tokens(
+                params, tokens, positions, cfg, cache, key,
+                lambda logits, sub: sample(logits, sub, self.sampling),
+                steps=self.decode_window, may_close=may_close,
+                max_len=self.max_len)
+
+        if self._eva:
+            self._admit_eva_fn = jax.jit(_admit_eva, donate_argnums=(5,))
+            self._decode_eva_fn = jax.jit(
+                _decode_eva, donate_argnums=(3,),
+                static_argnames=("may_close",))
 
         # ---- paged dispatch programs (kv_pool_blocks > 0) --------------
         # Two routes serve the same block-table semantics, selected by
@@ -1697,6 +1771,9 @@ class GenerationEngine:
         window of cache headroom, capped by the largest prefill bucket).
         Callers with longer prompts should route to the long-context
         engine (``engine/longctx.py``)."""
+        if self._eva:
+            # admitted piece by piece: no bucket bounds a prompt
+            return self.max_len - self._dispatch_steps
         return min(self.max_len - self._dispatch_steps, self.buckets[-1])
 
     def submit(self, prompt: list[int], max_new_tokens: int = 256, *,
@@ -1806,8 +1883,11 @@ class GenerationEngine:
             self._expire_deadlines()
             if self._sched is not None:
                 self._sched_pump()
-            self._admit()
-            if self._chunk_pending or self._chunking:
+            if self._eva:
+                self._admit_pieces()
+            else:
+                self._admit()
+            if not self._eva and (self._chunk_pending or self._chunking):
                 self._phase("plan", ahead=True)
                 self._chunk_step()
             if self.paged:
@@ -2495,6 +2575,165 @@ class GenerationEngine:
                 self._retire(slot,
                              "eos" if tok in self._eos_set else "length")
 
+    def _check_eva(self, asked: dict) -> None:
+        """Refuse, at construction and by mechanism, every option that
+        assumes a sequence's state is one column of keys and values
+        per position. No silent fallback."""
+        why = {
+            "mesh": "the window and summary stores have no sharding "
+                    "rules and eva.merge_dispatch's slabs were never "
+                    "partitioned",
+            "prefix_cache_blocks": "the prefix cache publishes and "
+                    "seeds blocks of per-position keys and values; a "
+                    "prefix here is summaries plus a partial window",
+            "kv_pool_blocks": "the block pool pages one column per "
+                    "position; it has no place for summaries",
+            "spec_decode": "the verify pass writes k + 1 columns and "
+                    "rolls rejected ones back by position; a window "
+                    "that was compacted cannot be rolled back",
+        }
+        for name, reason in why.items():
+            if asked[name]:
+                raise ValueError(
+                    f"{name} cannot serve attention='eva' "
+                    f"({self.cfg.name}): {reason}")
+        kv = resolve_kv_dtype(asked["kv_dtype"], None)
+        if kv is not None and jnp.dtype(kv).itemsize < 2:
+            raise ValueError(
+                f"kv_dtype {asked['kv_dtype']!r} cannot serve "
+                f"attention='eva': summaries pooled from 8-bit keys "
+                f"were never held against the reference")
+        if asked["quantize"] == "int4":
+            raise ValueError(
+                "quantize='int4' cannot serve attention='eva': the "
+                "fused int4 projections assume the dense decoder's "
+                "layer and output head")
+        if asked["windows_per_dispatch"] != 1:
+            raise ValueError(
+                "windows_per_dispatch > 1 cannot serve attention='eva': "
+                "a dispatch closes at most one window a slot")
+        if asked["piggyback_min_prompt"] < 10**9:
+            raise ValueError(
+                "piggyback prefill cannot serve attention='eva': its "
+                "chunk grid scatters per-position columns "
+                "(decoder.merge_prefill)")
+        cfg, w = self.cfg, self.cfg.window_size
+        if (cfg.n_kv_heads != cfg.n_heads or cfg.is_moe
+                or cfg.sliding_window or w <= 0 or cfg.chunk_size <= 0
+                or w % cfg.chunk_size or self.max_len % w):
+            raise ValueError(
+                f"attention='eva' needs one key/value head per head, a "
+                f"dense FFN, window_size a multiple of chunk_size and "
+                f"max_len ({self.max_len}) a multiple of window_size "
+                f"({w})")
+
+    def _eva_live(self) -> tuple[int, int]:
+        """(exact columns, summaries) held by all sequences in slots,
+        decoding or mid-admission, right now."""
+        held = [int(self._positions[s]) for s in self._active] \
+            + [e[1] for e in self._chunking.values()]
+        state = [eva.live_state(self.cfg, t) for t in held]
+        return sum(a for a, _ in state), sum(b for _, b in state)
+
+    def _admit_pieces(self) -> None:
+        """Admission for attention='eva': queued requests take free
+        slots, and ONE wave advances every admitted prompt by its next
+        piece — at most the largest bucket, never across a window edge,
+        so a piece that reaches the edge leaves summaries behind and
+        the last piece stays exact in the window. A prompt of four
+        windows is four waves, each sharing its weight pass with the
+        other rows' pieces and co-scheduled with the decode dispatches
+        in between; one program per (rows, bucket), whatever the
+        prompt length. Rows pad to a power of two with copies of the
+        first row (the same writes twice) and a wave stays under the
+        admission token budget."""
+        while (self._queue and self._free
+               and self._occupied < self._slot_cap):
+            self._chunking[self._free.pop(0)] = [
+                self._queue.pop(0), 0, time.monotonic()]
+        if not self._chunking:
+            return
+        t0 = time.monotonic()
+        w_sz = self.cfg.window_size
+        rows: list[tuple[int, int]] = []          # (slot, piece length)
+        bucket = n_pad = 0
+        for slot, (req, filled, _t) in self._chunking.items():
+            n = min(len(req.prompt) - filled, w_sz - filled % w_sz,
+                    self.buckets[-1])
+            b = _next_bucket(max([n] + [m for _, m in rows]), self.buckets)
+            pad = 1
+            while pad < len(rows) + 1:
+                pad *= 2
+            if rows and pad * b > self.admission_token_budget:
+                break
+            rows.append((slot, n))
+            bucket, n_pad = b, pad
+        tokens = np.zeros((n_pad, bucket), dtype=np.int32)
+        lens = np.ones((n_pad,), dtype=np.int32)
+        pos0 = np.zeros((n_pad,), dtype=np.int32)
+        slots = np.zeros((n_pad,), dtype=np.int32)
+        for r, (slot, n) in enumerate(rows):
+            req, filled, _t = self._chunking[slot]
+            tokens[r, :n] = req.prompt[filled:filled + n]
+            lens[r], pos0[r], slots[r] = n, filled, slot
+        for r in range(len(rows), n_pad):
+            tokens[r], lens[r] = tokens[0], lens[0]
+            pos0[r], slots[r] = pos0[0], slots[0]
+        win_tokens, sum_tokens = self._eva_live()
+        self._key, sub = jax.random.split(self._key)
+        seq = self.telemetry.next_step() if self.telemetry is not None \
+            else None
+        # On failure the _chunking entries are untouched (fills only
+        # advance after the host fetch): the same pieces go again.
+        self._phase(None)
+        with step_annotation("prefill", seq), \
+                self._dispatch_boundary("prefill"):
+            first_dev, self._cache = self._admit_eva_fn(
+                self.params, jnp.asarray(tokens), jnp.asarray(lens),
+                jnp.asarray(pos0), jnp.asarray(slots), self._cache, sub)
+            first = _host_fetch(first_dev)
+        step_s = time.monotonic() - t0
+        self._phase("commit")
+        self.admitted_s += step_s
+        for req in self._active.values():
+            req.stalled_s += step_s       # sat through this wave
+        now = time.monotonic()
+        fed = sum(n for _, n in rows)
+        self.prefill_tokens += fed
+        first_tokens = compacted = 0
+        for r, (slot, n) in enumerate(rows):
+            entry = self._chunking[slot]
+            req, _filled, started = entry
+            entry[1] += n
+            compacted += entry[1] % w_sz == 0
+            if entry[1] < len(req.prompt):
+                continue
+            del self._chunking[slot]
+            tok = int(first[r])
+            first_tokens += 1
+            if self.telemetry is not None:
+                self.telemetry.on_admit(
+                    req.request_id, wave_start=started,
+                    admit_kind="wave" if n == len(req.prompt)
+                    else "chunked")
+            self._active[slot] = req
+            self._generated[slot] = [tok]
+            self._positions[slot] = len(req.prompt)
+            self._next_tok[slot] = tok
+            self._t_prefill[slot] = now - started
+            req.decode_started_at = now
+            if tok in self._eos_set or req.max_new_tokens <= 1:
+                self._retire(slot,
+                             "eos" if tok in self._eos_set else "length")
+        if self.telemetry is not None:
+            self.telemetry.record_step(
+                "prefill", step_s, seq=seq, rows=len(rows), batch=n_pad,
+                tokens=fed, padded_tokens=n_pad * bucket, t_start=t0,
+                new_tokens=first_tokens, prompt_tokens=fed,
+                first_use=self._first_use("prefill", bucket, n_pad),
+                windows_compacted=compacted, window_tokens=win_tokens,
+                summary_tokens=sum_tokens)
+
     def _req_digests(self, req: Request) -> list:
         if req.block_digests is None:
             req.block_digests = self._prefix.prompt_digests(req.prompt)
@@ -3154,10 +3393,31 @@ class GenerationEngine:
             else None
         piggy_tok0, piggy_rows0 = self.piggy_tokens, self.piggy_rows
         kv_len = self._kv_bucket()
+        extra: dict = {}
+        if self._eva:
+            win_tokens, sum_tokens = self._eva_live()
+            w_sz = self.cfg.window_size
+            closing = sum(int(self._positions[s]) % w_sz + window >= w_sz
+                          for s in self._active)
+            extra = {"window_tokens": win_tokens,
+                     "summary_tokens": sum_tokens,
+                     "windows_compacted": closing}
+            # the static key: can some slot's window fill within this
+            # dispatch? Only that program holds the compaction; two
+            # decode programs in all
+            kv_len = closing > 0
         self._phase(None)
         with step_annotation(step_kind, seq), \
                 self._dispatch_boundary(step_kind):
-            if piggy:
+            if self._eva:
+                toks, self._cache = self._decode_eva_fn(
+                    self.params, jnp.asarray(self._next_tok),
+                    jnp.asarray(self._positions), self._cache, sub,
+                    may_close=kv_len)
+                toks = _host_fetch(toks)                 # [steps, slots]
+                self.plain_s += time.monotonic() - t0
+                self.plain_dispatches += 1
+            elif piggy:
                 toks = self._dispatch_piggyback(sub)
                 self.piggy_s += time.monotonic() - t0
                 self.piggy_dispatches += 1
@@ -3254,7 +3514,8 @@ class GenerationEngine:
                 + (self.piggy_rows - piggy_rows0),
                 prompt_tokens=self.piggy_tokens - piggy_tok0,
                 first_use=self._first_use(
-                    step_kind, kv_len, self.windows_per_dispatch))
+                    step_kind, kv_len, self.windows_per_dispatch),
+                **extra)
 
     def _spec_allowed(self) -> bool:
         """Spec-decode degraded-mode gate: the supervisor's

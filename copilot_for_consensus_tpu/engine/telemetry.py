@@ -332,6 +332,12 @@ class StepRecord:
     first_use: bool = False   # first dispatch of its kind with its
     #                           static shape key: it loaded (or
     #                           compiled) a program
+    # attention="eva" engines only (models/eva.py); 0 elsewhere
+    windows_compacted: int = 0  # slot-windows that filled and were
+    #                             pooled into summaries in the dispatch
+    window_tokens: int = 0      # exact key/value columns live in all
+    #                             slots when the dispatch began
+    summary_tokens: int = 0     # chunk summaries live in all slots then
 
     @property
     def occupancy(self) -> float:
@@ -358,8 +364,8 @@ class FlightRecorder:
 
     The default capacity holds an hour of serving: a 7B engine on one
     chip makes about 8 dispatches a second (PERF_LEDGER, PR 25), 8 x
-    3600 = 28,800 records, rounded up to 32,768; a record is 16 small
-    fields, about 200 bytes, so a full ring is 6-7 MB. A run's warm-up
+    3600 = 28,800 records, rounded up to 32,768; a record is 19 small
+    fields, about 250 bytes, so a full ring is 6-7 MB. A run's warm-up
     steps (``first_use``) are then still there when it ends."""
 
     def __init__(self, capacity: int = 32768):
@@ -574,7 +580,9 @@ class EngineTelemetry:
                     accepted_tokens: int = 0,
                     route: str = "", t_start: float | None = None,
                     new_tokens: int = 0, prompt_tokens: int = 0,
-                    first_use: bool = False) -> StepRecord:
+                    first_use: bool = False, windows_compacted: int = 0,
+                    window_tokens: int = 0,
+                    summary_tokens: int = 0) -> StepRecord:
         """``t_start`` is the dispatch's ``time.monotonic()`` start
         (default: now less ``duration_s``)."""
         if t_start is None:
@@ -587,7 +595,8 @@ class EngineTelemetry:
             accepted_tokens=accepted_tokens, route=route,
             t_start=t_start, t_end=t_start + duration_s,
             new_tokens=new_tokens, prompt_tokens=prompt_tokens,
-            first_use=first_use)
+            first_use=first_use, windows_compacted=windows_compacted,
+            window_tokens=window_tokens, summary_tokens=summary_tokens)
         self.recorder.record(rec)
         m, lb = self.metrics, self._labels
         m.observe("engine_step_seconds", duration_s,

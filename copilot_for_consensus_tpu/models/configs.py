@@ -33,6 +33,19 @@ class DecoderConfig:
     #: tensor-parallel stage-local views, where n_heads is divided by tp
     #: but each head keeps its full width.
     head_dim_override: int = 0
+    #: attention class. "softmax": causal attention over one column of
+    #: keys and values per position. "eva" (EvaByte's ``attention_class``;
+    #: models/eva.py): exact attention inside an aligned window of
+    #: ``window_size`` positions, every earlier window seen through one
+    #: summary key and value per ``chunk_size`` positions, one softmax.
+    attention: str = "softmax"
+    window_size: int = 0
+    chunk_size: int = 0
+    #: prediction heads of ``vocab_size`` logits each on the output
+    #: matrix; head i predicts the token i + 1 positions ahead
+    num_pred_heads: int = 1
+    #: RMS norm gains are stored as offsets from one: x̂ · (1 + g)
+    norm_unit_offset: bool = False
 
     @property
     def head_dim(self) -> int:
@@ -41,6 +54,10 @@ class DecoderConfig:
     @property
     def is_moe(self) -> bool:
         return self.n_experts > 0
+
+    @property
+    def is_eva(self) -> bool:
+        return self.attention == "eva"
 
 
 @dataclass(frozen=True)
@@ -86,6 +103,14 @@ DECODER_CONFIGS: dict[str, DecoderConfig] = {
     "tiny-swa": DecoderConfig(
         name="tiny-swa", vocab_size=512, d_model=128, n_layers=2, n_heads=4,
         n_kv_heads=2, d_ff=256, max_seq_len=512, sliding_window=64,
+    ),
+    # EvaByte class at test scale: four heads of 16, windows of 32
+    # positions summarized in chunks of 4, eight prediction heads.
+    "tiny-eva": DecoderConfig(
+        name="tiny-eva", vocab_size=320, d_model=64, n_layers=2, n_heads=4,
+        n_kv_heads=4, d_ff=128, rope_theta=1e5, max_seq_len=512,
+        attention="eva", window_size=32, chunk_size=4, num_pred_heads=8,
+        norm_unit_offset=True,
     ),
     "tiny-moe": DecoderConfig(
         name="tiny-moe", vocab_size=512, d_model=128, n_layers=2, n_heads=4,

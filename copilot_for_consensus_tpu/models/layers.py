@@ -85,11 +85,18 @@ def qmatmul(x: jax.Array, w) -> jax.Array:
 
 
 @scope("norm_rope")
-def rms_norm(x: jax.Array, weight: jax.Array, eps: float) -> jax.Array:
+def rms_norm(x: jax.Array, weight: jax.Array, eps: float, *,
+             unit_offset: bool = False, dtype=None) -> jax.Array:
+    """``unit_offset``: the gain is stored as an offset from one
+    (``x̂ · (1 + g)``). ``dtype``: the result's (default: ``x``'s) — a
+    float32 residual stream hands its blocks bf16 inputs."""
     xf = x.astype(jnp.float32)
     var = jnp.mean(xf * xf, axis=-1, keepdims=True)
     normed = xf * jax.lax.rsqrt(var + eps)
-    return (normed * weight.astype(jnp.float32)).astype(x.dtype)
+    gain = weight.astype(jnp.float32)
+    if unit_offset:
+        gain = 1.0 + gain
+    return (normed * gain).astype(dtype or x.dtype)
 
 
 def layer_norm(x: jax.Array, weight: jax.Array, bias: jax.Array,

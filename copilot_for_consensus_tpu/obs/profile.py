@@ -18,7 +18,8 @@ name go into it, each declared here and nowhere else:
   a host-side ``StepRecord`` name the SAME step. Read by
   ``benchmark/harness/trace_reduce.py`` (device time per step, idle
   gaps inside a dispatch).
-* **Device scopes** — ``SCOPES`` / ``scope(name)``: ``jax.named_scope``
+* **Device scopes** — ``SCOPES``, ``EVA_SCOPES`` / ``scope(name)``:
+  ``jax.named_scope``
   around the code that does each thing inside the jitted programs. The
   name lands in every HLO instruction's ``op_name`` metadata
   (``jit(_decode)/while/body/ffn/dot_general``) and from there in the
@@ -60,6 +61,16 @@ SCOPES = (
     "sample",     # engine/sampling.py:sample
     "kv_write",   # window-buffer updates, merge_window/merge_prefill,
     #               the admit program's cache scatter
+)
+
+#: scopes that only the attention="eva" programs hold (models/eva.py).
+#: Apart from ``SCOPES``: the benchmark's accepted scope table and its
+#: tests hold every name of ``SCOPES`` to the dense programs' trace;
+#: the readers of these name this tuple themselves
+#: (benchmark/readers/scope_share_of.py).
+EVA_SCOPES = (
+    "kv_compact",  # pooling a filled window of exact keys and values
+    #                into its chunk summaries
 )
 
 #: host phases of the serving loop (with the dispatch kinds ``decode``
@@ -110,12 +121,13 @@ def step_annotation(name: str, step_num: int | None = None):
 
 
 def scope(name: str):
-    """``jax.named_scope`` for one of ``SCOPES`` (trace time only)."""
+    """``jax.named_scope`` for one of ``SCOPES`` or ``EVA_SCOPES``
+    (trace time only)."""
     import jax
 
-    if name not in SCOPES:
+    if name not in SCOPES + EVA_SCOPES:
         raise ValueError(f"unknown device scope {name!r}; obs/profile.py "
-                         f"SCOPES has {SCOPES}")
+                         f"SCOPES has {SCOPES}, EVA_SCOPES {EVA_SCOPES}")
     return jax.named_scope(name)
 
 

@@ -311,3 +311,52 @@ def test_compiled_guard_trips_on_the_old_program(struct_engine, one_chip,
                              old_program(struct_engine))
     assert ("slice", True, False) in ops        # re-cut every token
     assert sum(o == ("copy", False, True) for o in ops) == 4
+
+
+# ---------------------------------------------------------------------------
+# the same for attention="eva" (models/eva.py): its window buffers are
+# the cache-sized arrays, and `merge_dispatch` writes them by the scatter
+# `merge_window` uses. Here, and not in tests/test_eva_engine.py: one
+# file describes the chip (a second could land on another worker and
+# find the TPU library taken).
+# ---------------------------------------------------------------------------
+
+EVA_CFG = decoder_config("tiny-eva", d_model=256, n_heads=2, n_kv_heads=2,
+                         d_ff=512, window_size=4096, chunk_size=16,
+                         max_seq_len=8192)
+EVA_MAX = 8192     # a window half: 2 x 8 x 2 x 4104 x 128 bf16 = 33.6 MB
+
+
+@pytest.mark.parametrize("may_close", [False, True],
+                         ids=["plain", "may-close"])
+def test_compiled_eva_decode_updates_the_window_buffers_in_place(
+        one_chip, may_close):
+    """The only ops whose result has a window buffer's shape are the
+    in-place updates the merge's scatter expands to: no copy and no
+    slice, inside a loop or out of it, in either decode program."""
+    eng = GenerationEngine(EVA_CFG, num_slots=SLOTS, max_len=EVA_MAX,
+                           prefill_buckets=(64,), dtype=jnp.bfloat16,
+                           attn_impl="xla", eos_id=-1)
+
+    def wrap(a):
+        return jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip)
+
+    i32 = wrap(jax.ShapeDtypeStruct((SLOTS,), jnp.int32))
+    text = eng._decode_eva_fn.lower(
+        jax.tree.map(wrap, eng.params), i32, i32,
+        jax.tree.map(wrap, eng._cache), wrap(jax.random.PRNGKey(0)),
+        may_close=may_close).compile().as_text()
+    window = tuple(eng._cache["k"].shape)
+    ops = set()
+    for line in text.splitlines():
+        m = re.match(r"\s*(?:ROOT )?%?[\w.\-]+ = \w+\[([\d,]+)\](\S*) "
+                     r"([\w\-]+)\(", line)
+        # (a result in memory space S(1) is a prefetch of a buffer this
+        # small into fast memory, not a relayout; the served buffers
+        # are 2 GB a half and are never moved there)
+        if m and m.group(3) not in ("parameter", "get-tuple-element",
+                                    "bitcast") \
+                and tuple(int(x) for x in m.group(1).split(",")) == window \
+                and "S(1)" not in m.group(2):
+            ops.add(m.group(3))
+    assert ops == {"dynamic-update-slice"}

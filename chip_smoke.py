@@ -10,7 +10,9 @@ weights made from a seed:
   its XLA reference on the chip: ``flash_attention`` (plain, windowed,
   ``kv_lengths``), the paged kernel against ``paged_gather_layer`` +
   ``decode_attention`` over an fp8 pool (decode rows ``r = group`` and
-  seeded rows ``r = group * S``), and ``int4_matmul``. Then one short
+  seeded rows ``r = group * S``), the EVA decode kernel over a slot's
+  live blocks (at EvaByte's widths) against ``joint_attention`` over
+  whole pieces, and ``int4_matmul``. Then one short
   paged ``GenerationEngine(kv_kernel="auto")`` run, depth cut to
   ``KERNEL_PHASE_LAYERS``, whose route must resolve to ``"kernel"``.
 * **serve** — ``python -m copilot_for_consensus_tpu serve`` with the
@@ -223,6 +225,36 @@ def phase_kernels(rehearse: bool) -> int:
             combine_partials([part], dtype),
             decode_attention(qs.reshape(pb, hkv * group * s_rows, d),
                              view_k, view_v, lengths).reshape(qs.shape))
+
+    # -- EVA decode attention over live blocks (attention="eva" on a
+    # TPU), at EvaByte's widths: 32 heads of 128, windows of 2,048 ----
+    from copilot_for_consensus_tpu.models import eva
+    from copilot_for_consensus_tpu.ops.eva_attention import plan_blocks
+
+    eh, ew, er, esteps = (4, 32, 16, 8) if rehearse else (32, 2048, 1024, 8)
+    state = {n: normal(n_l, pb, eh, t, d)
+             for n, t in (("k", ew + esteps), ("v", ew + esteps),
+                          ("ks", er), ("vs", er))}
+    # nothing live, a column, a window short of full behind a full
+    # store, mid-block extents
+    win_len = jnp.asarray([0, 1, ew - 1, ew // 2 + 5], jnp.int32)
+    sum_n = jnp.asarray([0, 0, er - er // 8, er // 4], jnp.int32)
+    qe, k_cur, v_cur = (normal(pb, eh, d) for _ in range(3))
+    local = [(normal(pb, eh, esteps, d), normal(pb, eh, esteps, d),
+              jnp.broadcast_to(jnp.arange(esteps)[None, :] < 3,
+                               (pb, esteps)))]
+    compare(
+        "eva_decode_attention",
+        eva.live_attention(
+            qe, k_cur, v_cur, local, state, jnp.asarray(li, jnp.int32),
+            plan_blocks(win_len, sum_n, window=ew, store=er), ew),
+        eva.joint_attention(
+            qe, k_cur, v_cur,
+            [(state["k"][li], state["v"][li],
+              jnp.arange(ew + esteps)[None, :] < win_len[:, None]),
+             *local,
+             (state["ks"][li], state["vs"][li],
+              jnp.arange(er)[None, :] >= er - sum_n[:, None])]))
 
     # -- int4 matmul (what quantize="int4" routes to) ------------------
     for name, (din, dout) in (("up", (cfg.d_model, cfg.d_ff)),

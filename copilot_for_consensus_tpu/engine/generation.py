@@ -87,6 +87,7 @@ from copilot_for_consensus_tpu.engine.tokenizer import (
 from copilot_for_consensus_tpu.obs.profile import scope, step_annotation
 from copilot_for_consensus_tpu.models import decoder, eva, quant
 from copilot_for_consensus_tpu.models.configs import DecoderConfig
+from copilot_for_consensus_tpu.ops.eva_attention import blocks_read
 from copilot_for_consensus_tpu.parallel.sharding import (
     DEFAULT_RULES,
     serving_param_rules,
@@ -2635,6 +2636,17 @@ class GenerationEngine:
         state = [eva.live_state(self.cfg, t) for t in held]
         return sum(a for a, _ in state), sum(b for _, b in state)
 
+    def _eva_read(self) -> int:
+        """Window columns and summaries under the blocks that decode
+        attention reads for the decoding slots right now: each one's
+        live extents rounded up to the kernel's blocks."""
+        store = self.max_len // self.cfg.chunk_size
+        return sum(
+            sum(blocks_read(*eva.live_state(self.cfg,
+                                            int(self._positions[s])),
+                            self.cfg.window_size, store))
+            for s in self._active)
+
     def _admit_pieces(self) -> None:
         """Admission for attention='eva': queued requests take free
         slots, and ONE wave advances every admitted prompt by its next
@@ -3401,7 +3413,8 @@ class GenerationEngine:
                           for s in self._active)
             extra = {"window_tokens": win_tokens,
                      "summary_tokens": sum_tokens,
-                     "windows_compacted": closing}
+                     "windows_compacted": closing,
+                     "state_tokens_read": self._eva_read()}
             # the static key: can some slot's window fill within this
             # dispatch? Only that program holds the compaction; two
             # decode programs in all

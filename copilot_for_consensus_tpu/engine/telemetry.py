@@ -338,6 +338,10 @@ class StepRecord:
     window_tokens: int = 0      # exact key/value columns live in all
     #                             slots when the dispatch began
     summary_tokens: int = 0     # chunk summaries live in all slots then
+    state_tokens_read: int = 0  # decode dispatches: the decoding
+    #                             slots' live columns and summaries,
+    #                             each rounded up to the blocks decode
+    #                             attention reads (ops/eva_attention.py)
 
     @property
     def occupancy(self) -> float:
@@ -581,8 +585,8 @@ class EngineTelemetry:
                     route: str = "", t_start: float | None = None,
                     new_tokens: int = 0, prompt_tokens: int = 0,
                     first_use: bool = False, windows_compacted: int = 0,
-                    window_tokens: int = 0,
-                    summary_tokens: int = 0) -> StepRecord:
+                    window_tokens: int = 0, summary_tokens: int = 0,
+                    state_tokens_read: int = 0) -> StepRecord:
         """``t_start`` is the dispatch's ``time.monotonic()`` start
         (default: now less ``duration_s``)."""
         if t_start is None:
@@ -596,7 +600,8 @@ class EngineTelemetry:
             t_start=t_start, t_end=t_start + duration_s,
             new_tokens=new_tokens, prompt_tokens=prompt_tokens,
             first_use=first_use, windows_compacted=windows_compacted,
-            window_tokens=window_tokens, summary_tokens=summary_tokens)
+            window_tokens=window_tokens, summary_tokens=summary_tokens,
+            state_tokens_read=state_tokens_read)
         self.recorder.record(rec)
         m, lb = self.metrics, self._labels
         m.observe("engine_step_seconds", duration_s,

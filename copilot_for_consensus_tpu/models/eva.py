@@ -41,6 +41,16 @@ the window restarts empty (``pool_chunks``, scope ``kv_compact``) — in
 an admission piece that ends on a window edge, and inside a decode
 dispatch, at the step where a slot's window fills, for exactly those
 slots, on the device (``decode_tokens``).
+
+Decode attention reads of this state what is live. On a TPU, block by
+block and in place: a kernel over the four stacked halves
+(``ops/eva_attention.py``) walks each slot's live window blocks and
+then its live summary blocks, and its flash partial is folded with the
+dispatch's own columns, a closing window's fresh summaries and the
+token's own key under the same one softmax
+(``ops.attention.combine_partials``; ``live_attention``). Elsewhere,
+whole pieces and a mask (``joint_attention``): the CPU's route, and
+what the tests hold the kernel to.
 """
 
 from __future__ import annotations
@@ -58,6 +68,12 @@ from copilot_for_consensus_tpu.obs.profile import scope
 from copilot_for_consensus_tpu.ops.attention import (
     _grouped_scores,
     _joint_probs,
+    _masked_partial,
+    combine_partials,
+)
+from copilot_for_consensus_tpu.ops.eva_attention import (
+    plan_blocks,
+    state_partial,
 )
 
 Params = dict[str, Any]
@@ -295,6 +311,19 @@ def prefill_piece(params: Params, tokens: jax.Array, lens: jax.Array,
 # ---------------------------------------------------------------------------
 
 
+def _masked_scores(q: jax.Array, k_cur: jax.Array, pieces: list) -> list:
+    """Scaled scores ``[B, H, 1, T]`` of one token's queries against
+    each piece ``(k [B, H, T, Dh], v, mask [B, T])``, ``-inf`` where
+    masked, and last against the token's own key."""
+    dt = q.dtype
+    qg = q[:, :, None, :]
+    logits = [jnp.where(m[:, None, None, :],
+                        _grouped_scores(qg, k.astype(dt)), -jnp.inf)
+              for k, _v, m in pieces]
+    logits.append(_grouped_scores(qg, k_cur.astype(dt)[:, :, None, :]))
+    return logits
+
+
 @scope("attn")
 def joint_attention(q: jax.Array, k_cur: jax.Array, v_cur: jax.Array,
                     pieces: list) -> jax.Array:
@@ -302,16 +331,38 @@ def joint_attention(q: jax.Array, k_cur: jax.Array, v_cur: jax.Array,
     and any number of pieces ``(k [B, H, T, Dh], v, mask [B, T])``:
     scores of every piece under one softmax."""
     dt = q.dtype
-    qg = q[:, :, None, :]
-    logits = [jnp.where(m[:, None, None, :],
-                        _grouped_scores(qg, k.astype(dt)), -jnp.inf)
-              for k, _v, m in pieces]
-    logits.append(_grouped_scores(qg, k_cur.astype(dt)[:, :, None, :]))
-    probs = _joint_probs(logits)
+    probs = _joint_probs(_masked_scores(q, k_cur, pieces))
     out = probs[-1].astype(dt) * v_cur.astype(dt)[:, :, None, :]
     for p, (_k, v, _m) in zip(probs, pieces):
         out += jnp.einsum("bhgt,bhtd->bhgd", p.astype(dt), v.astype(dt))
     return out[:, :, 0, :]
+
+
+def _reads_live_blocks() -> bool:
+    """Does decode attention go through the kernel that walks a slot's
+    live blocks (``ops/eva_attention.py``)? On a TPU; elsewhere the
+    XLA ``joint_attention`` over whole pieces serves (and is what the
+    tests hold the kernel to)."""
+    return jax.default_backend() == "tpu"
+
+
+@scope("attn")
+def live_attention(q: jax.Array, k_cur: jax.Array, v_cur: jax.Array,
+                   pieces: list, cache: Params, li: jax.Array,
+                   plan: tuple, window: int) -> jax.Array:
+    """``joint_attention`` of one token ``[B, H, Dh]`` over its own key
+    and value, the dispatch-local ``pieces`` and the live window
+    columns and summaries of layer ``li`` of the stacked ``cache``, of
+    which only the blocks that ``plan`` lists are read, in place
+    (``ops.eva_attention``: ``plan_blocks`` for windows of ``window``
+    columns, ``state_partial``). The local pieces stay in XLA as one
+    masked partial; the fold puts every score under the one
+    normaliser."""
+    state = state_partial(q, cache, li, plan, window=window)
+    local = _masked_partial(
+        jnp.concatenate(_masked_scores(q, k_cur, pieces), axis=-1),
+        [v for _k, v, _m in pieces] + [v_cur[:, :, None, :]])
+    return combine_partials([state, local], q.dtype)[:, :, 0, :]
 
 
 def decode_step(params: Params, tok: jax.Array, pos0: jax.Array,
@@ -322,11 +373,13 @@ def decode_step(params: Params, tok: jax.Array, pos0: jax.Array,
     """Step ``w`` (traced) of a dispatch that began at positions
     ``pos0``: one token per slot against a read-only cache.
 
-    The cache is read whole: the window buffers to their margin and
-    all of the summary stores, live or not (masked). Neither
-    is cut to what is live: a cut is a strided copy of what it keeps,
-    once per dispatch, and with a few slots the fullest window is most
-    of a window most of the time. ``k_buf``/``v_buf``
+    Of the cache a slot's live state is read: its window's columns
+    below its fill and its summaries, none for a slot that is not
+    decoding (position ``max_len``). On a TPU block by block, in place
+    (``live_attention``: the layer scan closes over the cache, and a
+    block with no live column is neither fetched nor scored);
+    elsewhere whole and masked (``joint_attention``; a cut in XLA is a
+    strided copy of what it keeps). ``k_buf``/``v_buf``
     ``[L, B, H, steps, Dh]``: the dispatch's own columns, valid below
     ``w``. ``k_new``/``v_new`` ``[L, B, H, W / C, Dh]`` or None: the
     summaries of a window that filled earlier in THIS dispatch. A slot
@@ -338,40 +391,54 @@ def decode_step(params: Params, tok: jax.Array, pos0: jax.Array,
     b = tok.shape[0]
     h, dh = cfg.n_heads, cfg.head_dim
     dt = params["tok_emb"].dtype
-    fill0 = pos0 % w_sz
-    nsum0 = pos0 // w_sz * (w_sz // c)
-    crossed = fill0 + w >= w_sz
     t_win, t_sum = cache["k"].shape[3], cache["ks"].shape[3]
+    fill0 = pos0 % w_sz
+    crossed = fill0 + w >= w_sz
+    live = pos0 < t_sum * c
+    win_len = jnp.where(crossed, 0, fill0)
+    sum_n = jnp.where(live, pos0 // w_sz * (w_sz // c), 0)
     steps = k_buf.shape[3]
     i_buf = jnp.arange(steps)[None, :]
-    m_win = (jnp.arange(t_win)[None, :] < fill0[:, None]) \
-        & ~crossed[:, None]
     m_buf = (i_buf >= jnp.where(crossed, w_sz - fill0, 0)[:, None]) \
         & (i_buf < w)
-    m_sum = jnp.arange(t_sum)[None, :] >= t_sum - nsum0[:, None]
     pos = (pos0 + w)[:, None]
     x = _embed(params, tok)[:, None, :].astype(jnp.float32)
+    in_place = _reads_live_blocks()
+    if in_place:
+        with scope("attn"):
+            plan = plan_blocks(win_len, sum_n, window=w_sz, store=t_sum)
+        halves = ()
+    else:
+        m_win = jnp.arange(t_win)[None, :] < win_len[:, None]
+        m_sum = jnp.arange(t_sum)[None, :] >= t_sum - sum_n[:, None]
+        halves = (cache["k"], cache["v"], cache["ks"], cache["vs"])
 
     def body(x, scanned):
-        layer, li, kw_l, vw_l, ks_l, vs_l = scanned
+        layer, li, *halves_l = scanned
         q, k, v = L._project_qkv(_norm(x, layer["attn_norm"], cfg, dt),
                                  layer, cfg, pos)
         take = lambda a: jax.lax.dynamic_index_in_dim(  # noqa: E731
             a, li, 0, keepdims=False)
-        pieces = [(kw_l, vw_l, m_win), (take(k_buf), take(v_buf), m_buf),
-                  (ks_l, vs_l, m_sum)]
+        local = [(take(k_buf), take(v_buf), m_buf)]
         if k_new is not None:
             kn_l = take(k_new)
-            pieces.append((kn_l, take(v_new), jnp.broadcast_to(
+            local.append((kn_l, take(v_new), jnp.broadcast_to(
                 crossed[:, None], (b, kn_l.shape[2]))))
         k_cur, v_cur = k[:, :, 0, :], v[:, :, 0, :]
-        o = joint_attention(q[:, :, 0, :], k_cur, v_cur, pieces)
+        if in_place:
+            o = live_attention(q[:, :, 0, :], k_cur, v_cur, local, cache,
+                               li, plan, w_sz)
+        else:
+            kw_l, vw_l, ks_l, vs_l = halves_l
+            o = joint_attention(
+                q[:, :, 0, :], k_cur, v_cur,
+                [(kw_l, vw_l, m_win), local[0], (ks_l, vs_l, m_sum),
+                 *local[1:]])
         x = _block_tail(x, o.reshape(b, 1, h * dh), layer, cfg, dt)
         return x, (k_cur, v_cur)
 
     x, (k_cols, v_cols) = jax.lax.scan(
-        body, x, (params["layers"], jnp.arange(cfg.n_layers),
-                  cache["k"], cache["v"], cache["ks"], cache["vs"]))
+        body, x, (params["layers"], jnp.arange(cfg.n_layers), *halves))
     return unembed(x, params, cfg)[:, 0], k_cols, v_cols
 
 

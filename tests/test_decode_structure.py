@@ -360,3 +360,87 @@ def test_compiled_eva_decode_updates_the_window_buffers_in_place(
                 and "S(1)" not in m.group(2):
             ops.add(m.group(3))
     assert ops == {"dynamic-update-slice"}
+
+
+# ---------------------------------------------------------------------------
+# and on a TPU, where decode attention reads the cache through the
+# kernel that walks a slot's live blocks (ops/eva_attention.py): the
+# layer scan closes over the cache, the kernel takes the four halves
+# whole, by pointer, and nothing else under `attn` touches them. The
+# trace sees a TPU here (`on_tpu`), as it does on the chip.
+# ---------------------------------------------------------------------------
+
+
+@pytest.fixture
+def on_tpu(monkeypatch):
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+
+
+def eva_decode_faults(one_chip, may_close):
+    """What the compiled EVA decode program may not hold: no kernel (or
+    more than the one); an op, other than the compaction's reads of a
+    closing window, whose result is one layer of a window or summary
+    half; an op that produces a whole half other than the merge's
+    in-place update (a copy, a relayout); an op under `attn` other than
+    the kernel that takes a whole half as an operand (a fusion that
+    reads all of it and masks)."""
+    eng = GenerationEngine(EVA_CFG, num_slots=SLOTS, max_len=EVA_MAX,
+                           prefill_buckets=(64,), dtype=jnp.bfloat16,
+                           attn_impl="xla", eos_id=-1)
+
+    def wrap(a):
+        return jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip)
+
+    i32 = wrap(jax.ShapeDtypeStruct((SLOTS,), jnp.int32))
+    text = eng._decode_eva_fn.lower(
+        jax.tree.map(wrap, eng.params), i32, i32,
+        jax.tree.map(wrap, eng._cache), wrap(jax.random.PRNGKey(0)),
+        may_close=may_close).compile().as_text()
+    halves = {tuple(eng._cache[n].shape) for n in ("k", "ks")}
+    one_layer = {s[1:] for s in halves} | {(1,) + s[1:] for s in halves}
+    kernels = [ln for ln in text.splitlines()
+               if 'custom_call_target="tpu_custom_call"' in ln]
+    faults = []
+    if len(kernels) != 1 or not re.search(
+            r'op_name="[^"]*/attn/eva_decode_attention/', kernels[0]):
+        faults.append(f"{len(kernels)} kernels")
+    shape_of = {}
+    for line in text.splitlines():
+        m = re.match(r"\s*(?:ROOT )?%?([\w.\-]+) = \w+\[([\d,]+)\](\S*) "
+                     r"([\w\-]+)\(([^)]*)", line)
+        if not m:
+            continue
+        name, dims, layout, op, operands = m.groups()
+        shape = shape_of[name] = tuple(int(x) for x in dims.split(","))
+        if op in ("parameter", "get-tuple-element", "bitcast"):
+            continue
+        if shape in one_layer and "/kv_compact/" not in line:
+            faults.append(f"{op} makes one layer of a half {shape}")
+        # (S(1): a prefetch of a buffer this small into fast memory)
+        if shape in halves and op != "dynamic-update-slice" \
+                and "S(1)" not in layout:
+            faults.append(f"{op} makes a whole half {shape}")
+        if "/attn/" in line and any(
+                shape_of.get(o) in halves
+                for o in re.findall(r"%([\w.\-]+)", operands)):
+            faults.append(f"{op} under attn reads a whole half")
+    return faults
+
+
+@pytest.mark.parametrize("may_close", [False, True],
+                         ids=["plain", "may-close"])
+def test_compiled_eva_decode_reads_the_cache_through_the_kernel_alone(
+        one_chip, on_tpu, may_close):
+    assert eva_decode_faults(one_chip, may_close) == []
+
+
+@pytest.mark.parametrize("may_close", [False, True],
+                         ids=["plain", "may-close"])
+def test_eva_guard_trips_on_joint_attention_over_whole_pieces(
+        one_chip, on_tpu, monkeypatch, may_close):
+    from copilot_for_consensus_tpu.models import eva
+
+    monkeypatch.setattr(eva, "_reads_live_blocks", lambda: False)
+    faults = eva_decode_faults(one_chip, may_close)
+    assert "0 kernels" in faults
+    assert sum("under attn reads a whole half" in f for f in faults) >= 2

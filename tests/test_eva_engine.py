@@ -241,6 +241,55 @@ def test_engine_serves_the_references_best_byte_at_every_position(params):
         == {True, False}
 
 
+def test_state_tokens_read_is_the_live_state_rounded_up_to_blocks(params):
+    """`StepRecord.state_tokens_read`: what the blocks of the decode
+    attention cover for the decoding slots. With every prompt admitted
+    in one wave no slot is mid-admission while another decodes, so the
+    live counts are the decoding slots': the blocks cover at least
+    that, and at most a block a piece a slot more. (At these sizes the
+    kernel's blocks are the window, 32 columns, and the whole store of
+    64 summaries.)"""
+    from copilot_for_consensus_tpu.ops.eva_attention import block_sizes
+
+    wb, sb = block_sizes(W, MAX_LEN // C)
+    assert (wb, sb) == (W, MAX_LEN // C)
+    rng = np.random.default_rng(6)
+    prompts = [rng.integers(0, V, size=n).tolist() for n in (5, 20, 31)]
+    eng = engine(params)
+    eng.generate(prompts, max_new_tokens=60)
+    recs = eng.telemetry.recorder.records()
+    decodes = [r for r in recs if r.kind == "decode"]
+    assert len(decodes) >= 8
+    for r in decodes:
+        live = r.window_tokens + r.summary_tokens
+        assert live <= r.state_tokens_read <= live + r.rows * (wb + sb)
+    # a window just opened behind summaries is read as one block of
+    # each; an empty window is not read at all
+    assert any(r.state_tokens_read > r.window_tokens + r.summary_tokens
+               for r in decodes)
+    assert all(r.state_tokens_read == 0 for r in recs
+               if r.kind == "prefill")
+
+
+def test_state_tokens_read_leaves_out_a_slot_that_is_mid_admission(params):
+    """A prompt of three windows is admitted in three waves with the
+    other slot's decode dispatches in between: its state counts as live
+    (`window_tokens`, `summary_tokens`: all slots) and is not read by
+    the decode attention, whose blocks cover the decoding slot only."""
+    rng = np.random.default_rng(7)
+    prompts = [rng.integers(0, V, size=n).tolist() for n in (9, 3 * W + 5)]
+    eng = engine(params, num_slots=2)
+    eng.generate(prompts, max_new_tokens=40)
+    decodes = [r for r in eng.telemetry.recorder.records()
+               if r.kind == "decode"]
+    beside = [r for r in decodes if r.rows == 1
+              and r.summary_tokens > 0 and r.state_tokens_read <= W]
+    assert beside       # decoded beside the long prompt's admission
+    for r in decodes:
+        assert r.state_tokens_read <= (r.window_tokens + r.summary_tokens
+                                       + r.rows * (W + MAX_LEN // C))
+
+
 def test_slot_reuse_after_retire_leaves_no_summary_behind(params):
     """One slot: a long sequence fills its summary store, retires, and
     a short prompt takes the slot. It is served as in a fresh engine."""
@@ -299,8 +348,8 @@ def test_dense_engines_write_no_eva_counts():
                            attn_impl="xla", eos_id=-1)
     eng.generate([[5, 6, 7]], max_new_tokens=9)
     for r in eng.telemetry.recorder.records():
-        assert (r.windows_compacted, r.window_tokens,
-                r.summary_tokens) == (0, 0, 0)
+        assert (r.windows_compacted, r.window_tokens, r.summary_tokens,
+                r.state_tokens_read) == (0, 0, 0, 0)
 
 
 def test_scopes_are_in_the_lowered_eva_programs(params):

@@ -16,8 +16,8 @@ One process per chip: the chip belongs to the process that first
 touches JAX, so the engine presets run entirely in THIS process and
 start nothing that needs the device. Every other row is its own
 command (``BENCH_PRESET=rag2k python bench.py``,
-``BENCH_PRESET=cap3072 python bench.py``, ``scripts/bench_poisson.py``,
-``scripts/bench_embed.py``), run one after another. Only the two
+``BENCH_PRESET=cap3072 python bench.py``, ``scripts/bench_embed.py``),
+run one after another. Only the two
 presets whose parent never imports jax (``multichip_serving``: virtual
 CPU devices pinned per child; ``pipeline_chaos``: host-only) spawn
 children.
@@ -2759,14 +2759,7 @@ def headline() -> dict:
         decode_window=window,
         windows_per_dispatch=n_windows,
         admission_token_budget=int(knob("BENCH_ADMIT_TOKENS", "16384")),
-        # Chunked-prefill piggybacking (prompts ≥ min_prompt ride the
-        # decode dispatches instead of stalling them in admission
-        # waves). BENCH_PIGGYBACK=0 restores the pure-wave path.
         prefill_chunk=int(knob("BENCH_PREFILL_CHUNK", "64")),
-        prefill_rows=int(knob("BENCH_PREFILL_ROWS", "4")),
-        piggyback_min_prompt=(
-            10**9 if knob("BENCH_PIGGYBACK", "0") != "1"
-            else int(knob("BENCH_PIGGYBACK_MIN", "512"))),
         spec_decode=spec_on,
         telemetry=tele_on,
     )

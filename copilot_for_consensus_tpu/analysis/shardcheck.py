@@ -19,7 +19,7 @@ spent. The bug class this catches is invisible to the syntactic pass:
   matching output: XLA drops the alias with only a warning and the
   buffer double-allocates (2x cache HBM on the decode path).
 * ``shard-kv-layout`` — the engine programs that hand the KV cache to
-  each other (admit / seeded admit / decode / piggyback / prefix-pool
+  each other (admit / seeded admit / decode / verify / prefix-pool
   publish) disagreeing on the one cache layout
   ``(n_layers, n_kv_heads, head_dim, dtype)``.
 * ``shard-bucket`` — a declared input length the padding-bucket table

@@ -21,8 +21,8 @@ True device-side overlap of prefill and decode is not possible on a
 single chip (programs serialize; this backend additionally blocks
 inside the dispatch call — the r2 window-pipelining experiment), so
 the steady-state duty cycle is decode_time / (decode_time +
-admission_time) — what ``scripts/bench_poisson.py`` measures against
-the batch bench.
+admission_time) — what the benchmark's ``qa-steady`` cell measures
+against its backlog cell.
 
 Resilience (``supervisor=``, engine/supervisor.py;
 docs/RESILIENCE.md): with a supervisor attached, an engine failure no
@@ -448,11 +448,10 @@ class AsyncEngineRunner:
     @staticmethod
     def _engine_idle(eng) -> bool:
         """No work anywhere in the engine: active slots, engine queue,
-        piggyback feed, AND (scheduler engines) the scheduler's tenant
+        AND (scheduler engines) the scheduler's tenant
         queues / chunked-prefill streams — a request parked in a tenant
         queue still needs step() calls to ever be released."""
-        if eng._active or eng._queue or getattr(eng, "_prefilling",
-                                                None):
+        if eng._active or eng._queue:
             return False
         if getattr(eng, "_chunking", None) \
                 or getattr(eng, "_chunk_pending", None):

@@ -34,7 +34,7 @@ Design constraints:
 
 Kinds are free-form strings; the engines wire the dispatch kinds they
 own (``prefill``/``prefill_seeded``/``prefill_chunk``/``decode``/
-``verify``/``piggyback``/``embed``) plus the host boundaries
+``verify``/``embed``) plus the host boundaries
 ``tokenize`` and ``prefix_publish``. Everything here is import-light
 host code (no jax).
 """
@@ -48,8 +48,7 @@ from dataclasses import dataclass, field
 #: dispatch kinds the engines wire fault points for (doc + test anchor;
 #: plans may name any kind — unknown kinds simply never fire)
 FAULT_KINDS = ("prefill", "prefill_seeded", "prefill_chunk", "decode",
-               "verify", "piggyback", "embed", "tokenize",
-               "prefix_publish")
+               "verify", "embed", "tokenize", "prefix_publish")
 
 #: spec.count value meaning "every occurrence from `at` on, forever"
 PERSISTENT = -1

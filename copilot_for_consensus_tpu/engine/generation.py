@@ -53,7 +53,7 @@ from __future__ import annotations
 
 import contextlib
 import time
-from dataclasses import dataclass, field
+from dataclasses import dataclass
 from typing import Any
 
 import jax
@@ -105,7 +105,6 @@ class Request:
     request_id: int
     prompt: list[int]
     max_new_tokens: int
-    submitted_at: float = field(default_factory=time.monotonic)
     decode_started_at: float = 0.0
     #: prefix-cache publish cap: how many LEADING prompt tokens may be
     #: published to the shared block pool on completion (None = whole
@@ -242,12 +241,7 @@ class GenerationEngine:
         decode_window: int = 8,
         windows_per_dispatch: int = 1,
         admission_token_budget: int = 16384,
-        admit_min_rows: int = 1,
-        admit_max_wait_s: float = 0.5,
         prefill_chunk: int = 64,
-        prefill_rows: int = 4,
-        piggyback_min_prompt: int = 10**9,
-        admit_hold_strict: bool = False,
         prefix_cache_blocks: int = 0,
         kv_pool_blocks: int = 0,
         kv_kernel: str = "auto",
@@ -342,8 +336,7 @@ class GenerationEngine:
                 mesh=mesh, prefix_cache_blocks=prefix_cache_blocks,
                 kv_pool_blocks=kv_pool_blocks, spec_decode=spec_decode,
                 kv_dtype=kv_dtype, quantize=quantize,
-                windows_per_dispatch=windows_per_dispatch,
-                piggyback_min_prompt=piggyback_min_prompt))
+                windows_per_dispatch=windows_per_dispatch))
             # a piece of a prompt fills a bucket and ends at or before
             # its window's edge: buckets that divide the window
             w = cfg.window_size
@@ -366,45 +359,8 @@ class GenerationEngine:
         # long-context engines (big caches) trade admission batching
         # for HBM headroom by lowering this.
         self.admission_token_budget = admission_token_budget
-        # Wave hysteresis for continuous arrivals: a prefill wave costs
-        # a full weight pass + pow-2 row padding regardless of size, so
-        # trickling arrivals amortize badly as 1-2-row waves. With
-        # admit_min_rows > 1 the engine keeps decoding until that many
-        # requests accumulate (or the oldest has waited admit_max_wait_s,
-        # or the batch is fully drained) and admits them as one wave.
-        self.admit_min_rows = max(1, admit_min_rows)
-        self.admit_max_wait_s = admit_max_wait_s
-        #: strict hold: apply the admit_min_rows hysteresis even when
-        #: many slots are free. Bigger waves amortize the weight pass
-        #: better (measured 9.9k vs 7k prompt tok/s at 64- vs 33-row
-        #: waves); under heavy continuous load the idle-slot bypass
-        #: defeats the batching, so load-oriented deployments set this.
-        self.admit_hold_strict = admit_hold_strict
-        # Chunked-prefill piggybacking: prompts in
-        # [piggyback_min_prompt, decode_window*prefill_chunk] skip the
-        # monolithic admission wave and ride the decode dispatches,
-        # prefill_chunk tokens per decode step across prefill_rows
-        # packed lanes — prefill FLOPs overlapping the bandwidth-bound
-        # decode stream. OPT-IN (default off): on this toolchain the
-        # piggyback program's structural costs (static P*C row padding
-        # in every matmul, ~65 µs per pallas call, scan-carry buffer
-        # rematerialization, no donation aliasing) measured above the
-        # overlap gain in every serving shape tried — an EMPTY chunk
-        # grid added +1.0 s to a 0.78 s dispatch — so the wave path
-        # stays the default. The machinery is kept correct (oracle
-        # tests vs the wave path) for backends where dispatch is
-        # cheaper; full measurements in docs/PERF.md (r4 study).
-        # Requires single-window dispatches and a dense model with no
-        # sliding window narrower than the cache.
+        # Tokens in one block of the prefix cache and of the KV pool.
         self.prefill_chunk = max(1, prefill_chunk)
-        self.prefill_rows = max(1, prefill_rows)
-        self.piggyback_min_prompt = piggyback_min_prompt
-        self._piggyback_ok = (
-            self.windows_per_dispatch == 1 and not cfg.is_moe
-            and not self._eva
-            and (cfg.sliding_window == 0
-                 or cfg.sliding_window >= self.max_len))
-        self._prefilling: list[tuple[Request, float]] = []  # packer feed
         self._dispatch_steps = self.decode_window * self.windows_per_dispatch
         if self.max_len - self._dispatch_steps < 1:
             raise ValueError(
@@ -631,10 +587,6 @@ class GenerationEngine:
             self.paged_admits = 0
             #: high-water mark of concurrently active streams
             self.peak_active = 0
-            # Piggyback packing binds rows to contiguous slot-cache
-            # spans; the paged layout serves the same overlap goal via
-            # chunked prefill, so the (default-off) path stays off.
-            self._piggyback_ok = False
             self._cache = None
         elif self._eva:
             # one manager for both kinds of state: per slot a window of
@@ -884,78 +836,6 @@ class GenerationEngine:
         self._decode_fn = jax.jit(_decode, donate_argnums=(3,),
                                   static_argnames=("kv_len", "n_windows"))
 
-        def _decode_piggyback(params, tokens, positions, cache, key,
-                              pre_tokens, pre_rope_base, pre_kv_begin,
-                              pre_kv_len, pre_sel_rel, pre_sel_w,
-                              pre_sel_p, pre_sidx, pre_pidx, *, kv_len):
-            """One decode window where every step also prefills C-token
-            chunks for P packed lanes (chunked-prefill piggybacking;
-            see ``decoder.decode_step_piggyback``). All packing
-            metadata is host-built (``_pack_prefill``): per-step arrays
-            [W, P] scan alongside the step index; the completion list
-            (sel_w, sel_p — up to W*P rows may finish per dispatch) and
-            the buffer→cache scatter maps are dispatch-level. Chunk KV
-            accumulates in dispatch buffers carried like the decode
-            window buffers and merges into the cache once; first tokens
-            for every completed row are sampled at the end from the
-            gathered last-position hidden states."""
-            w_sz = self.decode_window
-            n_l = cfg.n_layers
-            b = tokens.shape[0]
-            p, chunk = pre_tokens.shape[1], pre_tokens.shape[2]
-            win_shape = (n_l, b, cfg.n_kv_heads, w_sz, cfg.head_dim)
-            buf_shape = (n_l, p, cfg.n_kv_heads, w_sz * chunk,
-                         cfg.head_dim)
-
-            def body(carry, scanned):
-                tok, k_win, v_win, kbuf, vbuf, key = carry
-                w, pre_tok_w, rope_b, kv_b, kv_l, sel_r = scanned
-                key, sub = jax.random.split(key)
-                (logits, k_cols, v_cols, pre_k, pre_v,
-                 h_step) = decoder.decode_step_piggyback(
-                    params, tok, positions, w, cfg, cache, k_win,
-                    v_win, pre_tok_w, rope_b, kv_b, kv_l, sel_r,
-                    kbuf, vbuf, kv_len=kv_len)
-                k_win = decoder.put_window_column(k_win, k_cols, w)
-                v_win = decoder.put_window_column(v_win, v_cols, w)
-                with scope("kv_write"):
-                    kbuf = jax.lax.dynamic_update_slice_in_dim(
-                        kbuf, pre_k.astype(kbuf.dtype), w * chunk,
-                        axis=3)
-                    vbuf = jax.lax.dynamic_update_slice_in_dim(
-                        vbuf, pre_v.astype(vbuf.dtype), w * chunk,
-                        axis=3)
-                nxt = sample(logits, sub, self.sampling)
-                return (nxt, k_win, v_win, kbuf, vbuf, key), (nxt,
-                                                              h_step)
-
-            carry0 = (tokens,
-                      jnp.zeros(win_shape, self.kv_dtype),
-                      jnp.zeros(win_shape, self.kv_dtype),
-                      jnp.zeros(buf_shape, self.kv_dtype),
-                      jnp.zeros(buf_shape, self.kv_dtype),
-                      key)
-            (tok, k_win, v_win, kbuf, vbuf, key), (toks, h_all) = \
-                jax.lax.scan(body, carry0,
-                             (jnp.arange(w_sz), pre_tokens,
-                              pre_rope_base, pre_kv_begin, pre_kv_len,
-                              pre_sel_rel))
-            new_cache = decoder.merge_window(cache, k_win, v_win,
-                                            positions, steps=w_sz)
-            new_cache = decoder.merge_prefill(new_cache, kbuf, vbuf,
-                                              pre_sidx, pre_pidx)
-            # first tokens for completed rows: gather [M, D] hidden
-            # states at the host-chosen (step, lane) completion points
-            h_sel = h_all[pre_sel_w, pre_sel_p]            # [M, D]
-            first_logits = decoder._unembed(
-                h_sel[:, None, :], params, cfg)[:, 0]
-            key, sub = jax.random.split(key)
-            first = sample(first_logits, sub, self.sampling)
-            return toks, first, new_cache
-
-        self._piggy_fn = jax.jit(_decode_piggyback, donate_argnums=(3,),
-                                 static_argnames=("kv_len",))
-
         # ---- speculative decoding (prompt-lookup drafts) ---------------
         # Decode pays one full weight read per generated token; the
         # verify dispatch amortizes that read over k drafted tokens
@@ -1190,115 +1070,10 @@ class GenerationEngine:
                     0, s_v - 1)
                 return view[:, bidx, :, pidx, :].transpose(2, 0, 3, 1, 4)
 
-            def _admit_paged(params, tokens, lengths, pool_k, pool_v,
-                             sbids, soffs, key):
-                """Paged admission wave: prefill + pool scatter + first
-                token sample as ONE program. The scratch ferries the
-                fresh KV straight into pool blocks — no per-slot
-                contiguous cache exists to insert into."""
-                scratch = decoder.init_cache(cfg, tokens.shape[0],
-                                             tokens.shape[1],
-                                             dtype=self.kv_dtype)
-                logits, scratch = decoder.prefill(params, tokens,
-                                                  lengths, cfg, scratch,
-                                                  attn_impl=impl)
-                pool_k, pool_v = _pool_scatter(
-                    pool_k, pool_v, scratch["k"], scratch["v"], sbids,
-                    soffs)
-                first = sample(logits, key, self.sampling)
-                return first, pool_k, pool_v
-
-            def _admit_seeded_paged(params, tokens, lengths, pool_k,
-                                    pool_v, bids, pref_lens,
-                                    sbids, soffs, key):
-                """Zero-copy seeded admission: the matched prefix is
-                READ from its pool blocks for the suffix attention
-                (pointer indirection — the blocks were appended to the
-                slot's table host-side, nothing is copied into any
-                per-slot cache), the suffix prefills at the per-row
-                offset, and only the fresh suffix KV scatters into the
-                slot's OWN blocks. ``bids``: [N, NB] — 2-D so the dp
-                shard_map splits the row axis with its rows' block ids
-                (shard-local under dp sharding)."""
-                n, sbuc = tokens.shape
-                pk, pv = paged_gather_kv(pool_k, pool_v, bids)
-                scratch = decoder.init_cache(cfg, n, sbuc,
-                                             dtype=self.kv_dtype)
-                logits, scratch = decoder.prefill_seeded(
-                    params, tokens, lengths, pk, pv, pref_lens, cfg,
-                    scratch)
-                pool_k, pool_v = _pool_scatter(
-                    pool_k, pool_v, scratch["k"], scratch["v"], sbids,
-                    soffs)
-                first = sample(logits, key, self.sampling)
-                return first, pool_k, pool_v
-
-            def _decode_paged(params, tokens, positions, pool_k,
-                              pool_v, gbids, sbids, soffs, key, *,
-                              kv_len, n_windows=1):
-                """Windowed decode over the block tables: gather the
-                view ``gbids`` describes (wide enough for this
-                dispatch's writes), run the contiguous window program
-                over it unchanged, scatter the freshly merged columns
-                back into the pool."""
-                vk, vv = paged_gather_kv(pool_k, pool_v, gbids)
-                toks, view = _decode(params, tokens, positions,
-                                     {"k": vk, "v": vv}, key,
-                                     kv_len=kv_len,
-                                     n_windows=n_windows)
-                steps = n_windows * self.decode_window
-                k_new = _view_take(view["k"], positions, steps)
-                v_new = _view_take(view["v"], positions, steps)
-                pool_k, pool_v = _pool_scatter(pool_k, pool_v, k_new,
-                                               v_new, sbids, soffs)
-                return toks, pool_k, pool_v
-
-            def _verify_paged(params, tokens, qlens, positions,
-                              pool_k, pool_v, gbids, sbids, soffs,
-                              key, *, kv_len):
-                vk, vv = paged_gather_kv(pool_k, pool_v, gbids)
-                out, n_accept, view = _verify(
-                    params, tokens, qlens, positions,
-                    {"k": vk, "v": vv}, key, kv_len=kv_len)
-                k_new = _view_take(view["k"], positions,
-                                   tokens.shape[1])
-                v_new = _view_take(view["v"], positions,
-                                   tokens.shape[1])
-                pool_k, pool_v = _pool_scatter(pool_k, pool_v, k_new,
-                                               v_new, sbids, soffs)
-                return out, n_accept, pool_k, pool_v
-
-            def _chunk_paged(params, tokens, qlens, positions, pool_k,
-                             pool_v, gbids, sbids, soffs, key, *,
-                             kv_len):
-                vk, vv = paged_gather_kv(pool_k, pool_v, gbids)
-                first, view = _prefill_chunk(
-                    params, tokens, qlens, positions,
-                    {"k": vk, "v": vv}, key, kv_len=kv_len)
-                k_new = _view_take(view["k"], positions,
-                                   tokens.shape[1])
-                v_new = _view_take(view["v"], positions,
-                                   tokens.shape[1])
-                pool_k, pool_v = _pool_scatter(pool_k, pool_v, k_new,
-                                               v_new, sbids, soffs)
-                return first, pool_k, pool_v
-
             if mesh is None:
-                self._admit_paged_fn = jax.jit(
-                    _admit_paged, donate_argnums=(3, 4))
-                self._admit_seeded_paged_fn = jax.jit(
-                    _admit_seeded_paged, donate_argnums=(3, 4))
-                self._decode_paged_fn = jax.jit(
-                    _decode_paged, donate_argnums=(3, 4),
-                    static_argnames=("kv_len", "n_windows"))
-                self._verify_paged_fn = jax.jit(
-                    _verify_paged, donate_argnums=(4, 5),
-                    static_argnames=("kv_len",))
-                self._chunk_paged_fn = jax.jit(
-                    _chunk_paged, donate_argnums=(4, 5),
-                    static_argnames=("kv_len",))
+                gather, scatter = paged_gather_kv, _pool_scatter
             else:
-                # ---- mesh-sharded paged dispatches ------------------
+                # ---- mesh-sharded gather / scatter ------------------
                 # The block-table INDIRECTION (pool gather / pool
                 # scatter — the two ops GSPMD cannot partition: their
                 # indices are per-shard-local by the allocator's
@@ -1323,116 +1098,123 @@ class GenerationEngine:
                 VIEW = P(None, "dp", None, None, None)  # batch on dp
                 ROW2 = P("dp", None)
 
-                gather_sm = shard_map(
+                gather = shard_map(
                     paged_gather_kv, mesh=mesh,
                     in_specs=(POOL, POOL, ROW2),
                     out_specs=(VIEW, VIEW),
                     axis_names=manual, check_vma=False)
-                scatter_sm = shard_map(
+                scatter = shard_map(
                     _pool_scatter, mesh=mesh,
                     in_specs=(POOL, POOL, VIEW, VIEW, ROW2, ROW2),
                     out_specs=(POOL, POOL),
                     axis_names=manual, check_vma=False)
 
-                def _admit_paged_mesh(params, tokens, lengths, pool_k,
-                                      pool_v, sbids, soffs, key):
-                    scratch = decoder.init_cache(
-                        cfg, tokens.shape[0], tokens.shape[1],
-                        dtype=self.kv_dtype)
-                    logits, scratch = decoder.prefill(
-                        params, tokens, lengths, cfg, scratch,
-                        attn_impl=impl)
-                    pool_k, pool_v = scatter_sm(
-                        pool_k, pool_v, scratch["k"], scratch["v"],
-                        sbids, soffs)
-                    first = sample(logits, key, self.sampling)
-                    return first, pool_k, pool_v
+            def _admit_paged(params, tokens, lengths, pool_k, pool_v,
+                             sbids, soffs, key):
+                """Paged admission wave: prefill + pool scatter + first
+                token sample as ONE program. The scratch ferries the
+                fresh KV straight into pool blocks — no per-slot
+                contiguous cache exists to insert into."""
+                scratch = decoder.init_cache(cfg, tokens.shape[0],
+                                             tokens.shape[1],
+                                             dtype=self.kv_dtype)
+                logits, scratch = decoder.prefill(params, tokens,
+                                                  lengths, cfg, scratch,
+                                                  attn_impl=impl)
+                pool_k, pool_v = scatter(
+                    pool_k, pool_v, scratch["k"], scratch["v"], sbids,
+                    soffs)
+                first = sample(logits, key, self.sampling)
+                return first, pool_k, pool_v
 
-                self._admit_paged_fn = jax.jit(
-                    _admit_paged_mesh, donate_argnums=(3, 4))
+            def _admit_seeded_paged(params, tokens, lengths, pool_k,
+                                    pool_v, bids, pref_lens,
+                                    sbids, soffs, key):
+                """Zero-copy seeded admission: the matched prefix is
+                READ from its pool blocks for the suffix attention
+                (pointer indirection — the blocks were appended to the
+                slot's table host-side, nothing is copied into any
+                per-slot cache), the suffix prefills at the per-row
+                offset, and only the fresh suffix KV scatters into the
+                slot's OWN blocks. ``bids``: [N, NB] — 2-D so the dp
+                shard_map splits the row axis with its rows' block ids
+                (shard-local under dp sharding)."""
+                n, sbuc = tokens.shape
+                pk, pv = gather(pool_k, pool_v, bids)
+                scratch = decoder.init_cache(cfg, n, sbuc,
+                                             dtype=self.kv_dtype)
+                logits, scratch = decoder.prefill_seeded(
+                    params, tokens, lengths, pk, pv, pref_lens, cfg,
+                    scratch)
+                pool_k, pool_v = scatter(
+                    pool_k, pool_v, scratch["k"], scratch["v"], sbids,
+                    soffs)
+                first = sample(logits, key, self.sampling)
+                return first, pool_k, pool_v
 
-                def _admit_seeded_paged_mesh(params, tokens, lengths,
-                                             pool_k, pool_v, bids,
-                                             pref_lens, sbids, soffs,
-                                             key):
-                    pk, pv = gather_sm(pool_k, pool_v, bids)
-                    scratch = decoder.init_cache(
-                        cfg, tokens.shape[0], tokens.shape[1],
-                        dtype=self.kv_dtype)
-                    logits, scratch = decoder.prefill_seeded(
-                        params, tokens, lengths, pk, pv, pref_lens,
-                        cfg, scratch)
-                    pool_k, pool_v = scatter_sm(
-                        pool_k, pool_v, scratch["k"], scratch["v"],
-                        sbids, soffs)
-                    first = sample(logits, key, self.sampling)
-                    return first, pool_k, pool_v
+            def _decode_paged(params, tokens, positions, pool_k,
+                              pool_v, gbids, sbids, soffs, key, *,
+                              kv_len, n_windows=1):
+                """Windowed decode over the block tables: gather the
+                view ``gbids`` describes (wide enough for this
+                dispatch's writes), run the contiguous window program
+                over it unchanged, scatter the freshly merged columns
+                back into the pool."""
+                vk, vv = gather(pool_k, pool_v, gbids)
+                toks, view = _decode(params, tokens, positions,
+                                     {"k": vk, "v": vv}, key,
+                                     kv_len=kv_len,
+                                     n_windows=n_windows)
+                steps = n_windows * self.decode_window
+                k_new = _view_take(view["k"], positions, steps)
+                v_new = _view_take(view["v"], positions, steps)
+                pool_k, pool_v = scatter(pool_k, pool_v, k_new, v_new,
+                                         sbids, soffs)
+                return toks, pool_k, pool_v
 
-                self._admit_seeded_paged_fn = jax.jit(
-                    _admit_seeded_paged_mesh, donate_argnums=(3, 4))
+            def _verify_paged(params, tokens, qlens, positions,
+                              pool_k, pool_v, gbids, sbids, soffs,
+                              key, *, kv_len):
+                vk, vv = gather(pool_k, pool_v, gbids)
+                out, n_accept, view = _verify(
+                    params, tokens, qlens, positions,
+                    {"k": vk, "v": vv}, key, kv_len=kv_len)
+                k_new = _view_take(view["k"], positions,
+                                   tokens.shape[1])
+                v_new = _view_take(view["v"], positions,
+                                   tokens.shape[1])
+                pool_k, pool_v = scatter(pool_k, pool_v, k_new, v_new,
+                                         sbids, soffs)
+                return out, n_accept, pool_k, pool_v
 
-                def _decode_paged_mesh(params, tokens, positions,
-                                       pool_k, pool_v, gbids, sbids,
-                                       soffs, key, *, kv_len,
-                                       n_windows=1):
-                    vk, vv = gather_sm(pool_k, pool_v, gbids)
-                    toks, view = _decode(params, tokens, positions,
-                                         {"k": vk, "v": vv}, key,
-                                         kv_len=kv_len,
-                                         n_windows=n_windows)
-                    steps = n_windows * self.decode_window
-                    k_new = _view_take(view["k"], positions, steps)
-                    v_new = _view_take(view["v"], positions, steps)
-                    pool_k, pool_v = scatter_sm(pool_k, pool_v,
-                                                k_new, v_new, sbids,
-                                                soffs)
-                    return toks, pool_k, pool_v
+            def _chunk_paged(params, tokens, qlens, positions, pool_k,
+                             pool_v, gbids, sbids, soffs, key, *,
+                             kv_len):
+                vk, vv = gather(pool_k, pool_v, gbids)
+                first, view = _prefill_chunk(
+                    params, tokens, qlens, positions,
+                    {"k": vk, "v": vv}, key, kv_len=kv_len)
+                k_new = _view_take(view["k"], positions,
+                                   tokens.shape[1])
+                v_new = _view_take(view["v"], positions,
+                                   tokens.shape[1])
+                pool_k, pool_v = scatter(pool_k, pool_v, k_new, v_new,
+                                         sbids, soffs)
+                return first, pool_k, pool_v
 
-                self._decode_paged_fn = jax.jit(
-                    _decode_paged_mesh, donate_argnums=(3, 4),
-                    static_argnames=("kv_len", "n_windows"))
-
-                def _verify_paged_mesh(params, tokens, qlens,
-                                       positions, pool_k, pool_v,
-                                       gbids, sbids, soffs, key, *,
-                                       kv_len):
-                    vk, vv = gather_sm(pool_k, pool_v, gbids)
-                    out, n_accept, view = _verify(
-                        params, tokens, qlens, positions,
-                        {"k": vk, "v": vv}, key, kv_len=kv_len)
-                    k_new = _view_take(view["k"], positions,
-                                       tokens.shape[1])
-                    v_new = _view_take(view["v"], positions,
-                                       tokens.shape[1])
-                    pool_k, pool_v = scatter_sm(pool_k, pool_v,
-                                                k_new, v_new, sbids,
-                                                soffs)
-                    return out, n_accept, pool_k, pool_v
-
-                self._verify_paged_fn = jax.jit(
-                    _verify_paged_mesh, donate_argnums=(4, 5),
-                    static_argnames=("kv_len",))
-
-                def _chunk_paged_mesh(params, tokens, qlens,
-                                      positions, pool_k, pool_v,
-                                      gbids, sbids, soffs, key, *,
-                                      kv_len):
-                    vk, vv = gather_sm(pool_k, pool_v, gbids)
-                    first, view = _prefill_chunk(
-                        params, tokens, qlens, positions,
-                        {"k": vk, "v": vv}, key, kv_len=kv_len)
-                    k_new = _view_take(view["k"], positions,
-                                       tokens.shape[1])
-                    v_new = _view_take(view["v"], positions,
-                                       tokens.shape[1])
-                    pool_k, pool_v = scatter_sm(pool_k, pool_v,
-                                                k_new, v_new, sbids,
-                                                soffs)
-                    return first, pool_k, pool_v
-
-                self._chunk_paged_fn = jax.jit(
-                    _chunk_paged_mesh, donate_argnums=(4, 5),
-                    static_argnames=("kv_len",))
+            self._admit_paged_fn = jax.jit(
+                _admit_paged, donate_argnums=(3, 4))
+            self._admit_seeded_paged_fn = jax.jit(
+                _admit_seeded_paged, donate_argnums=(3, 4))
+            self._decode_paged_fn = jax.jit(
+                _decode_paged, donate_argnums=(3, 4),
+                static_argnames=("kv_len", "n_windows"))
+            self._verify_paged_fn = jax.jit(
+                _verify_paged, donate_argnums=(4, 5),
+                static_argnames=("kv_len",))
+            self._chunk_paged_fn = jax.jit(
+                _chunk_paged, donate_argnums=(4, 5),
+                static_argnames=("kv_len",))
 
             if self._kv_route == "kernel":
                 # ---- Pallas kernel route ----------------------------
@@ -1456,10 +1238,8 @@ class GenerationEngine:
                                 q_rows, pool_k, pool_v, li, tables,
                                 lengths, q_pos, window=window)
                         return call
-
-                    scatter_kfn = _pool_scatter
                 else:
-                    # dp MANUAL exactly like gather_sm/scatter_sm:
+                    # dp MANUAL exactly like gather/scatter above:
                     # the kernel indexes its shard-local pool slice
                     # with the shard-local ids the host built
                     # (per-shard OOB sentinel clamps in the wrapper,
@@ -1482,8 +1262,6 @@ class GenerationEngine:
                                       P("dp"), P("dp")),
                             out_specs=(QROWS, QROWS, QROWS),
                             axis_names=manual, check_vma=False)
-
-                    scatter_kfn = scatter_sm
 
                 partial_dec = _partial_for(cfg.sliding_window)
                 partial_seed = _partial_for(0)
@@ -1557,7 +1335,7 @@ class GenerationEngine:
                         v_all = jnp.concatenate(
                             [vw for _, vw in wins], 3)
                         toks_all = jnp.concatenate(outs, axis=0)
-                    pool_k, pool_v = scatter_kfn(
+                    pool_k, pool_v = scatter(
                         pool_k, pool_v, k_all, v_all, sbids, soffs)
                     return toks_all, pool_k, pool_v
 
@@ -1583,7 +1361,7 @@ class GenerationEngine:
                     logits, k_new, v_new = decoder.prefill_seeded_paged(
                         params, tokens, lengths, pref_lens, cfg,
                         partial_fn, all_logits=False)
-                    pool_k, pool_v = scatter_kfn(
+                    pool_k, pool_v = scatter(
                         pool_k, pool_v, k_new, v_new, sbids, soffs)
                     first = sample(logits, key, self.sampling)
                     return first, pool_k, pool_v
@@ -1604,7 +1382,7 @@ class GenerationEngine:
                     logits, k_new, v_new = decoder.prefill_seeded_paged(
                         params, tokens, qlens, positions, cfg,
                         partial_fn, all_logits=True)
-                    pool_k, pool_v = scatter_kfn(
+                    pool_k, pool_v = scatter(
                         pool_k, pool_v, k_new, v_new, sbids, soffs)
                     out, n_accept = verify_draft(
                         logits, tokens[:, 1:], qlens - 1, key,
@@ -1633,7 +1411,7 @@ class GenerationEngine:
                     last, k_new, v_new = decoder.prefill_seeded_paged(
                         params, tokens, qlens, positions, cfg,
                         partial_fn, all_logits=False)
-                    pool_k, pool_v = scatter_kfn(
+                    pool_k, pool_v = scatter(
                         pool_k, pool_v, k_new, v_new, sbids, soffs)
                     first = sample(last, key, self.sampling)
                     return first, pool_k, pool_v
@@ -1679,11 +1457,11 @@ class GenerationEngine:
         self._free = list(range(num_slots))
         self._active: dict[int, Request] = {}          # slot → request
         self._generated: dict[int, list[int]] = {}     # slot → new tokens
-        # Free/prefilling slots park at position max_len (out of range):
-        # every decode dispatch advances ALL rows and merges their
-        # garbage KV at positions0+w — an in-range stale position would
-        # let a freed slot's garbage overwrite a piggyback-prefilling
-        # occupant's freshly written timeline.
+        # Free slots, and slots a chunked prefill is still filling, park
+        # at position max_len (out of range): every decode dispatch
+        # advances ALL rows and merges their garbage KV at positions0+w,
+        # and an out-of-range column drops — an in-range stale position
+        # would overwrite what the slot's next occupant writes there.
         self._positions = np.full(num_slots, self.max_len,
                                   dtype=np.int32)
         self._next_tok = np.zeros(num_slots, dtype=np.int32)
@@ -1695,15 +1473,9 @@ class GenerationEngine:
         #: insert + first-token sync) since engine build — benches
         #: snapshot it around a run to split admission from decode.
         self.admitted_s = 0.0
-        #: dispatch accounting (benches read these to see where the
-        #: time went): piggybacked vs plain decode dispatches, and how
-        #: many prompt tokens / rows rode the piggyback path
-        self.piggy_s = 0.0
-        self.piggy_dispatches = 0
+        #: decode dispatches and their wall time (benches read these)
         self.plain_s = 0.0
         self.plain_dispatches = 0
-        self.piggy_rows = 0
-        self.piggy_tokens = 0
         #: speculative-decoding accounting (spec_stats()): lookups/hits
         #: count draft-index probes; drafted/accepted count draft
         #: tokens through verify; rows counts (slot, verify-dispatch)
@@ -1893,7 +1665,7 @@ class GenerationEngine:
                 self._chunk_step()
             if self.paged:
                 self.peak_active = max(self.peak_active, self._occupied)
-            if self._active or self._prefilling:
+            if self._active:
                 self._phase("plan", ahead=True)
                 self._decode_once()
             self._phase("upkeep")
@@ -1985,7 +1757,7 @@ class GenerationEngine:
     def prefix_stats(self) -> dict:
         """Prefix-cache counters for benches/metrics. ``hit_rate`` is
         over admission lookups; ``prefill_tokens``/``..._saved`` are
-        engine-wide prompt-token accounting (wave + piggyback paths)."""
+        engine-wide prompt-token accounting."""
         out = {
             "enabled": bool(self._prefixes),
             "prefill_tokens": self.prefill_tokens,
@@ -2088,8 +1860,8 @@ class GenerationEngine:
 
     @property
     def queue_depth(self) -> int:
-        n = (len(self._queue) + len(self._prefilling)
-             + len(self._chunk_pending) + len(self._chunking))
+        n = (len(self._queue) + len(self._chunk_pending)
+             + len(self._chunking))
         if self._sched is not None:
             n += self._sched.queued
         return n
@@ -2169,13 +1941,6 @@ class GenerationEngine:
                 expired += [r for r in self._chunk_pending
                             if r.deadline_at <= now]
                 self._chunk_pending = live
-        if self._prefilling:
-            live = [(r, t) for r, t in self._prefilling
-                    if r.deadline_at > now]
-            if len(live) != len(self._prefilling):
-                expired += [r for r, _t in self._prefilling
-                            if r.deadline_at <= now]
-                self._prefilling = live
         for slot in list(self._chunking):
             req = self._chunking[slot][0]
             if req.deadline_at <= now:
@@ -2224,63 +1989,6 @@ class GenerationEngine:
         batched cache insert, one sample, one host fetch of the N first
         tokens."""
         if not (self._queue and self._free):
-            return
-        if self._piggyback_ok:
-            # Eligible prompts ride the decode dispatches chunk by
-            # chunk (_decode_once) INSTEAD of a monolithic wave — up to
-            # ~two dispatches' worth of backlog, the piggyback grid's
-            # absorption rate. Beyond that the wave takes the overflow:
-            # bulk cold-start admission is MXU-bound either way and the
-            # wave's big matmuls do it at the best rate (measured on
-            # the one-shot 32×2048 batch), while a steady trickle rides
-            # the dispatches nearly free (measured: +0.18 s per
-            # dispatch carrying 8192 prompt tokens vs 0.77 s as a
-            # standalone wave). The backlog bound makes the policy
-            # self-balancing with no occupancy heuristics.
-            cap = self.decode_window * self.prefill_chunk
-            budget = 2 * cap * self.prefill_rows - sum(
-                len(r.prompt) for r, _ in self._prefilling)
-            keep = []
-            for req in self._queue:
-                plen = len(req.prompt)
-                # Prefix-cache integration with the piggyback path:
-                # requests whose prefix is cached route to the SEEDED
-                # admission wave instead — the piggyback chunk grid
-                # attends only its own dispatch buffer, so a hit riding
-                # it would re-prefill the cached span anyway. Misses
-                # still piggyback, and their completions still publish.
-                if (self._prefix is not None
-                        and self._prefix.match_tokens(
-                            req.prompt,
-                            digests=self._req_digests(req)) > 0):
-                    keep.append(req)
-                    continue
-                if (self.piggyback_min_prompt <= plen <= cap
-                        and plen <= budget):
-                    # whole prompts only: the packer places each row as
-                    # one consecutive chunk run inside a single
-                    # dispatch, so its kv never straddles buffers. NO
-                    # slot yet — slots are taken at PACK time, so a
-                    # slot is only occupied during the dispatch that
-                    # prefills it (binding at admit time measured ~2
-                    # dispatches of per-slot idleness under Poisson
-                    # load, which ate the whole piggyback win).
-                    self._prefilling.append((req, time.monotonic()))
-                    budget -= plen
-                else:
-                    keep.append(req)
-            self._queue = keep
-            if not (self._queue and self._free):
-                return
-        if (len(self._queue) < self.admit_min_rows
-                and (self.admit_hold_strict
-                     or len(self._free) * 4 <= self.num_slots)
-                and (time.monotonic() - self._queue[0].submitted_at
-                     < self.admit_max_wait_s)):
-            # Let the wave fill while decode keeps running — but only
-            # while the batch is ≥75% occupied; holding arrivals back
-            # while slots idle wastes more decode capacity than the
-            # wave-padding it saves.
             return
         t0 = time.monotonic()
         batch: list[tuple[int, Request]] = []
@@ -2613,11 +2321,6 @@ class GenerationEngine:
             raise ValueError(
                 "windows_per_dispatch > 1 cannot serve attention='eva': "
                 "a dispatch closes at most one window a slot")
-        if asked["piggyback_min_prompt"] < 10**9:
-            raise ValueError(
-                "piggyback prefill cannot serve attention='eva': its "
-                "chunk grid scatters per-position columns "
-                "(decoder.merge_prefill)")
         cfg, w = self.cfg, self.cfg.window_size
         if (cfg.n_kv_heads != cfg.n_heads or cfg.is_moe
                 or cfg.sliding_window or w <= 0 or cfg.chunk_size <= 0
@@ -2757,9 +2460,6 @@ class GenerationEngine:
         decode programs ever compile. The dispatch's own fresh KV lives
         in the window/done buffers until the final merge, so the extent
         covers only what was in the cache BEFORE the dispatch."""
-        # piggyback-prefilling rows have no cache prefix (whole rows
-        # pack into one dispatch), so only active decode positions
-        # constrain the extent
         hi = max([int(self._positions[s]) for s in self._active] + [0])
         return self._kv_extent(hi)
 
@@ -3202,8 +2902,7 @@ class GenerationEngine:
             # Decode ITL on the decode chips stays flat; the shed loop
             # (handoff_backlog signal) handles the door.
             return
-        staged = (len(self._queue) + len(self._prefilling)
-                  + len(self._chunk_pending))
+        staged = len(self._queue) + len(self._chunk_pending)
         room = len(self._free) - staged
         if room <= 0:
             return
@@ -3378,32 +3077,24 @@ class GenerationEngine:
         window = self._dispatch_steps
         # Speculation routes a step to the verify dispatch whenever any
         # active slot's draft index hits (the no-hit slots ride the
-        # same program in the k=0 lane). Steps with piggyback chunks
-        # pending keep the piggyback dispatch — its chunk grid and the
-        # verify suffix cannot share one program — and draft-less
-        # steps keep the plain windowed path: a window amortizes the
-        # host sync over ``decode_window`` tokens, which beats a
-        # 1-token verify dispatch when there is nothing to verify.
+        # same program in the k=0 lane). Draft-less steps keep the
+        # plain windowed path: a window amortizes the host sync over
+        # ``decode_window`` tokens, which beats a 1-token verify
+        # dispatch when there is nothing to verify.
         # _spec_allowed consults the supervisor's spec_verify circuit
         # breaker: open → plain decode serves (degraded mode), half-
         # open → exactly this step may probe with a verify dispatch.
-        if (self.spec_decode and self._active and self._spec_allowed()
-                and not (self._prefilling and self._free)):
+        if self.spec_decode and self._active and self._spec_allowed():
             drafts = self._spec_drafts()
             if drafts:
                 self._dispatch_verify(drafts)
                 return
         self._key, sub = jax.random.split(self._key)
-        # Snapshot BEFORE dispatch: rows the piggyback path activates
-        # mid-call were prefilling during this window — their decode
-        # lanes carried garbage and must not be harvested this round.
+        # a snapshot: the harvest below retires rows out of _active
         active_before = list(self._active.items())
         t0 = time.monotonic()
-        piggy = bool(self._prefilling and self._free)
-        step_kind = "piggyback" if piggy else "decode"
         seq = self.telemetry.next_step() if self.telemetry is not None \
             else None
-        piggy_tok0, piggy_rows0 = self.piggy_tokens, self.piggy_rows
         kv_len = self._kv_bucket()
         extra: dict = {}
         if self._eva:
@@ -3420,8 +3111,8 @@ class GenerationEngine:
             # decode programs in all
             kv_len = closing > 0
         self._phase(None)
-        with step_annotation(step_kind, seq), \
-                self._dispatch_boundary(step_kind):
+        with step_annotation("decode", seq), \
+                self._dispatch_boundary("decode"):
             if self._eva:
                 toks, self._cache = self._decode_eva_fn(
                     self.params, jnp.asarray(self._next_tok),
@@ -3430,10 +3121,6 @@ class GenerationEngine:
                 toks = _host_fetch(toks)                 # [steps, slots]
                 self.plain_s += time.monotonic() - t0
                 self.plain_dispatches += 1
-            elif piggy:
-                toks = self._dispatch_piggyback(sub)
-                self.piggy_s += time.monotonic() - t0
-                self.piggy_dispatches += 1
             else:
                 # the override (if any) is read at TRACE time; holding
                 # it around the call bakes the qmatmul route into the
@@ -3513,21 +3200,16 @@ class GenerationEngine:
             if finished:
                 self._retire(slot, finished)
         if self.telemetry is not None:
-            # tokens: harvested decode tokens + any prompt tokens the
-            # piggyback chunk grid prefilled this dispatch; the padded
-            # grid is window × slots (every row advances every step)
+            # the padded grid is window × slots (every row advances
+            # every step)
             self.telemetry.record_step(
-                step_kind, step_s, seq=seq, rows=len(active_before),
-                batch=self.num_slots,
-                tokens=harvested_total
-                + (self.piggy_tokens - piggy_tok0),
+                "decode", step_s, seq=seq, rows=len(active_before),
+                batch=self.num_slots, tokens=harvested_total,
                 padded_tokens=window * self.num_slots,
                 route=self._kv_route, t_start=t0,
-                new_tokens=harvested_total
-                + (self.piggy_rows - piggy_rows0),
-                prompt_tokens=self.piggy_tokens - piggy_tok0,
+                new_tokens=harvested_total,
                 first_use=self._first_use(
-                    step_kind, kv_len, self.windows_per_dispatch),
+                    "decode", kv_len, self.windows_per_dispatch),
                 **extra)
 
     def _spec_allowed(self) -> bool:
@@ -3709,135 +3391,6 @@ class GenerationEngine:
                 route=self._kv_route, t_start=t0,
                 new_tokens=self.spec_emitted_tokens - emitted0,
                 first_use=self._first_use("verify", kv_len, s))
-
-    def _pack_prefill(self):
-        """Pack whole pending prompts into the W×P chunk grid.
-
-        Each selected row occupies one consecutive run of steps in one
-        lane (its buffer span is contiguous, so the flash begin/length
-        bounds describe it exactly). First-fit over lanes; rows that
-        don't fit wait for the next dispatch. Returns the per-step
-        metadata arrays, the completion list, the buffer→cache scatter
-        maps, and the selected (slot, req, started, lane, end_step)
-        rows — everything ``_piggy_fn`` needs, all host-built.
-        """
-        w_sz, chunk = self.decode_window, self.prefill_chunk
-        p = self.prefill_rows
-        buf = w_sz * chunk
-        m_sel = w_sz * p                       # max completions
-        pre_tok = np.zeros((w_sz, p, chunk), dtype=np.int32)
-        rope_base = np.zeros((w_sz, p), dtype=np.int32)
-        kv_begin = np.full((w_sz, p), buf, dtype=np.int32)   # idle: all
-        kv_len = np.zeros((w_sz, p), dtype=np.int32)         # masked
-        sel_rel = np.zeros((w_sz, p), dtype=np.int32)
-        sel_w = np.zeros(m_sel, dtype=np.int32)
-        sel_p = np.zeros(m_sel, dtype=np.int32)
-        sidx = np.full((p, buf), self.num_slots, dtype=np.int32)  # OOB
-        pidx = np.full((p, buf), self.max_len, dtype=np.int32)
-        lane_next = [0] * p
-        placed = []
-        deferred = []
-        for req, started in self._prefilling:
-            plen = len(req.prompt)
-            steps = -(-plen // chunk)
-            lane = min(range(p), key=lambda i: lane_next[i])
-            if (lane_next[lane] + steps > w_sz or not self._free
-                    or self._occupied + len(placed) >= self._slot_cap):
-                deferred.append((req, started))
-                continue                        # wait for next dispatch
-            slot = self._free.pop(0)
-            s0 = lane_next[lane]
-            lane_next[lane] = s0 + steps
-            flat = np.zeros(steps * chunk, dtype=np.int32)
-            flat[:plen] = req.prompt
-            pre_tok[s0:s0 + steps, lane] = flat.reshape(steps, chunk)
-            rope_base[s0:s0 + steps, lane] = np.arange(steps) * chunk
-            kv_begin[s0:s0 + steps, lane] = s0 * chunk
-            kv_len[s0:s0 + steps, lane] = s0 * chunk + np.minimum(
-                (np.arange(steps) + 1) * chunk, plen)
-            end = s0 + steps - 1
-            sel_rel[end, lane] = (plen - 1) % chunk
-            sel_w[len(placed)] = end
-            sel_p[len(placed)] = lane
-            sidx[lane, s0 * chunk:s0 * chunk + plen] = slot
-            pidx[lane, s0 * chunk:s0 * chunk + plen] = np.arange(plen)
-            placed.append((slot, req, started, len(placed)))
-            self.piggy_rows += 1
-            self.piggy_tokens += plen
-            self.prefill_tokens += plen
-        self._prefilling = deferred
-        return (pre_tok, rope_base, kv_begin, kv_len, sel_rel, sel_w,
-                sel_p, sidx, pidx, placed)
-
-    def _dispatch_piggyback(self, key) -> np.ndarray:
-        """One decode window with packed prefill chunks riding it.
-        Returns the decoded tokens [window, slots]; completed prompts
-        are activated into their slots here."""
-        (pre_tok, rope_base, kv_begin, kv_len, sel_rel, sel_w, sel_p,
-         sidx, pidx, placed) = self._pack_prefill()
-        try:
-            toks = self._piggy_dispatch(
-                key, pre_tok, rope_base, kv_begin, kv_len, sel_rel,
-                sel_w, sel_p, sidx, pidx, placed)
-        except Exception:
-            # Lossless unwind (crash containment): packed rows took
-            # slots and left _prefilling but never activated — requeue
-            # them (queue head) and free their slots, and back out the
-            # accounting _pack_prefill charged for work that never ran.
-            for slot, req, _started, _i in placed:
-                self._free.append(slot)
-            self._queue[0:0] = [req for _s, req, _t, _i in placed]
-            n_tok = sum(len(req.prompt) for _s, req, _t, _i in placed)
-            self.piggy_rows -= len(placed)
-            self.piggy_tokens -= n_tok
-            self.prefill_tokens -= n_tok
-            raise
-        return toks
-
-    def _piggy_dispatch(self, key, pre_tok, rope_base, kv_begin,
-                        kv_len, sel_rel, sel_w, sel_p, sidx, pidx,
-                        placed) -> np.ndarray:
-        with quant.pallas_qmatmul_override(self._decode_pallas_override):
-            toks_dev, first_dev, self._cache = self._piggy_fn(
-                self.params,
-                jnp.asarray(self._next_tok),
-                jnp.asarray(self._positions),
-                self._cache,
-                key,
-                jnp.asarray(pre_tok),
-                jnp.asarray(rope_base),
-                jnp.asarray(kv_begin),
-                jnp.asarray(kv_len),
-                jnp.asarray(sel_rel),
-                jnp.asarray(sel_w),
-                jnp.asarray(sel_p),
-                jnp.asarray(sidx),
-                jnp.asarray(pidx),
-                kv_len=self._kv_bucket(),
-            )
-        toks = _host_fetch(toks_dev)
-        first = _host_fetch(first_dev)
-        now = time.monotonic()
-        for slot, req, started, i in placed:
-            # every placed row completed (whole prompts only); its
-            # first generated token was sampled in-program from the
-            # last prompt position
-            tok = int(first[i])
-            if self.telemetry is not None:
-                self.telemetry.on_admit(req.request_id,
-                                        wave_start=started,
-                                        admit_kind="piggyback")
-            self._active[slot] = req
-            self._generated[slot] = [tok]
-            self._spec_track(slot, req, tok)
-            self._positions[slot] = len(req.prompt)
-            self._next_tok[slot] = tok
-            self._t_prefill[slot] = now - started
-            req.decode_started_at = now
-            if tok in self._eos_set or req.max_new_tokens <= 1:
-                self._retire(slot,
-                             "eos" if tok in self._eos_set else "length")
-        return toks
 
     def _retire(self, slot: int, reason: str) -> None:
         self._positions[slot] = self.max_len   # park OOB (see __init__)
@@ -4060,10 +3613,9 @@ def _shardcheck_generation_engine():
 
     * every ``donate_argnums`` entry aliases a shape/dtype-matching
       output (an undonated slot cache double-allocates per dispatch);
-    * admit / seeded admit / decode / piggyback / verify / prefix-pool
-      publish all agree on ONE KV-cache layout (L, Hkv, Dh, dtype) —
-      the cache is handed between these six programs every serving
-      step;
+    * admit / seeded admit / decode / verify / prefix-pool publish all
+      agree on ONE KV-cache layout (L, Hkv, Dh, dtype) — the cache is
+      handed between these five programs every serving step;
     * the prefill bucket table covers the longest admissible prompt
       (``prompt_limit``), and the verify dispatch's token-width table
       covers every declared speculative draft length, both bounding
@@ -4092,7 +3644,7 @@ def _shardcheck_generation_engine():
     eng = GenerationEngine(cfg, num_slots=4, max_len=64,
                            prefill_buckets=(16, 32), decode_window=4,
                            windows_per_dispatch=1, prefill_chunk=8,
-                           prefill_rows=2, prefix_cache_blocks=4,
+                           prefix_cache_blocks=4,
                            spec_decode=True, spec_draft_lens=(0, 2, 4))
 
     def aval(tree):
@@ -4104,8 +3656,7 @@ def _shardcheck_generation_engine():
     cache = aval(eng._cache)
     pool = aval(eng._prefix.pool)
     key = jax.random.PRNGKey(0)
-    n, bucket, w, p, chunk = 4, 16, eng.decode_window, eng.prefill_rows, \
-        eng.prefill_chunk
+    n, bucket, chunk = 4, 16, eng.prefill_chunk
     group = "engine.generation-kv"
     return [
         ContractCase(
@@ -4148,17 +3699,6 @@ def _shardcheck_generation_engine():
             buckets=tuple(k + 1 for k in eng.spec_draft_lens),
             bucket_covers=(max(eng.spec_draft_lens) + 1,),
             hlo=HloSpec(peak_bytes=510_000)),
-        ContractCase(
-            label="piggyback",
-            fn=functools.partial(eng._piggy_fn, kv_len=eng.max_len),
-            args=(eng.params, S((eng.num_slots,), i32),
-                  S((eng.num_slots,), i32), cache, key,
-                  S((w, p, chunk), i32), S((w, p), i32), S((w, p), i32),
-                  S((w, p), i32), S((w, p), i32), S((w * p,), i32),
-                  S((w * p,), i32), S((p, w * chunk), i32),
-                  S((p, w * chunk), i32)),
-            donate_argnums=(3,), kv_group=group,
-            kv_caches=(("slot-cache", cache),)),
         ContractCase(
             label="prefix-publish", fn=eng._prefix._publish_fn,
             args=(pool, cache["k"], cache["v"], S((2,), i32),
@@ -4220,13 +3760,13 @@ def _paged_contract_cases(cfg, group):
     eng = GenerationEngine(cfg, num_slots=4, max_len=64,
                            prefill_buckets=(16, 32), decode_window=4,
                            windows_per_dispatch=1, prefill_chunk=8,
-                           prefill_rows=2, prefix_cache_blocks=4,
+                           prefix_cache_blocks=4,
                            kv_pool_blocks=16, spec_decode=True,
                            spec_draft_lens=(0, 2, 4))
     eng_k = GenerationEngine(cfg, num_slots=4, max_len=64,
                              prefill_buckets=(16, 32), decode_window=4,
                              windows_per_dispatch=1, prefill_chunk=8,
-                             prefill_rows=2, prefix_cache_blocks=4,
+                             prefix_cache_blocks=4,
                              kv_pool_blocks=16, kv_kernel="pallas",
                              spec_decode=True, spec_draft_lens=(0, 2, 4))
     S = jax.ShapeDtypeStruct

@@ -347,8 +347,8 @@ class Scheduler:
 
         Shed policy: level 1 (batch lane sheds) when the latency SLOs
         are violated while the slots are actually saturated — high TTFT
-        with idle slots is admission hysteresis, not overload — or when
-        the queue passes ``batch_shed_depth``; level 2 (everything
+        with idle slots is the admission path's doing, not overload —
+        or when the queue passes ``batch_shed_depth``; level 2 (everything
         sheds) at ``max_queue_depth``. The queue-depth terms mean the
         loop degrades gracefully when telemetry is disabled.
 

@@ -572,7 +572,7 @@ class EngineSupervisor:
     def purge_queued(self) -> list:
         """Drop every request still QUEUED inside the engine
         (DISPATCHER THREAD ONLY) — engine queue, chunk-pending,
-        piggyback feed, scheduler tenant queues (via
+        scheduler tenant queues (via
         ``Scheduler.purge``, which repays the quota ledgers and
         re-exports the gauges) — and abandon their telemetry spans.
         Used after a watchdog suspect event or a terminal unhealthy
@@ -582,13 +582,10 @@ class EngineSupervisor:
         dropped: list = []
         dropped += list(getattr(eng, "_queue", []))
         dropped += list(getattr(eng, "_chunk_pending", []))
-        dropped += [r for r, _t in getattr(eng, "_prefilling", [])]
         if hasattr(eng, "_queue"):
             eng._queue.clear()
         if hasattr(eng, "_chunk_pending"):
             eng._chunk_pending.clear()
-        if hasattr(eng, "_prefilling"):
-            eng._prefilling.clear()
         sched = getattr(eng, "_sched", None)
         if sched is not None:
             dropped += sched.purge()

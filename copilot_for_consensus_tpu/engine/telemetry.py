@@ -15,7 +15,7 @@ assigns to the TPU build, three pieces:
   and queue wait.
 * **Step telemetry** (``StepRecord`` + ``FlightRecorder``): a bounded,
   lock-cheap ring buffer with one record per device dispatch — wave
-  kind (prefill / prefill_seeded / decode / verify / piggyback /
+  kind (prefill / prefill_seeded / prefill_chunk / decode / verify /
   embed), batch occupancy, padding-bucket waste, draft acceptance,
   host wall time, and a monotonically increasing step id that matches
   the ``jax.profiler`` ``StepTraceAnnotation`` around the dispatch
@@ -100,7 +100,7 @@ METRICS: dict[str, tuple[str, tuple[str, ...], str]] = {
     "engine_step_seconds": (
         "histogram", ("engine", "kind"),
         "Host wall time per device dispatch, by wave kind (prefill|"
-        "prefill_seeded|decode|verify|piggyback|embed)."),
+        "prefill_seeded|prefill_chunk|decode|verify|embed)."),
     "engine_host_phase_seconds_total": (
         "counter", ("engine", "phase"),
         "Host seconds of the serving loop outside device dispatches, "
@@ -109,7 +109,7 @@ METRICS: dict[str, tuple[str, tuple[str, ...], str]] = {
         "the rest is time the chip waits on host work."),
     "engine_queue_depth": (
         "gauge", ("engine",),
-        "Requests waiting for a slot (queued + piggyback-prefilling)."),
+        "Requests waiting for a slot (queued + chunk-prefilling)."),
     "engine_slot_occupancy": (
         "gauge", ("engine",),
         "Active slots / total slots at the last step."),
@@ -256,7 +256,7 @@ DUMP_STEPS = 512
 
 #: step-record kinds the engines emit (doc + test anchor)
 STEP_KINDS = ("prefill", "prefill_seeded", "prefill_chunk", "decode",
-              "verify", "piggyback", "embed")
+              "verify", "embed")
 
 
 def prometheus_series(namespace: str = "copilot") -> dict[str, str]:
@@ -284,7 +284,8 @@ class RequestTrace:
     admitted_at: float = 0.0        # admission-wave start
     first_token_at: float = 0.0     # admission-wave end (first sample)
     finished_at: float = 0.0
-    admit_kind: str = ""            # wave | seeded | piggyback | longctx
+    admit_kind: str = ""            # wave | seeded | chunked | handoff
+    #                                 | longctx
     prefix_hit_tokens: int = 0      # prompt tokens seeded from the pool
     new_tokens: int = 0
     finish_reason: str = ""
@@ -327,7 +328,7 @@ class StepRecord:
     t_end: float = 0.0        # time.monotonic() at the host fetch
     new_tokens: int = 0       # tokens handed to requests: harvested
     #                           decode tokens + one first token per
-    #                           admitted row (a piggyback step: both)
+    #                           admitted row
     prompt_tokens: int = 0    # prompt tokens prefilled
     first_use: bool = False   # first dispatch of its kind with its
     #                           static shape key: it loaded (or
@@ -780,7 +781,7 @@ class EngineTelemetry:
             return sorted_vals[i]
 
         decode_steps = [r for r in self.recorder.records()
-                        if r.kind in ("decode", "verify", "piggyback")
+                        if r.kind in ("decode", "verify")
                         and r.batch]
         if last_n is not None and traces:
             # occupancy must describe the same window the percentiles

@@ -511,164 +511,6 @@ def prefill_seeded_paged(params: Params, tokens: jax.Array,
     return _unembed(x_last, params, cfg)[:, 0], k_new, v_new
 
 
-def decode_step_piggyback(params: Params, tokens: jax.Array,
-                          positions0: jax.Array, w: jax.Array,
-                          cfg: DecoderConfig, cache: Params,
-                          k_win: jax.Array, v_win: jax.Array,
-                          pre_tok: jax.Array, pre_rope_base: jax.Array,
-                          pre_kv_begin: jax.Array,
-                          pre_kv_len: jax.Array,
-                          pre_sel_rel: jax.Array,
-                          pre_kbuf: jax.Array, pre_vbuf: jax.Array,
-                          kv_len: int | None = None):
-    """One decode step that ALSO advances P prefill lanes by a C-token
-    chunk — chunked-prefill piggybacking with lane packing.
-
-    Decode at serving widths is weight-bandwidth-bound: every step
-    streams the full weights to advance `slots` rows while the MXU sits
-    mostly idle. A monolithic admission wave is the opposite — pure MXU
-    work that stalls decode for seconds at RAG prompt lengths (the r3
-    verdict's 2k-token 2.1x finding). Here each decode step's
-    projections/FFN matmuls take the decode rows AND ``P*C`` prompt
-    tokens as ONE row-concatenated matmul, so the prefill FLOPs ride
-    the weight stream decode was already paying for (measured: one
-    piggybacked dispatch carries 8192 prompt tokens for +0.18 s over a
-    plain decode dispatch, vs 0.77 s as a standalone wave). The
-    replaced role: the reference's blocking prompt pass inside
-    ``local_llm_summarizer.py:106-115``.
-
-    The engine PACKS whole prompts into the ``W x P`` chunk grid
-    host-side (``GenerationEngine._pack_prefill``): lane p's dispatch
-    buffer holds consecutive rows' chunks back to back, so one
-    dispatch can admit many short prompts per lane as well as one
-    2048-token prompt. All per-step per-lane metadata arrives as
-    arrays; nothing about the packing is traced:
-
-    * pre_tok [P, C]       — this step's chunk token ids per lane;
-    * pre_rope_base [P]    — chunk-start position WITHIN its row (RoPE);
-    * pre_kv_begin [P]     — buffer column where the row's kv starts
-      (earlier columns belong to other rows — masked in-kernel);
-    * pre_kv_len [P]       — valid buffer columns through this step
-      (masks the final partial chunk and idle lanes, which carry 0);
-    * pre_sel_rel [P]      — in-chunk index of the row's LAST prompt
-      token when this chunk completes the row (arbitrary otherwise);
-      the returned ``h_step`` is the hidden state at that index, from
-      which the engine samples the row's first generated token;
-    * pre_kbuf/pre_vbuf [L, P, Hkv, BUF, Dh] — the dispatch's chunk
-      buffers (carried by the engine scan like the decode window
-      buffers; scattered into the cache once per dispatch by
-      ``merge_prefill`` under host-built slot/position maps).
-
-    The chunk's attention is ONE flash call per layer over the buffer
-    (chunk kv written in first) with a dynamic query offset of ``w*C``
-    and the begin/length bounds above — a naive piecewise attention
-    materializes a [P, Hq, C, BUF] fp32 score tensor per layer per
-    step (~76 ms/step at rag2k shapes, measured), which is the exact
-    failure mode flash tiling exists to avoid.
-
-    Returns (logits [B, V], k_cols, v_cols, pre_k [L, P, Hkv, C, Dh],
-    pre_v, h_step [P, D]).
-    """
-    assert not cfg.is_moe, "piggyback prefill: dense FFN only"
-    from copilot_for_consensus_tpu.ops.attention import (
-        decode_attention_prefix_window,
-    )
-    from copilot_for_consensus_tpu.ops.flash_attention import (
-        flash_attention,
-    )
-
-    b = tokens.shape[0]
-    p, c = pre_tok.shape
-    dh, hq, hkv = cfg.head_dim, cfg.n_heads, cfg.n_kv_heads
-    x_dec = _embed(params, tokens)                      # [B, D]
-    x_pre = _embed(params, pre_tok)                     # [P, C, D]
-    d_model = x_dec.shape[-1]
-    x = jnp.concatenate([x_dec, x_pre.reshape(p * c, d_model)], axis=0)
-
-    pos_dec = (positions0 + w)[:, None]                    # [B, 1]
-    pos_pre = pre_rope_base[:, None] + jnp.arange(c)[None, :]  # [P, C]
-
-    pref = cache_prefix(cache, kv_len)
-    inv_freq = L.rope_frequencies(dh, cfg.rope_theta)
-    xs = (params["layers"], jnp.arange(cfg.n_layers), pref["k"], pref["v"])
-
-    def body(x, scanned):
-        layer, li, k_pref_l, v_pref_l = scanned
-        xa = L.rms_norm(x, layer["attn_norm"], cfg.norm_eps)
-        # ONE projection matmul over decode+prefill rows: the weight
-        # stream is shared — this is the piggyback.
-        with scope("qkv"):
-            if "wqkv" in layer:
-                nq, nkv = hq * dh, hkv * dh
-                qkv = L.qmatmul(xa, layer["wqkv"])
-                q_all, k_all, v_all = (qkv[..., :nq],
-                                       qkv[..., nq:nq + nkv],
-                                       qkv[..., nq + nkv:])
-            else:
-                q_all = L.qmatmul(xa, layer["wq"])
-                k_all = L.qmatmul(xa, layer["wk"])
-                v_all = L.qmatmul(xa, layer["wv"])
-
-        def split_heads(z, n_heads):
-            zd = z[:b].reshape(b, 1, n_heads, dh).transpose(0, 2, 1, 3)
-            zp = z[b:].reshape(p, c, n_heads, dh).transpose(0, 2, 1, 3)
-            return zd, zp
-
-        qd, qp = split_heads(q_all, hq)
-        kd, kp = split_heads(k_all, hkv)
-        vd, vp = split_heads(v_all, hkv)
-        qd = L.apply_rope(qd, pos_dec, inv_freq)
-        kd = L.apply_rope(kd, pos_dec, inv_freq)
-        qp = L.apply_rope(qp, pos_pre, inv_freq)
-        kp = L.apply_rope(kp, pos_pre, inv_freq)
-
-        # decode population: prefix + current-window pieces
-        k_win_l = jax.lax.dynamic_index_in_dim(k_win, li, 0,
-                                               keepdims=False)
-        v_win_l = jax.lax.dynamic_index_in_dim(v_win, li, 0,
-                                               keepdims=False)
-        o_dec = decode_attention_prefix_window(
-            qd[:, :, 0, :], k_pref_l, v_pref_l, k_win_l, v_win_l,
-            kd[:, :, 0, :], vd[:, :, 0, :], prefix_lengths=positions0,
-            w=w, window=cfg.sliding_window, kv_len=None)   # [B, Hq, Dh]
-
-        # prefill population: chunk kv joins the buffer, then ONE flash
-        # call over it with the query block offset at w*C; the
-        # begin/length bounds keep each row inside its own span.
-        kbuf_l = jax.lax.dynamic_index_in_dim(pre_kbuf, li, 0,
-                                              keepdims=False)
-        vbuf_l = jax.lax.dynamic_index_in_dim(pre_vbuf, li, 0,
-                                              keepdims=False)
-        with scope("kv_write"):
-            kbuf_cur = jax.lax.dynamic_update_slice_in_dim(
-                kbuf_l, kp.astype(kbuf_l.dtype), w * c, axis=2)
-            vbuf_cur = jax.lax.dynamic_update_slice_in_dim(
-                vbuf_l, vp.astype(vbuf_l.dtype), w * c, axis=2)
-        with scope("attn"):
-            o_pre = flash_attention(
-                qp, kbuf_cur.astype(qp.dtype), vbuf_cur.astype(qp.dtype),
-                causal=True, kv_lengths=pre_kv_len,
-                q_offsets=jnp.broadcast_to(w * c, (p,)),
-                kv_begins=pre_kv_begin)             # [P, Hq, C, Dh]
-
-        o = jnp.concatenate([
-            o_dec.reshape(b, hq * dh),
-            o_pre.transpose(0, 2, 1, 3).reshape(p * c, hq * dh),
-        ], axis=0)
-        x = x + L.attn_out(o, layer)               # one wo matmul
-        x = x + _ffn(L.rms_norm(x, layer["ffn_norm"], cfg.norm_eps),
-                     layer, cfg)                    # one FFN pass
-        return x, (kd[:, :, 0, :], vd[:, :, 0, :], kp, vp)
-
-    x, (k_cols, v_cols, pre_k, pre_v) = jax.lax.scan(body, x, xs)
-    logits = _unembed(x[:b][:, None, :], params, cfg)[:, 0]
-    x_pre_out = x[b:].reshape(p, c, d_model)
-    h_step = jnp.take_along_axis(
-        x_pre_out, jnp.clip(pre_sel_rel, 0, c - 1)[:, None, None],
-        axis=1)[:, 0]                                      # [P, D]
-    return logits, k_cols, v_cols, pre_k, pre_v, h_step
-
-
 @scope("kv_write")
 def put_window_column(win: jax.Array, cols: jax.Array,
                       w: jax.Array) -> jax.Array:
@@ -679,26 +521,6 @@ def put_window_column(win: jax.Array, cols: jax.Array,
 
 
 @scope("kv_write")
-def merge_prefill(cache: Params, k_buf: jax.Array, v_buf: jax.Array,
-                  sidx: jax.Array, pidx: jax.Array) -> Params:
-    """Scatter a dispatch's prefill-chunk buffers into the cache.
-
-    k_buf/v_buf: [L, P, Hkv, BUF, Dh]; the host-built maps say where
-    every buffer column goes: column j of lane i lands at cache
-    position ``pidx[i, j]`` of slot ``sidx[i, j]``. Padding/garbage
-    columns carry out-of-range indices and drop — nothing may write
-    into a live slot's timeline.
-    """
-    k = cache["k"].at[:, sidx, :, pidx, :].set(
-        k_buf.transpose(1, 3, 0, 2, 4).astype(cache["k"].dtype),
-        mode="drop")
-    v = cache["v"].at[:, sidx, :, pidx, :].set(
-        v_buf.transpose(1, 3, 0, 2, 4).astype(cache["v"].dtype),
-        mode="drop")
-    return {"k": k, "v": v}
-
-
-@scope("kv_write")
 def merge_window(cache: Params, k_win: jax.Array, v_win: jax.Array,
                  positions0: jax.Array, steps: int) -> Params:
     """Write a dispatch's fresh KV into the big cache, once, in place.
@@ -706,7 +528,7 @@ def merge_window(cache: Params, k_win: jax.Array, v_win: jax.Array,
     k_win/v_win: [L, B, Hkv, W, Dh]; slot b's first ``steps`` window
     columns land at cache positions ``positions0[b] + [0, steps)``, and
     a column whose position is at or past the cache extent is DROPPED
-    (free and prefilling slots park there; a live slot near the end
+    (free and chunk-prefilling slots park there; a live slot near the end
     loses only what does not fit).
 
     Each slot's columns go in as ONE slab [L, Hkv, W, Dh] at

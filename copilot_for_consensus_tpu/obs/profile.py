@@ -59,8 +59,8 @@ SCOPES = (
     "norm_rope",  # rms_norm, apply_rope
     "unembed",    # final norm + lm_head
     "sample",     # engine/sampling.py:sample
-    "kv_write",   # window-buffer updates, merge_window/merge_prefill,
-    #               the admit program's cache scatter
+    "kv_write",   # window-buffer updates, merge_window, the admit
+    #               program's cache scatter
 )
 
 #: scopes that only the attention="eva" programs hold (models/eva.py).

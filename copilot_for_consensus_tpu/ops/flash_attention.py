@@ -53,11 +53,11 @@ def _flash_kernel(
         l_scr[:] = jnp.zeros_like(l_scr)
         acc_scr[:] = jnp.zeros_like(acc_scr)
 
-    # q_offsets place the query block inside the kv timeline (chunked
-    # prefill: C fresh queries at the end of a growing kv run);
-    # kv_begins exclude a kv PREFIX (lane packing: earlier rows'
-    # chunks in the same dispatch buffer). Dynamic (SMEM) because both
-    # advance every engine scan step.
+    # q_offsets place the query block inside the kv timeline (a piece
+    # of a prompt: its fresh queries at the end of a growing kv run);
+    # kv_begins exclude a kv PREFIX (eva.piece_attention: the summary
+    # columns a row has not filled yet). Dynamic (SMEM) because both
+    # differ row by row and piece by piece.
     q_off = off_ref[bi]
     kv_begin = begin_ref[bi]
     q_start = qi * bq + q_off
@@ -135,12 +135,12 @@ def flash_attention(
     ``Sq`` and ``Skv`` may differ; ``q_offsets`` [B] (dynamic) places
     each row's query block at an offset in the kv timeline — query i is
     position ``q_offsets[b] + i`` for causal/window masking. This is
-    what lets a chunked prefill run its C fresh queries against the
+    what lets a piece of a prompt run its fresh queries against the
     full run of already-written kv with flash tiling instead of a
     materialized [C, Skv] score tensor. ``kv_begins`` [B] (dynamic)
-    masks a kv PREFIX per row (positions < begin never attend) — lane
-    packing puts several rows' chunks in one dispatch buffer, and a
-    row must not see its predecessors'.
+    masks a kv PREFIX per row (positions < begin never attend).
+    ``models/eva.py:piece_attention`` is the caller of both: a row's
+    timeline is its summaries, then its window.
     """
     b, hq, s_q_in, d = q.shape
     hkv, s_kv_in = k.shape[1], k.shape[2]

@@ -92,7 +92,6 @@ def storm_prompts(n: int, seed: int) -> list[list[int]]:
 
 def _busy(eng) -> bool:
     return bool(eng._queue or eng._active or eng._done
-                or getattr(eng, "_prefilling", None)
                 or getattr(eng, "_chunking", None)
                 or getattr(eng, "_chunk_pending", None))
 

@@ -1259,7 +1259,7 @@ def test_competing_subscribers_on_durable_broker_split_work(tmp_path):
 
 def test_publish_window_groups_wave_publishes_into_one_request():
     """Grouped publishes: N publish() calls inside a window reach the
-    broker as ONE pub_batch request, in order; depths piggyback."""
+    broker as ONE pub_batch request, in order; depths ride along."""
     stub = StubClient()
     pub = make_publisher(stub)
     with pub.publish_window():

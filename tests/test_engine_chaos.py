@@ -236,7 +236,6 @@ class StubEngine:
         self._prefix_pins = {}
         self._chunking = {}
         self._chunk_pending = []
-        self._prefilling = []
         self._sched = None
         self._free = list(range(self.num_slots))
         self._positions = np.full(self.num_slots, self.max_len,

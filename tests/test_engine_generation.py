@@ -198,101 +198,3 @@ def test_sliding_window_greedy_multi_window(n_windows):
         toks.append(nxt)
     assert comp.tokens == want[:len(comp.tokens)]
     assert len(comp.tokens) >= 8
-
-
-# ---------------------------------------------------------------------------
-# Chunked-prefill piggybacking (prefill chunks riding decode dispatches)
-# ---------------------------------------------------------------------------
-
-
-def _piggy_engine(**kw):
-    kw.setdefault("num_slots", 4)
-    kw.setdefault("max_len", 128)
-    kw.setdefault("prefill_buckets", (16, 32, 96))
-    kw.setdefault("decode_window", 8)
-    kw.setdefault("prefill_chunk", 8)      # capacity W*C = 64 per lane
-    kw.setdefault("prefill_rows", 2)
-    kw.setdefault("piggyback_min_prompt", 20)
-    return _engine(**kw)
-
-
-def _wave_engine(**kw):
-    kw.setdefault("num_slots", 4)
-    kw.setdefault("max_len", 128)
-    kw.setdefault("prefill_buckets", (16, 32, 96))
-    kw.setdefault("decode_window", 8)
-    kw.setdefault("piggyback_min_prompt", 10**9)   # never piggyback
-    return _engine(**kw)
-
-
-def _prompt(seed, n):
-    rng = np.random.default_rng(seed)
-    return rng.integers(3, CFG.vocab_size, size=n).tolist()
-
-
-def test_piggyback_matches_wave_path_exactly():
-    """Oracle: the piggybacked engine (float32 end to end, greedy) must
-    produce token-identical completions to the monolithic-wave engine —
-    chunked prefill is a scheduling change, not a numerics change."""
-    prompts = [_prompt(1, 40), _prompt(2, 25), _prompt(3, 5),
-               _prompt(4, 33)]                     # mixed: 3 piggy, 1 wave
-    want = _wave_engine().generate(prompts, max_new_tokens=6)
-    got = _piggy_engine().generate(prompts, max_new_tokens=6)
-    for w, g in zip(want, got):
-        assert g.tokens == w.tokens
-        assert g.prompt_len == w.prompt_len
-
-
-def test_piggyback_lane_packing_many_short_prompts():
-    """Several short prompts pack back-to-back into the same lane's
-    dispatch buffer (the packed-lane path short-prompt Poisson needs);
-    partial final chunks mask correctly; rows never see a packed
-    neighbor's kv."""
-    prompts = [_prompt(10 + i, 20 + i) for i in range(6)]  # 3 rows/lane
-    want = _wave_engine().generate(prompts, max_new_tokens=5)
-    got = _piggy_engine().generate(prompts, max_new_tokens=5)
-    for w, g in zip(want, got):
-        assert g.tokens == w.tokens
-
-
-def test_piggyback_oversize_prompts_fall_back_to_wave():
-    """Prompts beyond one dispatch's lane capacity (W*C = 64) must take
-    the monolithic wave and still interleave correctly with piggybacked
-    ones."""
-    prompts = [_prompt(20, 90), _prompt(21, 30), _prompt(22, 70),
-               _prompt(23, 64)]
-    want = _wave_engine().generate(prompts, max_new_tokens=5)
-    got = _piggy_engine().generate(prompts, max_new_tokens=5)
-    for w, g in zip(want, got):
-        assert g.tokens == w.tokens
-
-
-def test_piggyback_more_rows_than_capacity_and_slot_reuse():
-    """More prompts than lanes and slots: staged admission across
-    dispatches, slot reuse after retirement, everything still exact."""
-    prompts = [_prompt(30 + i, 24 + i) for i in range(6)]
-    want = _wave_engine(num_slots=2).generate(prompts, max_new_tokens=4)
-    got = _piggy_engine(num_slots=2).generate(prompts, max_new_tokens=4)
-    for w, g in zip(want, got):
-        assert g.tokens == w.tokens
-
-
-def test_piggyback_staggered_joins_do_not_disturb_decoding():
-    """A prompt joining mid-decode must not perturb tokens already
-    streaming from active slots (the freed/prefilling slots' garbage
-    decode lanes must drop, not overwrite live timelines)."""
-    eng = _piggy_engine()
-    first = _prompt(40, 28)
-    rid1 = eng.submit(first, max_new_tokens=10)
-    done = {}
-    for _ in range(2):
-        for c in eng.step():
-            done[c.request_id] = c
-    rid2 = eng.submit(_prompt(41, 45), max_new_tokens=10)
-    while len(done) < 2:
-        for c in eng.step():
-            done[c.request_id] = c
-    want = _wave_engine().generate([first, _prompt(41, 45)],
-                                   max_new_tokens=10)
-    assert done[rid1].tokens == want[0].tokens
-    assert done[rid2].tokens == want[1].tokens

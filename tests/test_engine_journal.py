@@ -145,7 +145,6 @@ class _StubJournalEngine:
         self._queue = []
         self._active = {}
         self._generated = {}
-        self._prefilling = []
         self._done = {}
         self._next = 0
 

@@ -204,7 +204,7 @@ def test_reintroduced_pool_gather_turns_the_hlo_lane_red(tmp_path):
     # anchor 2: decode's pool scatter (unique: only decode scatters
     # k_all). The gathered working set must be USED — a dead gather is
     # DCE'd before lowering and would never reach the StableHLO.
-    scatter = ("                    pool_k, pool_v = scatter_kfn(\n"
+    scatter = ("                    pool_k, pool_v = scatter(\n"
                "                        pool_k, pool_v, k_all, v_all, "
                "sbids, soffs)")
     assert src.count(scatter) == 1, "decode scatter moved; update the test"

@@ -237,7 +237,7 @@ def test_closed_loop_slo_violation_sheds_batch_lane():
                               prompt_tokens=8)
     sched.check_admission(tenant="t", priority="interactive",
                           prompt_tokens=8)
-    # idle slots = hysteresis, not overload: same latencies, no shed
+    # idle slots are not overload: same latencies, no shed
     sched2 = Scheduler(SchedulerConfig(queue_wait_p95_slo_s=20.0))
     sig2 = sched2.observe(queued=2, active=1, num_slots=8,
                           telemetry=Tele())

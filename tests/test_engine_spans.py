@@ -6,6 +6,7 @@ import time
 
 import pytest
 
+from copilot_for_consensus_tpu.engine.scheduler import SchedulerConfig
 from copilot_for_consensus_tpu.engine.telemetry import (
     FlightRecorder,
     StepRecord,
@@ -115,17 +116,38 @@ def test_phases_cover_the_loop_outside_dispatches(tiny_parts):
     assert exported == pytest.approx(tele.phase_seconds["harvest"])
 
 
-@pytest.mark.parametrize("piggyback", [False, True],
-                         ids=["waves", "piggyback"])
-def test_new_tokens_sum_to_tokens_delivered(tiny_parts, piggyback):
-    kw = dict(piggyback_min_prompt=20, prefill_chunk=8, prefill_rows=2,
-              decode_window=8) if piggyback else {}
+def _shared(n):
+    """The same 16 tokens open every prompt; the tail is its own."""
+    return _prompt(16) + [60 + n] * max(4, n - 16)
+
+
+def _cycle(n):
+    """A prompt that repeats itself: prompt-lookup drafts hit."""
+    return [5, 9, 13, 17] * (n // 4)
+
+
+#: every dispatch kind out_tok_s would count the day a cell turns it
+#: on: the options that bring it out, the prompts, and the kind of
+#: record that must appear
+_KINDS = {
+    "waves": (dict(), _prompt, "prefill"),
+    "seeded": (dict(prefix_cache_blocks=8, prefill_chunk=8), _shared,
+               "prefill_seeded"),
+    "verify": (dict(spec_decode=True), _cycle, "verify"),
+    "chunked": (dict(scheduler=SchedulerConfig(chunk_tokens=8)), _prompt,
+                "prefill_chunk"),
+    "paged": (dict(kv_pool_blocks=40, prefill_chunk=8), _prompt,
+              "prefill"),
+}
+
+
+@pytest.mark.parametrize("case", list(_KINDS))
+def test_new_tokens_sum_to_tokens_delivered(tiny_parts, case):
+    kw, make, kind = _KINDS[case]
     eng = _engine(*tiny_parts, prefill_buckets=(16, 32, 64), **kw)
-    prompts = [_prompt(5), _prompt(24), _prompt(40), _prompt(11),
-               _prompt(30), _prompt(26)]
+    prompts = [make(n) for n in (5, 24, 40, 11, 30, 26)]
     comps = eng.generate(prompts[:2], 9)
-    # arrivals while others decode: with piggybacking on, the long
-    # ones ride the decode dispatches
+    # arrivals while others decode
     rids = [eng.submit(p, 13) for p in prompts[2:]]
     done = {}
     for _ in range(200):
@@ -135,13 +157,16 @@ def test_new_tokens_sum_to_tokens_delivered(tiny_parts, piggyback):
             break
     comps += [done[r] for r in rids]
     recs = eng.telemetry.recorder.records()
-    if piggyback:
-        assert any(r.kind == "piggyback" and r.prompt_tokens
-                   for r in recs)
+    assert any(r.kind == kind for r in recs), {r.kind for r in recs}
+    if case == "paged":
+        assert {r.route for r in recs} == {"reference"}
     assert sum(r.new_tokens for r in recs) == \
         sum(len(c.tokens) for c in comps)
+    # prompt_tokens are the tokens PREFILLED: what a cached prefix
+    # seeded was not
+    assert (eng.prefill_tokens_saved > 0) == (case == "seeded")
     assert sum(r.prompt_tokens for r in recs) == \
-        sum(len(p) for p in prompts)
+        sum(len(p) for p in prompts) - eng.prefill_tokens_saved
 
 
 def test_step_times_run_forward_and_add_up(driven):

@@ -322,7 +322,6 @@ def test_scheduler_chunking_and_small_buckets_serve_the_same_bytes(params):
     (dict(kv_pool_blocks=64, prefill_chunk=16), "block pool"),
     (dict(spec_decode=True), "verify pass"),
     (dict(kv_dtype="float8_e4m3fn"), "8-bit keys"),
-    (dict(piggyback_min_prompt=4), "piggyback"),
     (dict(windows_per_dispatch=2), "one window"),
     (dict(quantize="int4"), "int4"),
     (dict(max_len=MAX_LEN - 8), "multiple of window_size"),

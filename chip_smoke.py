@@ -46,6 +46,7 @@ from __future__ import annotations
 import argparse
 import base64
 import dataclasses
+import functools
 import json
 import pathlib
 import queue
@@ -256,6 +257,125 @@ def phase_kernels(rehearse: bool) -> int:
              (state["ks"][li], state["vs"][li],
               jnp.arange(er)[None, :] >= er - sum_n[:, None])]))
 
+    # -- dense decode attention over live blocks (the contiguous cache
+    # on a TPU), at the served cells' shapes: 8 slots x 4096 columns of
+    # 8 kv heads of 128, all of Mistral's 32 layers. Two mixes of
+    # lengths: five sequences and three empty slots (`qa-steady`'s
+    # occupancy), eight sequences (`summarize-backlog`'s mean live
+    # columns). The kernel's partial folded with the dispatch's own
+    # columns against the XLA route over the prefix cut at the longest
+    # slot's bucket; then a dispatch's 8 tokens of 32 layer calls under
+    # one jit, timed: the XLA route whole (its one cut included), and
+    # the kernel ALONE at each block width tried (the served one is
+    # ``dense_attention.BLOCK``) ----------------------------------------
+    from copilot_for_consensus_tpu.models.decoder import cache_prefix
+    from copilot_for_consensus_tpu.ops import dense_attention
+    from copilot_for_consensus_tpu.ops.attention import (
+        decode_attention_prefix_window,
+        decode_window_partial,
+    )
+
+    n_dl, slots, ext, w_sz = (2, 8, 512, 8) if rehearse else (32, 8, 4096, 8)
+    mixes = {"qa": [600, 1200, 2100, 2300, 1700],
+             "backlog": [2150, 2250, 2100, 2200, 700, 1300, 1650, 900]}
+    if rehearse:
+        mixes = {m: [n // 8 for n in ns] for m, ns in mixes.items()}
+    dense = {h: jax.random.normal(jax.random.PRNGKey(i),
+                                  (n_dl, slots, hkv, ext, d), dtype)
+             for i, h in enumerate("kv")}        # made on the device
+    qd8, kcur, vcur = normal(slots, hq, d), normal(slots, hkv, d), \
+        normal(slots, hkv, d)
+    kwin, vwin = normal(slots, hkv, w_sz, d), normal(slots, hkv, w_sz, d)
+    w_at = jnp.asarray(3, jnp.int32)
+    sw = cfg.sliding_window
+
+    def positions(lens):
+        return jnp.asarray(lens + [ext] * (slots - len(lens)), jnp.int32)
+
+    def bucket(lens):
+        return -(-(max(lens) + 1) // 128) * 128
+
+    def xla_tokens(lens, tokens, dense, q):
+        pos0, pref = positions(lens), cache_prefix(dense, bucket(lens))
+
+        def layer(acc, kv):
+            return acc + decode_attention_prefix_window(
+                q, kv[0], kv[1], kwin, vwin, kcur, vcur, pos0, w_at,
+                window=sw), None
+
+        def token(acc, _):
+            return jax.lax.scan(layer, acc, (pref["k"], pref["v"]))[0], None
+
+        return jax.lax.scan(token, jnp.zeros_like(q), None,
+                            length=tokens)[0]
+
+    def kernel_tokens(lens, folded, tokens, dense, q):
+        pos0 = positions(lens)
+        plan = dense_attention.plan_blocks(
+            *dense_attention.live_range(pos0, pos0 + w_at, sw, ext),
+            extent=ext)
+        qg = q.reshape(slots, hkv, group, d)
+        local = decode_window_partial(qg, kwin, vwin, kcur, vcur, pos0,
+                                      w_at, window=sw)
+
+        def layer(acc, li):
+            part = dense_attention.live_partial(
+                qg, dense["k"], dense["v"], li, plan, interpret=interpret)
+            if not folded:
+                return acc + part[0], None
+            return acc + combine_partials([part, local], q.dtype).reshape(
+                q.shape), None
+
+        def token(acc, _):
+            return jax.lax.scan(layer, acc, jnp.arange(n_dl))[0], None
+
+        acc0 = jnp.zeros_like(q) if folded \
+            else jnp.zeros(qg.shape, jnp.float32)
+        return jax.lax.scan(token, acc0, None, length=tokens)[0]
+
+    def timed(fn) -> float:
+        """Milliseconds a token (a call a layer) of a dispatch of
+        ``w_sz`` tokens, best of 5."""
+        jax.block_until_ready(fn(dense, qd8))
+        best = float("inf")
+        for _ in range(1 if rehearse else 5):
+            t = time.perf_counter()
+            jax.block_until_ready(fn(dense, qd8))
+            best = min(best, time.perf_counter() - t)
+        return best * 1e3 / w_sz
+
+    def rate(columns: int, ms: float) -> dict:
+        col_bytes = 2 * hkv * d * dense["k"].dtype.itemsize   # both halves
+        return {"columns": columns, "ms_per_token": round(ms, 4),
+                "gb_per_s": round(columns * col_bytes * n_dl / ms / 1e6, 1)}
+
+    dense_rates: dict = {"served_block": dense_attention.BLOCK}
+    want = {}
+    for mix, lens in mixes.items():
+        want[mix] = jax.jit(functools.partial(xla_tokens, lens, 1))(
+            dense, qd8)
+        dense_rates[mix] = {
+            "live_columns": sum(lens),
+            "xla_prefix": rate(slots * bucket(lens), timed(jax.jit(
+                functools.partial(xla_tokens, lens, w_sz))))}
+    try:
+        for width in (128, 256, 512):
+            dense_attention.BLOCK = width
+            for mix, lens in mixes.items():
+                got = jax.jit(functools.partial(kernel_tokens, lens, True,
+                                                1))(dense, qd8)
+                compare(f"dense_decode_attention/{mix}/block={width}",
+                        got[:len(lens)], want[mix][:len(lens)])
+                dense_rates[mix][f"block_{width}"] = rate(
+                    sum(dense_attention.blocks_read(0, n, ext)
+                        for n in lens),
+                    timed(jax.jit(functools.partial(kernel_tokens, lens,
+                                                    False, w_sz))))
+    finally:
+        dense_attention.BLOCK = dense_rates["served_block"]
+    say(f"dense decode attention, {w_sz} tokens of {n_dl} layer calls a "
+        f"dispatch: {dense_rates}")
+
     # -- int4 matmul (what quantize="int4" routes to) ------------------
     for name, (din, dout) in (("up", (cfg.d_model, cfg.d_ff)),
                               ("down", (cfg.d_ff, cfg.d_model))):
@@ -303,6 +423,7 @@ def phase_kernels(rehearse: bool) -> int:
              "layers": cut.n_layers, "kv_route": eng._kv_route,
              "kv_dtype": "float8_e4m3fn",
              "zero_copy_admits": stats["zero_copy_admits"]},
+         dense_decode_attention=dense_rates,
          seconds=round(time.monotonic() - t0, 1))
     return 0
 

@@ -87,6 +87,7 @@ from copilot_for_consensus_tpu.engine.tokenizer import (
 from copilot_for_consensus_tpu.obs.profile import scope, step_annotation
 from copilot_for_consensus_tpu.models import decoder, eva, quant
 from copilot_for_consensus_tpu.models.configs import DecoderConfig
+from copilot_for_consensus_tpu.ops import dense_attention
 from copilot_for_consensus_tpu.ops.eva_attention import blocks_read
 from copilot_for_consensus_tpu.parallel.sharding import (
     DEFAULT_RULES,
@@ -779,12 +780,19 @@ class GenerationEngine:
             accumulates in small [L, B, Hkv, W, Dh] window buffers,
             merges in place once at the end. ``kv_len`` (static,
             bucketed by the caller) bounds that prefix and must cover
-            all n_windows."""
+            all n_windows. Where attention reads each slot's live
+            blocks in place (``_reads_live_blocks``) there is no cut:
+            the step takes the cache whole, and the caller's ``kv_len``
+            is always the full extent (one program)."""
             w_sz = self.decode_window
             n_l = cfg.n_layers
             b = tokens.shape[0]
             shape = (n_l, b, cfg.n_kv_heads, w_sz, cfg.head_dim)
-            prefix = decoder.cache_prefix(cache, kv_len)
+            if self._reads_live_blocks():
+                prefix, step = cache, decoder.decode_step_windowed_live
+            else:
+                prefix = decoder.cache_prefix(cache, kv_len)
+                step = decoder.decode_step_windowed
 
             def run_window(tok, key, done):
                 k_win = jnp.zeros(shape, self.kv_dtype)
@@ -794,7 +802,7 @@ class GenerationEngine:
                 def body(carry, w):
                     tok, k_win, v_win, key = carry
                     key, sub = jax.random.split(key)
-                    logits, k_cols, v_cols = decoder.decode_step_windowed(
+                    logits, k_cols, v_cols = step(
                         params, tok, positions, w, cfg, prefix, k_win,
                         v_win, k_done=k_done, v_done=v_done)
                     k_win = decoder.put_window_column(k_win, k_cols, w)
@@ -2454,12 +2462,42 @@ class GenerationEngine:
             req.block_digests = self._prefix.prompt_digests(req.prompt)
         return req.block_digests
 
+    def _reads_live_blocks(self) -> bool:
+        """Does the contiguous decode dispatch (``_decode``) read each
+        slot's live cache blocks in place, by the slot's own length
+        (``ops/dense_attention.py``)? On a TPU that holds the cache on
+        one device, as EvaByte's route is chosen
+        (``eva._reads_live_blocks``); under a mesh the cache is sharded
+        and the XLA prefix route serves, as it does off a TPU, where it
+        is what the tests hold the kernel to. Read when the program is
+        traced and before every dispatch: it follows the backend, no
+        option sets it."""
+        return (self.mesh is None and not self.paged and not self._eva
+                and dense_attention.serves(self.max_len))
+
+    def _live_blocks_read(self, steps: int) -> int:
+        """Cache columns under the blocks that a decode dispatch of
+        ``steps`` tokens reads for the decoding slots, summed over its
+        steps (the flight recorder's ``state_tokens_read``). Without a
+        sliding window every step reads the same blocks: what lay in
+        the cache when the dispatch began."""
+        pos0 = np.asarray([self._positions[s] for s in self._active],
+                          dtype=np.int64)
+        return sum(
+            dense_attention.blocks_read(int(lo), int(hi), self.max_len)
+            for t in range(steps)
+            for lo, hi in zip(*dense_attention.live_range(
+                pos0, pos0 + t, self.cfg.sliding_window, self.max_len)))
+
     def _kv_bucket(self) -> int:
         """Static attention extent for the next decode dispatch: the
         occupied cache prefix rounded up to 128, so only a handful of
         decode programs ever compile. The dispatch's own fresh KV lives
         in the window/done buffers until the final merge, so the extent
-        covers only what was in the cache BEFORE the dispatch."""
+        covers only what was in the cache BEFORE the dispatch. A plain
+        decode dispatch that reads live blocks in place takes no cut
+        and does not ask (``_decode_once``): its extent is the cache's,
+        whatever the lengths, and its program the one."""
         hi = max([int(self._positions[s]) for s in self._active] + [0])
         return self._kv_extent(hi)
 
@@ -3097,7 +3135,10 @@ class GenerationEngine:
             else None
         kv_len = self._kv_bucket()
         extra: dict = {}
-        if self._eva:
+        if self._reads_live_blocks():
+            kv_len = self.max_len
+            extra = {"state_tokens_read": self._live_blocks_read(window)}
+        elif self._eva:
             win_tokens, sum_tokens = self._eva_live()
             w_sz = self.cfg.window_size
             closing = sum(int(self._positions[s]) % w_sz + window >= w_sz

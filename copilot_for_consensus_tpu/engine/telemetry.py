@@ -333,7 +333,8 @@ class StepRecord:
     first_use: bool = False   # first dispatch of its kind with its
     #                           static shape key: it loaded (or
     #                           compiled) a program
-    # attention="eva" engines only (models/eva.py); 0 elsewhere
+    # attention="eva" engines (models/eva.py); 0 elsewhere (the last
+    # also on a dense engine that reads live cache blocks in place)
     windows_compacted: int = 0  # slot-windows that filled and were
     #                             pooled into summaries in the dispatch
     window_tokens: int = 0      # exact key/value columns live in all
@@ -342,7 +343,11 @@ class StepRecord:
     state_tokens_read: int = 0  # decode dispatches: the decoding
     #                             slots' live columns and summaries,
     #                             each rounded up to the blocks decode
-    #                             attention reads (ops/eva_attention.py)
+    #                             attention reads (ops/eva_attention.py);
+    #                             a dense engine on a TPU without a mesh
+    #                             (ops/dense_attention.py): the cache
+    #                             columns under the blocks read, summed
+    #                             over the dispatch's steps
 
     @property
     def occupancy(self) -> float:

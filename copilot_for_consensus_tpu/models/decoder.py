@@ -26,6 +26,7 @@ from copilot_for_consensus_tpu.models.configs import DecoderConfig
 from copilot_for_consensus_tpu.models import layers as L
 from copilot_for_consensus_tpu.models.moe import moe_ffn
 from copilot_for_consensus_tpu.obs.profile import scope
+from copilot_for_consensus_tpu.ops import dense_attention
 
 Params = dict[str, Any]
 
@@ -466,6 +467,41 @@ def decode_step_windowed_paged(params: Params, tokens: jax.Array,
 
     x, (k_cols, v_cols) = jax.lax.scan(body, x, xs)
     return _unembed(x, params, cfg)[:, 0], k_cols, v_cols
+
+
+def decode_step_windowed_live(params: Params, tokens: jax.Array,
+                              positions0: jax.Array, w: jax.Array,
+                              cfg: DecoderConfig, cache: Params,
+                              k_win: jax.Array, v_win: jax.Array,
+                              k_done: jax.Array | None = None,
+                              v_done: jax.Array | None = None
+                              ) -> tuple[jax.Array, jax.Array, jax.Array]:
+    """:func:`decode_step_windowed` without a cut of the cache: the
+    route of a TPU that holds the cache on one device. ``cache`` is the
+    stacked cache WHOLE; of it each slot's live blocks are read, by the
+    slot's own length, in place (``ops/dense_attention.py``: the layer
+    scan closes over the two halves, a block with no live column is
+    neither fetched nor scored, a free or parked slot reads nothing) —
+    where the XLA route reads every slot to the longest slot's bucket
+    out of a strided copy. The plan of blocks is made here, once a
+    token; the kernel's partial is the prefix piece of
+    :func:`decode_step_windowed_paged`, whose layer loop, dispatch-local
+    partial and fold serve unchanged. Same arguments and results as
+    :func:`decode_step_windowed`."""
+    extent = cache["k"].shape[3]
+    n_done = 0 if k_done is None else k_done.shape[3]
+    plan = dense_attention.plan_blocks(
+        *dense_attention.live_range(positions0, positions0 + n_done + w,
+                                    cfg.sliding_window, extent),
+        extent=extent)
+
+    def partial_fn(li, qg, lengths, q_pos):
+        return dense_attention.live_partial(qg, cache["k"], cache["v"],
+                                            li, plan)
+
+    return decode_step_windowed_paged(
+        params, tokens, positions0, w, cfg, partial_fn, k_win, v_win,
+        k_done=k_done, v_done=v_done)
 
 
 def prefill_seeded_paged(params: Params, tokens: jax.Array,

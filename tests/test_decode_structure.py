@@ -444,3 +444,147 @@ def test_eva_guard_trips_on_joint_attention_over_whole_pieces(
     faults = eva_decode_faults(one_chip, may_close)
     assert "0 kernels" in faults
     assert sum("under attn reads a whole half" in f for f in faults) >= 2
+
+
+# ---------------------------------------------------------------------------
+# the dense cache on a TPU (ops/dense_attention.py): `_decode` takes no
+# cut of the cache; the layer scan closes over the two halves and the
+# kernel reads each slot's live blocks in place. Compiled for the
+# described v5e with the trace seeing a TPU (`on_tpu`), and served here
+# through the interpreter with the route forced (`kernel_route`).
+# ---------------------------------------------------------------------------
+
+
+def dense_decode_faults(one_chip):
+    """What the compiled dense decode program may not hold on the
+    kernel's route: no kernel in the layer loop (or more than the one);
+    an op whose result is a cache half or a prefix of one other than the
+    merge's in-place update (a slice, a copy, a relayout); an op that
+    makes one layer of a half; an op under `attn` other than the kernel
+    that takes a whole half as an operand (a fusion that reads all of
+    it and masks)."""
+    eng = GenerationEngine(STRUCT_CFG, num_slots=SLOTS, max_len=MAX_LEN,
+                           prefill_buckets=(64,), dtype=jnp.bfloat16,
+                           attn_impl="xla", eos_id=-1)
+
+    def wrap(a):
+        return jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip)
+
+    kv_len = MAX_LEN if eng._reads_live_blocks() else KV_LEN
+    text = eng._decode_fn.lower(*_decode_args(eng, wrap), kv_len=kv_len,
+                                n_windows=1).compile().as_text()
+    half = tuple(eng._cache["k"].shape)
+    one_layer = {half[1:], (1,) + half[1:]}
+    comp, kernels, faults, shape_of, calls = None, [], [], {}, {}
+    for line in text.splitlines():
+        if line and not line[0].isspace() and line.rstrip().endswith("{"):
+            comp = line.split()[1 if line.startswith("ENTRY") else 0]
+            comp = comp.lstrip("%")
+        calls.setdefault(comp, set()).update(re.findall(
+            r"(?:calls|body|condition|to_apply)=%?([\w.\-]+)", line))
+        if 'custom_call_target="tpu_custom_call"' in line:
+            kernels.append((comp, line))
+        m = re.match(r"\s*(?:ROOT )?%?([\w.\-]+) = \w+\[([\d,]+)\](\S*) "
+                     r"([\w\-]+)\(([^)]*)", line)
+        if not m:
+            continue
+        name, dims, layout, op, operands = m.groups()
+        shape = shape_of[name] = tuple(int(x) for x in dims.split(","))
+        if op in ("parameter", "get-tuple-element", "bitcast"):
+            continue
+        if shape in one_layer:
+            faults.append(f"{op} makes one layer of a half {shape}")
+        if _is_cache_like(shape) and op != "dynamic-update-slice":
+            faults.append(f"{op} makes a half or a prefix of one {shape}")
+        if "/attn/" in line and "tpu_custom_call" not in line and any(
+                shape_of.get(o) == half
+                for o in re.findall(r"%([\w.\-]+)", operands)):
+            faults.append(f"{op} under attn reads a whole half")
+    looped = frontier = set(re.findall(r"body=%?([\w.\-]+)", text))
+    while frontier:
+        frontier = {c for f in frontier for c in calls.get(f, ())} - looped
+        looped = looped | frontier
+    if len(kernels) != 1 or kernels[0][0] not in looped or not re.search(
+            r'op_name="[^"]*/attn/dense_decode_attention/', kernels[0][1]):
+        faults.append(f"{len(kernels)} kernels")
+    return faults
+
+
+def test_compiled_dense_decode_reads_the_cache_through_the_kernel_alone(
+        one_chip, on_tpu):
+    assert dense_decode_faults(one_chip) == []
+
+
+def test_dense_guard_trips_on_the_xla_prefix_route(one_chip, on_tpu,
+                                                   monkeypatch):
+    from copilot_for_consensus_tpu.ops import dense_attention
+
+    monkeypatch.setattr(dense_attention, "serves", lambda extent: False)
+    faults = dense_decode_faults(one_chip)
+    assert "0 kernels" in faults
+    assert sum(f.startswith("slice makes a half or a prefix")
+               for f in faults) == 2
+
+
+@pytest.fixture
+def kernel_route(monkeypatch):
+    """The kernel's route on this backend: through the interpreter."""
+    from copilot_for_consensus_tpu.ops import dense_attention
+
+    monkeypatch.setattr(dense_attention, "serves", lambda extent: True)
+
+
+def _pinned_engine(**kw):
+    cfg = decoder_config("tiny")
+    params = decoder.init_params(jax.random.PRNGKey(11), cfg,
+                                 dtype=jnp.float32)
+    eng = GenerationEngine(cfg, params, num_slots=4, max_len=512,
+                           prefill_buckets=(64, 128), dtype=jnp.float32,
+                           attn_impl="xla", eos_id=-1, decode_window=8,
+                           **kw)
+    prompts = [[3 + (i * 7) % 50 for i in range(118)],
+               [5 + (i * 3) % 40 for i in range(41)]]
+    comps = eng.generate(prompts, max_new_tokens=30)
+    decodes = [r for r in eng.telemetry.recorder.records()
+               if r.kind == "decode"]
+    return eng, [c.tokens for c in comps], decodes
+
+
+def test_the_kernel_route_serves_the_pinned_tokens_with_one_decode_program(
+        kernel_route):
+    """The engine of the pinned test above, on the kernel's route: the
+    same greedy tokens across the 128-token boundary, and requests whose
+    lengths lie in different 128-stretches load ONE decode program where
+    the XLA route loads one a stretch."""
+    eng, tokens, decodes = _pinned_engine()
+    assert eng._reads_live_blocks()
+    assert tokens == PINNED
+    assert [r.first_use for r in decodes] == [True, False, False, False]
+    assert ("decode", 512, 1) in eng.programs_seen
+    assert sum(k[0] == "decode" for k in eng.programs_seen) == 1
+    assert all(r.state_tokens_read > 0 for r in decodes)
+
+
+def test_off_the_kernel_route_a_decode_program_per_stretch():
+    eng, tokens, decodes = _pinned_engine()
+    assert not eng._reads_live_blocks()
+    assert tokens == PINNED
+    assert [r.first_use for r in decodes] == [True, False, True, False]
+    assert [r.state_tokens_read for r in decodes] == [0] * 4
+
+
+def test_an_engine_with_a_mesh_keeps_the_xla_route(kernel_route):
+    """Where the kernel would serve a single device, a sharded cache
+    keeps the prefix route, its extents and its programs."""
+    from copilot_for_consensus_tpu.parallel.mesh import (
+        MeshConfig,
+        build_mesh,
+    )
+
+    eng, tokens, decodes = _pinned_engine(
+        mesh=build_mesh(MeshConfig(dp=2, tp=4)))
+    assert not eng._reads_live_blocks()
+    assert [r.first_use for r in decodes] == [True, False, True, False]
+    assert sorted(k[1] for k in eng.programs_seen
+                  if k[0] == "decode") == [128, 256]
+    assert tokens == PINNED

@@ -257,6 +257,31 @@ def phase_kernels(rehearse: bool) -> int:
              (state["ks"][li], state["vs"][li],
               jnp.arange(er)[None, :] >= er - sum_n[:, None])]))
 
+    # -- grouped int8 matmul over sparse experts (attention="mla" on a
+    # TPU, ops/grouped_matmul.py), at the served widths: a decode
+    # step's 32 token-expert pairs and an admission wave's 16,384 over
+    # 64 experts of 3584 x 1024, two layers' stack read at layer 1,
+    # empty experts and rows of no expert among them; against XLA's
+    # ragged_dot over the dequantized layer ----------------------------
+    from copilot_for_consensus_tpu.ops.grouped_matmul import grouped_qmatmul
+
+    ge, gk, gn = (8, 256, 128) if rehearse else (64, 3584, 1024)
+    gq = jnp.asarray(rng.integers(-127, 128, (2, ge, gk, gn)), jnp.int8)
+    gscale = jnp.asarray(rng.uniform(0.5, 1.5, (2, ge, 1, gn))
+                         * gk ** -0.5 / 73.3, jnp.float32)
+    for gm in (32, 256 if rehearse else 16384):
+        share = rng.multinomial(gm - gm // 8, np.ones(ge) / ge)
+        share[1] = 0                      # an expert nobody chose
+        sizes = jnp.asarray(share, jnp.int32)
+        lhs = normal(gm, gk)
+        got = grouped_qmatmul(lhs, gq, gscale, sizes, jnp.int32(1),
+                              interpret=interpret)
+        ref = jax.lax.ragged_dot(
+            lhs, (gq[1].astype(jnp.float32) * gscale[1]).astype(dtype),
+            sizes, preferred_element_type=jnp.float32)
+        used = int(share.sum())
+        compare(f"grouped_qmatmul/m={gm}", got[:used], ref[:used])
+
     # -- dense decode attention over live blocks (the contiguous cache
     # on a TPU), at the served cells' shapes: 8 slots x 4096 columns of
     # 8 kv heads of 128, all of Mistral's 32 layers. Two mixes of

@@ -380,37 +380,44 @@ class AsyncEngineRunner:
         cycle later. The engine's own submit re-checks on the
         dispatcher thread — this pre-check reads only the scheduler's
         shed state, which is GIL-safe counter reads."""
+        return self.submit_many([(prompt, max_new_tokens, dict(
+            cache_eligible_tokens=cache_eligible_tokens,
+            correlation_id=correlation_id, tenant=tenant,
+            priority=priority, deadline_s=deadline_s))])[0]
+
+    def submit_many(self, requests) -> list[Handle]:
+        """Enqueue a batch as ONE hand-over: ``requests`` is a list of
+        ``(prompt, max_new_tokens, keywords of submit)``; all of them
+        reach the dispatcher's same pass, so its next step admits from
+        the whole batch (a backlog handed over request by request is
+        seen one, two or all at a time, as the threads happen to run,
+        and the first admission wave differs with it). All or nothing:
+        a shed request raises before any is enqueued."""
         if self._thread is None:
             raise RuntimeError("runner not started")
         sched = getattr(self.engine, "_sched", None)
-        if sched is not None:
-            sched.check_admission(
-                tenant=tenant, priority=priority or "interactive",
-                prompt_tokens=len(prompt),
-                correlation_id=correlation_id)
         from copilot_for_consensus_tpu.obs import trace as _trace
 
-        h = Handle(correlation_id=correlation_id,
-                   trace_parent=_trace.current_ids())
-        kw: dict = {}
-        if cache_eligible_tokens is not None:
-            kw["cache_eligible_tokens"] = cache_eligible_tokens
-        if correlation_id:
-            kw["correlation_id"] = correlation_id
-        if tenant:
-            kw["tenant"] = tenant
-        if priority:
-            kw["priority"] = priority
-        if deadline_s is not None:
-            kw["deadline_s"] = deadline_s
+        entries = []
+        for prompt, max_new_tokens, kw in requests:
+            kw = {k: v for k, v in kw.items() if v not in (None, "")}
+            if sched is not None:
+                sched.check_admission(
+                    tenant=kw.get("tenant", ""),
+                    priority=kw.get("priority", "interactive"),
+                    prompt_tokens=len(prompt),
+                    correlation_id=kw.get("correlation_id", ""))
+            h = Handle(correlation_id=kw.get("correlation_id", ""),
+                       trace_parent=_trace.current_ids())
+            entries.append((prompt, max_new_tokens, kw, h))
         with self._work:
             if self._stop:
                 # a submit racing stop() must not enqueue a handle the
                 # (exiting) dispatcher will never resolve
                 raise RuntimeError("runner stopped")
-            self._pending.append((prompt, max_new_tokens, kw, h))
+            self._pending.extend(entries)
             self._work.notify()
-        return h
+        return [e[3] for e in entries]
 
     def prefix_stats(self) -> dict:
         """Prefix-cache counters passthrough (counter reads are atomic

@@ -43,6 +43,17 @@ DOMINANT LATENCY" in SURVEY.md §3.2). Design:
   that assume one column of keys and values per position refuse it at
   construction (``_check_eva``).
 
+* **A third kind of sequence state** (``cfg.attention == "mla"``,
+  Xing4.0 class; ``models/xing.py``): per position ONE latent row
+  shared by all heads, beside dropless sparse experts and a
+  multi-stream residual. Admission goes piece by piece through the
+  same ``_admit_pieces`` (each piece attends in expanded form to the
+  slot's latents, expanded again), decode scores the latents in
+  absorbed form, one program whatever the lengths; the routing's
+  counts come back with the tokens. What assumes a key and a value of
+  ``[Hkv, Dh]`` per position, or one weight pass a step, refuses it
+  at construction (``_check_mla``).
+
 The engine is synchronous and single-owner: services drive it through
 ``submit()`` + ``step()`` (or ``generate()`` for batch use) from their
 consumer thread, mirroring how the reference's summarization service owns
@@ -85,7 +96,7 @@ from copilot_for_consensus_tpu.engine.tokenizer import (
     Tokenizer,
 )
 from copilot_for_consensus_tpu.obs.profile import scope, step_annotation
-from copilot_for_consensus_tpu.models import decoder, eva, quant
+from copilot_for_consensus_tpu.models import decoder, eva, quant, xing
 from copilot_for_consensus_tpu.models.configs import DecoderConfig
 from copilot_for_consensus_tpu.ops import dense_attention
 from copilot_for_consensus_tpu.ops.eva_attention import blocks_read
@@ -220,6 +231,14 @@ def _host_fetch(x) -> "np.ndarray":
     return np.asarray(jax.device_get(x))
 
 
+def _expert_counts(counts) -> dict:
+    """A dispatch's routing counts (``xing.N_COUNTS``, summed over
+    layers and steps on the device) as StepRecord fields."""
+    touched, rows, busiest = (int(c) for c in counts)
+    return {"experts_touched": touched, "expert_rows": rows,
+            "expert_rows_max": busiest}
+
+
 class GenerationEngine:
     """Continuous-batching decoder serving. One instance per process/slice."""
 
@@ -343,6 +362,17 @@ class GenerationEngine:
             w = cfg.window_size
             self.buckets = tuple(b for b in self.buckets
                                  if w % b == 0) or (w,)
+        #: latent attention (models/xing.py): a slot's state is one
+        #: latent row per position; admitted piece by piece as well
+        self._mla = cfg.is_mla
+        if self._mla:
+            self._check_mla(dict(
+                mesh=mesh, prefix_cache_blocks=prefix_cache_blocks,
+                kv_pool_blocks=kv_pool_blocks, spec_decode=spec_decode,
+                kv_dtype=kv_dtype, quantize=quantize,
+                windows_per_dispatch=windows_per_dispatch))
+        #: admission advances every admitted prompt a piece a wave
+        self._pieces = self._eva or self._mla
         # eos_id may be a list (Llama-3.1-style multi-EOS checkpoints).
         eos_list = list(eos_id) if isinstance(eos_id, (list, tuple)) \
             else [int(eos_id)]
@@ -381,6 +411,14 @@ class GenerationEngine:
         if params is None and self._eva:
             params = eva.init_params(jax.random.PRNGKey(seed), cfg,
                                      dtype=dtype)
+        if self._mla:
+            # its own layout (two stacks of layers, expert stacks):
+            # made and quantized by its own module
+            if params is None:
+                params = xing.init_params(jax.random.PRNGKey(seed), cfg,
+                                          dtype=dtype)
+            if qmode:
+                params = xing.quantize_params(params)
         if params is None:
             if qmode:
                 params = quant.init_random_quantized(
@@ -393,8 +431,9 @@ class GenerationEngine:
             # yet; sharded engines fall back to the XLA dequant
             # expression, which partitions naturally over tp.
             quant.set_pallas_qmatmul(False)
-        if params is not None and qmode and not quant.is_quantized(
-                params.get("layers", {}).get("wq")):
+        if params is not None and qmode and not self._mla \
+                and not quant.is_quantized(
+                    params.get("layers", {}).get("wq")):
             # Caller provided full-precision weights: quantize on the fly.
             # (Real checkpoints should be quantized offline on the host —
             # this transient needs both copies in memory.)
@@ -597,6 +636,12 @@ class GenerationEngine:
             self._cache = eva.init_cache(cfg, num_slots, self.max_len,
                                          dtype=self.kv_dtype,
                                          margin=self._dispatch_steps)
+        elif self._mla:
+            # one latent row per position and layer, all heads' (a
+            # stack of layers an array: models/xing.py), donated to
+            # every program and updated in place
+            self._cache = xing.init_cache(cfg, num_slots, self.max_len,
+                                          dtype=self.kv_dtype)
         else:
             cache = decoder.init_cache(cfg, num_slots, self.max_len,
                                        dtype=self.kv_dtype)
@@ -929,7 +974,7 @@ class GenerationEngine:
                                         telemetry=self.telemetry)
         # Chunking rides prefill_attention_seeded, which (like spec
         # decode) does not implement absolute-timeline window masking.
-        self._chunk_ok = not self._eva and (
+        self._chunk_ok = not self._pieces and (
             cfg.sliding_window == 0
             or cfg.sliding_window >= self.max_len)
         ct = self.prompt_limit
@@ -1007,6 +1052,27 @@ class GenerationEngine:
             self._decode_eva_fn = jax.jit(
                 _decode_eva, donate_argnums=(3,),
                 static_argnames=("may_close",))
+
+        # ---- latent-attention programs (cfg.attention == "mla") --------
+        # The same two programs for models/xing.py: a piece of each
+        # admitted prompt per row (static key: rows x bucket), and ONE
+        # decode dispatch whatever the lengths. Each hands back the
+        # routing's counts beside its tokens.
+
+        def _admit_mla(params, tokens, lens, pos0, slots, cache, key):
+            logits, cache, counts = xing.prefill_piece(
+                params, tokens, lens, pos0, slots, cfg, cache)
+            return sample(logits, key, self.sampling), cache, counts
+
+        def _decode_mla(params, tokens, positions, cache, key):
+            return xing.decode_tokens(
+                params, tokens, positions, cfg, cache, key,
+                lambda logits, sub: sample(logits, sub, self.sampling),
+                steps=self.decode_window, max_len=self.max_len)
+
+        if self._mla:
+            self._admit_mla_fn = jax.jit(_admit_mla, donate_argnums=(5,))
+            self._decode_mla_fn = jax.jit(_decode_mla, donate_argnums=(3,))
 
         # ---- paged dispatch programs (kv_pool_blocks > 0) --------------
         # Two routes serve the same block-table semantics, selected by
@@ -1552,7 +1618,7 @@ class GenerationEngine:
         window of cache headroom, capped by the largest prefill bucket).
         Callers with longer prompts should route to the long-context
         engine (``engine/longctx.py``)."""
-        if self._eva:
+        if self._pieces:
             # admitted piece by piece: no bucket bounds a prompt
             return self.max_len - self._dispatch_steps
         return min(self.max_len - self._dispatch_steps, self.buckets[-1])
@@ -1664,11 +1730,12 @@ class GenerationEngine:
             self._expire_deadlines()
             if self._sched is not None:
                 self._sched_pump()
-            if self._eva:
+            if self._pieces:
                 self._admit_pieces()
             else:
                 self._admit()
-            if not self._eva and (self._chunk_pending or self._chunking):
+            if not self._pieces and (self._chunk_pending
+                                     or self._chunking):
                 self._phase("plan", ahead=True)
                 self._chunk_step()
             if self.paged:
@@ -2339,6 +2406,62 @@ class GenerationEngine:
                 f"max_len ({self.max_len}) a multiple of window_size "
                 f"({w})")
 
+    def _check_mla(self, asked: dict) -> None:
+        """Refuse, at construction and by mechanism, every option that
+        assumes a key and a value of ``[Hkv, Dh]`` per position, or a
+        dense feed-forward. No silent fallback."""
+        why = {
+            "mesh": "the latent cache and the expert stacks have no "
+                    "sharding rules, and experts over a mesh need an "
+                    "exchange of tokens that xing.routed_experts does "
+                    "not have",
+            "prefix_cache_blocks": "the prefix cache publishes and "
+                    "seeds blocks of per-head keys and values; a "
+                    "prefix here is latent rows shared by all heads",
+            "kv_pool_blocks": "the block pool pages keys and values of "
+                    "[Hkv, Dh]; it has no latent pages",
+            "spec_decode": "the verify pass scores k + 1 positions "
+                    "through decoder.verify_seeded, which knows "
+                    "neither latent attention nor the experts",
+        }
+        for name, reason in why.items():
+            if asked[name]:
+                raise ValueError(
+                    f"{name} cannot serve attention='mla' "
+                    f"({self.cfg.name}): {reason}")
+        kv = resolve_kv_dtype(asked["kv_dtype"], None)
+        if kv is not None and jnp.dtype(kv).itemsize < 2:
+            raise ValueError(
+                f"kv_dtype {asked['kv_dtype']!r} cannot serve "
+                f"attention='mla': an 8-bit latent row feeds every "
+                f"head's keys and values at once and was never held "
+                f"against the reference")
+        if asked["quantize"] == "int4":
+            raise ValueError(
+                "quantize='int4' cannot serve attention='mla': the "
+                "grouped expert matmul (ops/grouped_matmul.py) and "
+                "xing.quantize_params know int8 with a scale per "
+                "output channel only")
+        if asked["windows_per_dispatch"] != 1:
+            raise ValueError(
+                "windows_per_dispatch > 1 cannot serve attention='mla': "
+                "xing.decode_tokens keeps one window of latent rows a "
+                "dispatch")
+        cfg = self.cfg
+        if (cfg.is_moe or cfg.sliding_window or cfg.hc_mult < 1
+                or cfg.kv_lora_rank <= 0 or cfg.q_lora_rank <= 0
+                or cfg.qk_rope_head_dim % 2
+                or self.max_len % self.buckets[-1]
+                or self.max_len % min(xing.KV_BLOCK, self.max_len)):
+            raise ValueError(
+                f"attention='mla' needs a query and a key/value latent "
+                f"rank, an even rotary width, no sliding window, its "
+                f"own experts (n_routed_experts, not n_experts) and "
+                f"max_len ({self.max_len}) a multiple of the largest "
+                f"prefill bucket ({self.buckets[-1]}) and of the "
+                f"expansion block ({xing.KV_BLOCK}): a piece's rows "
+                f"are written as one slab at a multiple of the bucket")
+
     def _eva_live(self) -> tuple[int, int]:
         """(exact columns, summaries) held by all sequences in slots,
         decoding or mid-admission, right now."""
@@ -2359,12 +2482,13 @@ class GenerationEngine:
             for s in self._active)
 
     def _admit_pieces(self) -> None:
-        """Admission for attention='eva': queued requests take free
-        slots, and ONE wave advances every admitted prompt by its next
-        piece — at most the largest bucket, never across a window edge,
-        so a piece that reaches the edge leaves summaries behind and
-        the last piece stays exact in the window. A prompt of four
-        windows is four waves, each sharing its weight pass with the
+        """Admission for attention='eva' and 'mla': queued requests
+        take free slots, and ONE wave advances every admitted prompt by
+        its next piece — at most the largest bucket and, for 'eva',
+        never across a window edge, so a piece that reaches the edge
+        leaves summaries behind and the last piece stays exact in the
+        window. A prompt of four windows (or four times the largest
+        bucket) is four waves, each sharing its weight pass with the
         other rows' pieces and co-scheduled with the decode dispatches
         in between; one program per (rows, bucket), whatever the
         prompt length. Rows pad to a power of two with copies of the
@@ -2377,7 +2501,9 @@ class GenerationEngine:
         if not self._chunking:
             return
         t0 = time.monotonic()
-        w_sz = self.cfg.window_size
+        # 'mla' has no edge but the largest bucket's: pieces start at
+        # its multiples
+        w_sz = self.cfg.window_size if self._eva else self.buckets[-1]
         rows: list[tuple[int, int]] = []          # (slot, piece length)
         bucket = n_pad = 0
         for slot, (req, filled, _t) in self._chunking.items():
@@ -2402,18 +2528,28 @@ class GenerationEngine:
         for r in range(len(rows), n_pad):
             tokens[r], lens[r] = tokens[0], lens[0]
             pos0[r], slots[r] = pos0[0], slots[0]
-        win_tokens, sum_tokens = self._eva_live()
+        win_tokens, sum_tokens = self._eva_live() if self._eva \
+            else (int(pos0[:len(rows)].sum()), 0)
         self._key, sub = jax.random.split(self._key)
         seq = self.telemetry.next_step() if self.telemetry is not None \
             else None
         # On failure the _chunking entries are untouched (fills only
         # advance after the host fetch): the same pieces go again.
         self._phase(None)
+        extra: dict = {}
         with step_annotation("prefill", seq), \
                 self._dispatch_boundary("prefill"):
-            first_dev, self._cache = self._admit_eva_fn(
-                self.params, jnp.asarray(tokens), jnp.asarray(lens),
-                jnp.asarray(pos0), jnp.asarray(slots), self._cache, sub)
+            args = (self.params, jnp.asarray(tokens), jnp.asarray(lens),
+                    jnp.asarray(pos0), jnp.asarray(slots), self._cache,
+                    sub)
+            if self._mla:
+                first_dev, self._cache, counts = self._admit_mla_fn(*args)
+                extra = _expert_counts(_host_fetch(counts))
+                extra["attn_pairs"] = sum(
+                    n * int(pos0[r]) + n * (n + 1) // 2
+                    for r, (_slot, n) in enumerate(rows))
+            else:
+                first_dev, self._cache = self._admit_eva_fn(*args)
             first = _host_fetch(first_dev)
         step_s = time.monotonic() - t0
         self._phase("commit")
@@ -2428,7 +2564,7 @@ class GenerationEngine:
             entry = self._chunking[slot]
             req, _filled, started = entry
             entry[1] += n
-            compacted += entry[1] % w_sz == 0
+            compacted += self._eva and entry[1] % w_sz == 0
             if entry[1] < len(req.prompt):
                 continue
             del self._chunking[slot]
@@ -2455,7 +2591,7 @@ class GenerationEngine:
                 new_tokens=first_tokens, prompt_tokens=fed,
                 first_use=self._first_use("prefill", bucket, n_pad),
                 windows_compacted=compacted, window_tokens=win_tokens,
-                summary_tokens=sum_tokens)
+                summary_tokens=sum_tokens, **extra)
 
     def _req_digests(self, req: Request) -> list:
         if req.block_digests is None:
@@ -2472,7 +2608,8 @@ class GenerationEngine:
         is what the tests hold the kernel to. Read when the program is
         traced and before every dispatch: it follows the backend, no
         option sets it."""
-        return (self.mesh is None and not self.paged and not self._eva
+        return (self.mesh is None and not self.paged
+                and not self._pieces
                 and dense_attention.serves(self.max_len))
 
     def _live_blocks_read(self, steps: int) -> int:
@@ -3151,10 +3288,26 @@ class GenerationEngine:
             # dispatch? Only that program holds the compaction; two
             # decode programs in all
             kv_len = closing > 0
+        elif self._mla:
+            # ONE program: the latents are scored whole, each slot's
+            # below its own length
+            kv_len = self.max_len
+            extra = {"window_tokens": sum(int(self._positions[s])
+                                          for s in self._active),
+                     "state_tokens_read":
+                         window * self.num_slots * self.max_len}
         self._phase(None)
         with step_annotation("decode", seq), \
                 self._dispatch_boundary("decode"):
-            if self._eva:
+            if self._mla:
+                toks, self._cache, counts = self._decode_mla_fn(
+                    self.params, jnp.asarray(self._next_tok),
+                    jnp.asarray(self._positions), self._cache, sub)
+                toks = _host_fetch(toks)                 # [steps, slots]
+                extra.update(_expert_counts(_host_fetch(counts)))
+                self.plain_s += time.monotonic() - t0
+                self.plain_dispatches += 1
+            elif self._eva:
                 toks, self._cache = self._decode_eva_fn(
                     self.params, jnp.asarray(self._next_tok),
                     jnp.asarray(self._positions), self._cache, sub,

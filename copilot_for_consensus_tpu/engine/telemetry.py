@@ -338,7 +338,9 @@ class StepRecord:
     windows_compacted: int = 0  # slot-windows that filled and were
     #                             pooled into summaries in the dispatch
     window_tokens: int = 0      # exact key/value columns live in all
-    #                             slots when the dispatch began
+    #                             slots when the dispatch began; mla:
+    #                             latent rows live, then, in the slots
+    #                             the dispatch serves
     summary_tokens: int = 0     # chunk summaries live in all slots then
     state_tokens_read: int = 0  # decode dispatches: the decoding
     #                             slots' live columns and summaries,
@@ -347,7 +349,18 @@ class StepRecord:
     #                             a dense engine on a TPU without a mesh
     #                             (ops/dense_attention.py): the cache
     #                             columns under the blocks read, summed
-    #                             over the dispatch's steps
+    #                             over the dispatch's steps; mla: the
+    #                             latent rows its steps score, every
+    #                             slot's whole extent
+    # attention="mla" engines (models/xing.py); 0 elsewhere. Counted on
+    # the device over the dispatch's live tokens, summed over expert
+    # layers and steps, fetched with the tokens
+    experts_touched: int = 0    # distinct experts chosen
+    expert_rows: int = 0        # token x expert pairs
+    expert_rows_max: int = 0    # the busiest expert's pairs
+    attn_pairs: int = 0         # admission waves: query-key pairs the
+    #                             wave's real tokens attend to, each
+    #                             over its sequence's whole prefix
 
     @property
     def occupancy(self) -> float:
@@ -374,8 +387,8 @@ class FlightRecorder:
 
     The default capacity holds an hour of serving: a 7B engine on one
     chip makes about 8 dispatches a second (PERF_LEDGER, PR 25), 8 x
-    3600 = 28,800 records, rounded up to 32,768; a record is 19 small
-    fields, about 250 bytes, so a full ring is 6-7 MB. A run's warm-up
+    3600 = 28,800 records, rounded up to 32,768; a record is 24 small
+    fields, about 290 bytes, so a full ring is 6-7 MB. A run's warm-up
     steps (``first_use``) are then still there when it ends."""
 
     def __init__(self, capacity: int = 32768):
@@ -592,7 +605,10 @@ class EngineTelemetry:
                     new_tokens: int = 0, prompt_tokens: int = 0,
                     first_use: bool = False, windows_compacted: int = 0,
                     window_tokens: int = 0, summary_tokens: int = 0,
-                    state_tokens_read: int = 0) -> StepRecord:
+                    state_tokens_read: int = 0,
+                    experts_touched: int = 0, expert_rows: int = 0,
+                    expert_rows_max: int = 0,
+                    attn_pairs: int = 0) -> StepRecord:
         """``t_start`` is the dispatch's ``time.monotonic()`` start
         (default: now less ``duration_s``)."""
         if t_start is None:
@@ -607,7 +623,9 @@ class EngineTelemetry:
             new_tokens=new_tokens, prompt_tokens=prompt_tokens,
             first_use=first_use, windows_compacted=windows_compacted,
             window_tokens=window_tokens, summary_tokens=summary_tokens,
-            state_tokens_read=state_tokens_read)
+            state_tokens_read=state_tokens_read,
+            experts_touched=experts_touched, expert_rows=expert_rows,
+            expert_rows_max=expert_rows_max, attn_pairs=attn_pairs)
         self.recorder.record(rec)
         m, lb = self.metrics, self._labels
         m.observe("engine_step_seconds", duration_s,

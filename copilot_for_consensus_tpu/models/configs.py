@@ -46,10 +46,42 @@ class DecoderConfig:
     num_pred_heads: int = 1
     #: RMS norm gains are stored as offsets from one: x̂ · (1 + g)
     norm_unit_offset: bool = False
+    # attention == "mla" (models/xing.py), under the published keys'
+    # names: queries through a ``q_lora_rank`` bottleneck; per position
+    # ONE latent row of ``kv_lora_rank`` values plus one rotary key of
+    # ``qk_rope_head_dim``, shared by all heads, from which every head's
+    # ``qk_nope_head_dim`` key and ``v_head_dim`` value are expanded
+    q_lora_rank: int = 0
+    kv_lora_rank: int = 0
+    qk_nope_head_dim: int = 0
+    qk_rope_head_dim: int = 0
+    v_head_dim: int = 0
+    #: YaRN, as (key, value) pairs of the published ``rope_scaling``
+    rope_scaling: tuple = ()
+    #: dropless sparse experts beside shared ones (sigmoid scores,
+    #: ``noaux_tc`` choice of ``experts_per_token``) after
+    #: ``first_k_dense_replace`` dense layers of width ``d_ff``
+    n_routed_experts: int = 0
+    n_shared_experts: int = 0
+    moe_intermediate_size: int = 0
+    first_k_dense_replace: int = 0
+    routed_scaling_factor: float = 1.0
+    #: residual streams (manifold-constrained hyper-connections), the
+    #: Sinkhorn rounds of their mixing matrix, its denominators'
+    #: epsilon and the clamp on its logits
+    hc_mult: int = 1
+    hc_sinkhorn_iters: int = 20
+    hc_eps: float = 1e-6
+    mhc_h_res_clamp_min: float = -30.0
+    mhc_h_res_clamp_max: float = 30.0
 
     @property
     def head_dim(self) -> int:
         return self.head_dim_override or self.d_model // self.n_heads
+
+    @property
+    def is_mla(self) -> bool:
+        return self.attention == "mla"
 
     @property
     def is_moe(self) -> bool:
@@ -111,6 +143,22 @@ DECODER_CONFIGS: dict[str, DecoderConfig] = {
         n_kv_heads=4, d_ff=128, rope_theta=1e5, max_seq_len=512,
         attention="eva", window_size=32, chunk_size=4, num_pred_heads=8,
         norm_unit_offset=True,
+    ),
+    # Xing4.0 class at test scale: a latent cache of 32 + 8 values a
+    # position, four residual streams, one dense layer and two layers
+    # of 8 experts (2 a token) beside a shared one.
+    "tiny-xing": DecoderConfig(
+        name="tiny-xing", vocab_size=512, d_model=64, n_layers=3,
+        n_heads=4, n_kv_heads=4, d_ff=160, rope_theta=1e4,
+        max_seq_len=512, norm_eps=1e-6, attention="mla", q_lora_rank=48,
+        kv_lora_rank=32, qk_nope_head_dim=16, qk_rope_head_dim=8,
+        v_head_dim=16, n_routed_experts=8, n_shared_experts=1,
+        experts_per_token=2, moe_intermediate_size=32,
+        first_k_dense_replace=1, routed_scaling_factor=2.0, hc_mult=4,
+        rope_scaling=(("type", "yarn"), ("factor", 8.0),
+                      ("original_max_position_embeddings", 64),
+                      ("beta_fast", 32.0), ("beta_slow", 1.0),
+                      ("mscale", 1.0), ("mscale_all_dim", 1.0)),
     ),
     "tiny-moe": DecoderConfig(
         name="tiny-moe", vocab_size=512, d_model=128, n_layers=2, n_heads=4,

@@ -7,6 +7,15 @@ into all-to-all collectives over ICI. No data-dependent shapes, so the
 whole layer stays jit-compatible (static capacity; overflow tokens drop,
 standard for capacity-factor routing).
 
+This is the TRAINING dispatch. Serving does not go through it: a token
+over capacity is dropped, so a request's output would depend on its
+batch-mates, which breaks the greedy bit-identity that replay, the
+journal and the benchmark's ``correct`` rest on. The serving path's
+experts are ``models/xing.py:routed_experts`` (dropless: token-expert
+pairs sorted by expert, grouped matmuls over the experts held); the
+two share ``_q_einsum``'s convention of an int8 scale per expert and
+output channel, applied after the contraction.
+
 The reference has no MoE anywhere (SURVEY.md §2.3 — Mixtral-8x7B appears
 only as a BASELINE.json target config); this is new TPU-first capability.
 """

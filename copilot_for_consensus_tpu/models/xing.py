@@ -1,0 +1,785 @@
+"""Latent-attention decoder with dropless sparse experts and a
+multi-stream residual (Xing4.0 class; ``cfg.attention == "mla"``).
+
+Three things set the block apart from the dense decoder's, each under
+the published config's own keys (``models/configs.py``):
+
+* **Attention keeps one latent row per position** (MLA, DeepSeek-V2/V3):
+  ``[c ; k_r]``, ``kv_lora_rank`` values from which every head's key and
+  value are expanded by ``wkv_b``, and one rotary key of
+  ``qk_rope_head_dim`` shared by all heads (interleaved pairs, YaRN
+  frequencies, rotated at the absolute position). Queries pass through a
+  ``q_lora_rank`` bottleneck. The same function is computed two ways:
+  EXPANDED (an admission piece: keys of ``qk_nope + qk_rope`` and values
+  of ``v_head_dim`` a head, expanded again from the cache block by block
+  for every piece, online softmax over the blocks that hold something
+  live) and ABSORBED (a decode step: ``wkv_b``'s key half folded into the
+  query, its value half applied after the softmax, so all heads score
+  the one cached row as grouped queries score a shared kv head:
+  ``ops.attention.decode_attention_prefix_window`` with one kv head of
+  ``kv_lora_rank + qk_rope_head_dim``, whose first ``kv_lora_rank``
+  values are also the "value").
+* **The feed-forward part is sparse after ``first_k_dense_replace``
+  layers**: sigmoid scores over ``n_routed_experts``, the
+  ``experts_per_token`` largest of ``score + bias`` chosen, gates
+  normalised over the chosen and scaled, and a shared expert beside
+  them. DROPLESS: token-expert pairs are sorted by expert and meet the
+  experts' matrices in grouped matmuls (``ops/grouped_matmul.py``,
+  through the Pallas interpreter off a TPU), so a token's output never
+  depends on its batch-mates. The layer is told which experts it holds
+  (``held``), routes over all of them and adds its own experts' part.
+  (``models/moe.py`` is the capacity-dropping TRAINING dispatch.)
+* **The residual is ``hc_mult`` streams** (manifold-constrained
+  hyper-connections, arXiv:2512.24880): around each sublayer, per token
+  and in float32, a read-out of the streams (``H_pre``), a write-back
+  (``H_post``) and a doubly stochastic mixing of the streams (``H_res``,
+  ``hc_sinkhorn_iters`` Sinkhorn rounds), all three from the token's own
+  normalised streams (``mhc_maps``).
+
+State: ``{"dense": [k0, slots, R, max_len], "moe": [L - k0, slots, R,
+max_len]}`` latent rows (``R = kv_lora_rank + qk_rope_head_dim``), one
+array per stack of layers so that each layer scan takes its own as
+scanned input or carry and none is ever cut. A position is a COLUMN:
+the positions run along the minor axis. That is the layout the chip's
+compiler gives ``[.., max_len, R]`` of its own accord (R = 576 is four
+and a half lane tiles; the positions fill them whole) and the one its
+decode dots want; held the other way round, the admission program,
+which wants rows, turned the whole cache over on entry and back on
+exit. Admission writes a piece's rows in place, slot by slot; a decode
+dispatch keeps its own rows in a small buffer and merges them once
+(``merge_latents``, the slab scatter of ``decoder.merge_window``).
+"""
+
+from __future__ import annotations
+
+import functools
+import math
+from typing import Any
+
+import jax
+import jax.numpy as jnp
+
+from copilot_for_consensus_tpu.models import layers as L
+from copilot_for_consensus_tpu.models.configs import DecoderConfig
+from copilot_for_consensus_tpu.models.quant import (
+    quant_kind,
+    quantize_tensor,
+)
+from copilot_for_consensus_tpu.obs.profile import scope
+from copilot_for_consensus_tpu.ops.attention import (
+    decode_attention_prefix_window,
+)
+from copilot_for_consensus_tpu.ops.grouped_matmul import grouped_qmatmul
+
+Params = dict[str, Any]
+
+#: cached positions that one round of an admission piece's attention
+#: expands and scores (``piece_attention``); a cache extent is a
+#: multiple of it or shorter
+KV_BLOCK = 1024
+
+#: leaves served as int8 (``quantize_params``); ``wkv_b`` stays in the
+#: activation type (the absorbed form contracts it with activations on
+#: either side), the router, its bias and the mHC maps in float32
+MATRICES = ("wq_a", "wq_b", "wkv_a", "wo", "w_gate", "w_up", "w_down",
+            "we_gate", "we_up", "we_down")
+
+#: the expert stacks ``[layers, E, ...]``: a layer scan closes over them
+#: and hands the layer's index on, so that the grouped matmul reads a
+#: layer's experts in place (``ops/grouped_matmul.py``)
+EXPERTS = ("we_gate", "we_up", "we_down")
+
+#: (experts touched, token-expert pairs, the busiest expert's pairs):
+#: what a layer's routing reports, summed over layers and steps
+N_COUNTS = 3
+
+
+# ---------------------------------------------------------------------------
+# Shapes, parameters, cache
+# ---------------------------------------------------------------------------
+
+
+def latent_width(cfg: DecoderConfig) -> int:
+    return cfg.kv_lora_rank + cfg.qk_rope_head_dim
+
+
+def stacks(cfg: DecoderConfig) -> dict[str, int]:
+    """Layers per stack, in order: the leading dense ones, then the
+    expert layers. A stack with no layer is absent."""
+    k0 = min(cfg.first_k_dense_replace, cfg.n_layers)
+    out = {"dense": k0, "moe": cfg.n_layers - k0}
+    return {k: v for k, v in out.items() if v}
+
+
+def n_maps(cfg: DecoderConfig) -> int:
+    """Columns of a sublayer's mHC map: pre, post, and the n x n mix."""
+    return cfg.hc_mult * (cfg.hc_mult + 2)
+
+
+def init_params(rng: jax.Array, cfg: DecoderConfig, dtype=jnp.bfloat16,
+                quantize: bool = False) -> Params:
+    """Random weights in this module's layout. The mHC maps are drawn
+    alive: the maps' logits vary from token to token (``alpha`` of
+    order one, the mix's a quarter of that), so that a wrong Sinkhorn
+    or a dropped stream changes the logits."""
+    d, h, n = cfg.d_model, cfg.n_heads, cfg.hc_mult
+    rq, r, dr = cfg.q_lora_rank, cfg.kv_lora_rank, cfg.qk_rope_head_dim
+    dn, dv = cfg.qk_nope_head_dim, cfg.v_head_dim
+    e, fe = cfg.n_routed_experts, cfg.moe_intermediate_size
+    keys = iter(jax.random.split(rng, 64))
+
+    def dense(shape, fan_in, dt=dtype):
+        return (jax.random.truncated_normal(next(keys), -2, 2, shape,
+                                            jnp.float32)
+                * fan_in ** -0.5).astype(dt)
+
+    def gain(shape):
+        return (1.0 + 0.1 * jax.random.normal(next(keys), shape,
+                                              jnp.float32)).astype(dtype)
+
+    def stack(count: int, moe: bool) -> Params:
+        out = {
+            "attn_norm": gain((count, d)), "ffn_norm": gain((count, d)),
+            "wq_a": dense((count, d, rq), d),
+            "q_norm": gain((count, rq)),
+            "wq_b": dense((count, rq, h * (dn + dr)), rq),
+            "wkv_a": dense((count, d, r + dr), d),
+            "kv_norm": gain((count, r)),
+            "wkv_b": dense((count, r, h * (dn + dv)), r),
+            "wo": dense((count, h * dv, d), h * dv),
+        }
+        for sub in ("attn", "ffn"):
+            out[f"hc_{sub}_phi"] = dense((count, n, d, n_maps(cfg)), n * d,
+                                         jnp.float32)
+            # the mix's logits at a quarter of the others' size: twenty
+            # Sinkhorn rounds then end within 1e-6 of doubly stochastic
+            size = jnp.asarray([1.0, 1.0, 0.25])
+            out[f"hc_{sub}_alpha"] = size * jax.random.uniform(
+                next(keys), (count, 3), jnp.float32, 1.0, 2.0)
+            out[f"hc_{sub}_bias"] = 0.5 * jax.random.normal(
+                next(keys), (count, n_maps(cfg)), jnp.float32
+            ) * jnp.where(jnp.arange(n_maps(cfg)) < 2 * n, 1.0, 0.5)
+        f = fe * max(cfg.n_shared_experts, 1) if moe else cfg.d_ff
+        out.update(w_gate=dense((count, d, f), d),
+                   w_up=dense((count, d, f), d),
+                   w_down=dense((count, f, d), f))
+        if moe:
+            out.update(
+                router=dense((count, d, e), d, jnp.float32),
+                e_bias=0.01 * jax.random.normal(next(keys), (count, e),
+                                                jnp.float32),
+                we_gate=dense((count, e, d, fe), d),
+                we_up=dense((count, e, d, fe), d),
+                we_down=dense((count, e, fe, d), fe))
+        return out
+
+    params = {"tok_emb": dense((cfg.vocab_size, d), d),
+              "final_norm": gain((d,)),
+              "lm_head": dense((d, cfg.vocab_size), d)}
+    for name, count in stacks(cfg).items():
+        params[name] = stack(count, name == "moe")
+    return quantize_params(params) if quantize else params
+
+
+def quantize_params(params: Params) -> Params:
+    """int8 with one float32 scale per output channel (per expert and
+    channel in an expert stack) for every leaf of ``MATRICES`` and the
+    output head; leaves that are already quantized pass."""
+    def q(leaf):
+        return leaf if quant_kind(leaf) else quantize_tensor(leaf)
+
+    out = dict(params, lm_head=q(params["lm_head"]))
+    for name in ("dense", "moe"):
+        if name in params:
+            out[name] = {k: q(v) if k in MATRICES else v
+                         for k, v in params[name].items()}
+    return out
+
+
+def init_cache(cfg: DecoderConfig, batch: int, max_len: int,
+               dtype=jnp.bfloat16) -> Params:
+    if max_len % min(KV_BLOCK, max_len):
+        raise ValueError(
+            f"latent cache: max_len {max_len} must be a multiple of the "
+            f"expansion block ({KV_BLOCK})")
+    return {name: jnp.zeros((count, batch, latent_width(cfg), max_len),
+                            dtype)
+            for name, count in stacks(cfg).items()}
+
+
+# ---------------------------------------------------------------------------
+# Rotary frequencies (YaRN), interleaved pairs
+# ---------------------------------------------------------------------------
+
+
+def rope_inv_freq(cfg: DecoderConfig) -> jax.Array:
+    """``qk_rope_head_dim / 2`` inverse frequencies: ``theta ** (-2i /
+    d)`` blended, frequency by frequency, with the same divided by
+    ``factor``: a linear ramp between the two correction dimensions
+    (where ``beta_fast`` and ``beta_slow`` turns fit the original
+    context)."""
+    dim = cfg.qk_rope_head_dim
+    base = cfg.rope_theta
+    extra = 1.0 / base ** (jnp.arange(0, dim, 2, dtype=jnp.float32) / dim)
+    rs = dict(cfg.rope_scaling)
+    if rs.get("type") != "yarn":
+        return extra
+    orig = rs["original_max_position_embeddings"]
+
+    def correction_dim(turns):
+        return dim * math.log(orig / (turns * 2 * math.pi)) \
+            / (2 * math.log(base))
+
+    low = max(math.floor(correction_dim(rs["beta_fast"])), 0)
+    high = min(math.ceil(correction_dim(rs["beta_slow"])), dim - 1)
+    ramp = jnp.clip((jnp.arange(dim // 2, dtype=jnp.float32) - low)
+                    / max(high - low, 1e-3), 0.0, 1.0)
+    return extra / rs["factor"] * ramp + extra * (1.0 - ramp)
+
+
+def softmax_scale(cfg: DecoderConfig) -> float:
+    """``(qk_nope + qk_rope) ** -0.5``, times YaRN's ``mscale ** 2``
+    (``mscale = 0.1 mscale_all_dim ln(factor) + 1``)."""
+    scale = (cfg.qk_nope_head_dim + cfg.qk_rope_head_dim) ** -0.5
+    rs = dict(cfg.rope_scaling)
+    if rs.get("type") == "yarn" and rs.get("mscale_all_dim"):
+        m = 0.1 * rs["mscale_all_dim"] * math.log(rs["factor"]) + 1.0
+        scale *= m * m
+    return scale
+
+
+@scope("norm_rope")
+def rope(x: jax.Array, angles: jax.Array) -> jax.Array:
+    """Rotate the interleaved pairs ``(x[2i], x[2i + 1])`` of the last
+    axis by ``angles`` (broadcast against ``x``'s leading axes)."""
+    xf = x.astype(jnp.float32).reshape(*x.shape[:-1], -1, 2)
+    a, b = xf[..., 0], xf[..., 1]
+    cos, sin = jnp.cos(angles), jnp.sin(angles)
+    return jnp.stack([a * cos - b * sin, a * sin + b * cos],
+                     axis=-1).reshape(x.shape)
+
+
+# ---------------------------------------------------------------------------
+# The residual streams
+# ---------------------------------------------------------------------------
+
+
+def sinkhorn(logits: list, iters: int, eps: float) -> list:
+    """``iters`` rounds of row then column normalisation of
+    ``exp(logits)``: ``logits[i][j]`` are arrays of one shape (a value
+    per token), so every round is elementwise."""
+    a = [[jnp.exp(m) for m in row] for row in logits]
+    n = len(a)
+    for _ in range(iters):
+        for i in range(n):
+            inv = 1.0 / (sum(a[i]) + eps)
+            a[i] = [v * inv for v in a[i]]
+        for j in range(n):
+            inv = 1.0 / (sum(a[i][j] for i in range(n)) + eps)
+            for i in range(n):
+                a[i][j] = a[i][j] * inv
+    return a
+
+
+@scope("mhc")
+def mhc_maps(x: jax.Array, layer: Params, sub: str, cfg: DecoderConfig):
+    """The three maps of one sublayer from the streams ``x``
+    ``[n, ..., d]`` float32: (``H_pre`` n arrays, ``H_post`` n arrays,
+    ``H_res`` n x n arrays, each ``[...]``). The maps' input is the
+    token's streams under ONE norm over all ``n d`` values, no gain."""
+    n = cfg.hc_mult
+    inv = jax.lax.rsqrt(
+        jnp.mean(jnp.mean(x * x, axis=-1), axis=0) + cfg.norm_eps)
+    raw = jnp.einsum("n...d,ndk->k...", x, layer[f"hc_{sub}_phi"],
+                     precision=jax.lax.Precision.HIGHEST) * inv
+    alpha, bias = layer[f"hc_{sub}_alpha"], layer[f"hc_{sub}_bias"]
+    pre = [jax.nn.sigmoid(alpha[0] * raw[i] + bias[i]) for i in range(n)]
+    post = [2.0 * jax.nn.sigmoid(alpha[1] * raw[n + i] + bias[n + i])
+            for i in range(n)]
+    res = sinkhorn(
+        [[jnp.clip(alpha[2] * raw[2 * n + n * i + j]
+                   + bias[2 * n + n * i + j],
+                   cfg.mhc_h_res_clamp_min, cfg.mhc_h_res_clamp_max)
+          for j in range(n)] for i in range(n)],
+        cfg.hc_sinkhorn_iters, cfg.hc_eps)
+    return pre, post, res
+
+
+@scope("mhc")
+def mhc_read(x: jax.Array, pre: list) -> jax.Array:
+    """``H_pre · X``: the sublayer's input ``[..., d]`` float32."""
+    return sum(p[..., None] * x[i] for i, p in enumerate(pre))
+
+
+@scope("mhc")
+def mhc_write(x: jax.Array, y: jax.Array, post: list, res: list
+              ) -> jax.Array:
+    """``H_res · X + H_post ⊗ y``: the streams after the sublayer."""
+    y = y.astype(jnp.float32)
+    return jnp.stack([
+        sum(res[i][j][..., None] * x[j] for j in range(len(post)))
+        + post[i][..., None] * y for i in range(len(post))])
+
+
+# ---------------------------------------------------------------------------
+# Attention: projections, the expanded form, the absorbed form
+# ---------------------------------------------------------------------------
+
+
+def _norm(x, gain, cfg, dtype):
+    return L.rms_norm(x, gain, cfg.norm_eps, dtype=dtype)
+
+
+@scope("qkv")
+def project(hid: jax.Array, layer: Params, cfg: DecoderConfig,
+            angles: jax.Array, dtype
+            ) -> tuple[jax.Array, jax.Array, jax.Array]:
+    """hid ``[n, S, d]`` → (``q_n [n, S, H, dn]``, rotated ``q_r [n, S,
+    H, dr]``, the positions' latent rows ``[n, S, R]``: normed ``c``
+    and the rotated shared key), in ``dtype``."""
+    n, s, _ = hid.shape
+    r = cfg.kv_lora_rank
+    c_q = _norm(L.qmatmul(hid, layer["wq_a"]), layer["q_norm"], cfg,
+                hid.dtype)
+    q = L.qmatmul(c_q, layer["wq_b"]).reshape(n, s, cfg.n_heads, -1)
+    q_n, q_r = q[..., :cfg.qk_nope_head_dim], q[..., cfg.qk_nope_head_dim:]
+    kv = L.qmatmul(hid, layer["wkv_a"])
+    latent = jnp.concatenate(
+        [_norm(kv[..., :r], layer["kv_norm"], cfg, dtype),
+         rope(kv[..., r:], angles).astype(dtype)], axis=-1)
+    return q_n, rope(q_r, angles[:, :, None, :]).astype(hid.dtype), latent
+
+
+def _wkv_b(layer: Params, cfg: DecoderConfig) -> jax.Array:
+    """``[r, H, dn + dv]``."""
+    return layer["wkv_b"].reshape(cfg.kv_lora_rank, cfg.n_heads, -1)
+
+
+@scope("latent_expand")
+def expand(latent: jax.Array, layer: Params, cfg: DecoderConfig
+           ) -> tuple[jax.Array, jax.Array]:
+    """Latent rows as the cache holds them, ``[n, R, T]`` → every
+    head's keys ``[n, T, H, dn + dr]`` (the rotary part is the one
+    shared key, repeated) and values ``[n, T, H, dv]``."""
+    r, dn = cfg.kv_lora_rank, cfg.qk_nope_head_dim
+    dt = layer["wkv_b"].dtype
+    kv = jnp.einsum("nrt,rhd->nthd", latent[:, :r].astype(dt),
+                    _wkv_b(layer, cfg))
+    k_r = jnp.broadcast_to(
+        latent[:, r:].astype(dt).transpose(0, 2, 1)[:, :, None, :],
+        (*kv.shape[:3], cfg.qk_rope_head_dim))
+    return jnp.concatenate([kv[..., :dn], k_r], axis=-1), kv[..., dn:]
+
+
+def piece_attention(q: jax.Array, cache_a: jax.Array, li: jax.Array,
+                    slots: jax.Array, q_pos: jax.Array, kv_len: jax.Array,
+                    n_blocks: jax.Array, layer: Params, cfg: DecoderConfig
+                    ) -> jax.Array:
+    """Expanded attention of a piece's queries ``[n, S, H, dn + dr]``
+    (scaled) over their rows' cached latents, layer ``li`` of
+    ``cache_a`` ``[La, slots, R, T]`` (the piece's own rows already
+    written): query i of row r stands at ``q_pos[r, i]`` and sees the
+    columns up to its own, below ``kv_len[r]``. ``n_blocks`` (traced)
+    blocks of ``KV_BLOCK`` columns hold something some row sees; each
+    is read, expanded and folded into a running softmax, the rest is
+    never touched: one program whatever the lengths. → ``[n, S, H dv]``
+    in q's type."""
+    n, s, h, _ = q.shape
+    width, t = cache_a.shape[2:]
+    blk = min(KV_BLOCK, t)
+    dv = cfg.v_head_dim
+
+    def fold(j, carry):
+        acc, m, l = carry
+        with scope("kv_prefix"):     # ... and the expansion there
+            rows = jnp.stack([
+                jax.lax.dynamic_slice(cache_a, (li, slots[r], 0, j * blk),
+                                      (1, 1, width, blk))[0, 0]
+                for r in range(n)])
+            k, v = expand(rows, layer, cfg)
+        with scope("attn"):
+            sc = jnp.einsum("nshd,nthd->nhst", q, k.astype(q.dtype),
+                            preferred_element_type=jnp.float32)
+            col = j * blk + jnp.arange(blk)
+            seen = (col[None, None, :] <= q_pos[:, :, None]) \
+                & (col[None, None, :] < kv_len[:, None, None])
+            sc = jnp.where(seen[:, None], sc, -jnp.inf)
+            m_new = jnp.maximum(m, jnp.max(sc, axis=-1, keepdims=True))
+            m_safe = jnp.where(jnp.isfinite(m_new), m_new, 0.0)
+            corr = jnp.where(jnp.isfinite(m), jnp.exp(m - m_safe), 0.0)
+            p = jnp.exp(sc - m_safe)
+            l = l * corr + jnp.sum(p, axis=-1, keepdims=True)
+            acc = acc * corr + jnp.einsum(
+                "nhst,nthd->nhsd", p.astype(q.dtype), v.astype(q.dtype),
+                preferred_element_type=jnp.float32)
+        return acc, m_new, l
+
+    acc, _, l = jax.lax.fori_loop(
+        0, n_blocks, fold,
+        (jnp.zeros((n, h, s, dv), jnp.float32),
+         jnp.full((n, h, s, 1), -jnp.inf, jnp.float32),
+         jnp.zeros((n, h, s, 1), jnp.float32)))
+    with scope("attn"):
+        o = acc / jnp.where(l > 0, l, 1.0)
+        return o.transpose(0, 2, 1, 3).reshape(n, s, h * dv).astype(q.dtype)
+
+
+def absorbed_attention(q_n: jax.Array, q_r: jax.Array, cur: jax.Array,
+                       cache_l: jax.Array, win_l: jax.Array,
+                       pos0: jax.Array, w: jax.Array, layer: Params,
+                       cfg: DecoderConfig) -> jax.Array:
+    """One token's attention in absorbed form. ``q_n [B, H, dn]``,
+    rotated ``q_r [B, H, dr]``; the token's own latent row ``cur [B,
+    R]``; ``cache_l [B, R, T]`` one layer of the cache, live below
+    ``pos0``; ``win_l [B, W, R]`` the dispatch's own rows, live below
+    step ``w``. The key half of ``wkv_b`` goes into the query, all
+    heads score the shared rows under the dense decoder's joint softmax
+    as the groups of ONE kv head, the value half comes after:
+    → ``[B, H dv]``."""
+    r, dn = cfg.kv_lora_rank, cfg.qk_nope_head_dim
+    dt = q_n.dtype
+    wkv = _wkv_b(layer, cfg)
+    with scope("qkv"):
+        q_c = jnp.einsum("bhd,rhd->bhr", q_n, wkv[..., :dn].astype(dt),
+                         preferred_element_type=jnp.float32)
+        # the shared softmax scales by its own head width
+        fix = softmax_scale(cfg) * latent_width(cfg) ** 0.5
+        q_abs = (jnp.concatenate([q_c, q_r.astype(jnp.float32)], axis=-1)
+                 * fix).astype(dt)
+    one = lambda a: a[:, None]  # noqa: E731
+    rows = one(cache_l.transpose(0, 2, 1))   # a view: the dots take it
+    o = decode_attention_prefix_window(
+        q_abs, rows, rows, one(win_l), one(win_l), one(cur), one(cur),
+        pos0, w)
+    with scope("attn_out"):
+        return jnp.einsum("bhr,rhd->bhd", o[..., :r],
+                          wkv[..., dn:].astype(dt)).reshape(
+            o.shape[0], -1)
+
+
+# ---------------------------------------------------------------------------
+# Sparse experts, dropless
+# ---------------------------------------------------------------------------
+
+
+@scope("moe_route")
+def route(hid: jax.Array, layer: Params, cfg: DecoderConfig
+          ) -> tuple[jax.Array, jax.Array]:
+    """hid ``[T, d]`` float32, the sublayer's input BEFORE it is
+    rounded to the activation type → (chosen experts ``[T, k]``, their
+    gates ``[T, k]`` float32). Scores, the choice and the gates in
+    float32 at the highest matmul precision: a near-tie between the
+    last expert in and the first out flips as rarely as the arithmetic
+    allows."""
+    scores = jax.nn.sigmoid(jnp.matmul(
+        hid, layer["router"], precision=jax.lax.Precision.HIGHEST))
+    _, idx = jax.lax.top_k(scores + layer["e_bias"], cfg.experts_per_token)
+    chosen = jnp.take_along_axis(scores, idx, axis=-1)
+    gates = cfg.routed_scaling_factor * chosen \
+        / jnp.sum(chosen, axis=-1, keepdims=True)
+    return idx, gates
+
+
+@scope("moe_route")
+def group_by_expert(idx: jax.Array, live: jax.Array, n_experts: int,
+                    first: int, count: int):
+    """Sort the token-expert pairs ``idx [T, k]`` of live tokens by
+    expert, experts ``[first, first + count)`` first and in order;
+    pairs of other experts and of tokens that are not ``live`` go to
+    the end and belong to no group. → (token of each sorted pair
+    ``[T k]``, its group or ``count``, where each pair went, group
+    sizes ``[count]``, counts ``[N_COUNTS]`` over ALL experts)."""
+    t, k = idx.shape
+    flat = idx.reshape(-1)
+    alive = jnp.repeat(live, k)
+    mine = alive & (flat >= first) & (flat < first + count)
+    group = jnp.where(mine, flat - first, count)
+    order = jnp.argsort(group, stable=True)
+    sizes = jnp.zeros((count + 1,), jnp.int32).at[group].add(1)[:count]
+    per = jnp.zeros((n_experts + 1,), jnp.int32).at[
+        jnp.where(alive, flat, n_experts)].add(1)[:n_experts]
+    counts = jnp.stack([jnp.sum(per > 0), jnp.sum(per), jnp.max(per)])
+    return order // k, group[order], jnp.argsort(order), sizes, \
+        counts.astype(jnp.int32)
+
+
+def _grouped(x: jax.Array, w, li: jax.Array, sizes: jax.Array
+             ) -> jax.Array:
+    """Float32 ``x[rows of g] @ w[li, g]``: x ``[m, k]`` sorted by
+    group, w ``[L, G, k, n]``. int8 with scales ``[L, G, 1, n]`` (what
+    is served) goes through ``ops/grouped_matmul.py`` on every backend,
+    interpreted off a TPU; plain matrices (an engine without
+    ``quantize``) through ``jax.lax.ragged_dot``, which the tests hold
+    the kernel to."""
+    if quant_kind(w) == "int8":
+        return grouped_qmatmul(x, w["q"], w["scale"], sizes, li)
+    return jax.lax.ragged_dot(
+        x, jax.lax.dynamic_index_in_dim(w, li, 0, keepdims=False
+                                        ).astype(x.dtype),
+        sizes, preferred_element_type=jnp.float32)
+
+
+def routed_experts(hid: jax.Array, layer: Params, experts: Params,
+                   li: jax.Array, cfg: DecoderConfig, live: jax.Array,
+                   held: tuple[int, int] | None = None, dtype=None
+                   ) -> tuple[jax.Array, jax.Array]:
+    """The routed experts' part of the layer for tokens hid ``[T, d]``
+    float32 (routed as they are, multiplied in ``dtype``, default
+    bfloat16): float32 ``[T, d]`` and the routing's counts. ``layer`` has the
+    router; ``experts`` the stacks of ``EXPERTS`` for all layers, of
+    which layer ``li`` is read. ``held = (first, count)``: the experts
+    this device holds (default all; ``experts`` then holds those
+    alone); the choice is made over all experts and only the held
+    ones' terms are added. No token is dropped: every live pair of a
+    held expert is computed, each from its own row alone."""
+    first, count = held or (0, cfg.n_routed_experts)
+    idx, gates = route(hid, layer, cfg)
+    tok, group, back, sizes, counts = group_by_expert(
+        idx, live, cfg.n_routed_experts, first, count)
+    with scope("moe_experts"):
+        hid = hid.astype(dtype or jnp.bfloat16)
+        xs = hid[tok]
+        act = jax.nn.silu(
+            _grouped(xs, experts["we_gate"], li, sizes)) \
+            * _grouped(xs, experts["we_up"], li, sizes)
+        y = _grouped(act.astype(hid.dtype), experts["we_down"], li, sizes)
+        # a pair of no group has no product: what stands there is not
+        # a number the kernel wrote
+        y = jnp.where((group < count)[:, None], y, 0.0)[back]
+        y = y.reshape(*gates.shape, -1) * gates[..., None]
+        return jnp.sum(y, axis=1), counts
+
+
+def ffn(hid: jax.Array, layer: Params, experts: Params, li: jax.Array,
+        cfg: DecoderConfig, live: jax.Array, dtype
+        ) -> tuple[jax.Array, jax.Array]:
+    """The feed-forward sublayer: its input ``[n, S, d]`` float32 →
+    float32 ``[n, S, d]`` and the routing's counts: a SwiGLU (the dense
+    layers), or the routed experts plus the shared expert's SwiGLU,
+    multiplied in ``dtype``; the router reads the input unrounded."""
+    y = L.swiglu(hid.astype(dtype), layer).astype(jnp.float32)
+    if "router" not in layer:
+        return y, jnp.zeros((N_COUNTS,), jnp.int32)
+    n, s, d = hid.shape
+    # (under ``ffn`` as well: a reduction that knows ``SCOPES`` alone
+    # files the experts there, one that knows ``XING_SCOPES`` apart)
+    with scope("ffn"):
+        routed, counts = routed_experts(
+            hid.reshape(n * s, d), layer, experts, li, cfg,
+            live.reshape(n * s), dtype=dtype)
+    return y + routed.reshape(n, s, d), counts
+
+
+def _split(stack: Params) -> tuple[Params, Params]:
+    """A stack's leaves that a layer scan slices, and the expert
+    stacks it closes over."""
+    return ({k: v for k, v in stack.items() if k not in EXPERTS},
+            {k: v for k, v in stack.items() if k in EXPERTS})
+
+
+# ---------------------------------------------------------------------------
+# Embedding and head
+# ---------------------------------------------------------------------------
+
+
+def embed(params: Params, tokens: jax.Array, cfg: DecoderConfig
+          ) -> jax.Array:
+    """The streams at the input: the embedding, ``hc_mult`` times."""
+    with scope("embed"):
+        x = params["tok_emb"][tokens].astype(jnp.float32)
+        return jnp.broadcast_to(x, (cfg.hc_mult, *x.shape))
+
+
+@scope("unembed")
+def unembed(x: jax.Array, params: Params, cfg: DecoderConfig) -> jax.Array:
+    """Streams ``[n, ..., d]`` → float32 logits ``[..., V]``: the
+    streams summed, normed, and the head with float32 accumulation."""
+    w = params["lm_head"]
+    xn = _norm(jnp.sum(x, axis=0), params["final_norm"], cfg,
+               params["tok_emb"].dtype)
+    if quant_kind(w) == "int8":
+        return jnp.matmul(xn, w["q"].astype(xn.dtype),
+                          preferred_element_type=jnp.float32) * w["scale"]
+    return jnp.matmul(xn, w, preferred_element_type=jnp.float32)
+
+
+# ---------------------------------------------------------------------------
+# Admission: one piece of a prompt per row
+# ---------------------------------------------------------------------------
+
+
+def prefill_piece(params: Params, tokens: jax.Array, lens: jax.Array,
+                  pos0: jax.Array, slots: jax.Array, cfg: DecoderConfig,
+                  cache: Params) -> tuple[jax.Array, Params, jax.Array]:
+    """One piece of a prompt for each of n rows, into the rows' slots.
+
+    tokens ``[n, S]`` right-padded, ``lens[r]`` real; row r's piece
+    starts at absolute position ``pos0[r]`` and ``pos0[r] + S`` lies
+    within the cache (the engine cuts pieces at multiples of its
+    largest bucket, which divides ``max_len``). The piece's latent rows
+    go into the slot at their positions, then its queries attend in
+    expanded form to everything the slot holds up to themselves
+    (``piece_attention``). Columns past ``lens[r]`` take rows nobody
+    reads before they are written again; their tokens are not routed.
+    The cache rides the layer scans' carry and is touched a row at a
+    time. Rows may repeat (the engine pads a wave with copies of its
+    first row: the same writes twice). Returns (logits after each row's
+    last token ``[n, V]`` float32, cache, counts ``[N_COUNTS]``)."""
+    n, s = tokens.shape
+    dt = params["tok_emb"].dtype
+    q_pos = pos0[:, None] + jnp.arange(s)[None, :]
+    kv_len = pos0 + lens
+    live = jnp.arange(s)[None, :] < lens[:, None]
+    angles = q_pos[..., None].astype(jnp.float32) * rope_inv_freq(cfg)
+    t = next(iter(cache.values())).shape[3]
+    blk = min(KV_BLOCK, t)
+    n_blocks = (jnp.max(kv_len) + blk - 1) // blk
+    scale = softmax_scale(cfg)
+    x = embed(params, tokens, cfg)
+    counts = jnp.zeros((N_COUNTS,), jnp.int32)
+    out = {}
+
+    def body(experts, carry, scanned):
+        x, cache_a, counts = carry
+        layer, li = scanned
+        pre, post, res = mhc_maps(x, layer, "attn", cfg)
+        hid = _norm(mhc_read(x, pre), layer["attn_norm"], cfg, dt)
+        q_n, q_r, latent = project(hid, layer, cfg, angles, cache_a.dtype)
+        with scope("kv_write"):
+            for r in range(n):
+                cache_a = jax.lax.dynamic_update_slice(
+                    cache_a, latent[r].T[None, None],
+                    (li, slots[r], 0, pos0[r]))
+        with scope("qkv"):
+            q = (jnp.concatenate([q_n, q_r], axis=-1).astype(jnp.float32)
+                 * scale).astype(dt)
+        o = piece_attention(q, cache_a, li, slots, q_pos, kv_len,
+                            n_blocks, layer, cfg)
+        x = mhc_write(x, L.attn_out(o, layer), post, res)
+        pre, post, res = mhc_maps(x, layer, "ffn", cfg)
+        y, c = ffn(_norm(mhc_read(x, pre), layer["ffn_norm"], cfg,
+                         jnp.float32), layer, experts, li, cfg, live, dt)
+        return (mhc_write(x, y, post, res), cache_a, counts + c), None
+
+    for name, count in stacks(cfg).items():
+        layers, experts = _split(params[name])
+        (x, out[name], counts), _ = jax.lax.scan(
+            functools.partial(body, experts), (x, cache[name], counts),
+            (layers, jnp.arange(count)))
+    x_last = jnp.take_along_axis(
+        x, jnp.broadcast_to((lens - 1)[None, :, None, None],
+                            (cfg.hc_mult, n, 1, x.shape[-1])), axis=2)
+    return unembed(x_last[:, :, 0], params, cfg), out, counts
+
+
+# ---------------------------------------------------------------------------
+# Decode: a dispatch of ``steps`` tokens for every slot
+# ---------------------------------------------------------------------------
+
+
+def decode_step(params: Params, tok: jax.Array, pos0: jax.Array,
+                w: jax.Array, cfg: DecoderConfig, cache: Params,
+                win: Params, max_len: int
+                ) -> tuple[jax.Array, Params, jax.Array]:
+    """Step ``w`` (traced) of a dispatch that began at positions
+    ``pos0``: one token per slot against a read-only cache and the
+    dispatch's own rows ``win`` (a ``[La, B, W, R]`` per stack, live
+    below ``w``). A slot that is not decoding stands at ``max_len``:
+    its token is computed and not routed. Returns (logits ``[B, V]``
+    float32, this step's latent rows ``[La, B, R]`` per stack, counts)."""
+    dt = params["tok_emb"].dtype
+    live = (pos0 < max_len)[:, None]
+    angles = (pos0 + w)[:, None, None].astype(jnp.float32) \
+        * rope_inv_freq(cfg)
+    x = embed(params, tok[:, None], cfg)
+    counts = jnp.zeros((N_COUNTS,), jnp.int32)
+    cols = {}
+
+    def body(experts, carry, scanned):
+        x, counts = carry
+        layer, li, cache_l, win_l = scanned
+        pre, post, res = mhc_maps(x, layer, "attn", cfg)
+        hid = _norm(mhc_read(x, pre), layer["attn_norm"], cfg, dt)
+        q_n, q_r, latent = project(hid, layer, cfg, angles, win_l.dtype)
+        o = absorbed_attention(q_n[:, 0], q_r[:, 0], latent[:, 0],
+                               cache_l, win_l, pos0, w, layer, cfg)
+        x = mhc_write(x, L.attn_out(o[:, None], layer), post, res)
+        pre, post, res = mhc_maps(x, layer, "ffn", cfg)
+        y, c = ffn(_norm(mhc_read(x, pre), layer["ffn_norm"], cfg,
+                         jnp.float32), layer, experts, li, cfg, live, dt)
+        return (mhc_write(x, y, post, res), counts + c), latent[:, 0]
+
+    for name, count in stacks(cfg).items():
+        layers, experts = _split(params[name])
+        (x, counts), cols[name] = jax.lax.scan(
+            functools.partial(body, experts), (x, counts),
+            (layers, jnp.arange(count), cache[name], win[name]))
+    return unembed(x[:, :, 0], params, cfg), cols, counts
+
+
+@scope("kv_write")
+def merge_latents(cache_a: jax.Array, win_a: jax.Array, pos0: jax.Array,
+                  steps: int) -> jax.Array:
+    """A dispatch's rows ``win_a [La, B, W, R]`` into the cache
+    ``[La, B, R, T]``, once, in place: slot b's first ``steps`` rows
+    land at the columns ``pos0[b] + [0, steps)``; one at or past the
+    extent is dropped (a slot that is not decoding stands there). One
+    slab a slot, by ``decoder.merge_window``'s scatter, for its
+    reasons: the only index is the slab's first column, the batch axis
+    is the cache's own, and a slab that would run past the extent is
+    laid over the last columns with what the cache holds there."""
+    s_max = cache_a.shape[3]
+    w = min(win_a.shape[2], steps, s_max)
+    start = jnp.clip(pos0, 0, s_max - w)
+    shift = pos0 - start
+    fresh = (jnp.arange(w)[None, :] >= shift[:, None])[None, :, None, :]
+    roll = jax.vmap(lambda win_b, n: jnp.roll(win_b, n, axis=1),
+                    in_axes=(1, 0), out_axes=1)
+    new = roll(win_a[:, :, :w], shift).astype(cache_a.dtype)
+    slab = jnp.where(fresh, new.transpose(0, 1, 3, 2),
+                     cache_a[:, :, :, s_max - w:])
+    return jax.lax.scatter(
+        cache_a, start[:, None], slab.transpose(1, 0, 2, 3),
+        jax.lax.ScatterDimensionNumbers(
+            update_window_dims=(1, 2, 3), inserted_window_dims=(),
+            scatter_dims_to_operand_dims=(3,), operand_batching_dims=(1,),
+            scatter_indices_batching_dims=(0,)),
+        unique_indices=True,
+        mode=jax.lax.GatherScatterMode.PROMISE_IN_BOUNDS)
+
+
+def decode_tokens(params: Params, tokens: jax.Array, pos0: jax.Array,
+                  cfg: DecoderConfig, cache: Params, key: jax.Array,
+                  sample_fn, *, steps: int, max_len: int,
+                  with_logits: bool = False):
+    """``steps`` tokens for every slot in one program: decode → sample
+    → feed back, the cache read-only until one merge at the end (the
+    discipline of the engine's ``_decode``: a cache in the token loop's
+    carry is copied every token). Returns (tokens ``[steps, B]``,
+    cache, counts ``[N_COUNTS]`` summed over layers and steps) and,
+    ``with_logits``, every step's logits ``[steps, B, V]``."""
+    b = tokens.shape[0]
+    win = {name: jnp.zeros((a.shape[0], b, steps, a.shape[2]), a.dtype)
+           for name, a in cache.items()}
+
+    def body(carry, w):
+        tok, win, counts, key = carry
+        key, sub = jax.random.split(key)
+        logits, cols, c = decode_step(params, tok, pos0, w, cfg, cache,
+                                      win, max_len)
+        with scope("kv_write"):
+            win = {name: jax.lax.dynamic_update_slice_in_dim(
+                a, cols[name][:, :, None].astype(a.dtype), w, axis=2)
+                for name, a in win.items()}
+        nxt = sample_fn(logits, sub)
+        return (nxt, win, counts + c, key), \
+            (nxt, logits if with_logits else None)
+
+    (_, win, counts, _), (toks, logits) = jax.lax.scan(
+        body, (tokens, win, jnp.zeros((N_COUNTS,), jnp.int32), key),
+        jnp.arange(steps))
+    cache = {name: merge_latents(a, win[name], pos0, steps)
+             for name, a in cache.items()}
+    if with_logits:
+        return toks, cache, counts, logits
+    return toks, cache, counts

@@ -18,7 +18,8 @@ name go into it, each declared here and nowhere else:
   a host-side ``StepRecord`` name the SAME step. Read by
   ``benchmark/harness/trace_reduce.py`` (device time per step, idle
   gaps inside a dispatch).
-* **Device scopes** — ``SCOPES``, ``EVA_SCOPES`` / ``scope(name)``:
+* **Device scopes** — ``SCOPES``, ``EVA_SCOPES``, ``XING_SCOPES`` /
+  ``scope(name)``:
   ``jax.named_scope``
   around the code that does each thing inside the jitted programs. The
   name lands in every HLO instruction's ``op_name`` metadata
@@ -73,6 +74,17 @@ EVA_SCOPES = (
     #                into its chunk summaries
 )
 
+#: scopes that only the attention="mla" programs hold (models/xing.py),
+#: in a tuple of their own for the same reason
+XING_SCOPES = (
+    "moe_route",      # router scores, top-k, grouping tokens by expert
+    "moe_experts",    # the grouped matmuls over the experts held
+    "mhc",            # the residual streams' maps, their Sinkhorn
+    #                   projection and the two mixes
+    "latent_expand",  # prefill: every head's keys and values from the
+    #                   cached latents of earlier pieces
+)
+
 #: host phases of the serving loop (with the dispatch kinds ``decode``
 #: and ``prefill`` and ``_no_annotation_`` they fit the trace
 #: reducer's ten gap owners)
@@ -121,13 +133,14 @@ def step_annotation(name: str, step_num: int | None = None):
 
 
 def scope(name: str):
-    """``jax.named_scope`` for one of ``SCOPES`` or ``EVA_SCOPES``
-    (trace time only)."""
+    """``jax.named_scope`` for one of ``SCOPES``, ``EVA_SCOPES`` or
+    ``XING_SCOPES`` (trace time only)."""
     import jax
 
-    if name not in SCOPES + EVA_SCOPES:
+    if name not in SCOPES + EVA_SCOPES + XING_SCOPES:
         raise ValueError(f"unknown device scope {name!r}; obs/profile.py "
-                         f"SCOPES has {SCOPES}, EVA_SCOPES {EVA_SCOPES}")
+                         f"SCOPES has {SCOPES}, EVA_SCOPES {EVA_SCOPES}, "
+                         f"XING_SCOPES {XING_SCOPES}")
     return jax.named_scope(name)
 
 

@@ -588,3 +588,185 @@ def test_an_engine_with_a_mesh_keeps_the_xla_route(kernel_route):
     assert sorted(k[1] for k in eng.programs_seen
                   if k[0] == "decode") == [128, 256]
     assert tokens == PINNED
+
+
+# ---------------------------------------------------------------------------
+# the same for attention="mla" (models/xing.py): the latent cache is one
+# array per stack of layers, positions along the minor axis; admission
+# writes a piece's rows where they lie, decode merges its rows by the
+# scatter `merge_window` uses, and neither program turns the cache over
+# or cuts a layer out of it. On a TPU the experts' grouped matmuls are a
+# kernel (ops/grouped_matmul.py) that takes the stack of all layers'
+# experts whole, by pointer, with the layer's index: a layer's experts
+# are never cut out of the stack (a copy of every expert, chosen or
+# not, every step).
+# ---------------------------------------------------------------------------
+
+MLA_CFG = decoder_config(
+    "tiny-xing", d_model=512, d_ff=512, q_lora_rank=128, kv_lora_rank=128,
+    qk_nope_head_dim=32, qk_rope_head_dim=16, v_head_dim=32,
+    n_routed_experts=64, experts_per_token=4, moe_intermediate_size=512,
+    max_seq_len=8192)
+# the expert layers' rows: 2 x 8 x 144 x 8192 bf16 = 38 MB; one layer's
+# experts 16.8 MB a matrix: neither is small enough to be moved whole
+# into fast memory
+MLA_MAX = 8192
+
+
+def mla_program_faults(one_chip, which):
+    """What a compiled latent-attention program may not hold: an op
+    whose result is a stack of the cache, or one layer of it, other
+    than the in-place updates; on the kernel route, any number of
+    kernels but the three grouped matmuls of the one scanned expert
+    layer, or an op that makes a stack of experts or one layer's."""
+    eng = GenerationEngine(MLA_CFG, num_slots=SLOTS, max_len=MLA_MAX,
+                           prefill_buckets=(64,), dtype=jnp.bfloat16,
+                           eos_id=-1, quantize="int8")
+
+    def wrap(a):
+        return jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip)
+
+    params, cache = jax.tree.map(wrap, eng.params), \
+        jax.tree.map(wrap, eng._cache)
+    key = wrap(jax.random.PRNGKey(0))
+    i32 = wrap(jax.ShapeDtypeStruct((SLOTS,), jnp.int32))
+    if which == "decode":
+        text = eng._decode_mla_fn.lower(params, i32, i32, cache,
+                                        key).compile().as_text()
+    else:
+        two = wrap(jax.ShapeDtypeStruct((2,), jnp.int32))
+        text = eng._admit_mla_fn.lower(
+            params, wrap(jax.ShapeDtypeStruct((2, 64), jnp.int32)), two,
+            two, two, cache, key).compile().as_text()
+    stacks_ = {tuple(a.shape) for a in eng._cache.values()}
+    layers = {s[1:] for s in stacks_} | {(1,) + s[1:] for s in stacks_}
+    # one layer's experts (the stack whole may be laid out anew once a
+    # dispatch, outside the loops)
+    experts = {lead + tuple(eng.params["moe"][k]["q"].shape[1:])
+               for k in ("we_gate", "we_up", "we_down")
+               for lead in ((), (1,))}
+    # computations that only re-index (a dynamic-slice, a bitcast): as
+    # a fusion inside the dot that reads it such a one is the dot's
+    # operand, not a copy
+    views, comp = set(), None
+    for line in text.splitlines():
+        if line and not line[0].isspace() and line.rstrip().endswith("{"):
+            comp = line.split()[1 if line.startswith("ENTRY") else 0]
+            comp = comp.lstrip("%")
+            views.add(comp)
+        m = re.match(r"\s*(?:ROOT )?%?[\w.\-]+ = \S+ ([\w\-]+)\(", line)
+        if m and m.group(1) not in ("parameter", "dynamic-slice",
+                                    "bitcast", "constant"):
+            views.discard(comp)
+    faults = []
+    for line in text.splitlines():
+        m = re.match(r"\s*(?:ROOT )?%?[\w.\-]+ = \w+\[([\d,]+)\](\S*) "
+                     r"([\w\-]+)\(", line)
+        # (copy-/slice-start and -done: the compiler's own prefetch of
+        # something this small into fast memory)
+        if not m or m.group(3) in ("parameter", "get-tuple-element",
+                                   "bitcast", "copy-start", "copy-done",
+                                   "slice-start", "slice-done"):
+            continue
+        shape = tuple(int(x) for x in m.group(1).split(","))
+        # a layer's experts cut out of the stack, wherever to: every
+        # expert is read to make it
+        if shape in experts:
+            faults.append(f"{m.group(3)} makes experts {shape}")
+        # (S(1): a prefetch of a buffer this small into fast memory;
+        # the served cache is never moved there)
+        called = re.search(r"calls=%?([\w.\-]+)", line)
+        if "S(1)" in m.group(2) or m.group(3) == "dynamic-slice" or (
+                m.group(3) == "fusion" and called
+                and called.group(1) in views):
+            continue
+        if shape in stacks_ | layers \
+                and m.group(3) != "dynamic-update-slice":
+            faults.append(f"{m.group(3)} makes cache rows {shape}")
+    kernels = [ln for ln in text.splitlines()
+               if 'custom_call_target="tpu_custom_call"' in ln]
+    return faults, kernels, text
+
+
+@pytest.mark.parametrize("which", ["decode", "admit"])
+def test_compiled_mla_programs_hold_cache_and_experts_in_place(
+        one_chip, on_tpu, which):
+    faults, kernels, _text = mla_program_faults(one_chip, which)
+    assert faults == []
+    assert len(kernels) == 3 and all(
+        re.search(r'op_name="[^"]*/moe_experts/[^"]*grouped_qmatmul',
+                  k) for k in kernels)
+
+
+def test_mla_guard_trips_when_the_layer_scan_cuts_the_experts_out(
+        one_chip, on_tpu, monkeypatch):
+    """The formulation this replaced: the expert stacks among the
+    scanned leaves, a layer's handed to the kernel."""
+    from copilot_for_consensus_tpu.models import xing
+
+    def scanned_experts(stack):
+        return stack, {}
+
+    real = xing.routed_experts
+
+    def cut_out(hid, layer, experts, li, cfg, live, held=None, dtype=None):
+        mats = {k: jax.tree.map(lambda a: a[None], layer[k])
+                for k in xing.EXPERTS}
+        return real(hid, layer, mats, jnp.int32(0), cfg, live, held, dtype)
+
+    monkeypatch.setattr(xing, "_split", scanned_experts)
+    monkeypatch.setattr(xing, "routed_experts", cut_out)
+    faults, _kernels, _text = mla_program_faults(one_chip, "decode")
+    assert any("makes experts" in f for f in faults)
+
+
+# ---------------------------------------------------------------------------
+# and nothing of this architecture reaches the programs of the two
+# configurations the benchmark had: no op of theirs stands under one of
+# its scopes or calls its kernel (compiled at the tiny sizes, where the
+# scope is in every op's ``op_name``). (That their lowered text
+# equals the parent's, hash for hash, was checked once, when the
+# architecture came: PERF.md section 6, PR 33.)
+# ---------------------------------------------------------------------------
+
+OTHER_PROGRAMS = ("jit__decode", "jit__admit_fused", "jit__admit_eva",
+                  "jit__decode_eva[False]", "jit__decode_eva[True]")
+
+
+@pytest.fixture(scope="module")
+def lowered_programs():
+    key = jax.random.PRNGKey(0)
+    i32 = jnp.zeros((4,), jnp.int32)
+    rows = (jnp.zeros((2, 32), jnp.int32), jnp.ones((2,), jnp.int32))
+    two = jnp.zeros((2,), jnp.int32)
+    args = dict(num_slots=4, max_len=256, dtype=jnp.bfloat16,
+                attn_impl="xla", eos_id=-1, quantize="int8")
+    eng = GenerationEngine(decoder_config("tiny"), None,
+                           prefill_buckets=(32, 64), **args)
+    evb = GenerationEngine(decoder_config("tiny-eva"), None,
+                           prefill_buckets=(32,), **args)
+    texts = {
+        "jit__decode": eng._decode_fn.lower(
+            eng.params, i32, i32, eng._cache, key, kv_len=128,
+            n_windows=1),
+        "jit__admit_fused": eng._admit_fn.lower(
+            eng.params, *rows, eng._cache, two, key),
+        "jit__admit_eva": evb._admit_eva_fn.lower(
+            evb.params, *rows, two, two, evb._cache, key),
+    }
+    for may_close in (False, True):
+        texts[f"jit__decode_eva[{may_close}]"] = evb._decode_eva_fn.lower(
+            evb.params, i32, i32, evb._cache, key, may_close=may_close)
+    return {k: v.compile().as_text() for k, v in texts.items()}
+
+
+@pytest.mark.parametrize("program", OTHER_PROGRAMS)
+def test_the_other_configurations_programs_hold_nothing_of_this_one(
+        lowered_programs, program):
+    from copilot_for_consensus_tpu.obs.profile import SCOPES, XING_SCOPES
+
+    names = re.findall(r'op_name="([^"]*)"', lowered_programs[program])
+    under = {part for name in names for part in name.split("/")}
+    assert "attn" in under and "ffn" in under    # the scopes are there
+    assert not under & (set(XING_SCOPES) - set(SCOPES))
+    assert not any("grouped_qmatmul" in name for name in names)

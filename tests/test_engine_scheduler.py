@@ -539,3 +539,57 @@ def test_async_runner_propagates_shed_without_error_reports(
         runner.stop()
     assert rep.reports == []
     assert eng.telemetry.errors == 0
+
+
+@pytest.mark.parametrize("batch", [1, 3, 7])
+def test_a_batch_handed_over_whole_is_seen_whole(tiny_engine_parts, batch):
+    """``submit_many`` is ONE hand-over: the dispatcher's first step
+    after it finds every request of the batch in the engine (handed
+    over one by one it may find one, some or all), and each completes
+    as it does alone."""
+    from copilot_for_consensus_tpu.engine.async_runner import (
+        AsyncEngineRunner,
+    )
+
+    prompts = [[5 + i, 6, 7 + i, 8] for i in range(batch)]
+    alone = [c.tokens for c in _engine(tiny_engine_parts).generate(
+        prompts, max_new_tokens=5)]
+    eng = _engine(tiny_engine_parts)
+    seen, submit, step = [], eng.submit, eng.step
+    count = [0]
+
+    def counting_submit(*a, **kw):
+        count[0] += 1
+        return submit(*a, **kw)
+
+    def recording_step(*a, **kw):
+        seen.append(count[0])
+        return step(*a, **kw)
+
+    eng.submit, eng.step = counting_submit, recording_step
+    runner = AsyncEngineRunner(eng).start()
+    try:
+        handles = runner.submit_many(
+            [(p, 5, {"correlation_id": f"b{i}"})
+             for i, p in enumerate(prompts)])
+        assert [h.correlation_id for h in handles] \
+            == [f"b{i}" for i in range(batch)]
+        assert [h.result(timeout=120.0).tokens for h in handles] == alone
+    finally:
+        runner.stop()
+    assert seen[0] == batch
+
+
+def test_a_batch_is_refused_whole_after_stop(tiny_engine_parts):
+    from copilot_for_consensus_tpu.engine.async_runner import (
+        AsyncEngineRunner,
+    )
+
+    runner = AsyncEngineRunner(_engine(tiny_engine_parts))
+    with pytest.raises(RuntimeError, match="not started"):
+        runner.submit_many([([1, 2, 3], 4, {})])
+    runner.start()
+    runner.stop()
+    with pytest.raises(RuntimeError, match="stopped|not started"):
+        runner.submit_many([([1, 2, 3], 4, {}), ([4, 5], 4, {})])
+    assert runner._pending == []

@@ -12,7 +12,9 @@ weights made from a seed:
   ``decode_attention`` over an fp8 pool (decode rows ``r = group`` and
   seeded rows ``r = group * S``), the EVA decode kernel over a slot's
   live blocks (at EvaByte's widths) against ``joint_attention`` over
-  whole pieces, and ``int4_matmul``. Then one short
+  whole pieces, the dense and the latent decode kernels over live
+  blocks (at the served cells' shapes, timed at each block width
+  tried), and ``int4_matmul``. Then one short
   paged ``GenerationEngine(kv_kernel="auto")`` run, depth cut to
   ``KERNEL_PHASE_LAYERS``, whose route must resolve to ``"kernel"``.
 * **serve** — ``python -m copilot_for_consensus_tpu serve`` with the
@@ -358,21 +360,25 @@ def phase_kernels(rehearse: bool) -> int:
             else jnp.zeros(qg.shape, jnp.float32)
         return jax.lax.scan(token, acc0, None, length=tokens)[0]
 
-    def timed(fn) -> float:
+    def timed(fn, *args) -> float:
         """Milliseconds a token (a call a layer) of a dispatch of
         ``w_sz`` tokens, best of 5."""
-        jax.block_until_ready(fn(dense, qd8))
+        jax.block_until_ready(fn(*args))
         best = float("inf")
         for _ in range(1 if rehearse else 5):
             t = time.perf_counter()
-            jax.block_until_ready(fn(dense, qd8))
+            jax.block_until_ready(fn(*args))
             best = min(best, time.perf_counter() - t)
         return best * 1e3 / w_sz
 
-    def rate(columns: int, ms: float) -> dict:
-        col_bytes = 2 * hkv * d * dense["k"].dtype.itemsize   # both halves
+    def rate(columns: int, ms: float, col_bytes: int, layers: int) -> dict:
         return {"columns": columns, "ms_per_token": round(ms, 4),
-                "gb_per_s": round(columns * col_bytes * n_dl / ms / 1e6, 1)}
+                "gb_per_s": round(columns * col_bytes * layers / ms / 1e6,
+                                  1)}
+
+    dense_rate = functools.partial(            # a column of both halves
+        rate, col_bytes=2 * hkv * d * dense["k"].dtype.itemsize,
+        layers=n_dl)
 
     dense_rates: dict = {"served_block": dense_attention.BLOCK}
     want = {}
@@ -381,8 +387,8 @@ def phase_kernels(rehearse: bool) -> int:
             dense, qd8)
         dense_rates[mix] = {
             "live_columns": sum(lens),
-            "xla_prefix": rate(slots * bucket(lens), timed(jax.jit(
-                functools.partial(xla_tokens, lens, w_sz))))}
+            "xla_prefix": dense_rate(slots * bucket(lens), timed(jax.jit(
+                functools.partial(xla_tokens, lens, w_sz)), dense, qd8))}
     try:
         for width in (128, 256, 512):
             dense_attention.BLOCK = width
@@ -391,15 +397,106 @@ def phase_kernels(rehearse: bool) -> int:
                                                 1))(dense, qd8)
                 compare(f"dense_decode_attention/{mix}/block={width}",
                         got[:len(lens)], want[mix][:len(lens)])
-                dense_rates[mix][f"block_{width}"] = rate(
+                dense_rates[mix][f"block_{width}"] = dense_rate(
                     sum(dense_attention.blocks_read(0, n, ext)
                         for n in lens),
                     timed(jax.jit(functools.partial(kernel_tokens, lens,
-                                                    False, w_sz))))
+                                                    False, w_sz)),
+                          dense, qd8))
     finally:
         dense_attention.BLOCK = dense_rates["served_block"]
     say(f"dense decode attention, {w_sz} tokens of {n_dl} layer calls a "
         f"dispatch: {dense_rates}")
+
+    # -- absorbed latent attention over live blocks (attention="mla" on
+    # a TPU, ops/latent_attention.py), at the served cell's shapes: 8
+    # slots x 16,384 columns of 576 (the first 512 the value), 32 heads,
+    # 12 layers; the cell's mix of lengths, a free slot and a full one
+    # among them. The kernel's partial folded with the dispatch's own
+    # rows against the XLA route that scores every slot's whole extent;
+    # then a dispatch's 8 tokens of 12 layer calls under one jit, timed:
+    # the XLA route whole, and the kernel ALONE at each block width
+    # tried (the served one is ``latent_attention.BLOCK``) ---------------
+    from copilot_for_consensus_tpu.ops import latent_attention
+
+    n_ll, lh, lr, lrope, lext = (2, 4, 32, 8, 2048) if rehearse \
+        else (12, 32, 512, 64, 16384)
+    lwidth = lr + lrope
+    lens_l = [16375, 15600, 15900, 2500, 4300, 7700, 13100]
+    if rehearse:
+        lens_l = [n // 8 for n in lens_l]
+    pos_l = jnp.asarray(lens_l + [lext] * (slots - len(lens_l)), jnp.int32)
+    latents = jax.random.normal(jax.random.PRNGKey(7),
+                                (n_ll, slots, lwidth, lext), dtype)
+    ql, curl = normal(slots, lh, lwidth), normal(slots, lwidth)
+    winl = normal(slots, w_sz, lwidth)
+    one = lambda a: a[:, None]  # noqa: E731
+
+    def xla_latent(tokens, latents, q):
+        def layer(acc, cache_l):
+            rows = one(cache_l.transpose(0, 2, 1))
+            return acc + decode_attention_prefix_window(
+                q, rows, rows, one(winl), one(winl), one(curl), one(curl),
+                pos_l, w_at)[..., :lr], None
+
+        def token(acc, _):
+            return jax.lax.scan(layer, acc, latents)[0], None
+
+        return jax.lax.scan(token, jnp.zeros((slots, lh, lr), q.dtype),
+                            None, length=tokens)[0]
+
+    def kernel_latent(folded, tokens, latents, q):
+        plan = latent_attention.plan_blocks(pos_l, extent=lext)
+        local = decode_window_partial(
+            one(q), one(winl), one(winl[..., :lr]), one(curl),
+            one(curl[..., :lr]), pos_l, w_at)
+
+        def layer(acc, li):
+            part = latent_attention.live_partial(
+                q, latents, li, plan, rank=lr, interpret=interpret)
+            if not folded:
+                return acc + part[0], None
+            return acc + combine_partials(
+                [tuple(one(a) for a in part), local], q.dtype)[:, 0], None
+
+        def token(acc, _):
+            return jax.lax.scan(layer, acc, jnp.arange(n_ll))[0], None
+
+        return jax.lax.scan(
+            token, jnp.zeros((slots, lh, lr),
+                             q.dtype if folded else jnp.float32),
+            None, length=tokens)[0]
+
+    def timed_latent(fn) -> float:
+        return timed(jax.jit(fn), latents, ql)
+
+    rate_latent = functools.partial(
+        rate, col_bytes=lwidth * latents.dtype.itemsize, layers=n_ll)
+
+    want_l = jax.jit(functools.partial(xla_latent, 1))(latents, ql)
+    latent_rates: dict = {
+        "served_block": latent_attention.BLOCK,
+        "live_columns": sum(lens_l),
+        "xla_whole_extent": rate_latent(slots * lext, timed_latent(
+            functools.partial(xla_latent, w_sz)))}
+    try:
+        for width in (256, 512, 1024):
+            latent_attention.BLOCK = width
+            got = jax.jit(functools.partial(kernel_latent, True, 1))(
+                latents, ql)
+            compare(f"mla_decode_attention/block={width}",
+                    got[:len(lens_l)], want_l[:len(lens_l)])
+            latent_rates[f"block_{width}"] = rate_latent(
+                sum(latent_attention.blocks_read(n, lext) for n in lens_l),
+                timed_latent(functools.partial(kernel_latent, False,
+                                               w_sz)))
+            latent_rates[f"block_{width}"]["folded_ms_per_token"] = round(
+                timed_latent(functools.partial(kernel_latent, True, w_sz)),
+                4)
+    finally:
+        latent_attention.BLOCK = latent_rates["served_block"]
+    say(f"latent decode attention, {w_sz} tokens of {n_ll} layer calls a "
+        f"dispatch: {latent_rates}")
 
     # -- int4 matmul (what quantize="int4" routes to) ------------------
     for name, (din, dout) in (("up", (cfg.d_model, cfg.d_ff)),
@@ -449,6 +546,7 @@ def phase_kernels(rehearse: bool) -> int:
              "kv_dtype": "float8_e4m3fn",
              "zero_copy_admits": stats["zero_copy_admits"]},
          dense_decode_attention=dense_rates,
+         mla_decode_attention=latent_rates,
          seconds=round(time.monotonic() - t0, 1))
     return 0
 

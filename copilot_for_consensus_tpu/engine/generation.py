@@ -49,10 +49,11 @@ DOMINANT LATENCY" in SURVEY.md §3.2). Design:
   multi-stream residual. Admission goes piece by piece through the
   same ``_admit_pieces`` (each piece attends in expanded form to the
   slot's latents, expanded again), decode scores the latents in
-  absorbed form, one program whatever the lengths; the routing's
-  counts come back with the tokens. What assumes a key and a value of
-  ``[Hkv, Dh]`` per position, or one weight pass a step, refuses it
-  at construction (``_check_mla``).
+  absorbed form, one program whatever the lengths (on a TPU each
+  slot's live blocks of them, in place: ``_reads_latent_blocks``);
+  the routing's counts come back with the tokens. What assumes a key
+  and a value of ``[Hkv, Dh]`` per position, or one weight pass a
+  step, refuses it at construction (``_check_mla``).
 
 The engine is synchronous and single-owner: services drive it through
 ``submit()`` + ``step()`` (or ``generate()`` for batch use) from their
@@ -98,7 +99,7 @@ from copilot_for_consensus_tpu.engine.tokenizer import (
 from copilot_for_consensus_tpu.obs.profile import scope, step_annotation
 from copilot_for_consensus_tpu.models import decoder, eva, quant, xing
 from copilot_for_consensus_tpu.models.configs import DecoderConfig
-from copilot_for_consensus_tpu.ops import dense_attention
+from copilot_for_consensus_tpu.ops import dense_attention, latent_attention
 from copilot_for_consensus_tpu.ops.eva_attention import blocks_read
 from copilot_for_consensus_tpu.parallel.sharding import (
     DEFAULT_RULES,
@@ -1068,7 +1069,8 @@ class GenerationEngine:
             return xing.decode_tokens(
                 params, tokens, positions, cfg, cache, key,
                 lambda logits, sub: sample(logits, sub, self.sampling),
-                steps=self.decode_window, max_len=self.max_len)
+                steps=self.decode_window, max_len=self.max_len,
+                live_blocks=self._reads_latent_blocks())
 
         if self._mla:
             self._admit_mla_fn = jax.jit(_admit_mla, donate_argnums=(5,))
@@ -2626,6 +2628,29 @@ class GenerationEngine:
             for lo, hi in zip(*dense_attention.live_range(
                 pos0, pos0 + t, self.cfg.sliding_window, self.max_len)))
 
+    def _reads_latent_blocks(self) -> bool:
+        """Does the latent decode dispatch (``_decode_mla``) read each
+        slot's live blocks of the latent cache in place
+        (``ops/latent_attention.py``)? As ``_reads_live_blocks``: on a
+        TPU (the latent cache is always on one device: ``_check_mla``
+        refuses a mesh), read when the program is traced and before
+        every dispatch; elsewhere every slot's whole extent is scored
+        in XLA, which is what the tests hold the kernel to."""
+        return self._mla and latent_attention.serves(self.max_len)
+
+    def _latent_read(self, steps: int) -> int:
+        """Latent columns that a decode dispatch of ``steps`` tokens
+        reads, summed over its steps (the flight recorder's
+        ``state_tokens_read``): on the kernel's route what lies under
+        the blocks of the decoding slots, the same in every step (no
+        sliding window); on the XLA route every slot's whole extent."""
+        if not self._reads_latent_blocks():
+            return steps * self.num_slots * self.max_len
+        return steps * sum(
+            latent_attention.blocks_read(int(self._positions[s]),
+                                         self.max_len)
+            for s in self._active)
+
     def _kv_bucket(self) -> int:
         """Static attention extent for the next decode dispatch: the
         occupied cache prefix rounded up to 128, so only a handful of
@@ -3289,13 +3314,12 @@ class GenerationEngine:
             # decode programs in all
             kv_len = closing > 0
         elif self._mla:
-            # ONE program: the latents are scored whole, each slot's
-            # below its own length
+            # ONE program whatever the lengths: each slot's latents
+            # are scored below its own length
             kv_len = self.max_len
             extra = {"window_tokens": sum(int(self._positions[s])
                                           for s in self._active),
-                     "state_tokens_read":
-                         window * self.num_slots * self.max_len}
+                     "state_tokens_read": self._latent_read(window)}
         self._phase(None)
         with step_annotation("decode", seq), \
                 self._dispatch_boundary("decode"):

@@ -350,8 +350,11 @@ class StepRecord:
     #                             (ops/dense_attention.py): the cache
     #                             columns under the blocks read, summed
     #                             over the dispatch's steps; mla: the
-    #                             latent rows its steps score, every
-    #                             slot's whole extent
+    #                             latent rows its steps score: on a TPU
+    #                             (ops/latent_attention.py) the columns
+    #                             under the blocks read of the decoding
+    #                             slots, elsewhere every slot's whole
+    #                             extent
     # attention="mla" engines (models/xing.py); 0 elsewhere. Counted on
     # the device over the dispatch's live tokens, summed over expert
     # layers and steps, fetched with the tokens

@@ -18,7 +18,10 @@ the published config's own keys (``models/configs.py``):
   the one cached row as grouped queries score a shared kv head:
   ``ops.attention.decode_attention_prefix_window`` with one kv head of
   ``kv_lora_rank + qk_rope_head_dim``, whose first ``kv_lora_rank``
-  values are also the "value").
+  values are also the "value"; on a TPU the cached rows go through
+  ``ops/latent_attention.py`` instead, which reads each slot's live
+  blocks of them in place, once for both uses, and folds with the
+  dispatch's own rows under the same softmax).
 * **The feed-forward part is sparse after ``first_k_dense_replace``
   layers**: sigmoid scores over ``n_routed_experts``, the
   ``experts_per_token`` largest of ``score + bias`` chosen, gates
@@ -39,7 +42,8 @@ the published config's own keys (``models/configs.py``):
 State: ``{"dense": [k0, slots, R, max_len], "moe": [L - k0, slots, R,
 max_len]}`` latent rows (``R = kv_lora_rank + qk_rope_head_dim``), one
 array per stack of layers so that each layer scan takes its own as
-scanned input or carry and none is ever cut. A position is a COLUMN:
+scanned input or carry, or closes over it (decode on a TPU), and none
+is ever cut. A position is a COLUMN:
 the positions run along the minor axis. That is the layout the chip's
 compiler gives ``[.., max_len, R]`` of its own accord (R = 576 is four
 and a half lane tiles; the positions fill them whole) and the one its
@@ -66,8 +70,11 @@ from copilot_for_consensus_tpu.models.quant import (
     quantize_tensor,
 )
 from copilot_for_consensus_tpu.obs.profile import scope
+from copilot_for_consensus_tpu.ops import latent_attention
 from copilot_for_consensus_tpu.ops.attention import (
+    combine_partials,
     decode_attention_prefix_window,
+    decode_window_partial,
 )
 from copilot_for_consensus_tpu.ops.grouped_matmul import grouped_qmatmul
 
@@ -425,9 +432,9 @@ def piece_attention(q: jax.Array, cache_a: jax.Array, li: jax.Array,
 
 
 def absorbed_attention(q_n: jax.Array, q_r: jax.Array, cur: jax.Array,
-                       cache_l: jax.Array, win_l: jax.Array,
+                       cache_l: jax.Array | None, win_l: jax.Array,
                        pos0: jax.Array, w: jax.Array, layer: Params,
-                       cfg: DecoderConfig) -> jax.Array:
+                       cfg: DecoderConfig, live=None) -> jax.Array:
     """One token's attention in absorbed form. ``q_n [B, H, dn]``,
     rotated ``q_r [B, H, dr]``; the token's own latent row ``cur [B,
     R]``; ``cache_l [B, R, T]`` one layer of the cache, live below
@@ -435,7 +442,16 @@ def absorbed_attention(q_n: jax.Array, q_r: jax.Array, cur: jax.Array,
     step ``w``. The key half of ``wkv_b`` goes into the query, all
     heads score the shared rows under the dense decoder's joint softmax
     as the groups of ONE kv head, the value half comes after:
-    → ``[B, H dv]``."""
+    → ``[B, H dv]``.
+
+    Two routes to the same softmax. With ``cache_l``, the XLA one:
+    every slot's whole extent scored and masked below its length.
+    With ``live = (cache_a, li, plan)`` instead (a TPU's route): layer
+    ``li`` of the stack ``cache_a [La, B, R, T]`` whole, of which only
+    the blocks that ``plan`` lists are read, in place and once
+    (``ops/latent_attention.py``); the dispatch's own rows and the
+    token's own stay in XLA as one masked partial, and the fold puts
+    every score under the one normaliser."""
     r, dn = cfg.kv_lora_rank, cfg.qk_nope_head_dim
     dt = q_n.dtype
     wkv = _wkv_b(layer, cfg)
@@ -447,14 +463,23 @@ def absorbed_attention(q_n: jax.Array, q_r: jax.Array, cur: jax.Array,
         q_abs = (jnp.concatenate([q_c, q_r.astype(jnp.float32)], axis=-1)
                  * fix).astype(dt)
     one = lambda a: a[:, None]  # noqa: E731
-    rows = one(cache_l.transpose(0, 2, 1))   # a view: the dots take it
-    o = decode_attention_prefix_window(
-        q_abs, rows, rows, one(win_l), one(win_l), one(cur), one(cur),
-        pos0, w)
+    if live is None:
+        rows = one(cache_l.transpose(0, 2, 1))  # a view: the dots take it
+        o = decode_attention_prefix_window(
+            q_abs, rows, rows, one(win_l), one(win_l), one(cur), one(cur),
+            pos0, w)[..., :r]
+    else:
+        cache_a, li, plan = live
+        with scope("attn"):
+            past = latent_attention.live_partial(q_abs, cache_a, li, plan,
+                                                 rank=r)
+        own = decode_window_partial(
+            one(q_abs), one(win_l), one(win_l[..., :r]), one(cur),
+            one(cur[..., :r]), pos0, w)
+        o = combine_partials([tuple(one(a) for a in past), own], dt)[:, 0]
     with scope("attn_out"):
-        return jnp.einsum("bhr,rhd->bhd", o[..., :r],
-                          wkv[..., dn:].astype(dt)).reshape(
-            o.shape[0], -1)
+        return jnp.einsum("bhr,rhd->bhd", o, wkv[..., dn:].astype(dt)
+                          ).reshape(o.shape[0], -1)
 
 
 # ---------------------------------------------------------------------------
@@ -679,14 +704,19 @@ def prefill_piece(params: Params, tokens: jax.Array, lens: jax.Array,
 
 def decode_step(params: Params, tok: jax.Array, pos0: jax.Array,
                 w: jax.Array, cfg: DecoderConfig, cache: Params,
-                win: Params, max_len: int
+                win: Params, max_len: int, plan: tuple | None = None
                 ) -> tuple[jax.Array, Params, jax.Array]:
     """Step ``w`` (traced) of a dispatch that began at positions
     ``pos0``: one token per slot against a read-only cache and the
     dispatch's own rows ``win`` (a ``[La, B, W, R]`` per stack, live
     below ``w``). A slot that is not decoding stands at ``max_len``:
-    its token is computed and not routed. Returns (logits ``[B, V]``
-    float32, this step's latent rows ``[La, B, R]`` per stack, counts)."""
+    its token is computed and not routed. Without a ``plan`` a layer
+    scan takes its stack of the cache as scanned input and scores the
+    layer whole; with one (``latent_attention.plan_blocks`` for this
+    dispatch) it closes over the stack, of which the kernel reads each
+    slot's live blocks in place: no layer of the cache is ever made.
+    Returns (logits ``[B, V]`` float32, this step's latent rows ``[La,
+    B, R]`` per stack, counts)."""
     dt = params["tok_emb"].dtype
     live = (pos0 < max_len)[:, None]
     angles = (pos0 + w)[:, None, None].astype(jnp.float32) \
@@ -695,14 +725,15 @@ def decode_step(params: Params, tok: jax.Array, pos0: jax.Array,
     counts = jnp.zeros((N_COUNTS,), jnp.int32)
     cols = {}
 
-    def body(experts, carry, scanned):
+    def body(experts, cache_a, carry, scanned):
         x, counts = carry
-        layer, li, cache_l, win_l = scanned
+        layer, li, win_l, cache_l = scanned
         pre, post, res = mhc_maps(x, layer, "attn", cfg)
         hid = _norm(mhc_read(x, pre), layer["attn_norm"], cfg, dt)
         q_n, q_r, latent = project(hid, layer, cfg, angles, win_l.dtype)
-        o = absorbed_attention(q_n[:, 0], q_r[:, 0], latent[:, 0],
-                               cache_l, win_l, pos0, w, layer, cfg)
+        o = absorbed_attention(
+            q_n[:, 0], q_r[:, 0], latent[:, 0], cache_l, win_l, pos0, w,
+            layer, cfg, live=(cache_a, li, plan) if plan else None)
         x = mhc_write(x, L.attn_out(o[:, None], layer), post, res)
         pre, post, res = mhc_maps(x, layer, "ffn", cfg)
         y, c = ffn(_norm(mhc_read(x, pre), layer["ffn_norm"], cfg,
@@ -712,8 +743,9 @@ def decode_step(params: Params, tok: jax.Array, pos0: jax.Array,
     for name, count in stacks(cfg).items():
         layers, experts = _split(params[name])
         (x, counts), cols[name] = jax.lax.scan(
-            functools.partial(body, experts), (x, counts),
-            (layers, jnp.arange(count), cache[name], win[name]))
+            functools.partial(body, experts, cache[name]), (x, counts),
+            (layers, jnp.arange(count), win[name],
+             None if plan else cache[name]))
     return unembed(x[:, :, 0], params, cfg), cols, counts
 
 
@@ -751,22 +783,27 @@ def merge_latents(cache_a: jax.Array, win_a: jax.Array, pos0: jax.Array,
 def decode_tokens(params: Params, tokens: jax.Array, pos0: jax.Array,
                   cfg: DecoderConfig, cache: Params, key: jax.Array,
                   sample_fn, *, steps: int, max_len: int,
-                  with_logits: bool = False):
+                  with_logits: bool = False, live_blocks: bool = False):
     """``steps`` tokens for every slot in one program: decode → sample
     → feed back, the cache read-only until one merge at the end (the
     discipline of the engine's ``_decode``: a cache in the token loop's
-    carry is copied every token). Returns (tokens ``[steps, B]``,
+    carry is copied every token). ``live_blocks``: attention reads each
+    slot's live blocks of the cache in place (``decode_step`` with the
+    dispatch's plan, made here, once). Returns (tokens ``[steps, B]``,
     cache, counts ``[N_COUNTS]`` summed over layers and steps) and,
     ``with_logits``, every step's logits ``[steps, B, V]``."""
     b = tokens.shape[0]
     win = {name: jnp.zeros((a.shape[0], b, steps, a.shape[2]), a.dtype)
            for name, a in cache.items()}
+    plan = latent_attention.plan_blocks(
+        pos0, extent=next(iter(cache.values())).shape[3]) \
+        if live_blocks else None
 
     def body(carry, w):
         tok, win, counts, key = carry
         key, sub = jax.random.split(key)
         logits, cols, c = decode_step(params, tok, pos0, w, cfg, cache,
-                                      win, max_len)
+                                      win, max_len, plan)
         with scope("kv_write"):
             win = {name: jax.lax.dynamic_update_slice_in_dim(
                 a, cols[name][:, :, None].astype(a.dtype), w, axis=2)

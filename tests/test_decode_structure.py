@@ -7,6 +7,7 @@
 # the traced and of the compiled program (the latter for a described
 # v5e, no chip needed), each shown to trip on the old formulation.
 import functools
+import math
 import re
 
 import jax
@@ -616,9 +617,13 @@ MLA_MAX = 8192
 def mla_program_faults(one_chip, which):
     """What a compiled latent-attention program may not hold: an op
     whose result is a stack of the cache, or one layer of it, other
-    than the in-place updates; on the kernel route, any number of
-    kernels but the three grouped matmuls of the one scanned expert
-    layer, or an op that makes a stack of experts or one layer's."""
+    than the in-place updates; a float32 array of a score for every
+    head and cached column of every slot (the decode program alone:
+    what scoring the extent whole in XLA makes); on the kernel route,
+    any number of kernels but the three grouped matmuls of the one
+    scanned expert layer and, in the decode program, the absorbed
+    attention's one a stack of layers (`MLA_KERNELS`), or an op that
+    makes a stack of experts or one layer's."""
     eng = GenerationEngine(MLA_CFG, num_slots=SLOTS, max_len=MLA_MAX,
                            prefill_buckets=(64,), dtype=jnp.bfloat16,
                            eos_id=-1, quantize="int8")
@@ -683,9 +688,31 @@ def mla_program_faults(one_chip, which):
         if shape in stacks_ | layers \
                 and m.group(3) != "dynamic-update-slice":
             faults.append(f"{m.group(3)} makes cache rows {shape}")
+        if which == "decode" and " = f32[" in line and MLA_MAX in shape \
+                and math.prod(shape) >= SLOTS * MLA_CFG.n_heads * MLA_MAX:
+            faults.append(f"{m.group(3)} makes scores {shape}")
     kernels = [ln for ln in text.splitlines()
                if 'custom_call_target="tpu_custom_call"' in ln]
     return faults, kernels, text
+
+
+# the kernels of a compiled latent-attention program, by the scope and
+# name in their `op_name`: the three grouped matmuls of the scanned
+# expert layer in both; in decode also the absorbed attention over the
+# live latent blocks, one call site a stack of layers (the dense stack's
+# one layer and the expert stack's loop: 1 + 11 launches a token at the
+# served depth)
+MLA_KERNELS = {"admit": ["moe_experts/grouped_qmatmul"] * 3,
+               "decode": ["attn/mla_decode_attention"] * 2
+               + ["moe_experts/grouped_qmatmul"] * 3}
+
+
+def mla_kernel_names(kernels):
+    return sorted(
+        "/".join(re.search(
+            r'op_name="[^"]*/(attn|moe_experts)/(?:[^"]*/)?'
+            r'(mla_decode_attention|grouped_qmatmul)', k).groups())
+        for k in kernels)
 
 
 @pytest.mark.parametrize("which", ["decode", "admit"])
@@ -693,9 +720,19 @@ def test_compiled_mla_programs_hold_cache_and_experts_in_place(
         one_chip, on_tpu, which):
     faults, kernels, _text = mla_program_faults(one_chip, which)
     assert faults == []
-    assert len(kernels) == 3 and all(
-        re.search(r'op_name="[^"]*/moe_experts/[^"]*grouped_qmatmul',
-                  k) for k in kernels)
+    assert mla_kernel_names(kernels) == MLA_KERNELS[which]
+
+
+def test_mla_guard_trips_when_the_extent_is_scored_whole_in_xla(
+        one_chip, on_tpu, monkeypatch):
+    """The formulation this replaced: the cache among the layer scans'
+    scanned leaves, every slot's whole extent scored and masked."""
+    from copilot_for_consensus_tpu.ops import latent_attention
+
+    monkeypatch.setattr(latent_attention, "serves", lambda extent: False)
+    faults, kernels, _text = mla_program_faults(one_chip, "decode")
+    assert mla_kernel_names(kernels) == MLA_KERNELS["admit"]
+    assert any("makes scores" in f for f in faults)
 
 
 def test_mla_guard_trips_when_the_layer_scan_cuts_the_experts_out(
@@ -769,4 +806,5 @@ def test_the_other_configurations_programs_hold_nothing_of_this_one(
     under = {part for name in names for part in name.split("/")}
     assert "attn" in under and "ffn" in under    # the scopes are there
     assert not under & (set(XING_SCOPES) - set(SCOPES))
-    assert not any("grouped_qmatmul" in name for name in names)
+    assert not any("grouped_qmatmul" in name
+                   or "mla_decode_attention" in name for name in names)

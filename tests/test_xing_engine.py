@@ -426,3 +426,61 @@ def test_the_engine_records_the_counts_with_its_dispatches(engine):
         assert 0 < r.experts_touched <= r.expert_rows
         assert r.expert_rows_max * E >= r.expert_rows / (
             (STEPS if r.kind == "decode" else 1))
+
+
+# ---------------------------------------------------------------------------
+# (h) the kernel's route through the engine (a TPU's; here through the
+# Pallas interpreter): the same tokens, and the step records count the
+# blocks read
+# ---------------------------------------------------------------------------
+
+
+def test_the_kernel_route_serves_the_references_tokens_and_counts_its_blocks(
+        params, monkeypatch):
+    """A cache of four blocks a slot, three sequences that cross
+    dispatch edges and one a block's edge while decoding: every served
+    token is the reference's best, and each decode record's
+    `state_tokens_read` is the columns under the blocks of the decoding
+    slots' lengths, times the dispatch's steps."""
+    from copilot_for_consensus_tpu.ops import latent_attention
+
+    blk, max_len = 128, 512
+    monkeypatch.setattr(latent_attention, "BLOCK", blk)
+    monkeypatch.setattr(latent_attention, "serves", lambda extent: True)
+    eng = GenerationEngine(
+        CFG, params, num_slots=4, max_len=max_len, prefill_buckets=BUCKETS,
+        admission_token_budget=64, eos_id=-1, quantize="int8",
+        dtype=jnp.float32)
+    assert eng._reads_latent_blocks()
+    began = []
+    served = eng._decode_mla_fn
+
+    def watched(params, toks, positions, cache, key):
+        began.append(np.array(positions))      # a copy: the engine moves on
+        return served(params, toks, positions, cache, key)
+
+    eng._decode_mla_fn = watched
+    prompts = [tokens(n, seed=50 + n).tolist() for n in (5, 250, 130)]
+    done = eng.generate(prompts, 20)
+    for prompt, c in zip(prompts, done):
+        seq = prompt + list(c.tokens)
+        at = np.arange(len(prompt) - 1, len(seq) - 1)
+        want = ref.logits_at(params, DIMS, seq, at)
+        gap = want.max(-1) - want[np.arange(len(at)), c.tokens]
+        assert gap.max() < TOL
+    recs = [r for r in eng.telemetry.recorder.records()
+            if r.kind == "decode"]
+    assert len(recs) == len(began) >= 3
+    for rec, pos in zip(recs, began):
+        live = pos[pos < max_len]
+        assert rec.rows == len(live)
+        assert rec.window_tokens == live.sum()
+        assert rec.state_tokens_read == STEPS * (
+            -(-live // blk) * blk).sum()
+    # a sequence crossed a block's edge between two dispatches
+    assert any(250 <= p <= 256 for pos in began for p in pos) \
+        and any(256 < p < max_len for pos in began for p in pos)
+    # and off the kernel's route the whole extent is counted
+    monkeypatch.setattr(latent_attention, "serves", lambda extent: False)
+    assert not eng._reads_latent_blocks()
+    assert eng._latent_read(STEPS) == STEPS * 4 * max_len

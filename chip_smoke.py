@@ -498,6 +498,100 @@ def phase_kernels(rehearse: bool) -> int:
     say(f"latent decode attention, {w_sz} tokens of {n_ll} layer calls a "
         f"dispatch: {latent_rates}")
 
+    # -- the learned selection over the latent cache (attention="mla"
+    # with cfg.index_topk, models/xing.py), at the served cell's shapes:
+    # 8 slots x 32,768 columns of 576 + 128 (latent row, index key), 64
+    # heads, 32 index heads, the 2,048 best kept, 6 layers; the cell's
+    # mix of lengths, a free slot among them. (1) the threshold
+    # (ops/sparse_select.py) keeps exactly ``jax.lax.top_k``'s set;
+    # (2) the latent kernel walking live blocks under the selection's
+    # mask against the XLA route over the whole extent under the same
+    # mask; then a dispatch's 8 tokens of 6 layer calls, timed: indexer
+    # and selection alone, and attention on either route ------------------
+    from copilot_for_consensus_tpu.models import xing
+    from copilot_for_consensus_tpu.models.configs import decoder_config
+    from copilot_for_consensus_tpu.ops import sparse_select
+
+    gcfg = decoder_config("tiny-glm") if rehearse else dataclasses.replace(
+        decoder_config("tiny-glm"), kv_lora_rank=512, qk_rope_head_dim=64,
+        n_heads=64, index_n_heads=32, index_head_dim=128, index_topk=2048)
+    n_gl, gext = (2, 2048) if rehearse else (6, 32768)
+    gr, gw = gcfg.kv_lora_rank, xing.latent_width(gcfg)
+    gh, ghi, gdi = gcfg.n_heads, gcfg.index_n_heads, gcfg.index_head_dim
+    lens_g = [31744, 24303, 4096, 17022, 29040, 9977, 11922]
+    if rehearse:
+        lens_g = [n // 16 for n in lens_g]
+    pos_g = jnp.asarray(lens_g + [gext] * (slots - len(lens_g)), jnp.int32)
+    glat = jax.random.normal(jax.random.PRNGKey(11),
+                             (n_gl, slots, gw, gext), dtype)
+    gidx = jax.random.normal(jax.random.PRNGKey(12),
+                             (n_gl, slots, gdi, gext), dtype)
+    gq, gcur, gwin = normal(slots, gh, gw), normal(slots, gw), \
+        normal(slots, w_sz, gw)
+    gqi, gwi = normal(slots, ghi, gdi), normal(slots, ghi).astype(
+        jnp.float32)
+    gki, gwin_i = normal(slots, gdi), normal(slots, w_sz, gdi)
+
+    def keep_of(idx_l):
+        return xing.select_step(gqi, gwi, gki, idx_l, gwin_i, pos_g, w_at,
+                                gcfg)
+
+    keep_c, keep_o = jax.jit(keep_of)(gidx[0])
+    own = jnp.concatenate([gwin_i, gki[:, None]], axis=1)
+    scores = jnp.concatenate([
+        xing.index_scores(gqi[:, None], gwi[:, None], gidx[0])[:, 0],
+        xing.index_scores(gqi[:, None], gwi[:, None],
+                          own.transpose(0, 2, 1))[:, 0]], axis=-1)
+    col = np.arange(gext + w_sz + 1)
+    live = np.where(col < gext, col[None] < np.asarray(pos_g)[:, None],
+                    (col - gext)[None] < int(w_at)) | (col == gext + w_sz)
+    for b, n in enumerate(lens_g):
+        kk = min(gcfg.index_topk, n + int(w_at) + 1)
+        _, top = jax.lax.top_k(jnp.where(live[b], scores[b], -jnp.inf), kk)
+        got_set = np.flatnonzero(np.concatenate(
+            [np.asarray(keep_c[b]), np.asarray(keep_o[b])]))
+        check(set(got_set.tolist()) == set(np.asarray(top).tolist()),
+              f"sparse_select/slot={b}: the threshold keeps "
+              f"{len(got_set)} columns, not top_k's {kk}")
+
+    def kept_tokens(route, tokens, glat, gidx):
+        plan = latent_attention.plan_blocks(pos_g, extent=gext)
+
+        def layer(acc, xs):
+            li, cache_l, idx_l = xs
+            keep = keep_of(idx_l)
+            if route == "select":
+                return acc + keep[0].sum(-1, dtype=jnp.float32)[
+                    :, None, None], None
+            o = xing._kept_attention(
+                gq, gcur, cache_l, gwin,
+                (glat, li, plan) if route == "kernel" else None, keep, gr)
+            return acc + o.astype(jnp.float32), None
+
+        def token(acc, _):
+            return jax.lax.scan(
+                layer, acc,
+                (jnp.arange(n_gl), None if route == "kernel" else glat,
+                 gidx))[0], None
+
+        return jax.lax.scan(token, jnp.zeros((slots, gh, gr), jnp.float32),
+                            None, length=tokens)[0]
+
+    kept = {r: jax.jit(functools.partial(kept_tokens, r, 1))(glat, gidx)
+            for r in ("xla", "kernel")}
+    compare("mla_decode_attention/kept", kept["kernel"][:len(lens_g)],
+            kept["xla"][:len(lens_g)])
+    kept_rates = {
+        "live_columns": sum(lens_g),
+        "kept_columns": sum(min(n + 4, gcfg.index_topk) for n in lens_g),
+        **{f"{r}_ms_per_token": round(timed(jax.jit(functools.partial(
+            kept_tokens, r, w_sz)), glat, gidx), 4)
+           for r in ("select", "xla", "kernel")}}
+    say(f"selected latent decode attention, {w_sz} tokens of {n_gl} layer "
+        f"calls a dispatch (indexer + selection alone, then with "
+        f"attention on either route): {kept_rates}")
+    del glat, gidx
+
     # -- int4 matmul (what quantize="int4" routes to) ------------------
     for name, (din, dout) in (("up", (cfg.d_model, cfg.d_ff)),
                               ("down", (cfg.d_ff, cfg.d_model))):
@@ -547,6 +641,7 @@ def phase_kernels(rehearse: bool) -> int:
              "zero_copy_admits": stats["zero_copy_admits"]},
          dense_decode_attention=dense_rates,
          mla_decode_attention=latent_rates,
+         selected_latent_attention=kept_rates,
          seconds=round(time.monotonic() - t0, 1))
     return 0
 

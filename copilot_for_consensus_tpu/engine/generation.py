@@ -44,16 +44,23 @@ DOMINANT LATENCY" in SURVEY.md §3.2). Design:
   construction (``_check_eva``).
 
 * **A third kind of sequence state** (``cfg.attention == "mla"``,
-  Xing4.0 class; ``models/xing.py``): per position ONE latent row
-  shared by all heads, beside dropless sparse experts and a
-  multi-stream residual. Admission goes piece by piece through the
+  Xing4.0 and GLM-5 classes; ``models/xing.py``): per position ONE
+  latent row shared by all heads, beside dropless sparse experts (all
+  of them, or the share ``cfg.held_experts`` names) and, in the one
+  class, a multi-stream residual. In the other, attention is SELECTED
+  (``cfg.index_topk``): a second kind of per-position state, the
+  index key, lies beside the latent row in the cache (written by the
+  same admission wave, kept in the same dispatch's window buffer and
+  merged with it), and every query reads only the positions its
+  indexer scores highest. Admission goes piece by piece through the
   same ``_admit_pieces`` (each piece attends in expanded form to the
   slot's latents, expanded again), decode scores the latents in
   absorbed form, one program whatever the lengths (on a TPU each
   slot's live blocks of them, in place: ``_reads_latent_blocks``);
   the routing's counts come back with the tokens. What assumes a key
   and a value of ``[Hkv, Dh]`` per position, or one weight pass a
-  step, refuses it at construction (``_check_mla``).
+  step, refuses it at construction (``_check_mla``), for the index
+  keys as for the latent rows.
 
 The engine is synchronous and single-owner: services drive it through
 ``submit()`` + ``step()`` (or ``generate()`` for batch use) from their
@@ -230,6 +237,14 @@ def _host_fetch(x) -> "np.ndarray":
 
         return np.asarray(multihost_utils.process_allgather(x, tiled=True))
     return np.asarray(jax.device_get(x))
+
+
+def _selected(first: int, n: int, k: int) -> int:
+    """Positions that ``n`` successive tokens read under a selection of
+    ``k``, the first of them standing at position ``first``: the sum of
+    ``min(k, first + i + 1)`` over ``i < n``."""
+    grow = min(max(k - first, 0), n)         # tokens that still read all
+    return grow * first + grow * (grow + 1) // 2 + (n - grow) * k
 
 
 def _expert_counts(counts) -> dict:
@@ -2419,9 +2434,11 @@ class GenerationEngine:
                     "not have",
             "prefix_cache_blocks": "the prefix cache publishes and "
                     "seeds blocks of per-head keys and values; a "
-                    "prefix here is latent rows shared by all heads",
+                    "prefix here is latent rows shared by all heads "
+                    "(and, under a selection, index keys beside them)",
             "kv_pool_blocks": "the block pool pages keys and values of "
-                    "[Hkv, Dh]; it has no latent pages",
+                    "[Hkv, Dh]; it has no latent pages and none of "
+                    "index keys",
             "spec_decode": "the verify pass scores k + 1 positions "
                     "through decoder.verify_seeded, which knows "
                     "neither latent attention nor the experts",
@@ -2436,8 +2453,9 @@ class GenerationEngine:
             raise ValueError(
                 f"kv_dtype {asked['kv_dtype']!r} cannot serve "
                 f"attention='mla': an 8-bit latent row feeds every "
-                f"head's keys and values at once and was never held "
-                f"against the reference")
+                f"head's keys and values at once (and an 8-bit index "
+                f"key moves which positions a query reads) and was "
+                f"never held against the reference")
         if asked["quantize"] == "int4":
             raise ValueError(
                 "quantize='int4' cannot serve attention='mla': the "
@@ -2463,6 +2481,19 @@ class GenerationEngine:
                 f"prefill bucket ({self.buckets[-1]}) and of the "
                 f"expansion block ({xing.KV_BLOCK}): a piece's rows "
                 f"are written as one slab at a multiple of the bucket")
+        first, count = xing.held_experts(cfg)
+        if count < 1 or first < 0 or first + count > cfg.n_routed_experts:
+            raise ValueError(
+                f"held_experts {cfg.held_experts} is not a share of the "
+                f"{cfg.n_routed_experts} routed experts")
+        if cfg.index_topk and (
+                cfg.index_n_heads < 1
+                or cfg.index_head_dim < cfg.qk_rope_head_dim):
+            raise ValueError(
+                f"index_topk {cfg.index_topk} needs index heads "
+                f"(index_n_heads {cfg.index_n_heads}) of at least the "
+                f"rotary width (index_head_dim {cfg.index_head_dim}, "
+                f"qk_rope_head_dim {cfg.qk_rope_head_dim})")
 
     def _eva_live(self) -> tuple[int, int]:
         """(exact columns, summaries) held by all sequences in slots,
@@ -2550,6 +2581,13 @@ class GenerationEngine:
                 extra["attn_pairs"] = sum(
                     n * int(pos0[r]) + n * (n + 1) // 2
                     for r, (_slot, n) in enumerate(rows))
+                if self.cfg.selects:
+                    # every pair is scored by the indexer; attention
+                    # reads min(index_topk, position + 1) a query
+                    extra["live_tokens"] = extra["attn_pairs"]
+                    extra["selected_tokens"] = sum(
+                        _selected(int(pos0[r]), n, self.cfg.index_topk)
+                        for r, (_slot, n) in enumerate(rows))
             else:
                 first_dev, self._cache = self._admit_eva_fn(*args)
             first = _host_fetch(first_dev)
@@ -2650,6 +2688,26 @@ class GenerationEngine:
             latent_attention.blocks_read(int(self._positions[s]),
                                          self.max_len)
             for s in self._active)
+
+    def _selection_counts(self, steps: int) -> dict:
+        """What a decode dispatch of ``steps`` tokens reads under a
+        learned selection (``cfg.index_topk``; nothing without one),
+        summed over the decoding slots and the steps, the same in every
+        layer: ``live_tokens`` the positions a token could read (its
+        sequence's length, itself included), ``selected_tokens`` the
+        ``min(index_topk, live)`` it does read (``xing.select_step``
+        keeps exactly that many), ``index_tokens_read`` the index keys
+        the indexer scores for it: every slot's whole extent, in XLA
+        on every backend."""
+        if not self.cfg.selects:
+            return {}
+        at = [int(self._positions[s]) for s in self._active]
+        return {"live_tokens": sum(steps * n + steps * (steps + 1) // 2
+                                   for n in at),
+                "selected_tokens": sum(
+                    _selected(n, steps, self.cfg.index_topk) for n in at),
+                "index_tokens_read":
+                    steps * self.num_slots * self.max_len}
 
     def _kv_bucket(self) -> int:
         """Static attention extent for the next decode dispatch: the
@@ -3319,7 +3377,8 @@ class GenerationEngine:
             kv_len = self.max_len
             extra = {"window_tokens": sum(int(self._positions[s])
                                           for s in self._active),
-                     "state_tokens_read": self._latent_read(window)}
+                     "state_tokens_read": self._latent_read(window),
+                     **self._selection_counts(window)}
         self._phase(None)
         with step_annotation("decode", seq), \
                 self._dispatch_boundary("decode"):

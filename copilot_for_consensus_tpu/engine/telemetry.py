@@ -364,6 +364,18 @@ class StepRecord:
     attn_pairs: int = 0         # admission waves: query-key pairs the
     #                             wave's real tokens attend to, each
     #                             over its sequence's whole prefix
+    # attention="mla" engines whose attention is SELECTED
+    # (cfg.index_topk; models/xing.py); 0 elsewhere. Host arithmetic,
+    # the same in every layer: in a decode dispatch over the decoding
+    # slots, summed over its steps; in an admission wave over the
+    # wave's real queries (the first is 0 there)
+    index_tokens_read: int = 0  # index keys the indexer scores: every
+    #                             slot's whole extent (XLA, one layer
+    #                             of the index keys a layer and step)
+    selected_tokens: int = 0    # positions attention reads: min(
+    #                             index_topk, live) a token
+    live_tokens: int = 0        # positions a token could read: its
+    #                             sequence's length, itself included
 
     @property
     def occupancy(self) -> float:
@@ -611,7 +623,9 @@ class EngineTelemetry:
                     state_tokens_read: int = 0,
                     experts_touched: int = 0, expert_rows: int = 0,
                     expert_rows_max: int = 0,
-                    attn_pairs: int = 0) -> StepRecord:
+                    attn_pairs: int = 0, index_tokens_read: int = 0,
+                    selected_tokens: int = 0,
+                    live_tokens: int = 0) -> StepRecord:
         """``t_start`` is the dispatch's ``time.monotonic()`` start
         (default: now less ``duration_s``)."""
         if t_start is None:
@@ -628,7 +642,9 @@ class EngineTelemetry:
             window_tokens=window_tokens, summary_tokens=summary_tokens,
             state_tokens_read=state_tokens_read,
             experts_touched=experts_touched, expert_rows=expert_rows,
-            expert_rows_max=expert_rows_max, attn_pairs=attn_pairs)
+            expert_rows_max=expert_rows_max, attn_pairs=attn_pairs,
+            index_tokens_read=index_tokens_read,
+            selected_tokens=selected_tokens, live_tokens=live_tokens)
         self.recorder.record(rec)
         m, lb = self.metrics, self._labels
         m.observe("engine_step_seconds", duration_s,

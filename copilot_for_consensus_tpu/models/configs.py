@@ -74,6 +74,19 @@ class DecoderConfig:
     hc_eps: float = 1e-6
     mhc_h_res_clamp_min: float = -30.0
     mhc_h_res_clamp_max: float = 30.0
+    #: learned sparse attention over the latent cache (DSA,
+    #: ``glm_moe_dsa`` / DeepSeek-V3.2; ``index_topk`` 0 = every query
+    #: attends to every cached position): ``index_n_heads`` index
+    #: queries of ``index_head_dim`` score ONE index key a position (a
+    #: second cached row), and a query attends to the ``index_topk``
+    #: positions of largest score alone
+    index_n_heads: int = 0
+    index_head_dim: int = 0
+    index_topk: int = 0
+    #: ``(first, count)``: the routed experts this device holds of
+    #: ``n_routed_experts`` (the router keeps its width and chooses
+    #: over all; only the held ones' terms are computed). () = all
+    held_experts: tuple = ()
 
     @property
     def head_dim(self) -> int:
@@ -82,6 +95,13 @@ class DecoderConfig:
     @property
     def is_mla(self) -> bool:
         return self.attention == "mla"
+
+    @property
+    def selects(self) -> bool:
+        """Does attention read a learned selection of the cached
+        positions (and the cache hold an index key beside each latent
+        row)?"""
+        return self.is_mla and self.index_topk > 0
 
     @property
     def is_moe(self) -> bool:
@@ -159,6 +179,21 @@ DECODER_CONFIGS: dict[str, DecoderConfig] = {
                       ("original_max_position_embeddings", 64),
                       ("beta_fast", 32.0), ("beta_slow", 1.0),
                       ("mscale", 1.0), ("mscale_all_dim", 1.0)),
+    ),
+    # GLM-5 class (``glm_moe_dsa``) at test scale: a latent cache of
+    # 32 + 8 values a position and an index key of 16 beside it, 2
+    # index heads choosing 24 positions (well under the test lengths,
+    # so selection is real), ONE residual stream, plain RoPE, one dense
+    # layer and two layers of 8 experts (2 a token) beside a shared one.
+    "tiny-glm": DecoderConfig(
+        name="tiny-glm", vocab_size=512, d_model=64, n_layers=3,
+        n_heads=4, n_kv_heads=4, d_ff=160, rope_theta=1e4,
+        max_seq_len=512, norm_eps=1e-5, attention="mla", q_lora_rank=48,
+        kv_lora_rank=32, qk_nope_head_dim=16, qk_rope_head_dim=8,
+        v_head_dim=24, n_routed_experts=8, n_shared_experts=1,
+        experts_per_token=2, moe_intermediate_size=32,
+        first_k_dense_replace=1, routed_scaling_factor=2.5, hc_mult=1,
+        index_n_heads=2, index_head_dim=16, index_topk=24,
     ),
     "tiny-moe": DecoderConfig(
         name="tiny-moe", vocab_size=512, d_model=128, n_layers=2, n_heads=4,

@@ -1,8 +1,10 @@
-"""Latent-attention decoder with dropless sparse experts and a
-multi-stream residual (Xing4.0 class; ``cfg.attention == "mla"``).
+"""Latent-attention decoder with dropless sparse experts (Xing4.0
+class, with its multi-stream residual; GLM-5 class, with its learned
+sparse attention; ``cfg.attention == "mla"``).
 
 Three things set the block apart from the dense decoder's, each under
-the published config's own keys (``models/configs.py``):
+the published config's own keys (``models/configs.py``), and a fourth
+that one of the two classes adds:
 
 * **Attention keeps one latent row per position** (MLA, DeepSeek-V2/V3):
   ``[c ; k_r]``, ``kv_lora_rank`` values from which every head's key and
@@ -37,13 +39,29 @@ the published config's own keys (``models/configs.py``):
   and in float32, a read-out of the streams (``H_pre``), a write-back
   (``H_post``) and a doubly stochastic mixing of the streams (``H_res``,
   ``hc_sinkhorn_iters`` Sinkhorn rounds), all three from the token's own
-  normalised streams (``mhc_maps``).
+  normalised streams (``mhc_maps``). ``hc_mult == 1`` is the plain
+  residual ``x + F(norm(x))``: no maps are held or computed.
+* **Attention may be SELECTED** (``cfg.index_topk``; DSA, the
+  ``glm_moe_dsa`` class and DeepSeek-V3.2): a lightning indexer
+  (``index_n_heads`` queries of ``index_head_dim`` from the query
+  bottleneck, ONE index key a position from the sublayer's input,
+  per-head weights; ``ReLU`` dots, weighted and summed over heads, in
+  float32) scores every cached position for every query, and the query
+  attends to the ``index_topk`` of largest score alone (all of them
+  while there are fewer; ties to the lower position). The index key is a
+  SECOND cached row a position, written, kept in a dispatch's window
+  buffer and merged beside the latent row. The choice is the exact
+  top-k, found as a threshold by counting (``ops/sparse_select.py``),
+  and reaches both forms of attention as a mask over the columns they
+  walk.
 
 State: ``{"dense": [k0, slots, R, max_len], "moe": [L - k0, slots, R,
 max_len]}`` latent rows (``R = kv_lora_rank + qk_rope_head_dim``), one
 array per stack of layers so that each layer scan takes its own as
 scanned input or carry, or closes over it (decode on a TPU), and none
-is ever cut. A position is a COLUMN:
+is ever cut; with a selection, ``{"dense_idx", "moe_idx"}`` ``[.., slots,
+index_head_dim, max_len]`` index keys beside them, stack for stack. A
+position is a COLUMN:
 the positions run along the minor axis. That is the layout the chip's
 compiler gives ``[.., max_len, R]`` of its own accord (R = 576 is four
 and a half lane tiles; the positions fill them whole) and the one its
@@ -70,7 +88,7 @@ from copilot_for_consensus_tpu.models.quant import (
     quantize_tensor,
 )
 from copilot_for_consensus_tpu.obs.profile import scope
-from copilot_for_consensus_tpu.ops import latent_attention
+from copilot_for_consensus_tpu.ops import latent_attention, sparse_select
 from copilot_for_consensus_tpu.ops.attention import (
     combine_partials,
     decode_attention_prefix_window,
@@ -85,11 +103,26 @@ Params = dict[str, Any]
 #: multiple of it or shorter
 KV_BLOCK = 1024
 
-#: leaves served as int8 (``quantize_params``); ``wkv_b`` stays in the
-#: activation type (the absorbed form contracts it with activations on
-#: either side), the router, its bias and the mHC maps in float32
+#: leaves served as int8 (``quantize_params``); ``wkv_b`` and the
+#: indexer's head weights ``w_idx`` stay in the activation type (the
+#: absorbed form contracts the one with activations on either side; the
+#: other's 32 columns weigh every score), the router, its bias and the
+#: mHC maps in float32
 MATRICES = ("wq_a", "wq_b", "wkv_a", "wo", "w_gate", "w_up", "w_down",
-            "we_gate", "we_up", "we_down")
+            "we_gate", "we_up", "we_down", "wq_idx", "wk_idx")
+
+#: a stack's index keys in the cache, the window buffer and a step's
+#: rows: the stack's name and this
+IDX = "_idx"
+
+#: the index key's LayerNorm epsilon (DeepSeek-V3.2's inference code;
+#: ``config.json`` has no key for it)
+INDEX_NORM_EPS = 1e-6
+
+#: float32 index dots held at once (elements): the index heads are
+#: scored in groups that keep to it, so that an admission piece's
+#: ``[rows, heads, block]`` never exists for all heads
+INDEX_DOTS = 1 << 25
 
 #: the expert stacks ``[layers, E, ...]``: a layer scan closes over them
 #: and hands the layer's index on, so that the grouped matmul reads a
@@ -118,6 +151,11 @@ def stacks(cfg: DecoderConfig) -> dict[str, int]:
     return {k: v for k, v in out.items() if v}
 
 
+def held_experts(cfg: DecoderConfig) -> tuple[int, int]:
+    """(first, count) of the routed experts this device holds."""
+    return tuple(cfg.held_experts) or (0, cfg.n_routed_experts)
+
+
 def n_maps(cfg: DecoderConfig) -> int:
     """Columns of a sublayer's mHC map: pre, post, and the n x n mix."""
     return cfg.hc_mult * (cfg.hc_mult + 2)
@@ -133,6 +171,7 @@ def init_params(rng: jax.Array, cfg: DecoderConfig, dtype=jnp.bfloat16,
     rq, r, dr = cfg.q_lora_rank, cfg.kv_lora_rank, cfg.qk_rope_head_dim
     dn, dv = cfg.qk_nope_head_dim, cfg.v_head_dim
     e, fe = cfg.n_routed_experts, cfg.moe_intermediate_size
+    e_held = held_experts(cfg)[1]
     keys = iter(jax.random.split(rng, 64))
 
     def dense(shape, fan_in, dt=dtype):
@@ -155,7 +194,7 @@ def init_params(rng: jax.Array, cfg: DecoderConfig, dtype=jnp.bfloat16,
             "wkv_b": dense((count, r, h * (dn + dv)), r),
             "wo": dense((count, h * dv, d), h * dv),
         }
-        for sub in ("attn", "ffn"):
+        for sub in ("attn", "ffn") if n > 1 else ():
             out[f"hc_{sub}_phi"] = dense((count, n, d, n_maps(cfg)), n * d,
                                          jnp.float32)
             # the mix's logits at a quarter of the others' size: twenty
@@ -175,9 +214,18 @@ def init_params(rng: jax.Array, cfg: DecoderConfig, dtype=jnp.bfloat16,
                 router=dense((count, d, e), d, jnp.float32),
                 e_bias=0.01 * jax.random.normal(next(keys), (count, e),
                                                 jnp.float32),
-                we_gate=dense((count, e, d, fe), d),
-                we_up=dense((count, e, d, fe), d),
-                we_down=dense((count, e, fe, d), fe))
+                we_gate=dense((count, e_held, d, fe), d),
+                we_up=dense((count, e_held, d, fe), d),
+                we_down=dense((count, e_held, fe, d), fe))
+        if cfg.selects:
+            hi, di = cfg.index_n_heads, cfg.index_head_dim
+            out.update(
+                wq_idx=dense((count, rq, hi * di), rq),
+                wk_idx=dense((count, d, di), d),
+                k_idx_gain=gain((count, di)),
+                k_idx_bias=0.1 * jax.random.normal(
+                    next(keys), (count, di), jnp.float32).astype(dtype),
+                w_idx=dense((count, d, hi), d))
         return out
 
     params = {"tok_emb": dense((cfg.vocab_size, d), d),
@@ -209,9 +257,14 @@ def init_cache(cfg: DecoderConfig, batch: int, max_len: int,
         raise ValueError(
             f"latent cache: max_len {max_len} must be a multiple of the "
             f"expansion block ({KV_BLOCK})")
-    return {name: jnp.zeros((count, batch, latent_width(cfg), max_len),
-                            dtype)
-            for name, count in stacks(cfg).items()}
+    out = {name: jnp.zeros((count, batch, latent_width(cfg), max_len),
+                           dtype)
+           for name, count in stacks(cfg).items()}
+    if cfg.selects:
+        out.update({name + IDX: jnp.zeros(
+            (count, batch, cfg.index_head_dim, max_len), dtype)
+            for name, count in stacks(cfg).items()})
+    return out
 
 
 # ---------------------------------------------------------------------------
@@ -328,6 +381,26 @@ def mhc_write(x: jax.Array, y: jax.Array, post: list, res: list
         + post[i][..., None] * y for i in range(len(post))])
 
 
+def sublayer_in(x: jax.Array, layer: Params, sub: str, cfg: DecoderConfig
+                ) -> tuple[jax.Array, tuple | None]:
+    """The sublayer's input ``[..., d]`` float32 from the streams
+    ``[n, ..., d]``, before its norm, and what ``sublayer_out`` needs
+    to write back: the maps' two halves, or nothing under the plain
+    residual (``hc_mult == 1``: the one stream itself)."""
+    if cfg.hc_mult == 1:
+        return x[0], None
+    pre, post, res = mhc_maps(x, layer, sub, cfg)
+    return mhc_read(x, pre), (post, res)
+
+
+def sublayer_out(x: jax.Array, y: jax.Array, maps: tuple | None
+                 ) -> jax.Array:
+    """The streams after the sublayer wrote ``y``."""
+    if maps is None:
+        return x + y.astype(jnp.float32)[None]
+    return mhc_write(x, y, *maps)
+
+
 # ---------------------------------------------------------------------------
 # Attention: projections, the expanded form, the absorbed form
 # ---------------------------------------------------------------------------
@@ -340,10 +413,11 @@ def _norm(x, gain, cfg, dtype):
 @scope("qkv")
 def project(hid: jax.Array, layer: Params, cfg: DecoderConfig,
             angles: jax.Array, dtype
-            ) -> tuple[jax.Array, jax.Array, jax.Array]:
+            ) -> tuple[jax.Array, jax.Array, jax.Array, jax.Array]:
     """hid ``[n, S, d]`` → (``q_n [n, S, H, dn]``, rotated ``q_r [n, S,
     H, dr]``, the positions' latent rows ``[n, S, R]``: normed ``c``
-    and the rotated shared key), in ``dtype``."""
+    and the rotated shared key, in ``dtype``; the normed query
+    bottleneck ``c_q [n, S, rq]``, which the indexer reads too)."""
     n, s, _ = hid.shape
     r = cfg.kv_lora_rank
     c_q = _norm(L.qmatmul(hid, layer["wq_a"]), layer["q_norm"], cfg,
@@ -354,7 +428,8 @@ def project(hid: jax.Array, layer: Params, cfg: DecoderConfig,
     latent = jnp.concatenate(
         [_norm(kv[..., :r], layer["kv_norm"], cfg, dtype),
          rope(kv[..., r:], angles).astype(dtype)], axis=-1)
-    return q_n, rope(q_r, angles[:, :, None, :]).astype(hid.dtype), latent
+    return q_n, rope(q_r, angles[:, :, None, :]).astype(hid.dtype), \
+        latent, c_q
 
 
 def _wkv_b(layer: Params, cfg: DecoderConfig) -> jax.Array:
@@ -378,10 +453,31 @@ def expand(latent: jax.Array, layer: Params, cfg: DecoderConfig
     return jnp.concatenate([kv[..., :dn], k_r], axis=-1), kv[..., dn:]
 
 
+def _block_rows(stack_a: jax.Array, li: jax.Array, slots: jax.Array,
+                j: jax.Array, blk: int) -> jax.Array:
+    """Block j (``blk`` columns) of layer ``li`` of a stack ``[La,
+    slots, width, T]`` for each of a piece's rows: ``[n, width, blk]``,
+    a read a row."""
+    width = stack_a.shape[2]
+    return jnp.stack([
+        jax.lax.dynamic_slice(stack_a, (li, slots[r], 0, j * blk),
+                              (1, 1, width, blk))[0, 0]
+        for r in range(slots.shape[0])])
+
+
+def _seen(col: jax.Array, q_pos: jax.Array, kv_len: jax.Array
+          ) -> jax.Array:
+    """Which of the columns ``col [blk]`` each query of a piece sees
+    ``[n, S, blk]``: those up to its own position, below its row's
+    ``kv_len``."""
+    return (col[None, None, :] <= q_pos[:, :, None]) \
+        & (col[None, None, :] < kv_len[:, None, None])
+
+
 def piece_attention(q: jax.Array, cache_a: jax.Array, li: jax.Array,
                     slots: jax.Array, q_pos: jax.Array, kv_len: jax.Array,
-                    n_blocks: jax.Array, layer: Params, cfg: DecoderConfig
-                    ) -> jax.Array:
+                    n_blocks: jax.Array, layer: Params, cfg: DecoderConfig,
+                    keep=None) -> jax.Array:
     """Expanded attention of a piece's queries ``[n, S, H, dn + dr]``
     (scaled) over their rows' cached latents, layer ``li`` of
     ``cache_a`` ``[La, slots, R, T]`` (the piece's own rows already
@@ -389,27 +485,28 @@ def piece_attention(q: jax.Array, cache_a: jax.Array, li: jax.Array,
     columns up to its own, below ``kv_len[r]``. ``n_blocks`` (traced)
     blocks of ``KV_BLOCK`` columns hold something some row sees; each
     is read, expanded and folded into a running softmax, the rest is
-    never touched: one program whatever the lengths. → ``[n, S, H dv]``
-    in q's type."""
+    never touched: one program whatever the lengths. ``keep(j)``
+    (a selection: ``select_piece``) → which of block j's columns each
+    query reads ``[n, S, blk]``, causal and below ``kv_len`` among its
+    conditions; without it every column a query sees. → ``[n, S, H
+    dv]`` in q's type."""
     n, s, h, _ = q.shape
-    width, t = cache_a.shape[2:]
-    blk = min(KV_BLOCK, t)
+    blk = min(KV_BLOCK, cache_a.shape[3])
     dv = cfg.v_head_dim
 
     def fold(j, carry):
         acc, m, l = carry
         with scope("kv_prefix"):     # ... and the expansion there
-            rows = jnp.stack([
-                jax.lax.dynamic_slice(cache_a, (li, slots[r], 0, j * blk),
-                                      (1, 1, width, blk))[0, 0]
-                for r in range(n)])
-            k, v = expand(rows, layer, cfg)
+            k, v = expand(_block_rows(cache_a, li, slots, j, blk), layer,
+                          cfg)
         with scope("attn"):
             sc = jnp.einsum("nshd,nthd->nhst", q, k.astype(q.dtype),
                             preferred_element_type=jnp.float32)
-            col = j * blk + jnp.arange(blk)
-            seen = (col[None, None, :] <= q_pos[:, :, None]) \
-                & (col[None, None, :] < kv_len[:, None, None])
+            if keep is None:
+                seen = _seen(j * blk + jnp.arange(blk), q_pos, kv_len)
+            else:
+                with scope("select"):
+                    seen = keep(j)
             sc = jnp.where(seen[:, None], sc, -jnp.inf)
             m_new = jnp.maximum(m, jnp.max(sc, axis=-1, keepdims=True))
             m_safe = jnp.where(jnp.isfinite(m_new), m_new, 0.0)
@@ -434,7 +531,8 @@ def piece_attention(q: jax.Array, cache_a: jax.Array, li: jax.Array,
 def absorbed_attention(q_n: jax.Array, q_r: jax.Array, cur: jax.Array,
                        cache_l: jax.Array | None, win_l: jax.Array,
                        pos0: jax.Array, w: jax.Array, layer: Params,
-                       cfg: DecoderConfig, live=None) -> jax.Array:
+                       cfg: DecoderConfig, live=None, keep=None
+                       ) -> jax.Array:
     """One token's attention in absorbed form. ``q_n [B, H, dn]``,
     rotated ``q_r [B, H, dr]``; the token's own latent row ``cur [B,
     R]``; ``cache_l [B, R, T]`` one layer of the cache, live below
@@ -451,7 +549,13 @@ def absorbed_attention(q_n: jax.Array, q_r: jax.Array, cur: jax.Array,
     the blocks that ``plan`` lists are read, in place and once
     (``ops/latent_attention.py``); the dispatch's own rows and the
     token's own stay in XLA as one masked partial, and the fold puts
-    every score under the one normaliser."""
+    every score under the one normaliser.
+
+    ``keep = (cached [B, T], own [B, W + 1])`` (a selection:
+    ``select_step``): the columns of the cache, and the dispatch's rows
+    with the token's own last, that this token reads; live and causal
+    are among its conditions. The same two routes, each piece a masked
+    partial (the kernel takes the cached mask beside its blocks)."""
     r, dn = cfg.kv_lora_rank, cfg.qk_nope_head_dim
     dt = q_n.dtype
     wkv = _wkv_b(layer, cfg)
@@ -463,7 +567,9 @@ def absorbed_attention(q_n: jax.Array, q_r: jax.Array, cur: jax.Array,
         q_abs = (jnp.concatenate([q_c, q_r.astype(jnp.float32)], axis=-1)
                  * fix).astype(dt)
     one = lambda a: a[:, None]  # noqa: E731
-    if live is None:
+    if keep is not None:
+        o = _kept_attention(q_abs, cur, cache_l, win_l, live, keep, r)
+    elif live is None:
         rows = one(cache_l.transpose(0, 2, 1))  # a view: the dots take it
         o = decode_attention_prefix_window(
             q_abs, rows, rows, one(win_l), one(win_l), one(cur), one(cur),
@@ -480,6 +586,189 @@ def absorbed_attention(q_n: jax.Array, q_r: jax.Array, cur: jax.Array,
     with scope("attn_out"):
         return jnp.einsum("bhr,rhd->bhd", o, wkv[..., dn:].astype(dt)
                           ).reshape(o.shape[0], -1)
+
+
+def _partial(logits: jax.Array, values: jax.Array, dt
+             ) -> tuple[jax.Array, jax.Array, jax.Array]:
+    """Flash partial (acc, m, l) of masked float32 score rows ``[B, H,
+    T]`` over values ``[B, T, r]``, in ``combine_partials``'s
+    convention; the probabilities meet the values in ``dt``."""
+    m = jnp.max(logits, axis=-1, keepdims=True)
+    p = jnp.exp(logits - jnp.where(jnp.isfinite(m), m, 0.0))
+    acc = jnp.einsum("bht,btr->bhr", p.astype(dt), values.astype(dt),
+                     preferred_element_type=jnp.float32)
+    return acc, m, jnp.sum(p, axis=-1, keepdims=True)
+
+
+def _kept_attention(q_abs: jax.Array, cur: jax.Array,
+                    cache_l: jax.Array | None, win_l: jax.Array, live,
+                    keep: tuple, r: int) -> jax.Array:
+    """``absorbed_attention``'s softmax over the columns ``keep``
+    marks: ``[B, H, r]``."""
+    dt = q_abs.dtype
+    scale = q_abs.shape[-1] ** -0.5
+    keep_c, keep_o = keep
+    own = jnp.concatenate([win_l, cur[:, None]], axis=1)      # [B, W+1, R]
+    with scope("attn"):
+        s_o = jnp.einsum("bhr,bwr->bhw", q_abs, own.astype(dt),
+                         preferred_element_type=jnp.float32) * scale
+        part_o = _partial(jnp.where(keep_o[:, None], s_o, -jnp.inf),
+                          own[..., :r], dt)
+        if live is None:
+            s_c = jnp.einsum("bhr,brt->bht", q_abs, cache_l.astype(dt),
+                             preferred_element_type=jnp.float32) * scale
+            part_c = _partial(jnp.where(keep_c[:, None], s_c, -jnp.inf),
+                              cache_l[:, :r].transpose(0, 2, 1), dt)
+        else:
+            cache_a, li, plan = live
+            part_c = latent_attention.live_partial(
+                q_abs, cache_a, li, plan, rank=r,
+                keep=keep_c[:, None].astype(jnp.int32))
+    return combine_partials([part_c, part_o], dt)
+
+
+# ---------------------------------------------------------------------------
+# The indexer and the selection (cfg.index_topk)
+# ---------------------------------------------------------------------------
+
+
+@scope("indexer")
+def index_project(hid: jax.Array, c_q: jax.Array, layer: Params,
+                  cfg: DecoderConfig, angles: jax.Array, dtype
+                  ) -> tuple[jax.Array, jax.Array, jax.Array]:
+    """The indexer's three projections for positions hid ``[n, S, d]``
+    (the attention sublayer's normed input) with query bottleneck
+    ``c_q``: index queries ``[n, S, Hi, Di]`` in hid's type, the
+    positions' index keys ``[n, S, Di]`` in ``dtype`` (the second
+    cached row: a LayerNorm of ``wk_idx``'s product), the head weights
+    ``[n, S, Hi]`` float32 with both of the score's scale factors in
+    them. The first ``qk_rope_head_dim`` values of a query and of the
+    key are rotary (interleaved pairs, the attention's frequencies)."""
+    n, s, _ = hid.shape
+    hi, di, dr = cfg.index_n_heads, cfg.index_head_dim, cfg.qk_rope_head_dim
+    q = L.qmatmul(c_q, layer["wq_idx"]).reshape(n, s, hi, di)
+    q = jnp.concatenate([rope(q[..., :dr], angles[:, :, None, :]),
+                         q[..., dr:].astype(jnp.float32)], axis=-1)
+    k = L.layer_norm(L.qmatmul(hid, layer["wk_idx"]).astype(jnp.float32),
+                     layer["k_idx_gain"], layer["k_idx_bias"],
+                     INDEX_NORM_EPS)
+    k = jnp.concatenate([rope(k[..., :dr], angles), k[..., dr:]], axis=-1)
+    w = jnp.matmul(hid, layer["w_idx"].astype(hid.dtype),
+                   preferred_element_type=jnp.float32) \
+        * (hi ** -0.5 * di ** -0.5)
+    return q.astype(hid.dtype), k.astype(dtype), w
+
+
+@scope("indexer")
+def index_scores(q_i: jax.Array, w_i: jax.Array, keys: jax.Array
+                 ) -> jax.Array:
+    """``I[n, s, t] = sum_j w[n, s, j] ReLU(q[n, s, j] . k[n, :, t])``
+    float32: queries ``[n, S, Hi, Di]`` and cached index keys ``[n, Di,
+    T]`` meet in their own type with float32 accumulation; the ReLU,
+    the weights and the sum over heads are float32. The heads go in
+    groups of ``INDEX_DOTS`` elements' worth."""
+    n, s, hi, _ = q_i.shape
+    t = keys.shape[-1]
+    g = max(1, min(hi, INDEX_DOTS // max(n * s * t, 1)))
+    while hi % g:
+        g -= 1
+    keys = keys.astype(q_i.dtype)
+
+    def group(acc, qw):
+        q_g, w_g = qw                       # [n, g, S, Di], [n, g, S]
+        dots = jnp.einsum("ngsd,ndt->ngst", q_g, keys,
+                          preferred_element_type=jnp.float32)
+        return acc + jnp.sum(jax.nn.relu(dots) * w_g[..., None], axis=1), \
+            None
+
+    def heads(a):                           # [n, S, Hi, ..] → [G, n, g, S, ..]
+        a = a.reshape(n, s, hi // g, g, *a.shape[3:])
+        return jnp.moveaxis(jnp.moveaxis(a, 1, 3), 1, 0)
+
+    acc, _ = jax.lax.scan(group, jnp.zeros((n, s, t), jnp.float32),
+                          (heads(q_i), heads(w_i)))
+    return acc
+
+
+def select_piece(q_i: jax.Array, w_i: jax.Array, idx_a: jax.Array,
+                 li: jax.Array, slots: jax.Array, q_pos: jax.Array,
+                 kv_len: jax.Array, n_blocks: jax.Array,
+                 cfg: DecoderConfig):
+    """Which cached columns each query of an admission piece reads:
+    the ``index_topk`` of largest index score among those it sees (all
+    of them while fewer). The scores of the ``n_blocks`` live blocks
+    of layer ``li`` of the index keys ``idx_a [La, slots, Di, T]`` (the
+    piece's own keys written) go, as sort keys, into one buffer ``[n,
+    S, T]``; the threshold is counted over those blocks alone. →
+    ``keep(j)`` for ``piece_attention``."""
+    n, s = q_pos.shape
+    t = idx_a.shape[3]
+    blk = min(KV_BLOCK, t)
+
+    def cols(j):
+        return j * blk + jnp.arange(blk, dtype=jnp.int32)
+
+    def fill(j, buf):
+        return jax.lax.dynamic_update_slice(
+            buf, sparse_select.sort_keys(
+                index_scores(q_i, w_i,
+                             _block_rows(idx_a, li, slots, j, blk)),
+                _seen(cols(j), q_pos, kv_len)), (0, 0, j * blk))
+
+    with scope("indexer"):
+        buf = jax.lax.fori_loop(
+            0, n_blocks, fill,
+            jnp.full((n, s, t), sparse_select.NEVER, jnp.int32))
+
+    def block(j):
+        return jax.lax.dynamic_slice(buf, (0, 0, j * blk), (n, s, blk))
+
+    def count(test):
+        def one(j):
+            return jnp.sum(test(block(j), cols(j)), axis=-1,
+                           dtype=jnp.int32)
+
+        return jax.lax.fori_loop(
+            0, n_blocks, lambda j, acc: acc + one(j),
+            jnp.zeros(jax.eval_shape(one, 0).shape, jnp.int32))
+
+    with scope("select"):
+        seen = jnp.minimum(q_pos + 1, kv_len[:, None])
+        thr, cut = sparse_select.threshold(
+            count, jnp.clip(seen, 1, cfg.index_topk), t)
+    return lambda j: sparse_select.chosen(
+        block(j), cols(j), thr[..., None], cut[..., None])
+
+
+def select_step(q_i: jax.Array, w_i: jax.Array, k_cur: jax.Array,
+                idx_l: jax.Array, idx_win_l: jax.Array, pos0: jax.Array,
+                w: jax.Array, cfg: DecoderConfig
+                ) -> tuple[jax.Array, jax.Array]:
+    """Which positions a decode step's token reads, ``absorbed_
+    attention``'s ``keep``: index queries ``q_i [B, Hi, Di]`` with
+    weights ``w_i [B, Hi]`` score one layer of the cached index keys
+    ``idx_l [B, Di, T]`` (live below ``pos0``), the dispatch's own
+    ``idx_win_l [B, W, Di]`` (live below step ``w``) and the token's
+    own ``k_cur [B, Di]``; the ``index_topk`` largest of the live ones
+    (all while fewer) are kept, ties to the lower position: cached
+    columns come before the dispatch's rows, as their positions do."""
+    t, n_win = idx_l.shape[-1], idx_win_l.shape[1]
+    own = jnp.concatenate([idx_win_l, k_cur[:, None]], axis=1)
+    scores = jnp.concatenate([
+        index_scores(q_i[:, None], w_i[:, None], idx_l)[:, 0],
+        index_scores(q_i[:, None], w_i[:, None],
+                     own.transpose(0, 2, 1))[:, 0]], axis=-1)
+    with scope("select"):
+        col = jnp.arange(t + n_win + 1)
+        live = jnp.where(col < t, col[None] < pos0[:, None],
+                         (col - t)[None] < w) | (col == t + n_win)
+        keys = sparse_select.sort_keys(scores, live)
+        seen = jnp.minimum(pos0, t) + w + 1
+        thr, cut = sparse_select.threshold(
+            sparse_select.count_over(keys),
+            jnp.minimum(seen, cfg.index_topk), t + n_win + 1)
+        keep = sparse_select.chosen(keys, col, thr[:, None], cut[:, None])
+    return keep[:, :t], keep[:, t:]
 
 
 # ---------------------------------------------------------------------------
@@ -555,12 +844,19 @@ def routed_experts(hid: jax.Array, layer: Params, experts: Params,
     which layer ``li`` is read. ``held = (first, count)``: the experts
     this device holds (default all; ``experts`` then holds those
     alone); the choice is made over all experts and only the held
-    ones' terms are added. No token is dropped: every live pair of a
-    held expert is computed, each from its own row alone."""
+    ones' terms are added; the counts are over all experts too, unless
+    the config itself names a share (``cfg.held_experts``: the served
+    path of a device that holds one counts what it is asked for). No
+    token is dropped: every live pair of a held expert is computed,
+    each from its own row alone."""
     first, count = held or (0, cfg.n_routed_experts)
     idx, gates = route(hid, layer, cfg)
     tok, group, back, sizes, counts = group_by_expert(
         idx, live, cfg.n_routed_experts, first, count)
+    if cfg.held_experts:
+        # a device that holds a share counts what it is asked for
+        counts = jnp.stack([jnp.sum(sizes > 0), jnp.sum(sizes),
+                            jnp.max(sizes)]).astype(jnp.int32)
     with scope("moe_experts"):
         hid = hid.astype(dtype or jnp.bfloat16)
         xs = hid[tok]
@@ -581,7 +877,10 @@ def ffn(hid: jax.Array, layer: Params, experts: Params, li: jax.Array,
     """The feed-forward sublayer: its input ``[n, S, d]`` float32 →
     float32 ``[n, S, d]`` and the routing's counts: a SwiGLU (the dense
     layers), or the routed experts plus the shared expert's SwiGLU,
-    multiplied in ``dtype``; the router reads the input unrounded."""
+    multiplied in ``dtype``; the router reads the input unrounded.
+    Under ``cfg.held_experts`` ``experts`` holds that share alone and
+    only its terms are added (``routed_experts``); the shared expert is
+    whole on every device."""
     y = L.swiglu(hid.astype(dtype), layer).astype(jnp.float32)
     if "router" not in layer:
         return y, jnp.zeros((N_COUNTS,), jnp.int32)
@@ -591,7 +890,8 @@ def ffn(hid: jax.Array, layer: Params, experts: Params, li: jax.Array,
     with scope("ffn"):
         routed, counts = routed_experts(
             hid.reshape(n * s, d), layer, experts, li, cfg,
-            live.reshape(n * s), dtype=dtype)
+            live.reshape(n * s), tuple(cfg.held_experts) or None,
+            dtype=dtype)
     return y + routed.reshape(n, s, d), counts
 
 
@@ -644,7 +944,9 @@ def prefill_piece(params: Params, tokens: jax.Array, lens: jax.Array,
     largest bucket, which divides ``max_len``). The piece's latent rows
     go into the slot at their positions, then its queries attend in
     expanded form to everything the slot holds up to themselves
-    (``piece_attention``). Columns past ``lens[r]`` take rows nobody
+    (``piece_attention``; with a selection the positions' index keys go
+    into the slot beside them and each query reads the columns
+    ``select_piece`` keeps). Columns past ``lens[r]`` take rows nobody
     reads before they are written again; their tokens are not routed.
     The cache rides the layer scans' carry and is touched a row at a
     time. Rows may repeat (the engine pads a wave with copies of its
@@ -664,33 +966,48 @@ def prefill_piece(params: Params, tokens: jax.Array, lens: jax.Array,
     counts = jnp.zeros((N_COUNTS,), jnp.int32)
     out = {}
 
-    def body(experts, carry, scanned):
-        x, cache_a, counts = carry
-        layer, li = scanned
-        pre, post, res = mhc_maps(x, layer, "attn", cfg)
-        hid = _norm(mhc_read(x, pre), layer["attn_norm"], cfg, dt)
-        q_n, q_r, latent = project(hid, layer, cfg, angles, cache_a.dtype)
+    def write(cache_a, rows, li):
         with scope("kv_write"):
             for r in range(n):
                 cache_a = jax.lax.dynamic_update_slice(
-                    cache_a, latent[r].T[None, None],
+                    cache_a, rows[r].T[None, None],
                     (li, slots[r], 0, pos0[r]))
+        return cache_a
+
+    def body(experts, carry, scanned):
+        x, cache_a, idx_a, counts = carry
+        layer, li = scanned
+        u, maps = sublayer_in(x, layer, "attn", cfg)
+        hid = _norm(u, layer["attn_norm"], cfg, dt)
+        q_n, q_r, latent, c_q = project(hid, layer, cfg, angles,
+                                        cache_a.dtype)
+        cache_a = write(cache_a, latent, li)
+        keep = None
+        if cfg.selects:
+            q_i, k_i, w_i = index_project(hid, c_q, layer, cfg, angles,
+                                          idx_a.dtype)
+            idx_a = write(idx_a, k_i, li)
+            keep = select_piece(q_i, w_i, idx_a, li, slots, q_pos, kv_len,
+                                n_blocks, cfg)
         with scope("qkv"):
             q = (jnp.concatenate([q_n, q_r], axis=-1).astype(jnp.float32)
                  * scale).astype(dt)
         o = piece_attention(q, cache_a, li, slots, q_pos, kv_len,
-                            n_blocks, layer, cfg)
-        x = mhc_write(x, L.attn_out(o, layer), post, res)
-        pre, post, res = mhc_maps(x, layer, "ffn", cfg)
-        y, c = ffn(_norm(mhc_read(x, pre), layer["ffn_norm"], cfg,
-                         jnp.float32), layer, experts, li, cfg, live, dt)
-        return (mhc_write(x, y, post, res), cache_a, counts + c), None
+                            n_blocks, layer, cfg, keep)
+        x = sublayer_out(x, L.attn_out(o, layer), maps)
+        u, maps = sublayer_in(x, layer, "ffn", cfg)
+        y, c = ffn(_norm(u, layer["ffn_norm"], cfg, jnp.float32), layer,
+                   experts, li, cfg, live, dt)
+        return (sublayer_out(x, y, maps), cache_a, idx_a, counts + c), None
 
     for name, count in stacks(cfg).items():
         layers, experts = _split(params[name])
-        (x, out[name], counts), _ = jax.lax.scan(
-            functools.partial(body, experts), (x, cache[name], counts),
+        (x, out[name], idx_a, counts), _ = jax.lax.scan(
+            functools.partial(body, experts),
+            (x, cache[name], cache.get(name + IDX), counts),
             (layers, jnp.arange(count)))
+        if cfg.selects:
+            out[name + IDX] = idx_a
     x_last = jnp.take_along_axis(
         x, jnp.broadcast_to((lens - 1)[None, :, None, None],
                             (cfg.hc_mult, n, 1, x.shape[-1])), axis=2)
@@ -715,8 +1032,11 @@ def decode_step(params: Params, tok: jax.Array, pos0: jax.Array,
     layer whole; with one (``latent_attention.plan_blocks`` for this
     dispatch) it closes over the stack, of which the kernel reads each
     slot's live blocks in place: no layer of the cache is ever made.
-    Returns (logits ``[B, V]`` float32, this step's latent rows ``[La,
-    B, R]`` per stack, counts)."""
+    With a selection the index keys (cache and window, ``name + IDX``)
+    ride the scan as scanned inputs on either route: the indexer scores
+    a layer of them whole. Returns (logits ``[B, V]`` float32, this
+    step's rows ``[La, B, R]`` per stack, and index keys ``[La, B, Di]``
+    beside them, counts)."""
     dt = params["tok_emb"].dtype
     live = (pos0 < max_len)[:, None]
     angles = (pos0 + w)[:, None, None].astype(jnp.float32) \
@@ -727,25 +1047,37 @@ def decode_step(params: Params, tok: jax.Array, pos0: jax.Array,
 
     def body(experts, cache_a, carry, scanned):
         x, counts = carry
-        layer, li, win_l, cache_l = scanned
-        pre, post, res = mhc_maps(x, layer, "attn", cfg)
-        hid = _norm(mhc_read(x, pre), layer["attn_norm"], cfg, dt)
-        q_n, q_r, latent = project(hid, layer, cfg, angles, win_l.dtype)
+        layer, li, win_l, cache_l, idx_win_l, idx_l = scanned
+        u, maps = sublayer_in(x, layer, "attn", cfg)
+        hid = _norm(u, layer["attn_norm"], cfg, dt)
+        q_n, q_r, latent, c_q = project(hid, layer, cfg, angles,
+                                        win_l.dtype)
+        keep = k_i = None
+        if cfg.selects:
+            q_i, k_i, w_i = index_project(hid, c_q, layer, cfg, angles,
+                                          idx_win_l.dtype)
+            keep = select_step(q_i[:, 0], w_i[:, 0], k_i[:, 0], idx_l,
+                               idx_win_l, pos0, w, cfg)
+            k_i = k_i[:, 0]
         o = absorbed_attention(
             q_n[:, 0], q_r[:, 0], latent[:, 0], cache_l, win_l, pos0, w,
-            layer, cfg, live=(cache_a, li, plan) if plan else None)
-        x = mhc_write(x, L.attn_out(o[:, None], layer), post, res)
-        pre, post, res = mhc_maps(x, layer, "ffn", cfg)
-        y, c = ffn(_norm(mhc_read(x, pre), layer["ffn_norm"], cfg,
-                         jnp.float32), layer, experts, li, cfg, live, dt)
-        return (mhc_write(x, y, post, res), counts + c), latent[:, 0]
+            layer, cfg, live=(cache_a, li, plan) if plan else None,
+            keep=keep)
+        x = sublayer_out(x, L.attn_out(o[:, None], layer), maps)
+        u, maps = sublayer_in(x, layer, "ffn", cfg)
+        y, c = ffn(_norm(u, layer["ffn_norm"], cfg, jnp.float32), layer,
+                   experts, li, cfg, live, dt)
+        return (sublayer_out(x, y, maps), counts + c), (latent[:, 0], k_i)
 
     for name, count in stacks(cfg).items():
         layers, experts = _split(params[name])
-        (x, counts), cols[name] = jax.lax.scan(
+        (x, counts), (cols[name], k_i) = jax.lax.scan(
             functools.partial(body, experts, cache[name]), (x, counts),
             (layers, jnp.arange(count), win[name],
-             None if plan else cache[name]))
+             None if plan else cache[name], win.get(name + IDX),
+             cache.get(name + IDX)))
+        if cfg.selects:
+            cols[name + IDX] = k_i
     return unembed(x[:, :, 0], params, cfg), cols, counts
 
 

@@ -18,8 +18,8 @@ name go into it, each declared here and nowhere else:
   a host-side ``StepRecord`` name the SAME step. Read by
   ``benchmark/harness/trace_reduce.py`` (device time per step, idle
   gaps inside a dispatch).
-* **Device scopes** — ``SCOPES``, ``EVA_SCOPES``, ``XING_SCOPES`` /
-  ``scope(name)``:
+* **Device scopes** — ``SCOPES``, ``EVA_SCOPES``, ``XING_SCOPES``,
+  ``GLM_SCOPES`` / ``scope(name)``:
   ``jax.named_scope``
   around the code that does each thing inside the jitted programs. The
   name lands in every HLO instruction's ``op_name`` metadata
@@ -85,6 +85,22 @@ XING_SCOPES = (
     #                   cached latents of earlier pieces
 )
 
+#: scopes of the attention="mla" programs of a config that SELECTS
+#: what attention reads (``cfg.index_topk``; GLM-5 class): the four it
+#: shares with ``XING_SCOPES`` (it has one residual stream: no ``mhc``)
+#: and its own two. A tuple of its own, as those: a reader reduces a
+#: program's trace over ``SCOPES`` plus the ONE tuple it names
+GLM_SCOPES = (
+    "moe_route",
+    "moe_experts",
+    "latent_expand",
+    "indexer",        # index queries, keys and head weights; the index
+    #                   scores over the cached index keys
+    "select",         # the exact top-k of a query's index scores as a
+    #                   threshold (ops/sparse_select.py) and the mask
+    #                   over the columns attention walks
+)
+
 #: host phases of the serving loop (with the dispatch kinds ``decode``
 #: and ``prefill`` and ``_no_annotation_`` they fit the trace
 #: reducer's ten gap owners)
@@ -133,14 +149,15 @@ def step_annotation(name: str, step_num: int | None = None):
 
 
 def scope(name: str):
-    """``jax.named_scope`` for one of ``SCOPES``, ``EVA_SCOPES`` or
-    ``XING_SCOPES`` (trace time only)."""
+    """``jax.named_scope`` for one of ``SCOPES``, ``EVA_SCOPES``,
+    ``XING_SCOPES`` or ``GLM_SCOPES`` (trace time only)."""
     import jax
 
-    if name not in SCOPES + EVA_SCOPES + XING_SCOPES:
+    if name not in SCOPES + EVA_SCOPES + XING_SCOPES + GLM_SCOPES:
         raise ValueError(f"unknown device scope {name!r}; obs/profile.py "
                          f"SCOPES has {SCOPES}, EVA_SCOPES {EVA_SCOPES}, "
-                         f"XING_SCOPES {XING_SCOPES}")
+                         f"XING_SCOPES {XING_SCOPES}, GLM_SCOPES "
+                         f"{GLM_SCOPES}")
     return jax.named_scope(name)
 
 

@@ -111,12 +111,15 @@ def plan_blocks(pos0: jax.Array, *, extent: int
     return steps, n_steps, hi.astype(jnp.int32)
 
 
-def _live_kernel(li_ref, steps_ref, hi_ref, q_ref, c_ref,
-                 acc_out, m_out, l_out, m_ref, l_ref, acc_ref, *,
-                 block: int, rank: int, scale: float):
+def _live_kernel(li_ref, steps_ref, hi_ref, q_ref, c_ref, *rest,
+                 block: int, rank: int, scale: float, kept: bool):
     """One step of the plan: fold one block of one slot, fetched once,
-    into the slot's running (max, sum, acc) for all heads."""
+    into the slot's running (max, sum, acc) for all heads. ``kept``: a
+    block of the slot's ``keep`` row comes with the latent block, and a
+    column counts only where it is non-zero."""
     del li_ref
+    keep_ref = rest[0] if kept else None
+    acc_out, m_out, l_out, m_ref, l_ref, acc_ref = rest[kept:]
     g = pl.program_id(0)
 
     @pl.when(steps_ref[_FIRST, g] == 1)
@@ -135,14 +138,16 @@ def _live_kernel(li_ref, steps_ref, hi_ref, q_ref, c_ref,
         col = steps_ref[_KBLK, g] * block \
             + jax.lax.broadcasted_iota(jnp.int32, (1, block), 1)
         live = col < hi_ref[steps_ref[_SLOT, g]]
+        if kept:
+            live = live & (keep_ref[0] != 0)
         s = jnp.dot(q, c, preferred_element_type=jnp.float32) * scale
         s = jnp.where(live, s, -jnp.inf)
         v = jnp.where(live, c[:rank], 0)                 # [rank, block]
         m_prev = m_ref[...]                              # [H, 1]
         m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
-        # a slot's first block has column 0 live, so m_new is finite;
-        # the subtrahends are pinned all the same
-        # (dense_attention._live_kernel)
+        # a slot's first block has column 0 live, so m_new is finite
+        # (not so under ``keep``, which may choose none of a block);
+        # the subtrahends are pinned (dense_attention._live_kernel)
         m_safe = jnp.where(jnp.isfinite(m_new), m_new, 0.0)
         alpha = jnp.where(jnp.isfinite(m_prev),
                           jnp.exp(m_prev - m_safe), 0.0)
@@ -161,7 +166,8 @@ def _live_kernel(li_ref, steps_ref, hi_ref, q_ref, c_ref,
 
 
 def live_partial(q_abs: jax.Array, cache_a: jax.Array, li: jax.Array,
-                 plan: tuple, *, rank: int, interpret: bool | None = None
+                 plan: tuple, *, rank: int, keep: jax.Array | None = None,
+                 interpret: bool | None = None
                  ) -> tuple[jax.Array, jax.Array, jax.Array]:
     """Flash partials of one token's absorbed queries ``q_abs``
     ``[B, H, R]`` (scaled as ``xing.absorbed_attention`` scales them:
@@ -169,7 +175,9 @@ def live_partial(q_abs: jax.Array, cache_a: jax.Array, li: jax.Array,
     live columns of layer ``li`` (traced) of a stack of the latent
     cache ``[La, B, R, extent]``, read in place by ``plan``
     (``plan_blocks`` for the same extent). A column's first ``rank``
-    values are its value.
+    values are its value. ``keep`` ``[B, 1, extent]`` int32 (a learned
+    selection: ``models/xing.py``): of the live columns only those it
+    marks non-zero are scored; its blocks ride beside the cache's.
 
     Returns f32 (acc ``[B, H, rank]``, m ``[B, H, 1]``, l ``[B, H,
     1]``); a slot with nothing live carries ``m = -inf``, ``l = 0``.
@@ -190,12 +198,18 @@ def live_partial(q_abs: jax.Array, cache_a: jax.Array, li: jax.Array,
     def at_block(g, li, steps, hi):
         return li[0], steps[_KSLOT, g], 0, steps[_KBLK, g]
 
+    def at_keep(g, li, steps, hi):
+        return steps[_KSLOT, g], 0, steps[_KBLK, g]
+
+    kept = keep is not None
+
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=3,            # layer index, steps, bounds
         # the interpreter takes no dynamic bound
         grid=(steps.shape[1] if interpret else n_steps,),
         in_specs=[pl.BlockSpec((1, h, width), at_slot),
-                  pl.BlockSpec((1, 1, width, block), at_block)],
+                  pl.BlockSpec((1, 1, width, block), at_block)]
+        + [pl.BlockSpec((1, 1, block), at_keep)] * kept,
         out_specs=[pl.BlockSpec((1, h, rank), at_slot),
                    pl.BlockSpec((1, h, 1), at_slot),
                    pl.BlockSpec((1, h, 1), at_slot)],
@@ -205,7 +219,7 @@ def live_partial(q_abs: jax.Array, cache_a: jax.Array, li: jax.Array,
     )
     return pl.pallas_call(
         functools.partial(_live_kernel, block=block, rank=rank,
-                          scale=width ** -0.5),
+                          scale=width ** -0.5, kept=kept),
         grid_spec=grid_spec,
         out_shape=[jax.ShapeDtypeStruct((b, h, rank), jnp.float32),
                    jax.ShapeDtypeStruct((b, h, 1), jnp.float32),
@@ -215,4 +229,4 @@ def live_partial(q_abs: jax.Array, cache_a: jax.Array, li: jax.Array,
             vmem_limit_bytes=64 * 1024 * 1024),
         interpret=interpret,
         name="mla_decode_attention",
-    )(li, steps, hi, q_abs, cache_a)
+    )(li, steps, hi, q_abs, cache_a, *([keep] if kept else []))

@@ -614,7 +614,7 @@ MLA_CFG = decoder_config(
 MLA_MAX = 8192
 
 
-def mla_program_faults(one_chip, which):
+def mla_program_faults(one_chip, which, cfg=None):
     """What a compiled latent-attention program may not hold: an op
     whose result is a stack of the cache, or one layer of it, other
     than the in-place updates; a float32 array of a score for every
@@ -624,7 +624,8 @@ def mla_program_faults(one_chip, which):
     scanned expert layer and, in the decode program, the absorbed
     attention's one a stack of layers (`MLA_KERNELS`), or an op that
     makes a stack of experts or one layer's."""
-    eng = GenerationEngine(MLA_CFG, num_slots=SLOTS, max_len=MLA_MAX,
+    cfg = cfg or MLA_CFG
+    eng = GenerationEngine(cfg, num_slots=SLOTS, max_len=MLA_MAX,
                            prefill_buckets=(64,), dtype=jnp.bfloat16,
                            eos_id=-1, quantize="int8")
 
@@ -689,7 +690,7 @@ def mla_program_faults(one_chip, which):
                 and m.group(3) != "dynamic-update-slice":
             faults.append(f"{m.group(3)} makes cache rows {shape}")
         if which == "decode" and " = f32[" in line and MLA_MAX in shape \
-                and math.prod(shape) >= SLOTS * MLA_CFG.n_heads * MLA_MAX:
+                and math.prod(shape) >= SLOTS * cfg.n_heads * MLA_MAX:
             faults.append(f"{m.group(3)} makes scores {shape}")
     kernels = [ln for ln in text.splitlines()
                if 'custom_call_target="tpu_custom_call"' in ln]
@@ -721,6 +722,32 @@ def test_compiled_mla_programs_hold_cache_and_experts_in_place(
     faults, kernels, _text = mla_program_faults(one_chip, which)
     assert faults == []
     assert mla_kernel_names(kernels) == MLA_KERNELS[which]
+
+
+# the same two programs for a config that SELECTS what attention reads
+# (cfg.index_topk; GLM-5 class): a second stack of rows a stack of
+# layers (the index keys), a share of the experts, one residual stream.
+# The latent stacks are still read through the kernel alone, now with
+# the selection's mask beside its blocks, and still never cut or turned
+# over; the index keys ride the decode scan as scanned inputs (the
+# indexer scores a layer of them whole, in XLA: fewer heads than
+# attention has, so no array of the size the guard above names)
+GLM_CFG = decoder_config(
+    "tiny-glm", d_model=512, d_ff=512, q_lora_rank=128, kv_lora_rank=128,
+    qk_nope_head_dim=32, qk_rope_head_dim=16, v_head_dim=32,
+    n_routed_experts=64, held_experts=(16, 16), experts_per_token=4,
+    moe_intermediate_size=512, index_n_heads=2, index_head_dim=64,
+    index_topk=1024, max_seq_len=8192)
+
+
+@pytest.mark.parametrize("which", ["decode", "admit"])
+def test_compiled_selected_mla_programs_hold_both_caches_in_place(
+        one_chip, on_tpu, which):
+    faults, kernels, text = mla_program_faults(one_chip, which, GLM_CFG)
+    assert faults == []
+    assert mla_kernel_names(kernels) == MLA_KERNELS[which]
+    # the selection's counting is there, under its own scope
+    assert "/select/" in text and "/indexer/" in text
 
 
 def test_mla_guard_trips_when_the_extent_is_scored_whole_in_xla(

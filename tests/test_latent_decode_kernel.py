@@ -310,3 +310,68 @@ def test_a_dispatch_through_the_kernel_equals_the_xla_dispatch(step_state):
     for name in cache:
         assert np.abs(np.asarray(cache_k[name])
                       - np.asarray(cache_x[name]))[:, live].max() < STEP
+
+
+# ---------------------------------------------------------------------------
+# under a selection (`keep`, PR 37): of the live columns only those the
+# mask marks are scored; its blocks ride beside the cache's
+# ---------------------------------------------------------------------------
+
+
+def kept_routes(cache_a, local, pos0, w, keep_c, keep_o, li=1):
+    """`xing._kept_attention` on its two routes: the layer's rows whole
+    in XLA under the masks, and the kernel over the live blocks with
+    the cached mask beside them (dead columns NaN)."""
+    pos0 = jnp.asarray(pos0, jnp.int32)
+    _, hi = dense_attention.live_range(pos0, pos0, 0, EXTENT)
+    keep = (jnp.asarray(keep_c), jnp.asarray(keep_o))
+    q, win, cur = local["q"], local["win"], local["cur"]
+    want = xing._kept_attention(q, cur, cache_a[li], win, None, keep, RANK)
+    got = jax.jit(lambda read, q: xing._kept_attention(
+        q, cur, None, win,
+        (read, jnp.int32(li), la.plan_blocks(pos0, extent=EXTENT)), keep,
+        RANK))(poisoned(cache_a, hi), q)
+    return (np.asarray(want.astype(jnp.float32)),
+            np.asarray(got.astype(jnp.float32)))
+
+
+@pytest.mark.parametrize("dtype,tol", [(jnp.float32, F32),
+                                       (jnp.bfloat16, BF16)],
+                         ids=["f32", "bf16"])
+@pytest.mark.parametrize("share", [0.03, 0.5, 1.0],
+                         ids=["sparse", "half", "all"])
+def test_kernel_route_under_a_mask_equals_the_xla_route(share, dtype, tol):
+    """Three slots (one past a block's edge, one a few columns long, one
+    free), a random share of each slot's live columns kept (sparse
+    enough that whole blocks hold none), the dispatch's own rows kept
+    in part."""
+    rng = np.random.default_rng(int(share * 100))
+    lens = [2 * BLK + 5, 7, EXTENT]
+    cache_a, local = state(3, 3, dtype)
+    live = np.arange(EXTENT)[None, :] < np.asarray(lens)[:, None]
+    keep_c = live & (rng.random((3, EXTENT)) < share)
+    keep_c[:2, 0] = True            # a query always keeps something
+    keep_o = np.zeros((3, W + 1), bool)
+    keep_o[:, :3] = rng.random((3, 3)) < 0.5
+    keep_o[:, -1] = True
+    want, got = kept_routes(cache_a, local, lens, 3, keep_c, keep_o)
+    assert np.isfinite(got[:2]).all()
+    assert np.abs(got[:2] - want[:2]).max() < tol
+
+
+def test_a_slot_whose_cached_columns_are_all_dropped_reads_its_own_rows():
+    """`keep` drops every cached column of a slot: the kernel's partial
+    carries no mass and the output is the softmax over the dispatch's
+    own rows alone."""
+    cache_a, local = state(5, 2, jnp.float32)
+    lens = [BLK + 3, 40]
+    keep_c = np.zeros((2, EXTENT), bool)
+    keep_c[1, :40] = True
+    keep_o = np.ones((2, W + 1), bool)
+    keep_o[:, 3:-1] = False
+    want, got = kept_routes(cache_a, local, lens, 3, keep_c, keep_o)
+    assert np.abs(got - want).max() < F32
+    alone = xing._kept_attention(
+        local["q"], local["cur"], jnp.zeros_like(cache_a[1]), local["win"],
+        None, (jnp.zeros((2, EXTENT), bool), jnp.asarray(keep_o)), RANK)
+    assert np.abs(got[0] - np.asarray(alone)[0]).max() < F32

@@ -14,7 +14,10 @@ weights made from a seed:
   live blocks (at EvaByte's widths) against ``joint_attention`` over
   whole pieces, the dense and the latent decode kernels over live
   blocks (at the served cells' shapes, timed at each block width
-  tried), and ``int4_matmul``. Then one short
+  tried), the latent admission kernel against ``piece_attention``'s
+  XLA rounds (a wave of 2 x 2,048 queries at both MLA cells' shapes,
+  GLM's under a selection's mask: ms a round on either route), and
+  ``int4_matmul``. Then one short
   paged ``GenerationEngine(kv_kernel="auto")`` run, depth cut to
   ``KERNEL_PHASE_LAYERS``, whose route must resolve to ``"kernel"``.
 * **serve** — ``python -m copilot_for_consensus_tpu serve`` with the
@@ -131,6 +134,105 @@ def _bring_up(rehearse: bool) -> dict:
 # ---------------------------------------------------------------------------
 # child: kernels phase
 # ---------------------------------------------------------------------------
+
+
+def admission_attention_rates(rehearse: bool, compare) -> dict:
+    """The admission kernel (``ops/latent_prefill_attention.py``)
+    against ``models/xing.py:piece_attention``'s XLA rounds at both
+    served cells' shapes: a wave of 2 x 2,048 queries (32 heads of 192
+    / 128; 64 heads of 256 / 256 under a mask that keeps 2,048 columns
+    a query) whose piece ends 4, 8 and 15 / 23 live rounds into its
+    slots' latent caches, the expansion of each round included on both
+    routes. → a cell and a number of rounds: agreement (``compare``),
+    milliseconds a round on either route, and the share of the MXU's
+    peak that the kernel route's time stands for, counted over the
+    (query, column) pairs attention needs: the seen ones, under a mask
+    or not, since the program scores them all."""
+    from unittest import mock
+
+    import jax
+    import jax.numpy as jnp
+
+    from copilot_for_consensus_tpu.models import xing
+    from copilot_for_consensus_tpu.models.configs import decoder_config
+    from copilot_for_consensus_tpu.ops import latent_prefill_attention
+
+    on_tpu = jax.default_backend() == "tpu"
+    dtype = jnp.bfloat16 if on_tpu else jnp.float32
+    blk = xing.KV_BLOCK
+    cells = {
+        "xing": (decoder_config("tiny-xing"), dict(
+            kv_lora_rank=512, qk_nope_head_dim=128, qk_rope_head_dim=64,
+            v_head_dim=128, n_heads=32), 16384, (4, 8, 15), 0),
+        "glm": (decoder_config("tiny-glm"), dict(
+            kv_lora_rank=512, qk_nope_head_dim=192, qk_rope_head_dim=64,
+            v_head_dim=256, n_heads=64), 32768, (4, 8, 23), 2048)}
+    n, s = (2, 64) if rehearse else (2, 2048)
+    rates: dict = {"tiles": [latent_prefill_attention.TQ,
+                             latent_prefill_attention.TK]}
+    for cell, (cfg, widths, extent, rounds, topk) in cells.items():
+        if rehearse:
+            extent, rounds, topk = 4 * blk, (2, 4), 24 * bool(topk)
+        else:
+            cfg = dataclasses.replace(cfg, **widths)
+        h, dk, dv = cfg.n_heads, cfg.qk_nope_head_dim \
+            + cfg.qk_rope_head_dim, cfg.v_head_dim
+        keys = jax.random.split(jax.random.PRNGKey(38), 4)
+        cache_a = jax.random.normal(
+            keys[0], (1, 4, xing.latent_width(cfg), extent), dtype)
+        layer = {"wkv_b": (jax.random.normal(
+            keys[1], (cfg.kv_lora_rank, h * (cfg.qk_nope_head_dim + dv)),
+            jnp.float32) * cfg.kv_lora_rank ** -0.5).astype(dtype)}
+        q = (jax.random.normal(keys[2], (n, s, h, dk), jnp.float32)
+             * dk ** -0.5).astype(dtype)
+        slots = jnp.asarray([2, 0], jnp.int32)
+        chance = jax.random.uniform(keys[3], (n, s, extent))
+
+        def piece(q, cache_a, chance, pos0, kernel, topk=topk, cfg=cfg,
+                  layer=layer, slots=slots):
+            q_pos = pos0 + jnp.arange(s)[None, :] + jnp.zeros((n, 1),
+                                                              jnp.int32)
+            kv_len = jnp.full((n,), pos0 + s, jnp.int32)
+            keep = None
+            if topk:
+                # each query keeps about topk of the columns it sees
+                kept = xing._seen(jnp.arange(extent), q_pos, kv_len) \
+                    & (chance * (q_pos[..., None] + 1) < topk)
+                keep = lambda j: jax.lax.dynamic_slice(  # noqa: E731
+                    kept, (0, 0, j * blk), (n, s, blk))
+            # the route is read when the program is traced
+            with mock.patch.object(latent_prefill_attention, "serves",
+                                   lambda block: kernel):
+                return xing.piece_attention(
+                    q, cache_a, jnp.int32(0), slots, q_pos, kv_len,
+                    (pos0 + s + blk - 1) // blk, layer, cfg, keep)
+
+        routes = {name: jax.jit(functools.partial(piece, kernel=kernel))
+                  for name, kernel in (("xla", False), ("kernel", True))}
+        rates[cell] = {}
+        for live in rounds:
+            pos0 = jnp.int32(live * blk - s)
+            ms = {}
+            for name, fn in routes.items():
+                out = jax.block_until_ready(fn(q, cache_a, chance, pos0))
+                best = float("inf")
+                for _ in range(1 if rehearse else 5):
+                    t = time.perf_counter()
+                    jax.block_until_ready(fn(q, cache_a, chance, pos0))
+                    best = min(best, time.perf_counter() - t)
+                ms[name] = best * 1e3
+                if name == "xla":
+                    want = out
+            compare(f"mla_prefill_attention/{cell}/rounds={live}", out, want)
+            pairs = n * (s * (live * blk - s) + s * (s + 1) // 2)
+            rates[cell][f"rounds_{live}"] = {
+                "xla_ms_per_round": round(ms["xla"] / live, 4),
+                "kernel_ms_per_round": round(ms["kernel"] / live, 4),
+                "kernel_mxu_share": round(
+                    2 * pairs * h * (dk + dv) / (ms["kernel"] * 1e-3)
+                    / 197e12, 4)}
+        del cache_a, chance
+    return rates
 
 
 def phase_kernels(rehearse: bool) -> int:
@@ -592,6 +694,12 @@ def phase_kernels(rehearse: bool) -> int:
         f"attention on either route): {kept_rates}")
     del glat, gidx
 
+    # -- admission attention over latents (attention="mla" on a TPU,
+    # ops/latent_prefill_attention.py): a round's scores stay in VMEM --
+    prefill_rates = admission_attention_rates(rehearse, compare)
+    say(f"latent admission attention, a wave of 2 x 2,048 queries: "
+        f"{prefill_rates}")
+
     # -- int4 matmul (what quantize="int4" routes to) ------------------
     for name, (din, dout) in (("up", (cfg.d_model, cfg.d_ff)),
                               ("down", (cfg.d_ff, cfg.d_model))):
@@ -642,6 +750,7 @@ def phase_kernels(rehearse: bool) -> int:
          dense_decode_attention=dense_rates,
          mla_decode_attention=latent_rates,
          selected_latent_attention=kept_rates,
+         mla_prefill_attention=prefill_rates,
          seconds=round(time.monotonic() - t0, 1))
     return 0
 

@@ -88,7 +88,11 @@ from copilot_for_consensus_tpu.models.quant import (
     quantize_tensor,
 )
 from copilot_for_consensus_tpu.obs.profile import scope
-from copilot_for_consensus_tpu.ops import latent_attention, sparse_select
+from copilot_for_consensus_tpu.ops import (
+    latent_attention,
+    latent_prefill_attention,
+    sparse_select,
+)
 from copilot_for_consensus_tpu.ops.attention import (
     combine_partials,
     decode_attention_prefix_window,
@@ -489,10 +493,18 @@ def piece_attention(q: jax.Array, cache_a: jax.Array, li: jax.Array,
     (a selection: ``select_piece``) → which of block j's columns each
     query reads ``[n, S, blk]``, causal and below ``kv_len`` among its
     conditions; without it every column a query sees. → ``[n, S, H
-    dv]`` in q's type."""
+    dv]`` in q's type.
+
+    Two routes to the same fold. On a TPU a round's scores stay on the
+    chip: ``ops/latent_prefill_attention.py`` folds the round into the
+    carry a tile at a time and makes the causal mask itself. Elsewhere
+    the XLA rounds below, which the tests hold the kernel to."""
     n, s, h, _ = q.shape
     blk = min(KV_BLOCK, cache_a.shape[3])
     dv = cfg.v_head_dim
+    if latent_prefill_attention.serves(blk):
+        return _piece_attention_kernel(q, cache_a, li, slots, q_pos, kv_len,
+                                       n_blocks, layer, cfg, keep)
 
     def fold(j, carry):
         acc, m, l = carry
@@ -526,6 +538,40 @@ def piece_attention(q: jax.Array, cache_a: jax.Array, li: jax.Array,
     with scope("attn"):
         o = acc / jnp.where(l > 0, l, 1.0)
         return o.transpose(0, 2, 1, 3).reshape(n, s, h * dv).astype(q.dtype)
+
+
+def _piece_attention_kernel(q, cache_a, li, slots, q_pos, kv_len, n_blocks,
+                            layer, cfg, keep):
+    """``piece_attention`` with each round folded by the kernel: the
+    same walk of the live rounds, the same expansion a round, heads
+    before positions as the kernel's tiles want them."""
+    n, s, h, _ = q.shape
+    blk = min(KV_BLOCK, cache_a.shape[3])
+    heads_first = lambda a: a.transpose(0, 2, 1, 3)  # noqa: E731
+    with scope("attn"):
+        plan = latent_prefill_attention.plan_queries(q_pos, kv_len)
+        q = heads_first(q)
+
+    def fold(j, carry):
+        with scope("kv_prefix"):
+            k, v = expand(_block_rows(cache_a, li, slots, j, blk), layer,
+                          cfg)
+            with scope("latent_expand"):
+                k, v = heads_first(k.astype(q.dtype)), \
+                    heads_first(v.astype(q.dtype))
+        with scope("attn"):
+            seen = None
+            if keep is not None:
+                with scope("select"):
+                    seen = keep(j)
+            return latent_prefill_attention.fold_round(
+                q, k, v, carry, j * blk, plan, seen)
+
+    carry = jax.lax.fori_loop(
+        0, n_blocks, fold,
+        latent_prefill_attention.empty_carry(n, h, s, cfg.v_head_dim))
+    with scope("attn"):
+        return latent_prefill_attention.finish(carry, q.dtype)
 
 
 def absorbed_attention(q_n: jax.Array, q_r: jax.Array, cur: jax.Array,

@@ -16,11 +16,12 @@ import numpy as np
 import pytest
 
 from copilot_for_consensus_tpu.engine.generation import GenerationEngine
-from copilot_for_consensus_tpu.models import decoder
+from copilot_for_consensus_tpu.models import decoder, xing
 from copilot_for_consensus_tpu.models.configs import (
     DecoderConfig,
     decoder_config,
 )
+from copilot_for_consensus_tpu.ops import latent_prefill_attention
 
 
 def merge_window_by_column(cache, k_win, v_win, positions0, steps):
@@ -618,12 +619,15 @@ def mla_program_faults(one_chip, which, cfg=None):
     """What a compiled latent-attention program may not hold: an op
     whose result is a stack of the cache, or one layer of it, other
     than the in-place updates; a float32 array of a score for every
-    head and cached column of every slot (the decode program alone:
-    what scoring the extent whole in XLA makes); on the kernel route,
-    any number of kernels but the three grouped matmuls of the one
-    scanned expert layer and, in the decode program, the absorbed
-    attention's one a stack of layers (`MLA_KERNELS`), or an op that
-    makes a stack of experts or one layer's."""
+    head and cached column of every slot (the decode program: what
+    scoring the extent whole in XLA makes) or for every head, query and
+    column of an admission round (the admission program: what folding
+    a round in XLA makes, `ADMIT`); on the kernel route, any number of
+    kernels but the three grouped matmuls of the one scanned expert
+    layer and the attention's one a stack of layers, absorbed in the
+    decode program, a round's fold in the admission program
+    (`MLA_KERNELS`), or an op that makes a stack of experts or one
+    layer's."""
     cfg = cfg or MLA_CFG
     eng = GenerationEngine(cfg, num_slots=SLOTS, max_len=MLA_MAX,
                            prefill_buckets=(64,), dtype=jnp.bfloat16,
@@ -640,12 +644,13 @@ def mla_program_faults(one_chip, which, cfg=None):
         text = eng._decode_mla_fn.lower(params, i32, i32, cache,
                                         key).compile().as_text()
     else:
-        two = wrap(jax.ShapeDtypeStruct((2,), jnp.int32))
+        two = wrap(jax.ShapeDtypeStruct((ADMIT[0],), jnp.int32))
         text = eng._admit_mla_fn.lower(
-            params, wrap(jax.ShapeDtypeStruct((2, 64), jnp.int32)), two,
+            params, wrap(jax.ShapeDtypeStruct(ADMIT, jnp.int32)), two,
             two, two, cache, key).compile().as_text()
     stacks_ = {tuple(a.shape) for a in eng._cache.values()}
     layers = {s[1:] for s in stacks_} | {(1,) + s[1:] for s in stacks_}
+    round_ = min(xing.KV_BLOCK, MLA_MAX)     # columns an admission round scores
     # one layer's experts (the stack whole may be laid out anew once a
     # dispatch, outside the loops)
     experts = {lead + tuple(eng.params["moe"][k]["q"].shape[1:])
@@ -692,6 +697,16 @@ def mla_program_faults(one_chip, which, cfg=None):
         if which == "decode" and " = f32[" in line and MLA_MAX in shape \
                 and math.prod(shape) >= SLOTS * cfg.n_heads * MLA_MAX:
             faults.append(f"{m.group(3)} makes scores {shape}")
+        if which == "admit" and " = f32[" in line and round_ in shape \
+                and math.prod(shape) >= math.prod(ADMIT) * cfg.n_heads \
+                * round_:
+            faults.append(f"{m.group(3)} makes scores {shape}")
+        # the fold's empty carry as a literal: megabytes of every
+        # admission program's binary, read again at every load
+        if which == "admit" and m.group(3) == "constant" \
+                and math.prod(shape) >= math.prod(ADMIT) * cfg.n_heads \
+                * latent_prefill_attention.STAT_ROWS:
+            faults.append(f"constant holds a carry {shape}")
     kernels = [ln for ln in text.splitlines()
                if 'custom_call_target="tpu_custom_call"' in ln]
     return faults, kernels, text
@@ -702,17 +717,23 @@ def mla_program_faults(one_chip, which, cfg=None):
 # expert layer in both; in decode also the absorbed attention over the
 # live latent blocks, one call site a stack of layers (the dense stack's
 # one layer and the expert stack's loop: 1 + 11 launches a token at the
-# served depth)
-MLA_KERNELS = {"admit": ["moe_experts/grouped_qmatmul"] * 3,
-               "decode": ["attn/mla_decode_attention"] * 2
-               + ["moe_experts/grouped_qmatmul"] * 3}
+# served depth); in admission the fold of a round of expanded attention
+# (ops/latent_prefill_attention.py), one call site a stack of layers
+# too, inside the loop over the live rounds
+GROUPED = ["moe_experts/grouped_qmatmul"] * 3
+MLA_KERNELS = {"admit": ["attn/mla_prefill_attention"] * 2 + GROUPED,
+               "decode": ["attn/mla_decode_attention"] * 2 + GROUPED}
+
+# an admission wave of the compiled programs: rows x bucket
+ADMIT = (2, 64)
 
 
 def mla_kernel_names(kernels):
     return sorted(
         "/".join(re.search(
             r'op_name="[^"]*/(attn|moe_experts)/(?:[^"]*/)?'
-            r'(mla_decode_attention|grouped_qmatmul)', k).groups())
+            r'(mla_decode_attention|mla_prefill_attention|grouped_qmatmul)',
+            k).groups())
         for k in kernels)
 
 
@@ -758,8 +779,40 @@ def test_mla_guard_trips_when_the_extent_is_scored_whole_in_xla(
 
     monkeypatch.setattr(latent_attention, "serves", lambda extent: False)
     faults, kernels, _text = mla_program_faults(one_chip, "decode")
-    assert mla_kernel_names(kernels) == MLA_KERNELS["admit"]
+    assert mla_kernel_names(kernels) == GROUPED
     assert any("makes scores" in f for f in faults)
+
+
+@pytest.mark.parametrize("cfg", [MLA_CFG, GLM_CFG], ids=["xing", "glm"])
+def test_mla_guard_trips_when_a_round_is_folded_in_xla(
+        one_chip, on_tpu, monkeypatch, cfg):
+    """The formulation this replaced, and the CPU's route: a round's
+    float32 scores `[rows, heads, bucket, round]` made, masked, and read
+    again for the maximum, the exponentials and the sums."""
+    monkeypatch.setattr(latent_prefill_attention, "serves",
+                        lambda block: False)
+    faults, kernels, _text = mla_program_faults(one_chip, "admit", cfg)
+    assert mla_kernel_names(kernels) == GROUPED
+    assert any("makes scores" in f for f in faults)
+    assert not any("makes cache rows" in f or "makes experts" in f
+                   for f in faults)
+
+
+def test_mla_guard_trips_when_the_empty_carry_is_a_literal(
+        one_chip, on_tpu, monkeypatch):
+    """Zeros with a row set to ``-inf`` are folded into a literal of the
+    whole array (8.4 MB a call site at GLM's served shape: PERF.md §6,
+    PR 38); from an iota the compiler makes them where they are used."""
+
+    def folded(n, h, s, dv):
+        return jnp.zeros((n, h, s, dv), jnp.float32), jnp.zeros(
+            (n, h, latent_prefill_attention.STAT_ROWS, s),
+            jnp.float32).at[:, :, 0].set(-jnp.inf)
+
+    monkeypatch.setattr(latent_prefill_attention, "empty_carry", folded)
+    faults, kernels, _text = mla_program_faults(one_chip, "admit")
+    assert mla_kernel_names(kernels) == MLA_KERNELS["admit"]
+    assert faults and all("constant holds a carry" in f for f in faults)
 
 
 def test_mla_guard_trips_when_the_layer_scan_cuts_the_experts_out(
@@ -834,4 +887,5 @@ def test_the_other_configurations_programs_hold_nothing_of_this_one(
     assert "attn" in under and "ffn" in under    # the scopes are there
     assert not under & (set(XING_SCOPES) - set(SCOPES))
     assert not any("grouped_qmatmul" in name
-                   or "mla_decode_attention" in name for name in names)
+                   or "mla_decode_attention" in name
+                   or "mla_prefill_attention" in name for name in names)

@@ -517,3 +517,56 @@ def test_a_config_that_is_no_share_or_no_indexer_is_refused(bad):
     with pytest.raises(ValueError, match="held_experts|index_topk"):
         GenerationEngine(cfg, None, num_slots=2, max_len=MAX_LEN,
                          prefill_buckets=BUCKETS, dtype=jnp.float32)
+
+
+def test_an_admission_of_several_pieces_through_the_kernel_is_the_xla_routes(
+        params, monkeypatch):
+    """A prompt of 100 tokens admitted in pieces of 32 over rounds of
+    16, on the admission kernel's route (ops/latent_prefill_attention.py:
+    the selection's mask one more operand) and on the XLA rounds': the
+    same chosen sets in every layer of every piece, the same last
+    logits, both caches."""
+    from copilot_for_consensus_tpu.ops import latent_prefill_attention
+
+    seq = tokens(100, seed=9)
+    real_attn = xing.piece_attention
+
+    def admitted(kernel):
+        monkeypatch.setattr(latent_prefill_attention, "serves",
+                            lambda block: kernel)
+        taken = []
+
+        def attn(q, cache_a, li, slots, q_pos, kv_len, n_blocks, layer, cfg,
+                 keep=None):
+            sel = jnp.concatenate(
+                [keep(j) for j in range(MAX_LEN // xing.KV_BLOCK)], axis=-1)
+            jax.debug.callback(lambda a: taken.append(np.asarray(a)), sel,
+                               ordered=True)
+            return real_attn(q, cache_a, li, slots, q_pos, kv_len, n_blocks,
+                             layer, cfg, keep)
+
+        monkeypatch.setattr(xing, "piece_attention", attn)
+        fn = piece_fn(CFG)
+        text = str(jax.make_jaxpr(fn)(
+            params, jnp.zeros((1, 32), jnp.int32), jnp.ones((1,), jnp.int32),
+            jnp.zeros((1,), jnp.int32), jnp.zeros((1,), jnp.int32),
+            xing.init_cache(CFG, 2, MAX_LEN, jnp.float32)))
+        assert ("mla_prefill_attention" in text) == kernel
+        taken.clear()
+        logits, cache = prefill(fn, params,
+                                xing.init_cache(CFG, 2, MAX_LEN, jnp.float32),
+                                1, seq)
+        jax.effects_barrier()
+        return logits, cache, taken
+
+    (want, cache_x, sets_x), (got, cache_k, sets_k) = (
+        admitted(False), admitted(True))
+    assert len(sets_x) == len(sets_k) == 4 * CFG.n_layers
+    for a, b in zip(sets_x, sets_k):
+        assert np.array_equal(a, b)
+    # past the first pieces most queries really select
+    assert sets_k[-1][0, :4].sum(-1).tolist() == [TOPK] * 4
+    assert np.abs(got - want).max() < TOL
+    for name in cache_x:
+        assert np.abs(np.asarray(cache_k[name][:, 1, :, :100])
+                      - np.asarray(cache_x[name][:, 1, :, :100])).max() < TOL

@@ -80,7 +80,7 @@ def decode_fn(params, tok, pos, cache):
         steps=STEPS, max_len=MAX_LEN, with_logits=True)
 
 
-def prefill(params, cache, rows, piece=32):
+def prefill(params, cache, rows, piece=32, piece_fn=piece_fn):
     """Admit ``rows`` ``[(slot, seq)]`` together, a piece of at most
     ``piece`` a wave (what the engine's admission does). Returns each
     row's logits after its last token, the cache, the summed counts."""
@@ -484,3 +484,40 @@ def test_the_kernel_route_serves_the_references_tokens_and_counts_its_blocks(
     monkeypatch.setattr(latent_attention, "serves", lambda extent: False)
     assert not eng._reads_latent_blocks()
     assert eng._latent_read(STEPS) == STEPS * 4 * max_len
+
+
+def test_an_admission_of_several_pieces_through_the_kernel_is_the_xla_routes(
+        params, monkeypatch):
+    """Two prompts admitted together in pieces of 32 over rounds of 16
+    (three waves; the shorter row ends in the second, in mid-piece),
+    on the admission kernel's route (ops/latent_prefill_attention.py)
+    and on the XLA rounds': each row's last logits, the latent rows
+    written, the routing's counts, and the reference's logits."""
+    from copilot_for_consensus_tpu.ops import latent_prefill_attention
+
+    rows = [(2, tokens(90, seed=4)), (0, tokens(37, seed=5))]
+
+    def admitted(kernel):
+        monkeypatch.setattr(latent_prefill_attention, "serves",
+                            lambda block: kernel)
+        # a jit of its own: the route is read when the program is traced
+        fn = jax.jit(lambda *a: xing.prefill_piece(*a[:5], CFG, a[5]))
+        text = str(jax.make_jaxpr(fn)(
+            params, jnp.zeros((2, 32), jnp.int32), jnp.ones((2,), jnp.int32),
+            jnp.zeros((2,), jnp.int32), jnp.arange(2),
+            xing.init_cache(CFG, 4, MAX_LEN, jnp.float32)))
+        assert ("mla_prefill_attention" in text) == kernel
+        return prefill(params, xing.init_cache(CFG, 4, MAX_LEN, jnp.float32),
+                       rows, piece_fn=fn)
+
+    (want, cache_x, counts_x), (got, cache_k, counts_k) = (
+        admitted(False), admitted(True))
+    assert counts_x.tolist() == counts_k.tolist()
+    for (slot, seq), w, g in zip(rows, want, got):
+        assert np.abs(g - w).max() < TOL
+        assert np.abs(g - ref.logits_at(params, DIMS, seq.tolist(),
+                                        [len(seq) - 1])[0]).max() < TOL
+        for name in cache_x:
+            assert np.abs(np.asarray(cache_k[name][:, slot, :, :len(seq)])
+                          - np.asarray(cache_x[name][:, slot, :, :len(seq)])
+                          ).max() < TOL

@@ -16,8 +16,11 @@ weights made from a seed:
   blocks (at the served cells' shapes, timed at each block width
   tried), the latent admission kernel against ``piece_attention``'s
   XLA rounds (a wave of 2 x 2,048 queries at both MLA cells' shapes,
-  GLM's under a selection's mask: ms a round on either route), and
-  ``int4_matmul``. Then one short
+  GLM's under a selection's mask: ms a round on either route), the
+  experts' grouped int8 matmul against ``ragged_dot`` (a decode step's
+  pairs and both MLA cells' admission waves: ms a matmul under the
+  served tiling beside the deep-k one that served them until PR 42),
+  and ``int4_matmul``. Then one short
   paged ``GenerationEngine(kv_kernel="auto")`` run, depth cut to
   ``KERNEL_PHASE_LAYERS``, whose route must resolve to ``"kernel"``.
 * **serve** — ``python -m copilot_for_consensus_tpu serve`` with the
@@ -235,6 +238,104 @@ def admission_attention_rates(rehearse: bool, compare) -> dict:
     return rates
 
 
+def grouped_matmul_rates(rehearse: bool, compare) -> dict:
+    """The experts' grouped int8 matmul (``ops/grouped_matmul.py``) at
+    the served shapes, against XLA's ``ragged_dot`` over the
+    dequantized layer (``compare``) and TIMED: an admission wave of one
+    row of 256 tokens and of 1 and 2 x 2,048 (Xing4.0: 1,024, 8,192 and
+    16,384 pairs, all over 64 experts of 1024 x 3584 and, the down
+    matrix, 3584 x 1024; GLM-5: 16,384 and 32,768 pairs, an eighth of
+    them over the 32 held experts of 6144 x 2048 and 2048 x 6144, the
+    others of no group) and a decode step's 32 pairs; two layers' stack
+    read at layer 1, an expert nobody chose among them. → a shape and a
+    tiling (``served``; ``deep``, which served every shape until PR 42
+    and is left out where the served tiles ARE the deep ones, a decode
+    step's): milliseconds a matmul (the metadata's few XLA ops
+    included), the share of the MXU's peak that stands for over the
+    pairs that exist, the share of 819 GB/s over the touched experts'
+    bytes, and the fill (pairs over visits x row tile)."""
+    from unittest import mock
+
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from copilot_for_consensus_tpu.ops import grouped_matmul
+
+    on_tpu = jax.default_backend() == "tpu"
+    dtype = jnp.bfloat16 if on_tpu else jnp.float32
+    rng = np.random.default_rng(42)
+    # experts, one pair in how many is of a held one, [k, n] of the
+    # gate / up matrices and of the down matrix, the waves' pairs
+    waves = {"xing": (8, 1, (256, 128), (128, 256), (128, 256)),
+             "glm": (8, 8, (256, 128), (128, 256), (256, 512))} \
+        if rehearse else {
+            "xing": (64, 1, (1024, 3584), (3584, 1024),
+                     (1024, 8192, 16384)),
+            "glm": (32, 8, (6144, 2048), (2048, 6144), (16384, 32768))}
+
+    def timed(fn, *args) -> float:
+        best = float("inf")
+        for _ in range(1 if rehearse else 5):
+            t = time.perf_counter()
+            for _ in range(8):
+                out = fn(*args)
+            jax.block_until_ready(out)
+            best = min(best, (time.perf_counter() - t) / 8)
+        return 1e3 * best
+
+    rates: dict = {}
+    for cell, (ge, held_of, up, down, ms_) in waves.items():
+        # a decode step's pairs meet the first matrix, once
+        for (gk, gn), gms in ((up, (32, *ms_) if cell == "xing" else ms_),
+                              (down, ms_)):
+            gq = jnp.asarray(rng.integers(-127, 128, (2, ge, gk, gn)),
+                             jnp.int8)
+            gscale = jnp.asarray(rng.uniform(0.5, 1.5, (2, ge, 1, gn))
+                                 * gk ** -0.5 / 73.3, jnp.float32)
+            layer = (gq[1].astype(jnp.float32) * gscale[1]).astype(dtype)
+            for gm in gms:
+                # a wave's pairs of held experts; a decode step's, an
+                # eighth of its slots idle
+                pairs = gm // held_of if gm > 64 else gm - gm // 8
+                share = rng.multinomial(pairs, np.ones(ge) / ge)
+                share[1] = 0                  # an expert nobody chose
+                sizes = jnp.asarray(share, jnp.int32)
+                used = int(share.sum())
+                lhs = jnp.asarray(rng.standard_normal((gm, gk)), dtype)
+                ref = jax.lax.ragged_dot(
+                    lhs, layer, sizes, preferred_element_type=jnp.float32)
+                tried = {"served": grouped_matmul.tiling(gm, ge, gk, gn)}
+                deep = grouped_matmul.deep_tiling(gm, gk, gn)
+                if deep != tried["served"]:
+                    tried["deep"] = deep
+                at = rates.setdefault(f"{cell}/{gk}x{gn}/m={gm}", {})
+                for name, tiles in tried.items():
+                    # the tiles are read when the program is traced
+                    with mock.patch.object(grouped_matmul, "tiling",
+                                           lambda *_a, t=tiles: t):
+                        fn = jax.jit(functools.partial(
+                            grouped_matmul.grouped_qmatmul.__wrapped__,
+                            interpret=not on_tpu))
+                        got = fn(lhs, gq, gscale, sizes, jnp.int32(1))
+                        kept, mult = (int(c) for c in
+                                      grouped_matmul.tile_counts(
+                                          sizes, gm, gk, gn))
+                    compare(f"grouped_qmatmul/{cell}/{gk}x{gn}/m={gm}"
+                            f"/{name}", got[:used], ref[:used])
+                    ms = timed(fn, lhs, gq, gscale, sizes, jnp.int32(1))
+                    at[name] = {
+                        "tiles": list(tiles[:3]), "ms": round(ms, 4),
+                        "mxu_share": round(
+                            2 * used * gk * gn / (ms * 1e-3) / 197e12, 4),
+                        "hbm_share": round(
+                            int((share > 0).sum()) * gk * gn
+                            / (ms * 1e-3) / 819e9, 4),
+                        "fill": round(kept / mult, 4)}
+            del gq, gscale, layer
+    return rates
+
+
 def phase_kernels(rehearse: bool) -> int:
     t0 = time.monotonic()
     facts = _bring_up(rehearse)
@@ -362,29 +463,11 @@ def phase_kernels(rehearse: bool) -> int:
               jnp.arange(er)[None, :] >= er - sum_n[:, None])]))
 
     # -- grouped int8 matmul over sparse experts (attention="mla" on a
-    # TPU, ops/grouped_matmul.py), at the served widths: a decode
-    # step's 32 token-expert pairs and an admission wave's 16,384 over
-    # 64 experts of 3584 x 1024, two layers' stack read at layer 1,
-    # empty experts and rows of no expert among them; against XLA's
-    # ragged_dot over the dequantized layer ----------------------------
-    from copilot_for_consensus_tpu.ops.grouped_matmul import grouped_qmatmul
-
-    ge, gk, gn = (8, 256, 128) if rehearse else (64, 3584, 1024)
-    gq = jnp.asarray(rng.integers(-127, 128, (2, ge, gk, gn)), jnp.int8)
-    gscale = jnp.asarray(rng.uniform(0.5, 1.5, (2, ge, 1, gn))
-                         * gk ** -0.5 / 73.3, jnp.float32)
-    for gm in (32, 256 if rehearse else 16384):
-        share = rng.multinomial(gm - gm // 8, np.ones(ge) / ge)
-        share[1] = 0                      # an expert nobody chose
-        sizes = jnp.asarray(share, jnp.int32)
-        lhs = normal(gm, gk)
-        got = grouped_qmatmul(lhs, gq, gscale, sizes, jnp.int32(1),
-                              interpret=interpret)
-        ref = jax.lax.ragged_dot(
-            lhs, (gq[1].astype(jnp.float32) * gscale[1]).astype(dtype),
-            sizes, preferred_element_type=jnp.float32)
-        used = int(share.sum())
-        compare(f"grouped_qmatmul/m={gm}", got[:used], ref[:used])
+    # TPU, ops/grouped_matmul.py), at the served shapes of a decode
+    # step and of both MLA cells' admission waves: compared and timed --
+    grouped_rates = grouped_matmul_rates(rehearse, compare)
+    say(f"grouped int8 matmul over the experts, ms a matmul: "
+        f"{grouped_rates}")
 
     # -- dense decode attention over live blocks (the contiguous cache
     # on a TPU), at the served cells' shapes: 8 slots x 4096 columns of
@@ -751,6 +834,7 @@ def phase_kernels(rehearse: bool) -> int:
          mla_decode_attention=latent_rates,
          selected_latent_attention=kept_rates,
          mla_prefill_attention=prefill_rates,
+         grouped_qmatmul=grouped_rates,
          seconds=round(time.monotonic() - t0, 1))
     return 0
 

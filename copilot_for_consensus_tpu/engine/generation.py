@@ -250,9 +250,10 @@ def _selected(first: int, n: int, k: int) -> int:
 def _expert_counts(counts) -> dict:
     """A dispatch's routing counts (``xing.N_COUNTS``, summed over
     layers and steps on the device) as StepRecord fields."""
-    touched, rows, busiest = (int(c) for c in counts)
+    touched, rows, busiest, grouped, tiled = (int(c) for c in counts)
     return {"experts_touched": touched, "expert_rows": rows,
-            "expert_rows_max": busiest}
+            "expert_rows_max": busiest, "expert_group_rows": grouped,
+            "expert_tile_rows": tiled}
 
 
 class GenerationEngine:

@@ -361,6 +361,11 @@ class StepRecord:
     experts_touched: int = 0    # distinct experts chosen
     expert_rows: int = 0        # token x expert pairs
     expert_rows_max: int = 0    # the busiest expert's pairs
+    expert_group_rows: int = 0  # pairs of an expert held here: the
+    #                             rows the grouped matmul keeps
+    expert_tile_rows: int = 0   # the rows it multiplies for them:
+    #                             visits x row tile of one of a layer's
+    #                             three (ops/grouped_matmul.py)
     attn_pairs: int = 0         # admission waves: query-key pairs the
     #                             wave's real tokens attend to, each
     #                             over its sequence's whole prefix
@@ -623,6 +628,8 @@ class EngineTelemetry:
                     state_tokens_read: int = 0,
                     experts_touched: int = 0, expert_rows: int = 0,
                     expert_rows_max: int = 0,
+                    expert_group_rows: int = 0,
+                    expert_tile_rows: int = 0,
                     attn_pairs: int = 0, index_tokens_read: int = 0,
                     selected_tokens: int = 0,
                     live_tokens: int = 0) -> StepRecord:
@@ -642,7 +649,9 @@ class EngineTelemetry:
             window_tokens=window_tokens, summary_tokens=summary_tokens,
             state_tokens_read=state_tokens_read,
             experts_touched=experts_touched, expert_rows=expert_rows,
-            expert_rows_max=expert_rows_max, attn_pairs=attn_pairs,
+            expert_rows_max=expert_rows_max,
+            expert_group_rows=expert_group_rows,
+            expert_tile_rows=expert_tile_rows, attn_pairs=attn_pairs,
             index_tokens_read=index_tokens_read,
             selected_tokens=selected_tokens, live_tokens=live_tokens)
         self.recorder.record(rec)

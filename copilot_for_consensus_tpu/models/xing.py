@@ -98,7 +98,10 @@ from copilot_for_consensus_tpu.ops.attention import (
     decode_attention_prefix_window,
     decode_window_partial,
 )
-from copilot_for_consensus_tpu.ops.grouped_matmul import grouped_qmatmul
+from copilot_for_consensus_tpu.ops.grouped_matmul import (
+    grouped_qmatmul,
+    tile_counts,
+)
 
 Params = dict[str, Any]
 
@@ -133,9 +136,11 @@ INDEX_DOTS = 1 << 25
 #: layer's experts in place (``ops/grouped_matmul.py``)
 EXPERTS = ("we_gate", "we_up", "we_down")
 
-#: (experts touched, token-expert pairs, the busiest expert's pairs):
-#: what a layer's routing reports, summed over layers and steps
-N_COUNTS = 3
+#: (experts touched, token-expert pairs, the busiest expert's pairs;
+#: the pairs that have a group here, the rows the grouped matmul
+#: multiplies for them: ``ops/grouped_matmul.py:tile_counts``): what a
+#: layer's routing reports, summed over layers and steps
+N_COUNTS = 5
 
 
 # ---------------------------------------------------------------------------
@@ -848,7 +853,8 @@ def group_by_expert(idx: jax.Array, live: jax.Array, n_experts: int,
     pairs of other experts and of tokens that are not ``live`` go to
     the end and belong to no group. → (token of each sorted pair
     ``[T k]``, its group or ``count``, where each pair went, group
-    sizes ``[count]``, counts ``[N_COUNTS]`` over ALL experts)."""
+    sizes ``[count]``, the first three of ``N_COUNTS`` over ALL
+    experts)."""
     t, k = idx.shape
     flat = idx.reshape(-1)
     alive = jnp.repeat(live, k)
@@ -903,6 +909,8 @@ def routed_experts(hid: jax.Array, layer: Params, experts: Params,
         # a device that holds a share counts what it is asked for
         counts = jnp.stack([jnp.sum(sizes > 0), jnp.sum(sizes),
                             jnp.max(sizes)]).astype(jnp.int32)
+    counts = jnp.concatenate([counts, tile_counts(
+        sizes, idx.size, hid.shape[-1], cfg.moe_intermediate_size)])
     with scope("moe_experts"):
         hid = hid.astype(dtype or jnp.bfloat16)
         xs = hid[tok]

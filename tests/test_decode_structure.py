@@ -21,7 +21,10 @@ from copilot_for_consensus_tpu.models.configs import (
     DecoderConfig,
     decoder_config,
 )
-from copilot_for_consensus_tpu.ops import latent_prefill_attention
+from copilot_for_consensus_tpu.ops import (
+    grouped_matmul,
+    latent_prefill_attention,
+)
 
 
 def merge_window_by_column(cache, k_win, v_win, positions0, steps):
@@ -743,6 +746,42 @@ def test_compiled_mla_programs_hold_cache_and_experts_in_place(
     faults, kernels, _text = mla_program_faults(one_chip, which)
     assert faults == []
     assert mla_kernel_names(kernels) == MLA_KERNELS[which]
+    # the three grouped matmuls are call sites of ONE scanned body
+    bodies = {re.search(r'op_name="([^"]*)/moe_experts/', k).group(1)
+              for k in kernels if "grouped_qmatmul" in k}
+    assert len(bodies) == 1 and "/while/body/" in bodies.pop() + "/"
+
+
+# a decode step's grouped matmul at the served shapes (m, groups, k, n):
+# the tiles, the grid (the visits are counted on the device) and the
+# kernel's refs as they stood before an admission wave got tiles of its
+# own (PR 42), which no decode program was to notice
+DECODE_GROUPED = {
+    "xing-gate-up": ((32, 64, 1024, 3584), (32, 1024, 896), (4, None, 1)),
+    "xing-down": ((32, 64, 3584, 1024), (32, 1792, 1024), (1, None, 2)),
+    "glm-gate-up": ((64, 32, 6144, 2048), (64, 1024, 1024), (2, None, 6)),
+    "glm-down": ((64, 32, 2048, 6144), (64, 1024, 1024), (6, None, 2)),
+}
+
+
+@pytest.mark.parametrize("case", list(DECODE_GROUPED))
+def test_a_decode_steps_grouped_matmul_is_tiled_as_it_was(case):
+    (m, groups, k, n), (tm, tk, tn), grid = DECODE_GROUPED[case]
+    assert grouped_matmul.tiling(m, groups, k, n) == (tm, tk, tn, False)
+    shapes = (((m, k), jnp.bfloat16), ((2, groups, k, n), jnp.int8),
+              ((2, groups, 1, n), jnp.float32), ((groups,), jnp.int32),
+              ((), jnp.int32))
+    jaxpr = jax.make_jaxpr(functools.partial(
+        grouped_matmul.grouped_qmatmul.__wrapped__, interpret=False))(
+            *(jax.ShapeDtypeStruct(*s) for s in shapes))
+    (call,) = [e for e in jaxpr.eqns if e.primitive.name == "pallas_call"]
+    assert tuple(d if isinstance(d, int) else None
+                 for d in call.params["grid_mapping"].grid) == grid
+    # rows, the int8 tile, its scales, the product; the accumulator
+    refs = [v.aval for v in call.params["jaxpr"].invars[5:]]
+    assert [r.shape for r in refs] == [
+        (tm, tk), (tk, tn), (1, tn), (tm, tn), (tm, tn)]
+    assert refs[-1].dtype == jnp.float32
 
 
 # the same two programs for a config that SELECTS what attention reads

@@ -465,6 +465,10 @@ def test_the_engine_records_what_the_selection_read(engine):
             assert r.selected_tokens == sum(
                 min(n + t + 1, TOPK) for n in lens for t in range(STEPS))
     assert recs[-1].expert_rows > 0
+    # a share is held: the rows the grouped matmul keeps are the pairs
+    # counted, and a visit multiplies a whole tile for them
+    assert all(0 < r.expert_group_rows == r.expert_rows
+               <= r.expert_tile_rows for r in recs)
 
 
 def test_the_kernel_route_serves_the_references_tokens(params, monkeypatch):

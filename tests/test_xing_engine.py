@@ -24,6 +24,7 @@ from benchmark.reference import xing as ref
 from copilot_for_consensus_tpu.engine.generation import GenerationEngine
 from copilot_for_consensus_tpu.models import xing
 from copilot_for_consensus_tpu.models.configs import decoder_config
+from copilot_for_consensus_tpu.ops import grouped_matmul
 from copilot_for_consensus_tpu.ops.grouped_matmul import grouped_qmatmul
 
 TOL = 1e-4
@@ -259,15 +260,20 @@ def test_disjoint_shares_of_the_experts_add_up_to_the_uncut_layer(params):
     whole, counts = xing.ffn(hid, layer, experts, jnp.int32(1), CFG, live,
                              jnp.float32)
     shared = xing.L.swiglu(hid, layer).astype(jnp.float32)
-    parts = []
+    parts, kept = [], []
     for first in range(0, E, 2):
         held = {k: jax.tree.map(lambda a: a[:, first:first + 2], v)
                 for k, v in experts.items()}
         part, c = xing.routed_experts(
             hid[0], layer, held, jnp.int32(1), CFG, live[0],
             held=(first, 2), dtype=jnp.float32)
-        np.testing.assert_array_equal(np.asarray(c), np.asarray(counts))
+        # the routing is counted over all experts; the rows the grouped
+        # matmul keeps are this share's
+        np.testing.assert_array_equal(np.asarray(c[:3]),
+                                      np.asarray(counts[:3]))
+        kept.append(int(c[3]))
         parts.append(np.asarray(part))
+    assert sum(kept) == int(counts[3]) == int(counts[1])
     np.testing.assert_allclose(np.asarray(shared[0]) + sum(parts),
                                np.asarray(whole[0]), atol=1e-5)
     # and the reference's feed-forward part on the same input
@@ -283,25 +289,58 @@ def test_disjoint_shares_of_the_experts_add_up_to_the_uncut_layer(params):
                                atol=1e-4)
 
 
-def test_the_grouped_kernel_equals_ragged_dot():
+# m, groups' sizes, k, n, the row tile the shapes give, the layer read;
+# the rows past the sizes' sum belong to no group
+GROUPED_CASES = {
+    # 8 rows a group: the decode regime's one short m-tile, deep k
+    "a-handful-of-rows": (64, [10, 0, 7, 20, 0, 0, 3, 9], 256, 384, 64, 1),
+    # 32 rows a group and more: the row tile follows them
+    "a-group-of-exactly-a-tile": (128, [32, 32, 20, 10], 64, 128, 32, 1),
+    "a-group-across-several-tiles": (128, [5, 100, 3, 0], 64, 128, 32, 0),
+    "an-empty-group-between-two-full-ones":
+        (128, [32, 0, 64, 0], 64, 128, 32, 1),
+    "rows-of-no-group-at-the-end": (256, [10, 0, 15, 7], 64, 128, 64, 1),
+    "the-first-layer-of-the-stack": (128, [40, 30, 8, 50], 64, 128, 32, 0),
+    "several-n-tiles": (96, [20, 0, 41, 30], 192, 384, 16, 1),
+}
+
+
+@pytest.mark.parametrize("case", list(GROUPED_CASES))
+def test_the_grouped_kernel_equals_ragged_dot(case, monkeypatch):
     """ops/grouped_matmul.py (the TPU's route, here through the Pallas
-    interpreter) against jax.lax.ragged_dot over dequantized experts:
-    groups that are empty, rows that belong to no group, a layer read
-    out of the stack in place."""
+    interpreter) against jax.lax.ragged_dot over dequantized experts,
+    in both of its regimes: groups that are empty, that fill a tile to
+    the row and that span several, rows that belong to no group, a
+    layer read out of the stack in place by a traced index; and what it
+    says of its tiles against a recount."""
+    m, sizes, k, n, tm, li = GROUPED_CASES[case]
+    if case == "several-n-tiles":
+        # a block of 128 columns is all that fits (shapes no other
+        # case has: the jitted kernel is cached by them)
+        monkeypatch.setattr(grouped_matmul, "RESIDENT", k * 128)
+    tiles = grouped_matmul.tiling(m, len(sizes), k, n)
+    assert tiles.tm == tm and tiles.resident == (m // len(sizes) >= 16)
+    assert n // tiles.tn == (3 if case == "several-n-tiles" else
+                             n // 128 if not tiles.resident else 1)
     rng = np.random.default_rng(7)
-    m, k, n, groups = 64, 256, 384, 8
     lhs = jnp.asarray(rng.standard_normal((m, k)), jnp.bfloat16)
-    q = jnp.asarray(rng.integers(-127, 128, (2, groups, k, n)), jnp.int8)
-    scale = jnp.asarray(rng.uniform(0.005, 0.015, (2, groups, 1, n)),
+    q = jnp.asarray(rng.integers(-127, 128, (2, len(sizes), k, n)),
+                    jnp.int8)
+    scale = jnp.asarray(rng.uniform(0.005, 0.015, (2, len(sizes), 1, n)),
                         jnp.float32)
-    sizes = jnp.asarray([10, 0, 7, 20, 0, 0, 3, 9], jnp.int32)
-    got = grouped_qmatmul(lhs, q, scale, sizes, jnp.int32(1))
+    got = grouped_qmatmul(lhs, q, scale, jnp.asarray(sizes), jnp.int32(li))
     want = jax.lax.ragged_dot(
-        lhs.astype(jnp.float32), q[1].astype(jnp.float32) * scale[1],
-        sizes, precision="highest")
-    used = int(sizes.sum())
+        lhs.astype(jnp.float32), q[li].astype(jnp.float32) * scale[li],
+        jnp.asarray(sizes), precision="highest")
+    used = sum(sizes)
     np.testing.assert_allclose(np.asarray(got[:used]),
                                np.asarray(want[:used]), atol=1e-3)
+    # a visit for every m-tile a group has a row in, tm rows each
+    ends = np.cumsum(sizes)
+    visits = sum(-(-e // tm) - (e - size) // tm
+                 for e, size in zip(ends, sizes) if size)
+    assert grouped_matmul.tile_counts(jnp.asarray(sizes), m, k, n
+                                      ).tolist() == [used, visits * tm]
 
 
 # ---------------------------------------------------------------------------
@@ -408,7 +447,7 @@ def test_the_counts_of_a_dispatch_equal_a_numpy_recount(params):
             touched += (per > 0).sum()
             rows += per.sum()
             busiest += per.max()
-    assert got_d.tolist() == [touched, rows, busiest]
+    assert got_d[:3].tolist() == [touched, rows, busiest]
 
 
 def test_the_engine_records_the_counts_with_its_dispatches(engine):
@@ -424,6 +463,8 @@ def test_the_engine_records_the_counts_with_its_dispatches(engine):
             assert r.expert_rows == r.rows * STEPS * K * n_moe
             assert r.state_tokens_read == STEPS * 4 * MAX_LEN
         assert 0 < r.experts_touched <= r.expert_rows
+        # every expert is held here, and a visit multiplies a whole tile
+        assert r.expert_group_rows == r.expert_rows <= r.expert_tile_rows
         assert r.expert_rows_max * E >= r.expert_rows / (
             (STEPS if r.kind == "decode" else 1))
 

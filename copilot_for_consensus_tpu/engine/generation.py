@@ -62,6 +62,18 @@ DOMINANT LATENCY" in SURVEY.md §3.2). Design:
   step, refuses it at construction (``_check_mla``), for the index
   keys as for the latent rows.
 
+* **Two kinds of sequence state side by side** (``cfg.attention ==
+  "mixed"``, the ``cohere2_moe`` class; ``models/mixed.py``): layers
+  that attend within a sliding window keep a RING of ``sliding_window +
+  largest piece`` columns a slot, the layers that attend to everything
+  a full extent, one array per kind in the one cache dict; attention
+  and the experts (``models/xing.py``'s, shared) hang side by side on
+  one norm. Admission goes through the same ``_admit_pieces``, decode
+  is one program whatever the lengths (on a TPU each slot's live
+  blocks of either cache in place: ``_reads_ring_blocks``). What
+  assumes one cache layout for all layers refuses it at construction
+  (``_check_mixed``).
+
 The engine is synchronous and single-owner: services drive it through
 ``submit()`` + ``step()`` (or ``generate()`` for batch use) from their
 consumer thread, mirroring how the reference's summarization service owns
@@ -104,7 +116,13 @@ from copilot_for_consensus_tpu.engine.tokenizer import (
     Tokenizer,
 )
 from copilot_for_consensus_tpu.obs.profile import scope, step_annotation
-from copilot_for_consensus_tpu.models import decoder, eva, quant, xing
+from copilot_for_consensus_tpu.models import (
+    decoder,
+    eva,
+    mixed,
+    quant,
+    xing,
+)
 from copilot_for_consensus_tpu.models.configs import DecoderConfig
 from copilot_for_consensus_tpu.ops import dense_attention, latent_attention
 from copilot_for_consensus_tpu.ops.eva_attention import blocks_read
@@ -388,8 +406,18 @@ class GenerationEngine:
                 kv_pool_blocks=kv_pool_blocks, spec_decode=spec_decode,
                 kv_dtype=kv_dtype, quantize=quantize,
                 windows_per_dispatch=windows_per_dispatch))
+        #: window and global layers in one model (models/mixed.py): a
+        #: slot's state is a ring for the one kind and a full extent
+        #: for the other; admitted piece by piece as well
+        self._mixed = cfg.is_mixed
+        if self._mixed:
+            self._check_mixed(dict(
+                mesh=mesh, prefix_cache_blocks=prefix_cache_blocks,
+                kv_pool_blocks=kv_pool_blocks, spec_decode=spec_decode,
+                kv_dtype=kv_dtype, quantize=quantize,
+                windows_per_dispatch=windows_per_dispatch))
         #: admission advances every admitted prompt a piece a wave
-        self._pieces = self._eva or self._mla
+        self._pieces = self._eva or self._mla or self._mixed
         # eos_id may be a list (Llama-3.1-style multi-EOS checkpoints).
         eos_list = list(eos_id) if isinstance(eos_id, (list, tuple)) \
             else [int(eos_id)]
@@ -436,6 +464,12 @@ class GenerationEngine:
                                           dtype=dtype)
             if qmode:
                 params = xing.quantize_params(params)
+        if self._mixed:
+            if params is None:
+                params = mixed.init_params(jax.random.PRNGKey(seed), cfg,
+                                           dtype=dtype)
+            if qmode:
+                params = mixed.quantize_params(params)
         if params is None:
             if qmode:
                 params = quant.init_random_quantized(
@@ -448,7 +482,8 @@ class GenerationEngine:
             # yet; sharded engines fall back to the XLA dequant
             # expression, which partitions naturally over tp.
             quant.set_pallas_qmatmul(False)
-        if params is not None and qmode and not self._mla \
+        if params is not None and qmode \
+                and not (self._mla or self._mixed) \
                 and not quant.is_quantized(
                     params.get("layers", {}).get("wq")):
             # Caller provided full-precision weights: quantize on the fly.
@@ -659,6 +694,12 @@ class GenerationEngine:
             # every program and updated in place
             self._cache = xing.init_cache(cfg, num_slots, self.max_len,
                                           dtype=self.kv_dtype)
+        elif self._mixed:
+            # one array per layer KIND and half (models/mixed.py): a
+            # ring for the window layers, a full extent for the others
+            self._cache = mixed.init_cache(cfg, num_slots, self.max_len,
+                                           self.buckets[-1],
+                                           dtype=self.kv_dtype)
         else:
             cache = decoder.init_cache(cfg, num_slots, self.max_len,
                                        dtype=self.kv_dtype)
@@ -735,7 +776,7 @@ class GenerationEngine:
                     "pool and a dp-sharded slot cache would live on "
                     "different shards; the paged pool shards WITH its "
                     "per-shard tries")
-            if cfg.sliding_window and cfg.sliding_window < self.max_len:
+            if self._windowed():
                 raise ValueError(
                     "prefix_cache_blocks requires full attention: a "
                     "reused prefix under a sliding window needs "
@@ -924,7 +965,7 @@ class GenerationEngine:
         self.spec_ngram = int(spec_ngram)
         self.spec_min_ngram = int(spec_min_ngram)
         if self.spec_decode:
-            if cfg.sliding_window and cfg.sliding_window < self.max_len:
+            if self._windowed():
                 raise ValueError(
                     "spec_decode requires full attention: the verify "
                     "pass rides prefill_attention_seeded, which does "
@@ -991,9 +1032,7 @@ class GenerationEngine:
                                         telemetry=self.telemetry)
         # Chunking rides prefill_attention_seeded, which (like spec
         # decode) does not implement absolute-timeline window masking.
-        self._chunk_ok = not self._pieces and (
-            cfg.sliding_window == 0
-            or cfg.sliding_window >= self.max_len)
+        self._chunk_ok = not self._pieces and not self._windowed()
         ct = self.prompt_limit
         if self._sched is not None:
             ct = max(1, min(self._sched.cfg.chunk_tokens,
@@ -1091,6 +1130,28 @@ class GenerationEngine:
         if self._mla:
             self._admit_mla_fn = jax.jit(_admit_mla, donate_argnums=(5,))
             self._decode_mla_fn = jax.jit(_decode_mla, donate_argnums=(3,))
+
+        # ---- window and global layers (cfg.attention == "mixed") -------
+        # The same two programs for models/mixed.py, of the same form
+        # and with the same counts beside the tokens.
+
+        def _admit_mixed(params, tokens, lens, pos0, slots, cache, key):
+            logits, cache, counts = mixed.prefill_piece(
+                params, tokens, lens, pos0, slots, cfg, cache)
+            return sample(logits, key, self.sampling), cache, counts
+
+        def _decode_mixed(params, tokens, positions, cache, key):
+            return mixed.decode_tokens(
+                params, tokens, positions, cfg, cache, key,
+                lambda logits, sub: sample(logits, sub, self.sampling),
+                steps=self.decode_window, max_len=self.max_len,
+                live_blocks=self._reads_ring_blocks())
+
+        if self._mixed:
+            self._admit_mixed_fn = jax.jit(_admit_mixed,
+                                           donate_argnums=(5,))
+            self._decode_mixed_fn = jax.jit(_decode_mixed,
+                                            donate_argnums=(3,))
 
         # ---- paged dispatch programs (kv_pool_blocks > 0) --------------
         # Two routes serve the same block-table semantics, selected by
@@ -2496,6 +2557,81 @@ class GenerationEngine:
                 f"rotary width (index_head_dim {cfg.index_head_dim}, "
                 f"qk_rope_head_dim {cfg.qk_rope_head_dim})")
 
+    def _windowed(self) -> bool:
+        """Does some query see less than its whole sequence: is the
+        sliding window shorter than the cache extent? (What rides
+        ``prefill_attention_seeded`` has no absolute-timeline window
+        masking and refuses then; the mixed layers need it true.)"""
+        return 0 < self.cfg.sliding_window < self.max_len
+
+    def _check_mixed(self, asked: dict) -> None:
+        """Refuse, at construction and by mechanism, every option that
+        assumes one cache layout for all layers, or a dense
+        feed-forward. No silent fallback."""
+        why = {
+            "mesh": "the two kinds of cache and the expert stacks have "
+                    "no sharding rules, and experts over a mesh need an "
+                    "exchange of tokens that xing.routed_experts does "
+                    "not have",
+            "prefix_cache_blocks": "the prefix cache publishes and "
+                    "seeds blocks of ONE cache layout; here a prefix is "
+                    "whole in the full layers and only its last window "
+                    "in the rings, which a later request cannot be "
+                    "seeded from",
+            "kv_pool_blocks": "the block pool pages one extent per "
+                    "layer; it has no pages for a ring that turns over "
+                    "beside a full extent",
+            "spec_decode": "the verify pass scores k + 1 positions "
+                    "through decoder.verify_seeded, which knows neither "
+                    "the ring's columns nor the experts",
+        }
+        for name, reason in why.items():
+            if asked[name]:
+                raise ValueError(
+                    f"{name} cannot serve attention='mixed' "
+                    f"({self.cfg.name}): {reason}")
+        kv = resolve_kv_dtype(asked["kv_dtype"], None)
+        if kv is not None and jnp.dtype(kv).itemsize < 2:
+            raise ValueError(
+                f"kv_dtype {asked['kv_dtype']!r} cannot serve "
+                f"attention='mixed': 8-bit keys and values under 16 "
+                f"queries a group were never held against the "
+                f"reference")
+        if asked["quantize"] == "int4":
+            raise ValueError(
+                "quantize='int4' cannot serve attention='mixed': the "
+                "grouped expert matmul (ops/grouped_matmul.py) and "
+                "mixed.quantize_params know int8 with a scale per "
+                "output channel only")
+        if asked["windows_per_dispatch"] != 1:
+            raise ValueError(
+                "windows_per_dispatch > 1 cannot serve "
+                "attention='mixed': mixed.decode_tokens keeps one "
+                "window of columns a dispatch and turns the rings once")
+        cfg, piece = self.cfg, self.buckets[-1]
+        if (cfg.is_moe or not self._windowed() or not cfg.parallel_block
+                or cfg.layer_period < 2
+                or not 0 <= cfg.global_member < cfg.layer_period
+                or cfg.n_layers % cfg.layer_period
+                or cfg.head_dim % 2 or cfg.n_heads % cfg.n_kv_heads
+                or cfg.n_shared_experts < 1 or not cfg.tie_embeddings
+                or cfg.sliding_window % piece or self.max_len % piece):
+            raise ValueError(
+                f"attention='mixed' needs whole periods of layer_period "
+                f"layers (one of them global), a sliding window shorter "
+                f"than max_len, a parallel block, its own experts "
+                f"(n_routed_experts, not n_experts) beside shared ones, a "
+                f"tied head, and sliding_window ({cfg.sliding_window}) and max_len "
+                f"({self.max_len}) multiples of the largest prefill "
+                f"bucket ({piece}): a piece's columns are written as "
+                f"one slab, in a ring of window + bucket columns as in "
+                f"a full extent")
+        first, count = xing.held_experts(cfg)
+        if count < 1 or first < 0 or first + count > cfg.n_routed_experts:
+            raise ValueError(
+                f"held_experts {cfg.held_experts} is not a share of the "
+                f"{cfg.n_routed_experts} routed experts")
+
     def _eva_live(self) -> tuple[int, int]:
         """(exact columns, summaries) held by all sequences in slots,
         decoding or mid-admission, right now."""
@@ -2516,7 +2652,7 @@ class GenerationEngine:
             for s in self._active)
 
     def _admit_pieces(self) -> None:
-        """Admission for attention='eva' and 'mla': queued requests
+        """Admission for attention='eva', 'mla' and 'mixed': queued requests
         take free slots, and ONE wave advances every admitted prompt by
         its next piece — at most the largest bucket and, for 'eva',
         never across a window edge, so a piece that reaches the edge
@@ -2535,8 +2671,8 @@ class GenerationEngine:
         if not self._chunking:
             return
         t0 = time.monotonic()
-        # 'mla' has no edge but the largest bucket's: pieces start at
-        # its multiples
+        # 'mla' and 'mixed' have no edge but the largest bucket's:
+        # pieces start at its multiples
         w_sz = self.cfg.window_size if self._eva else self.buckets[-1]
         rows: list[tuple[int, int]] = []          # (slot, piece length)
         bucket = n_pad = 0
@@ -2576,12 +2712,20 @@ class GenerationEngine:
             args = (self.params, jnp.asarray(tokens), jnp.asarray(lens),
                     jnp.asarray(pos0), jnp.asarray(slots), self._cache,
                     sub)
-            if self._mla:
-                first_dev, self._cache, counts = self._admit_mla_fn(*args)
+            if self._mla or self._mixed:
+                admit = self._admit_mla_fn if self._mla \
+                    else self._admit_mixed_fn
+                first_dev, self._cache, counts = admit(*args)
                 extra = _expert_counts(_host_fetch(counts))
                 extra["attn_pairs"] = sum(
                     n * int(pos0[r]) + n * (n + 1) // 2
                     for r, (_slot, n) in enumerate(rows))
+                if self._mixed:
+                    # a window layer's queries read at most a window
+                    extra["window_attn_pairs"] = sum(
+                        _selected(int(pos0[r]), n,
+                                  self.cfg.sliding_window)
+                        for r, (_slot, n) in enumerate(rows))
                 if self.cfg.selects:
                     # every pair is scored by the indexer; attention
                     # reads min(index_topk, position + 1) a query
@@ -2676,6 +2820,55 @@ class GenerationEngine:
         every dispatch; elsewhere every slot's whole extent is scored
         in XLA, which is what the tests hold the kernel to."""
         return self._mla and latent_attention.serves(self.max_len)
+
+    def _reads_ring_blocks(self) -> bool:
+        """Does the mixed decode dispatch (``_decode_mixed``) read each
+        slot's live blocks of both kinds of cache in place
+        (``ops/dense_attention.py``: a full layer's one range, a ring's
+        one or two)? As ``_reads_live_blocks``: on a TPU (the caches
+        are always on one device: ``_check_mixed`` refuses a mesh),
+        read when the program is traced and before every dispatch;
+        elsewhere every column of either cache is scored in XLA, which
+        is what the tests hold the kernel's route to."""
+        return self._mixed and dense_attention.serves(self.max_len) \
+            and dense_attention.serves(
+                mixed.ring_len(self.cfg, self.buckets[-1]))
+
+    def _mixed_read(self, steps: int) -> dict:
+        """What a mixed decode dispatch of ``steps`` tokens reads in
+        ONE layer of each kind, over the decoding slots and summed over
+        its steps. The positions a token attends to, itself included
+        (``live_tokens`` in a full layer, ``window_live_tokens`` = at
+        most ``sliding_window`` of them in a window layer), and the
+        cache columns fetched for them (``state_tokens_read``,
+        ``window_tokens_read``): on the kernel's route what lies under
+        the blocks of the decoding slots (a ring: the blocks of the
+        range a token's window leaves of the cached positions, in its
+        two runs); on the XLA route every slot's whole extent, or
+        ring."""
+        ring = mixed.ring_len(self.cfg, self.buckets[-1])
+        pos0 = [int(self._positions[s]) for s in self._active]
+        live = {"live_tokens": sum(steps * p + steps * (steps + 1) // 2
+                                   for p in pos0),
+                "window_live_tokens": sum(
+                    _selected(p, steps, self.cfg.sliding_window)
+                    for p in pos0)}
+        if not self._reads_ring_blocks():
+            return {**live, "state_tokens_read": steps * self.num_slots
+                    * self.max_len,
+                    "window_tokens_read": steps * self.num_slots * ring}
+        return {
+            **live,
+            "state_tokens_read": steps * sum(
+                dense_attention.blocks_read(0, p, self.max_len)
+                for p in pos0),
+            "window_tokens_read": sum(
+                dense_attention.blocks_read(a, b, ring)
+                for p in pos0 for t in range(steps)
+                for a, b in mixed.ring_ranges(
+                    max(p + t + 1 - self.cfg.sliding_window, 0), p,
+                    ring)),
+        }
 
     def _latent_read(self, steps: int) -> int:
         """Latent columns that a decode dispatch of ``steps`` tokens
@@ -3380,11 +3573,19 @@ class GenerationEngine:
                                           for s in self._active),
                      "state_tokens_read": self._latent_read(window),
                      **self._selection_counts(window)}
+        elif self._mixed:
+            # ONE program whatever the lengths, as above
+            kv_len = self.max_len
+            extra = {"window_tokens": sum(int(self._positions[s])
+                                          for s in self._active),
+                     **self._mixed_read(window)}
         self._phase(None)
         with step_annotation("decode", seq), \
                 self._dispatch_boundary("decode"):
-            if self._mla:
-                toks, self._cache, counts = self._decode_mla_fn(
+            if self._mla or self._mixed:
+                decode = self._decode_mla_fn if self._mla \
+                    else self._decode_mixed_fn
+                toks, self._cache, counts = decode(
                     self.params, jnp.asarray(self._next_tok),
                     jnp.asarray(self._positions), self._cache, sub)
                 toks = _host_fetch(toks)                 # [steps, slots]

@@ -381,6 +381,21 @@ class StepRecord:
     #                             index_topk, live) a token
     live_tokens: int = 0        # positions a token could read: its
     #                             sequence's length, itself included
+    # attention="mixed" engines (models/mixed.py); 0 elsewhere. Host
+    # arithmetic, for ONE window layer (``state_tokens_read``,
+    # ``live_tokens`` and ``attn_pairs`` are one full layer's there)
+    window_live_tokens: int = 0  # decode dispatches: positions a token
+    #                             reads in a window layer, min(
+    #                             sliding_window, live), summed as
+    #                             ``live_tokens``
+    window_tokens_read: int = 0  # decode dispatches: ring columns the
+    #                             steps read for the decoding slots: on
+    #                             a TPU under the blocks of the range a
+    #                             token's window leaves, elsewhere the
+    #                             whole ring of every slot
+    window_attn_pairs: int = 0  # admission waves: query-key pairs the
+    #                             wave's real tokens attend to, each
+    #                             over min(sliding_window, its prefix)
 
     @property
     def occupancy(self) -> float:
@@ -632,7 +647,9 @@ class EngineTelemetry:
                     expert_tile_rows: int = 0,
                     attn_pairs: int = 0, index_tokens_read: int = 0,
                     selected_tokens: int = 0,
-                    live_tokens: int = 0) -> StepRecord:
+                    live_tokens: int = 0, window_live_tokens: int = 0,
+                    window_tokens_read: int = 0,
+                    window_attn_pairs: int = 0) -> StepRecord:
         """``t_start`` is the dispatch's ``time.monotonic()`` start
         (default: now less ``duration_s``)."""
         if t_start is None:
@@ -653,7 +670,10 @@ class EngineTelemetry:
             expert_group_rows=expert_group_rows,
             expert_tile_rows=expert_tile_rows, attn_pairs=attn_pairs,
             index_tokens_read=index_tokens_read,
-            selected_tokens=selected_tokens, live_tokens=live_tokens)
+            selected_tokens=selected_tokens, live_tokens=live_tokens,
+            window_live_tokens=window_live_tokens,
+            window_tokens_read=window_tokens_read,
+            window_attn_pairs=window_attn_pairs)
         self.recorder.record(rec)
         m, lb = self.metrics, self._labels
         m.observe("engine_step_seconds", duration_s,

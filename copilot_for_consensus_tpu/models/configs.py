@@ -1,9 +1,17 @@
 """Model configuration registry.
 
-Serving targets mirror BASELINE.json's five configs: MiniLM-class encoder
-(embedding service), Mistral-7B-class and Llama-3-8B-class dense decoders
-(summarization / RAG Q&A), Mixtral-8x7B-class MoE decoder (long-context
-consensus). `tiny_*` variants keep the full code path but run in tests.
+What the registry holds: the published shapes the package was first
+sized for (a MiniLM-class encoder for the embedding service;
+Mistral-7B-class and Llama-3-8B-class dense decoders for summarization
+and RAG Q&A; a Mixtral-8x7B-class MoE decoder, training dispatch only),
+and a ``tiny-*`` preset for every kind of decoder the serving engine
+runs, each the full code path at test sizes: dense (``tiny``,
+``tiny-swa``), EVA (``tiny-eva``), latent attention with dropless
+experts (``tiny-xing``, ``tiny-glm``), window and global layers mixed
+(``tiny-mixed``), capacity-dropping experts (``tiny-moe``). The served
+benchmark configurations are built from their own files
+(``benchmark/configs/*.json``) by ``benchmark/builders/``, not from
+here.
 """
 
 from __future__ import annotations
@@ -87,6 +95,26 @@ class DecoderConfig:
     #: ``n_routed_experts`` (the router keeps its width and chooses
     #: over all; only the held ones' terms are computed). () = all
     held_experts: tuple = ()
+    # attention == "mixed" (models/mixed.py): layers of two KINDS in
+    # one model, ``layer_period`` to a period, of which member
+    # ``global_member`` (counted from 0) attends to every earlier
+    # position and the others to the last ``sliding_window``; each kind
+    # keeps a cache of its own (a ring beside a full extent). The rest
+    # of what such a layer needs, as data: whether a kind's queries and
+    # keys are rotated at all (interleaved pairs), the norm
+    # (``"rms"``, or ``"layer"``: mean-centred, a gain, no bias), one
+    # norm feeding attention and experts side by side
+    # (``parallel_block``), how ``n_shared_experts`` outputs combine
+    # (``"sum"`` | ``"average"``), and the scale of the logits of a
+    # tied head (``tie_embeddings``)
+    layer_period: int = 0
+    global_member: int = 0
+    window_rope: bool = True
+    global_rope: bool = True
+    norm_kind: str = "rms"
+    parallel_block: bool = False
+    shared_expert_combine: str = "sum"
+    logit_scale: float = 1.0
 
     @property
     def head_dim(self) -> int:
@@ -102,6 +130,10 @@ class DecoderConfig:
         positions (and the cache hold an index key beside each latent
         row)?"""
         return self.is_mla and self.index_topk > 0
+
+    @property
+    def is_mixed(self) -> bool:
+        return self.attention == "mixed"
 
     @property
     def is_moe(self) -> bool:
@@ -194,6 +226,23 @@ DECODER_CONFIGS: dict[str, DecoderConfig] = {
         experts_per_token=2, moe_intermediate_size=32,
         first_k_dense_replace=1, routed_scaling_factor=2.5, hc_mult=1,
         index_n_heads=2, index_head_dim=16, index_topk=24,
+    ),
+    # Window and global layers mixed (``cohere2_moe`` class) at test
+    # scale: two periods of three window layers (16 positions, rotary)
+    # and one global layer (no positional encoding), 8 query heads on 2
+    # key/value heads, LayerNorm, attention and experts side by side on
+    # one norm, 8 experts (2 a token) beside 2 shared ones whose
+    # outputs are averaged, a tied head.
+    "tiny-mixed": DecoderConfig(
+        name="tiny-mixed", vocab_size=512, d_model=64, n_layers=8,
+        n_heads=8, n_kv_heads=2, d_ff=32, rope_theta=5e4,
+        max_seq_len=512, sliding_window=16, norm_eps=1e-5,
+        head_dim_override=16, attention="mixed", layer_period=4,
+        global_member=3, window_rope=True, global_rope=False,
+        norm_kind="layer", parallel_block=True, n_routed_experts=8,
+        n_shared_experts=2, shared_expert_combine="average",
+        experts_per_token=2, moe_intermediate_size=32,
+        tie_embeddings=True, logit_scale=1.0,
     ),
     "tiny-moe": DecoderConfig(
         name="tiny-moe", vocab_size=512, d_model=128, n_layers=2, n_heads=4,

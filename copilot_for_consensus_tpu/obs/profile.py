@@ -19,7 +19,7 @@ name go into it, each declared here and nowhere else:
   ``benchmark/harness/trace_reduce.py`` (device time per step, idle
   gaps inside a dispatch).
 * **Device scopes** — ``SCOPES``, ``EVA_SCOPES``, ``XING_SCOPES``,
-  ``GLM_SCOPES`` / ``scope(name)``:
+  ``GLM_SCOPES``, ``MIXED_SCOPES`` / ``scope(name)``:
   ``jax.named_scope``
   around the code that does each thing inside the jitted programs. The
   name lands in every HLO instruction's ``op_name`` metadata
@@ -101,6 +101,23 @@ GLM_SCOPES = (
     #                   over the columns attention walks
 )
 
+#: scopes of the attention="mixed" programs (models/mixed.py): the two
+#: it shares with ``XING_SCOPES`` (the experts are that module's) and
+#: its own four. A tuple of its own, as those. Innermost wins: the
+#: dispatch's own columns and the fold of the partials stay under
+#: ``attn`` (``ops/attention.py``'s helpers), so ``attn_window`` /
+#: ``attn_full`` are a layer's read of its CACHE (decode) and the whole
+#: of a piece's attention (admission)
+MIXED_SCOPES = (
+    "moe_route",
+    "moe_experts",
+    "attn_window",     # a window layer's attention over its ring
+    "attn_full",       # a full layer's attention over its extent
+    "ring_write",      # a piece's, and a dispatch's, columns into the
+    #                    rings (``kv_write``: into the full extents)
+    "shared_experts",  # the shared experts' SwiGLU
+)
+
 #: host phases of the serving loop (with the dispatch kinds ``decode``
 #: and ``prefill`` and ``_no_annotation_`` they fit the trace
 #: reducer's ten gap owners)
@@ -150,14 +167,16 @@ def step_annotation(name: str, step_num: int | None = None):
 
 def scope(name: str):
     """``jax.named_scope`` for one of ``SCOPES``, ``EVA_SCOPES``,
-    ``XING_SCOPES`` or ``GLM_SCOPES`` (trace time only)."""
+    ``XING_SCOPES``, ``GLM_SCOPES`` or ``MIXED_SCOPES`` (trace time
+    only)."""
     import jax
 
-    if name not in SCOPES + EVA_SCOPES + XING_SCOPES + GLM_SCOPES:
+    if name not in SCOPES + EVA_SCOPES + XING_SCOPES + GLM_SCOPES \
+            + MIXED_SCOPES:
         raise ValueError(f"unknown device scope {name!r}; obs/profile.py "
                          f"SCOPES has {SCOPES}, EVA_SCOPES {EVA_SCOPES}, "
                          f"XING_SCOPES {XING_SCOPES}, GLM_SCOPES "
-                         f"{GLM_SCOPES}")
+                         f"{GLM_SCOPES}, MIXED_SCOPES {MIXED_SCOPES}")
     return jax.named_scope(name)
 
 

@@ -877,6 +877,96 @@ def test_mla_guard_trips_when_the_layer_scan_cuts_the_experts_out(
 
 
 # ---------------------------------------------------------------------------
+# attention="mixed" (models/mixed.py): two kinds of cache in one dict.
+# The compiled programs hold both in place: a ring is laid in two slabs
+# a slot and never copied to be laid, a full extent as the dense
+# engine's; decode attention reads either through the dense kernel (a
+# ring's range in its two runs), admission through the flash kernel,
+# and no array of a score for every head, query and cached column is
+# made. A layer's matrices are read out of the one stack where they lie
+# (a period's slice cut again member by member copied them every step).
+# ---------------------------------------------------------------------------
+
+MIXED_CFG = decoder_config(
+    "tiny-mixed", d_model=512, n_heads=16, n_kv_heads=2,
+    head_dim_override=128, sliding_window=2048, n_routed_experts=16,
+    moe_intermediate_size=512, max_seq_len=8192)
+MIXED_MAX, MIXED_PIECE = 8192, 256
+
+
+def mixed_program(one_chip, which):
+    eng = GenerationEngine(MIXED_CFG, num_slots=SLOTS, max_len=MIXED_MAX,
+                           prefill_buckets=(MIXED_PIECE,),
+                           dtype=jnp.bfloat16, eos_id=-1, quantize="int8")
+    assert eng._reads_ring_blocks()
+
+    def wrap(a):
+        return jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip)
+
+    params, cache = jax.tree.map(wrap, eng.params), \
+        jax.tree.map(wrap, eng._cache)
+    key = wrap(jax.random.PRNGKey(0))
+    i32 = wrap(jax.ShapeDtypeStruct((SLOTS,), jnp.int32))
+    if which == "decode":
+        text = eng._decode_mixed_fn.lower(params, i32, i32, cache,
+                                          key).compile().as_text()
+    else:
+        two = wrap(jax.ShapeDtypeStruct((2,), jnp.int32))
+        text = eng._admit_mixed_fn.lower(
+            params, wrap(jax.ShapeDtypeStruct((2, MIXED_PIECE), jnp.int32)),
+            two, two, two, cache, key).compile().as_text()
+    return eng, text
+
+
+@pytest.mark.parametrize("which", ["decode", "admit"])
+def test_compiled_mixed_programs_hold_both_caches_in_place(
+        one_chip, on_tpu, which):
+    eng, text = mixed_program(one_chip, which)
+    stacks_ = {tuple(a.shape) for a in eng._cache.values()}
+    ring = eng._cache["window_k"].shape[3]
+    assert ring == 2048 + MIXED_PIECE and len(stacks_) == 2
+    matrices = {tuple(v["q"].shape[1:])
+                for k, v in eng.params["layers"].items()
+                if isinstance(v, dict)}
+    faults = []
+    for line in text.splitlines():
+        m = re.match(r"\s*(?:ROOT )?%?[\w.\-]+ = (\w+)\[([\d,]+)\](\S*) "
+                     r"([\w\-]+)\(", line)
+        if not m or m.group(4) in ("parameter", "get-tuple-element",
+                                   "bitcast"):
+            continue
+        shape = tuple(int(x) for x in m.group(2).split(","))
+        if shape in stacks_ and m.group(4) not in (
+                "dynamic-update-slice", "scatter", "while", "fusion"):
+            faults.append(f"{m.group(4)} makes a cache {shape}")
+        if shape in stacks_ and m.group(4) == "fusion" \
+                and "dynamic-update-slice" not in line \
+                and "scatter" not in line:
+            faults.append(f"a fusion makes a cache {shape}: {line[:120]}")
+        # a period's matrices cut out of the stack
+        if len(shape) >= 3 and shape[0] == MIXED_CFG.layer_period \
+                and shape[1:] in matrices and m.group(4) != "dynamic-slice":
+            faults.append(f"{m.group(4)} makes a period's matrix {shape}")
+        if m.group(1) == "f32" and (MIXED_MAX in shape or ring in shape) \
+                and math.prod(shape) >= MIXED_CFG.n_heads * MIXED_MAX * (
+                    SLOTS if which == "decode" else MIXED_PIECE):
+            faults.append(f"{m.group(4)} makes scores {shape}")
+    assert faults == []
+    kernels = [re.search(r'op_name="([^"]*)"', ln).group(1)
+               for ln in text.splitlines()
+               if 'custom_call_target="tpu_custom_call"' in ln]
+    sites = {"window": sum("attn_window/" in k for k in kernels),
+             "full": sum("attn_full/" in k for k in kernels),
+             "experts": sum("moe_experts/" in k and "grouped_qmatmul" in k
+                            for k in kernels)}
+    # one period's four layers unrolled in ONE scanned body: three
+    # window layers (decode: a ring's two runs each) and a full one
+    assert sites == {"window": 6 if which == "decode" else 3, "full": 1,
+                     "experts": 12}
+    assert len(kernels) == sum(sites.values())
+
+
+# ---------------------------------------------------------------------------
 # and nothing of this architecture reaches the programs of the two
 # configurations the benchmark had: no op of theirs stands under one of
 # its scopes or calls its kernel (compiled at the tiny sizes, where the
@@ -919,12 +1009,16 @@ def lowered_programs():
 @pytest.mark.parametrize("program", OTHER_PROGRAMS)
 def test_the_other_configurations_programs_hold_nothing_of_this_one(
         lowered_programs, program):
-    from copilot_for_consensus_tpu.obs.profile import SCOPES, XING_SCOPES
+    from copilot_for_consensus_tpu.obs.profile import (
+        MIXED_SCOPES,
+        SCOPES,
+        XING_SCOPES,
+    )
 
     names = re.findall(r'op_name="([^"]*)"', lowered_programs[program])
     under = {part for name in names for part in name.split("/")}
     assert "attn" in under and "ffn" in under    # the scopes are there
-    assert not under & (set(XING_SCOPES) - set(SCOPES))
+    assert not under & (set(XING_SCOPES + MIXED_SCOPES) - set(SCOPES))
     assert not any("grouped_qmatmul" in name
                    or "mla_decode_attention" in name
                    or "mla_prefill_attention" in name for name in names)

@@ -8,7 +8,9 @@ weights made from a seed:
 * **kernels** — every Pallas kernel an ``auto`` route selects on a TPU is
   compiled (``interpret=False``) at Mistral-7B widths and compared with
   its XLA reference on the chip: ``flash_attention`` (plain, windowed,
-  ``kv_lengths``), the paged kernel against ``paged_gather_layer`` +
+  ``kv_lengths``; then command-a-plus's, Mistral's and EvaByte's
+  admission calls at their served shapes, timed), the paged kernel
+  against ``paged_gather_layer`` +
   ``decode_attention`` over an fp8 pool (decode rows ``r = group`` and
   seeded rows ``r = group * S``), the EVA decode kernel over a slot's
   live blocks (at EvaByte's widths) against ``joint_attention`` over
@@ -238,6 +240,95 @@ def admission_attention_rates(rehearse: bool, compare) -> dict:
     return rates
 
 
+def flash_attention_rates(rehearse: bool, compare) -> dict:
+    """The admission kernel of the GQA configurations
+    (``ops/flash_attention.py``) at the shapes it is served with, each
+    against a masked softmax in XLA over the first and the last query
+    head (a full layer's float32 scores for all 128 would not fit) and
+    timed: command-a-plus's full layer (a wave of 2 x 2,048 queries,
+    128 heads on 8, over a 32,768-column extent, the pieces at ``pos0``
+    0 and 22,528) and its ring in timeline order (6,144 columns, window
+    4,096, a young row's begin bound and an old one's), Mistral's
+    admission wave (4 x 2,048, 32 heads on 8) and EvaByte's piece
+    (2,048 queries of 32 heads over 1,024 summary columns, 640 of them
+    filled, then the window), all at the tiles the wrapper picks from
+    the shapes. → a case: agreement (``compare``), milliseconds a call,
+    the key tiles a head's rows walk (whole, edge) and leave (dead),
+    and the share of the MXU's peak the time stands for, counted over
+    the (query, column) pairs the mask leaves."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from copilot_for_consensus_tpu.ops import flash_attention as fa
+
+    on_tpu = jax.default_backend() == "tpu"
+    dtype = jnp.bfloat16 if on_tpu else jnp.float32
+    d = 32 if rehearse else 128
+    # name: (rows, heads, kv heads, queries, columns, window,
+    #        query offsets, begin bounds, lengths)
+    if rehearse:
+        cases = {
+            "full/pos0=0": (2, 8, 2, 32, 256, 0, [0] * 2, [0] * 2, [32] * 2),
+            "ring/young+old": (2, 8, 2, 32, 96, 64, [64] * 2, [64, 0],
+                               [96] * 2)}
+    else:
+        cases = {
+            "full/pos0=0": (2, 128, 8, 2048, 32768, 0, [0] * 2, [0] * 2,
+                            [2048] * 2),
+            "full/pos0=22528": (2, 128, 8, 2048, 32768, 0, [22528] * 2,
+                                [0] * 2, [24576] * 2),
+            "ring/young+old": (2, 128, 8, 2048, 6144, 4096, [4096] * 2,
+                               [4096, 0], [6144, 5000]),
+            "mistral/4x2048": (4, 32, 8, 2048, 2048, 4096, [0] * 4, [0] * 4,
+                               [2048, 1877, 1707, 1536]),
+            "evabyte/2x2048": (2, 32, 32, 2048, 3072, 0, [1024] * 2,
+                               [384] * 2, [3072] * 2)}
+    rates: dict = {}
+    for name, (b, hq, hkv, s, t, window, off, begin, kv_len) in \
+            cases.items():
+        keys = jax.random.split(jax.random.PRNGKey(44), 3)
+        q = jax.random.normal(keys[0], (b, hq, s, d), dtype)
+        k = jax.random.normal(keys[1], (b, hkv, t, d), dtype)
+        v = jax.random.normal(keys[2], (b, hkv, t, d), dtype)
+        off, begin, kv_len = (jnp.asarray(a, jnp.int32)
+                              for a in (off, begin, kv_len))
+        fn = functools.partial(
+            fa.flash_attention, causal=True, window=window,
+            kv_lengths=kv_len, q_offsets=off, kv_begins=begin,
+            interpret=not on_tpu)
+        got = jax.block_until_ready(fn(q, k, v))
+        best = float("inf")
+        for _ in range(1 if rehearse else 5):
+            t0 = time.perf_counter()
+            jax.block_until_ready(fn(q, k, v))
+            best = min(best, time.perf_counter() - t0)
+        at = np.arange(s)[None, :, None] + np.asarray(off)[:, None, None]
+        col = np.arange(t)[None, None, :]
+        mask = ((col <= at) & (col > at - (window or t + s))
+                & (col >= np.asarray(begin)[:, None, None])
+                & (col < np.asarray(kv_len)[:, None, None]))
+        pairs = int(mask.sum())
+        heads = jnp.asarray([0, hq - 1])
+        logits = jnp.einsum(
+            "bhqd,bhkd->bhqk", q[:, heads], k[:, heads // (hq // hkv)],
+            preferred_element_type=jnp.float32) * d ** -0.5
+        probs = jax.nn.softmax(
+            jnp.where(jnp.asarray(mask)[:, None], logits, -jnp.inf), axis=-1)
+        want = jnp.einsum("bhqk,bhkd->bhqd", probs.astype(dtype),
+                          v[:, heads // (hq // hkv)])
+        compare(f"flash_attention/{name}", got[:, heads], want)
+        del logits, probs
+        whole, edge, dead = fa.tile_counts(
+            np.asarray(off), np.asarray(begin), np.asarray(kv_len), s, t, d,
+            causal=True, window=window)
+        rates[name] = {
+            "tile": list(fa.tiles(s, t, d)), "ms": round(best * 1e3, 4),
+            "tiles_whole": whole, "tiles_edge": edge, "tiles_dead": dead,
+            "mxu_share": round(4 * pairs * hq * d / best / 197e12, 4)}
+    return rates
+
+
 def grouped_matmul_rates(rehearse: bool, compare) -> dict:
     """The experts' grouped int8 matmul (``ops/grouped_matmul.py``) at
     the served shapes, against XLA's ``ragged_dot`` over the
@@ -401,6 +492,8 @@ def phase_kernels(rehearse: bool) -> int:
                 flash_attention(q, k, v, causal=True, interpret=interpret,
                                 **kw),
                 attention_xla(q, k, v, causal=True, **kw))
+    flash_rates = flash_attention_rates(rehearse, compare)
+    say(f"flash attention at the served shapes: {flash_rates}")
 
     # -- paged kernel over an fp8 pool (kv_kernel="auto" on a TPU) -----
     n_l, nbtot, nb, blk, li = 2, 48, 8, 64, 1
@@ -834,6 +927,7 @@ def phase_kernels(rehearse: bool) -> int:
          mla_decode_attention=latent_rates,
          selected_latent_attention=kept_rates,
          mla_prefill_attention=prefill_rates,
+         flash_attention=flash_rates,
          grouped_qmatmul=grouped_rates,
          seconds=round(time.monotonic() - t0, 1))
     return 0

@@ -2726,6 +2726,10 @@ class GenerationEngine:
                         _selected(int(pos0[r]), n,
                                   self.cfg.sliding_window)
                         for r, (_slot, n) in enumerate(rows))
+                    (extra["attn_tiles_whole"], extra["attn_tiles_edge"],
+                     extra["attn_tiles_dead"]) = mixed.piece_tiles(
+                        pos0, lens, bucket, self.max_len,
+                        self._cache["window_k"].shape[3], self.cfg)
                 if self.cfg.selects:
                     # every pair is scored by the indexer; attention
                     # reads min(index_topk, position + 1) a query

@@ -396,6 +396,15 @@ class StepRecord:
     window_attn_pairs: int = 0  # admission waves: query-key pairs the
     #                             wave's real tokens attend to, each
     #                             over min(sliding_window, its prefix)
+    # admission waves: key tiles the admission kernel walked
+    # (ops/flash_attention.py), summed over the wave's rows (padding
+    # rows too), query tiles, query heads and ALL layers, of both kinds
+    attn_tiles_whole: int = 0   # seen whole by every query of the
+    #                             query tile: folded without a mask
+    attn_tiles_edge: int = 0    # seen in part: folded under the mask
+    attn_tiles_dead: int = 0    # seen by none: neither fetched nor
+    #                             stepped over (a walk of every tile of
+    #                             a timeline fetched them)
 
     @property
     def occupancy(self) -> float:
@@ -649,7 +658,9 @@ class EngineTelemetry:
                     selected_tokens: int = 0,
                     live_tokens: int = 0, window_live_tokens: int = 0,
                     window_tokens_read: int = 0,
-                    window_attn_pairs: int = 0) -> StepRecord:
+                    window_attn_pairs: int = 0,
+                    attn_tiles_whole: int = 0, attn_tiles_edge: int = 0,
+                    attn_tiles_dead: int = 0) -> StepRecord:
         """``t_start`` is the dispatch's ``time.monotonic()`` start
         (default: now less ``duration_s``)."""
         if t_start is None:
@@ -673,7 +684,10 @@ class EngineTelemetry:
             selected_tokens=selected_tokens, live_tokens=live_tokens,
             window_live_tokens=window_live_tokens,
             window_tokens_read=window_tokens_read,
-            window_attn_pairs=window_attn_pairs)
+            window_attn_pairs=window_attn_pairs,
+            attn_tiles_whole=attn_tiles_whole,
+            attn_tiles_edge=attn_tiles_edge,
+            attn_tiles_dead=attn_tiles_dead)
         self.recorder.record(rec)
         m, lb = self.metrics, self._labels
         m.observe("engine_step_seconds", duration_s,

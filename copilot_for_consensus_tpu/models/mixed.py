@@ -58,6 +58,7 @@ from typing import Any
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from copilot_for_consensus_tpu.models import decoder, xing
 from copilot_for_consensus_tpu.models import layers as L
@@ -67,7 +68,7 @@ from copilot_for_consensus_tpu.models.quant import (
     quantize_tensor,
 )
 from copilot_for_consensus_tpu.obs.profile import scope
-from copilot_for_consensus_tpu.ops import dense_attention
+from copilot_for_consensus_tpu.ops import dense_attention, flash_attention
 from copilot_for_consensus_tpu.ops.attention import (
     combine_partials,
     decode_window_partial,
@@ -81,13 +82,6 @@ MATRICES = ("wq", "wk", "wv", "wo", "w_gate", "w_up", "w_down",
             *xing.EXPERTS)
 
 N_COUNTS = xing.N_COUNTS
-
-#: query and key/value rows a grid step of the admission kernel holds
-#: (``ops/flash_attention.py``; an extent is a multiple of the second
-#: or shorter)
-PIECE_Q_BLOCK = 512
-PIECE_KV_BLOCK = 1024
-
 
 # ---------------------------------------------------------------------------
 # Shapes, parameters, cache
@@ -298,6 +292,40 @@ def unembed(x: jax.Array, params: Params, cfg: DecoderConfig) -> jax.Array:
 # ---------------------------------------------------------------------------
 
 
+def piece_timeline(kind: str, pos0, lens, s: int, t: int,
+                   cfg: DecoderConfig):
+    """Where a piece of ``s`` queries a row (row r's at positions
+    ``pos0[r] + [0, s)``, ``lens[r]`` real) stands in the timeline of
+    ``t`` columns that ``piece_attention`` lays out for a layer of
+    ``kind``: (the first query's column, the first column that holds a
+    position, the columns that hold one, each ``[n]``; the window as a
+    distance, 0 for none). Traced arrays or, for the engine's step
+    records, numpy's: the same arithmetic."""
+    if kind == "window":
+        return (pos0 * 0 + (t - s), (t - s - pos0).clip(0), t - s + lens,
+                cfg.sliding_window)
+    return pos0, pos0 * 0, pos0 + lens, 0
+
+
+def piece_tiles(pos0, lens, s: int, max_len: int, ring: int,
+                cfg: DecoderConfig) -> tuple[int, int, int]:
+    """Key tiles the admission kernel walks for a wave (host ``pos0``,
+    ``lens [n]``, pieces of ``s``), summed over the model's layers and
+    query heads: ``(whole, edge, dead)``, dead what a walk of every
+    tile of a timeline would have fetched beside (``ops/
+    flash_attention.py:tile_counts``)."""
+    total = np.zeros(3, np.int64)
+    kinds = layer_kinds(cfg)
+    for kind, t in (("full", max_len), ("window", ring)):
+        q_off, begin, kv_len, window = piece_timeline(
+            kind, np.asarray(pos0), np.asarray(lens), s, t, cfg)
+        tiles = flash_attention.tile_counts(
+            q_off, begin, kv_len, s, t, cfg.head_dim, causal=True,
+            window=window)
+        total += kinds.count(kind) * cfg.n_heads * np.asarray(tiles)
+    return tuple(int(x) for x in total)
+
+
 def piece_attention(q: jax.Array, cache_k: jax.Array, cache_v: jax.Array,
                     lk: jax.Array, slots: jax.Array, pos0: jax.Array,
                     lens: jax.Array, kind: str, cfg: DecoderConfig,
@@ -328,24 +356,16 @@ def piece_attention(q: jax.Array, cache_k: jax.Array, cache_v: jax.Array,
         turn = jax.vmap(lambda a, by: jnp.roll(a, -by, axis=1))
         k_all = turn(k_all, (pos0 + s) % t)
         v_all = turn(v_all, (pos0 + s) % t)
-        q_off = jnp.full((n,), t - s, jnp.int32)
-        begin = jnp.maximum(t - s - pos0, 0)
-        kv_len = t - s + lens
-        window = cfg.sliding_window
-    else:
-        q_off, begin, kv_len, window = pos0, pos0 * 0, pos0 + lens, 0
+    q_off, begin, kv_len, window = piece_timeline(kind, pos0, lens, s, t,
+                                                  cfg)
     qh = q.transpose(0, 2, 1, 3)
     if impl == "auto":
         impl = "pallas" if jax.default_backend() == "tpu" else "xla"
     with scope(f"attn_{kind}"):
         if impl == "pallas":
-            from copilot_for_consensus_tpu.ops.flash_attention import (
-                flash_attention,
-            )
-            o = flash_attention(
+            o = flash_attention.flash_attention(
                 qh, k_all, v_all, causal=True, window=window,
-                kv_lengths=kv_len, q_offsets=q_off, kv_begins=begin,
-                block_q=PIECE_Q_BLOCK, block_kv=PIECE_KV_BLOCK)
+                kv_lengths=kv_len, q_offsets=q_off, kv_begins=begin)
         else:
             qg = qh.reshape(n, hkv, h // hkv, s, dh)
             logits = jnp.einsum("nhgsd,nhtd->nhgst", qg, k_all,

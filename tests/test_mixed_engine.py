@@ -400,7 +400,16 @@ def test_the_engine_records_what_each_kind_of_layer_read(engine):
                 assert r.attn_pairs == 16 * 17 // 2 + 12 * 13 // 2
                 assert r.window_attn_pairs == r.attn_pairs
             assert r.window_tokens_read == r.state_tokens_read == 0
+            # a piece here is one tile against an extent that is one
+            # (the kernel's tiles are wider than either): a (padded
+            # row, layer, head) each, seen in part or, where a row's
+            # piece lies wholly ahead of the ring's first column, whole
+            tiles = (r.attn_tiles_whole, r.attn_tiles_edge,
+                     r.attn_tiles_dead)
+            assert sum(tiles) == r.batch * CFG.n_layers * CFG.n_heads
+            assert r.attn_tiles_edge > 0 == r.attn_tiles_dead
             continue
+        assert r.attn_tiles_whole == r.attn_tiles_edge == 0
         # off a TPU every column of either cache is scored
         assert r.state_tokens_read == STEPS * 4 * MAX_LEN
         assert r.window_tokens_read == STEPS * 4 * RING

@@ -19,7 +19,9 @@ weights made from a seed:
   tried), the latent admission kernel against ``piece_attention``'s
   XLA rounds (a wave of 2 x 2,048 queries at both MLA cells' shapes,
   GLM's under a selection's mask: ms a round on either route), the
-  experts' grouped int8 matmul against ``ragged_dot`` (a decode step's
+  admission threshold's kernel against ``sparse_select.threshold``'s
+  XLA rounds and ``jax.lax.top_k`` (the sort keys of such a wave: ms a
+  call on either route at three live extents), the experts' grouped int8 matmul against ``ragged_dot`` (a decode step's
   pairs and both MLA cells' admission waves: ms a matmul under the
   served tiling beside the deep-k one that served them until PR 42),
   and ``int4_matmul``. Then one short
@@ -237,6 +239,120 @@ def admission_attention_rates(rehearse: bool, compare) -> dict:
                     2 * pairs * h * (dk + dv) / (ms["kernel"] * 1e-3)
                     / 197e12, 4)}
         del cache_a, chance
+    return rates
+
+
+def select_threshold_rates(rehearse: bool) -> dict:
+    """The admission threshold (``models/xing.py:piece_threshold``) on
+    its two routes at the served shape: the sort keys of a wave of 2 x
+    2,048 queries in a buffer of 32,768 columns, the 2,048 best kept.
+    (1) Rows of unequal extents, one with scores on a coarse grid (ties
+    at the k-th score in most queries): the kernel route's (``thr``,
+    ``cut``) are the XLA rounds' integers, and the kept set of a few
+    queries a row is ``jax.lax.top_k``'s. (2) TIMED, both rows 4,096 /
+    12,288 / 23,552 columns into their slots, every score distinct (the
+    ties' branch, which both routes run in XLA, stays out): milliseconds
+    a call on either route, and what the kernel's time is a key and
+    round; then at 12,288 with the tied scores, where that branch runs
+    on both routes."""
+    from unittest import mock
+
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from copilot_for_consensus_tpu.models import xing
+    from copilot_for_consensus_tpu.ops import (
+        latent_prefill_attention,
+        select_threshold,
+        sparse_select,
+    )
+
+    blk = xing.KV_BLOCK
+    n, s, extent, topk, lives = (2, 64, 4 * blk, 24, (2, 4)) if rehearse \
+        else (2, 2048, 32768, 2048, (4, 12, 23))
+    col = jnp.arange(extent, dtype=jnp.int32)
+
+    @jax.jit
+    def keys_of(scores, kv_len):
+        q_pos = kv_len[:, None] - s + jnp.arange(s)[None, :]
+        return sparse_select.sort_keys(
+            scores, xing._seen(col, q_pos, kv_len)), q_pos
+
+    def route(kernel):
+        def fn(buf, q_pos, kv_len):
+            # the route is read when the program is traced
+            with mock.patch.object(latent_prefill_attention, "serves",
+                                   lambda block: kernel):
+                return xing.piece_threshold(
+                    buf, q_pos, kv_len, (jnp.max(kv_len) + blk - 1) // blk,
+                    topk)
+        return jax.jit(fn)
+
+    routes = {"xla": route(False), "kernel": route(True)}
+    scores = jax.random.normal(jax.random.PRNGKey(47), (n, s, extent),
+                               jnp.float32)
+    rates: dict = {"tile": select_threshold.TQ}
+
+    # (1) the same integers, and top_k's set
+    tied = scores.at[1].set(jnp.round(scores[1] * 8) / 8)
+    kv_len = jnp.asarray([lives[-1] * blk - 7, lives[0] * blk + 5], jnp.int32)
+    buf, q_pos = keys_of(tied, kv_len)
+    (thr, cut), (thr_x, cut_x) = (
+        routes[r](buf, q_pos, kv_len) for r in ("kernel", "xla"))
+    check(bool((thr == thr_x).all() & (cut == cut_x).all()),
+          "select_threshold: the kernel's (thr, cut) are not the XLA "
+          "rounds'")
+    check(bool((cut[1] < extent).any()),
+          "select_threshold: no query's k-th score is tied")
+    for r in range(n):
+        for i in (0, s // 2 + 1, s - 1):
+            kk = min(topk, int(q_pos[r, i]) + 1)
+            # (-0.0 and 0.0 are one score: top_k tells them apart)
+            _, top = jax.lax.top_k(
+                jnp.where(buf[r, i] > sparse_select.NEVER,
+                          jnp.where(tied[r, i] == 0, 0.0, tied[r, i]),
+                          -jnp.inf), kk)
+            got = np.flatnonzero(np.asarray(sparse_select.chosen(
+                buf[r, i], col, thr[r, i], cut[r, i])))
+            check(set(got.tolist()) == set(np.asarray(top).tolist()),
+                  f"select_threshold/row={r}/query={i}: the threshold "
+                  f"keeps {len(got)} columns, not top_k's {kk}")
+
+    # (2) timed: every query's scores distinct (a multiplier's residues
+    # modulo a prime over the extent), so the ties' branch stays out;
+    # then the tied scores of (1), where it runs on both routes
+    def timed(fn, *args) -> float:
+        jax.block_until_ready(fn(*args))
+        best = float("inf")
+        for _ in range(1 if rehearse else 5):
+            t = time.perf_counter()
+            jax.block_until_ready(fn(*args))
+            best = min(best, time.perf_counter() - t)
+        return best * 1e3
+
+    prime = 32771
+    distinct = ((col[None, None, :] * 7919
+                 + jnp.arange(s)[None, :, None] * 104729
+                 + jnp.arange(n)[:, None, None] * 13) % prime
+                ).astype(jnp.float32)
+    for live in lives:
+        kv_len = jnp.full((n,), live * blk, jnp.int32)
+        buf, q_pos = keys_of(distinct, kv_len)
+        check(bool((routes["kernel"](buf, q_pos, kv_len)[1] == extent).all()),
+              "select_threshold: a k-th score is tied among distinct scores")
+        ms = {name: timed(fn, buf, q_pos, kv_len)
+              for name, fn in routes.items()}
+        rates[f"live_{live * blk}"] = {
+            "xla_ms": round(ms["xla"], 4),
+            "kernel_ms": round(ms["kernel"], 4),
+            "kernel_ps_per_key_round": round(
+                ms["kernel"] * 1e9 / (n * s * live * blk * 32), 3)}
+    kv_len = jnp.full((n,), lives[1] * blk, jnp.int32)
+    buf, q_pos = keys_of(tied, kv_len)
+    rates[f"tied_{lives[1] * blk}"] = {
+        f"{name}_ms": round(timed(fn, buf, q_pos, kv_len), 4)
+        for name, fn in routes.items()}
     return rates
 
 
@@ -870,6 +986,12 @@ def phase_kernels(rehearse: bool) -> int:
         f"attention on either route): {kept_rates}")
     del glat, gidx
 
+    # -- the admission threshold (ops/select_threshold.py on a TPU): a
+    # query tile's sort keys in VMEM, the rounds of counting there ------
+    threshold_rates = select_threshold_rates(rehearse)
+    say(f"admission threshold, a wave of 2 x 2,048 queries: "
+        f"{threshold_rates}")
+
     # -- admission attention over latents (attention="mla" on a TPU,
     # ops/latent_prefill_attention.py): a round's scores stay in VMEM --
     prefill_rates = admission_attention_rates(rehearse, compare)
@@ -926,6 +1048,7 @@ def phase_kernels(rehearse: bool) -> int:
          dense_decode_attention=dense_rates,
          mla_decode_attention=latent_rates,
          selected_latent_attention=kept_rates,
+         select_threshold=threshold_rates,
          mla_prefill_attention=prefill_rates,
          flash_attention=flash_rates,
          grouped_qmatmul=grouped_rates,

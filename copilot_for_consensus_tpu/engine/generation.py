@@ -2737,6 +2737,9 @@ class GenerationEngine:
                     extra["selected_tokens"] = sum(
                         _selected(int(pos0[r]), n, self.cfg.index_topk)
                         for r, (_slot, n) in enumerate(rows))
+                    # (padded rows are counted: their keys are read)
+                    extra["select_keys_read"] = xing.threshold_keys_read(
+                        (pos0 + lens).tolist(), bucket, self.max_len)
             else:
                 first_dev, self._cache = self._admit_eva_fn(*args)
             first = _host_fetch(first_dev)

@@ -381,6 +381,13 @@ class StepRecord:
     #                             index_topk, live) a token
     live_tokens: int = 0        # positions a token could read: its
     #                             sequence's length, itself included
+    select_keys_read: int = 0   # admission waves: sort keys the
+    #                             threshold's counting reads, padded
+    #                             rows included: on a TPU each row's
+    #                             live blocks once, into VMEM (ops/
+    #                             select_threshold.py); elsewhere the
+    #                             wave's live blocks of every row, ten
+    #                             passes (ops/sparse_select.py)
     # attention="mixed" engines (models/mixed.py); 0 elsewhere. Host
     # arithmetic, for ONE window layer (``state_tokens_read``,
     # ``live_tokens`` and ``attn_pairs`` are one full layer's there)
@@ -656,7 +663,8 @@ class EngineTelemetry:
                     expert_tile_rows: int = 0,
                     attn_pairs: int = 0, index_tokens_read: int = 0,
                     selected_tokens: int = 0,
-                    live_tokens: int = 0, window_live_tokens: int = 0,
+                    live_tokens: int = 0, select_keys_read: int = 0,
+                    window_live_tokens: int = 0,
                     window_tokens_read: int = 0,
                     window_attn_pairs: int = 0,
                     attn_tiles_whole: int = 0, attn_tiles_edge: int = 0,
@@ -682,6 +690,7 @@ class EngineTelemetry:
             expert_tile_rows=expert_tile_rows, attn_pairs=attn_pairs,
             index_tokens_read=index_tokens_read,
             selected_tokens=selected_tokens, live_tokens=live_tokens,
+            select_keys_read=select_keys_read,
             window_live_tokens=window_live_tokens,
             window_tokens_read=window_tokens_read,
             window_attn_pairs=window_attn_pairs,

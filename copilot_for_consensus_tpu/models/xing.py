@@ -51,9 +51,10 @@ that one of the two classes adds:
   while there are fewer; ties to the lower position). The index key is a
   SECOND cached row a position, written, kept in a dispatch's window
   buffer and merged beside the latent row. The choice is the exact
-  top-k, found as a threshold by counting (``ops/sparse_select.py``),
-  and reaches both forms of attention as a mask over the columns they
-  walk.
+  top-k, found as a threshold by counting (``ops/sparse_select.py``; an
+  admission piece's rounds on a TPU over keys held in VMEM,
+  ``ops/select_threshold.py``), and reaches both forms of attention as
+  a mask over the columns they walk.
 
 State: ``{"dense": [k0, slots, R, max_len], "moe": [L - k0, slots, R,
 max_len]}`` latent rows (``R = kv_lora_rank + qk_rope_head_dim``), one
@@ -91,6 +92,7 @@ from copilot_for_consensus_tpu.obs.profile import scope
 from copilot_for_consensus_tpu.ops import (
     latent_attention,
     latent_prefill_attention,
+    select_threshold,
     sparse_select,
 )
 from copilot_for_consensus_tpu.ops.attention import (
@@ -750,45 +752,89 @@ def select_piece(q_i: jax.Array, w_i: jax.Array, idx_a: jax.Array,
     of them while fewer). The scores of the ``n_blocks`` live blocks
     of layer ``li`` of the index keys ``idx_a [La, slots, Di, T]`` (the
     piece's own keys written) go, as sort keys, into one buffer ``[n,
-    S, T]``; the threshold is counted over those blocks alone. →
-    ``keep(j)`` for ``piece_attention``."""
+    S, T]``; the threshold is counted over those blocks alone
+    (``piece_threshold``). → ``keep(j)`` for ``piece_attention``."""
     n, s = q_pos.shape
     t = idx_a.shape[3]
     blk = min(KV_BLOCK, t)
-
-    def cols(j):
-        return j * blk + jnp.arange(blk, dtype=jnp.int32)
 
     def fill(j, buf):
         return jax.lax.dynamic_update_slice(
             buf, sparse_select.sort_keys(
                 index_scores(q_i, w_i,
                              _block_rows(idx_a, li, slots, j, blk)),
-                _seen(cols(j), q_pos, kv_len)), (0, 0, j * blk))
+                _seen(_key_cols(j, blk), q_pos, kv_len)), (0, 0, j * blk))
 
     with scope("indexer"):
         buf = jax.lax.fori_loop(
             0, n_blocks, fill,
             jnp.full((n, s, t), sparse_select.NEVER, jnp.int32))
+    with scope("select"):
+        thr, cut = piece_threshold(buf, q_pos, kv_len, n_blocks,
+                                   cfg.index_topk)
+    return lambda j: sparse_select.chosen(
+        _key_block(buf, j, blk), _key_cols(j, blk), thr[..., None],
+        cut[..., None])
 
-    def block(j):
-        return jax.lax.dynamic_slice(buf, (0, 0, j * blk), (n, s, blk))
+
+def _key_cols(j: jax.Array, blk: int) -> jax.Array:
+    return j * blk + jnp.arange(blk, dtype=jnp.int32)
+
+
+def _key_block(buf: jax.Array, j: jax.Array, blk: int) -> jax.Array:
+    """Block j of a piece's sort keys ``buf [n, S, T]``."""
+    return jax.lax.dynamic_slice(buf, (0, 0, j * blk), (*buf.shape[:2], blk))
+
+
+def piece_threshold(buf: jax.Array, q_pos: jax.Array, kv_len: jax.Array,
+                    n_blocks: jax.Array, topk: int
+                    ) -> tuple[jax.Array, jax.Array]:
+    """``sparse_select.threshold``'s (``thr``, ``cut``) ``[n, S]`` for
+    an admission piece's sort keys ``buf [n, S, T]``, live in the first
+    ``n_blocks`` blocks of ``KV_BLOCK`` columns: query i of row r keeps
+    the ``topk`` largest of the keys it sees (all while fewer).
+
+    Two routes to the same integers, chosen as ``piece_attention``'s
+    are. On a TPU the rounds of counting run over a query tile's keys
+    held in VMEM, of its row's own live blocks
+    (``ops/select_threshold.py``); only the ties' cut walks the buffer
+    in XLA, in a wave some query of which has its k-th score tied.
+    Elsewhere every round walks the buffer's live blocks in XLA, which
+    the tests hold the kernel to."""
+    t = buf.shape[2]
+    blk = min(KV_BLOCK, t)
 
     def count(test):
         def one(j):
-            return jnp.sum(test(block(j), cols(j)), axis=-1,
-                           dtype=jnp.int32)
+            return jnp.sum(test(_key_block(buf, j, blk), _key_cols(j, blk)),
+                           axis=-1, dtype=jnp.int32)
 
         return jax.lax.fori_loop(
             0, n_blocks, lambda j, acc: acc + one(j),
             jnp.zeros(jax.eval_shape(one, 0).shape, jnp.int32))
 
-    with scope("select"):
-        seen = jnp.minimum(q_pos + 1, kv_len[:, None])
-        thr, cut = sparse_select.threshold(
-            count, jnp.clip(seen, 1, cfg.index_topk), t)
-    return lambda j: sparse_select.chosen(
-        block(j), cols(j), thr[..., None], cut[..., None])
+    k = jnp.clip(jnp.minimum(q_pos + 1, kv_len[:, None]), 1, topk)
+    if not latent_prefill_attention.serves(blk):
+        return sparse_select.threshold(count, k, t)
+    # (one block at least: a row of no length counts a block of NEVER,
+    # as the XLA rounds do)
+    thr, above, at = select_threshold.piece_threshold(
+        buf, k, jnp.clip((kv_len + blk - 1) // blk, 1, n_blocks), blk)
+    return thr, sparse_select.tie_cut(count, thr, k - above, at, t)
+
+
+def threshold_keys_read(kv_len: list[int], s: int, extent: int) -> int:
+    """Sort keys that ``piece_threshold`` reads for a wave of pieces of
+    ``s`` queries whose rows hold ``kv_len`` positions (host numbers),
+    in a buffer of ``extent`` columns, on the route it takes here: the
+    kernel copies each row's own live blocks in once; the XLA rounds
+    walk the wave's live blocks of every row ``sparse_select.PASSES``
+    times."""
+    blk = min(KV_BLOCK, extent)
+    live = [max(-(-n // blk), 1) for n in kv_len]
+    if latent_prefill_attention.serves(blk):
+        return s * blk * sum(live)
+    return sparse_select.PASSES * len(live) * s * blk * max(live)
 
 
 def select_step(q_i: jax.Array, w_i: jax.Array, k_cur: jax.Array,

@@ -97,8 +97,12 @@ GLM_SCOPES = (
     "indexer",        # index queries, keys and head weights; the index
     #                   scores over the cached index keys
     "select",         # the exact top-k of a query's index scores as a
-    #                   threshold (ops/sparse_select.py) and the mask
-    #                   over the columns attention walks
+    #                   threshold and the mask over the columns
+    #                   attention walks. The threshold's counting: in
+    #                   XLA for a decode step and off a TPU
+    #                   (ops/sparse_select.py); for an admission piece
+    #                   on a TPU over keys held in VMEM
+    #                   (ops/select_threshold.py), its ties' cut in XLA
 )
 
 #: scopes of the attention="mixed" programs (models/mixed.py): the two

@@ -19,6 +19,16 @@ The counting is handed in (``count``): a decode step counts over one
 array, an admission piece block by block over the blocks of its score
 buffer that hold something live, so its rounds cost what is live and
 not the cache's extent.
+
+Which route counts where. In XLA a round is a pass over HBM, which is
+why a round here settles four bits with fifteen compares. A decode
+step's threshold (``models/xing.py:select_step``) counts here on every
+backend, and so does an admission piece's off a TPU (the CPU's route,
+and the oracle of the tests). On a TPU an admission piece's rounds run
+in ``ops/select_threshold.py``, over a query tile's keys held in VMEM,
+where a round costs its compares and settles one bit; that kernel
+returns ``threshold``'s own ``thr``, and only ``tie_cut`` still walks
+the buffer here.
 """
 
 from __future__ import annotations
@@ -36,6 +46,10 @@ _TOP = np.uint32(1 << 31)
 #: bits of the threshold a round of counting settles (a pass over the
 #: columns a round, ``2 ** BITS - 1`` compares a column in it)
 BITS = 4
+
+#: passes over the columns that ``threshold`` makes where no k-th score
+#: is tied: the rounds, then the keys above the threshold and at it
+PASSES = 32 // BITS + 2
 
 
 def sort_keys(scores: jax.Array, valid: jax.Array) -> jax.Array:
@@ -81,7 +95,14 @@ def threshold(count, k: jax.Array, n_cols: int
     thr = jax.lax.bitcast_convert_type(thr_u ^ _TOP, jnp.int32)
     above = count(lambda keys, col: keys > thr[..., None])
     at = count(lambda keys, col: keys == thr[..., None])
-    need = k - above                       # of the columns at ``thr``
+    return thr, tie_cut(count, thr, k - above, at, n_cols)
+
+
+def tie_cut(count, thr: jax.Array, need: jax.Array, at: jax.Array,
+            n_cols: int) -> jax.Array:
+    """``threshold``'s ``cut``: of the ``at`` columns at ``thr`` a row
+    takes the ``need`` lowest-numbered, those below column ``cut``
+    (``n_cols`` where it takes them all)."""
 
     def first_of_the_ties(_):
         # the largest c with fewer than ``need`` ties below column c:
@@ -94,14 +115,13 @@ def threshold(count, k: jax.Array, n_cols: int
 
         n_bits = max(int(n_cols - 1).bit_length(), 1)
         return jax.lax.fori_loop(0, n_bits, bit,
-                                 jnp.zeros(k.shape, jnp.int32)) + 1
+                                 jnp.zeros(thr.shape, jnp.int32)) + 1
 
     # (scores are sums of float32 products: two columns at the k-th
     # score is the rare case, and its rounds are skipped without it)
-    cut = jax.lax.cond(jnp.any(at > need), first_of_the_ties,
-                       lambda _: jnp.full(k.shape, n_cols, jnp.int32),
-                       None)
-    return thr, cut
+    return jax.lax.cond(jnp.any(at > need), first_of_the_ties,
+                        lambda _: jnp.full(thr.shape, n_cols, jnp.int32),
+                        None)
 
 
 def chosen(keys: jax.Array, col: jax.Array, thr: jax.Array,

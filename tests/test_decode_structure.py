@@ -734,8 +734,9 @@ ADMIT = (2, 64)
 def mla_kernel_names(kernels):
     return sorted(
         "/".join(re.search(
-            r'op_name="[^"]*/(attn|moe_experts)/(?:[^"]*/)?'
-            r'(mla_decode_attention|mla_prefill_attention|grouped_qmatmul)',
+            r'op_name="[^"]*/(attn|moe_experts|select)/(?:[^"]*/)?'
+            r'(mla_decode_attention|mla_prefill_attention|grouped_qmatmul'
+            r'|select_threshold)',
             k).groups())
         for k in kernels)
 
@@ -791,7 +792,13 @@ def test_a_decode_steps_grouped_matmul_is_tiled_as_it_was(case):
 # the selection's mask beside its blocks, and still never cut or turned
 # over; the index keys ride the decode scan as scanned inputs (the
 # indexer scores a layer of them whole, in XLA: fewer heads than
-# attention has, so no array of the size the guard above names)
+# attention has, so no array of the size the guard above names). An
+# admission piece's threshold is a kernel too, one call site a stack of
+# layers (ops/select_threshold.py: its rounds of counting over keys in
+# VMEM); a decode step's counts in XLA
+SELECTED_KERNELS = {"admit": sorted(MLA_KERNELS["admit"]
+                                    + ["select/select_threshold"] * 2),
+                    "decode": MLA_KERNELS["decode"]}
 GLM_CFG = decoder_config(
     "tiny-glm", d_model=512, d_ff=512, q_lora_rank=128, kv_lora_rank=128,
     qk_nope_head_dim=32, qk_rope_head_dim=16, v_head_dim=32,
@@ -805,9 +812,28 @@ def test_compiled_selected_mla_programs_hold_both_caches_in_place(
         one_chip, on_tpu, which):
     faults, kernels, text = mla_program_faults(one_chip, which, GLM_CFG)
     assert faults == []
-    assert mla_kernel_names(kernels) == MLA_KERNELS[which]
+    assert mla_kernel_names(kernels) == SELECTED_KERNELS[which]
     # the selection's counting is there, under its own scope
     assert "/select/" in text and "/indexer/" in text
+
+
+@pytest.mark.parametrize("rows,bucket", [(2, 2048), (1, 256)])
+def test_the_threshold_kernel_compiles_at_the_served_shapes(
+        one_chip, rows, bucket):
+    """An admission wave's sort keys at the GLM cell's sizes (a buffer
+    of 32,768 columns, rounds of 1,024): two halves of a query tile's
+    keys are the kernel's VMEM, which the interpreter does not hold it
+    to and the chip's compiler does."""
+    from copilot_for_consensus_tpu.ops import select_threshold
+
+    def shape(*dims):
+        return jax.ShapeDtypeStruct(dims, jnp.int32, sharding=one_chip)
+
+    text = jax.jit(functools.partial(
+        select_threshold.piece_threshold, blk=1024, interpret=False)).lower(
+            shape(rows, bucket, 32768), shape(rows, bucket),
+            shape(rows)).compile().as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 1
 
 
 def test_mla_guard_trips_when_the_extent_is_scored_whole_in_xla(

@@ -319,6 +319,92 @@ def test_the_threshold_is_top_k_with_ties_to_the_lower_position(case):
         assert (got[r] == want).all(), (case, r)
 
 
+PIECE_CASES = ["distinct", "ties", "zeros", "few-valid", "unequal-rows",
+               "causal-k"]
+
+
+@pytest.mark.parametrize("case", PIECE_CASES)
+def test_the_threshold_kernel_returns_the_xla_rounds_integers(
+        case, monkeypatch):
+    """An admission piece's threshold on the kernel's route (ops/
+    select_threshold.py through the interpreter: the rounds over a
+    query tile's keys, of its row's own live blocks) against the XLA
+    rounds over the same buffer of sort keys: the same (thr, cut) for
+    every query, and the chosen set ``jax.lax.top_k``'s, ties to the
+    lower position."""
+    from copilot_for_consensus_tpu.ops import (
+        latent_prefill_attention,
+        select_threshold,
+    )
+
+    rng = np.random.default_rng(6)
+    n, s, t, k = 2, 16, 96, 17          # six blocks of 16, tiles of 8
+    monkeypatch.setattr(select_threshold, "TQ", 8)
+    scores = rng.normal(size=(n, s, t)).astype(np.float32)
+    kv_len = np.asarray([t, t])
+    q_pos = np.full((n, s), t - 1)       # every query sees its whole row
+    if case == "ties":
+        scores = np.round(scores * 2) / 2
+    if case == "zeros":
+        scores = np.where(rng.random((n, s, t)) < 0.8, 0.0,
+                          scores).astype(np.float32)
+        scores[0] *= -1.0
+    if case == "few-valid":
+        kv_len = np.asarray([18, 5])
+    if case == "unequal-rows":
+        # one wave: six live blocks beside two, the second row tied
+        kv_len = np.asarray([t - 3, 29])
+        scores[1] = np.round(scores[1] * 2) / 2
+    if case == "causal-k":
+        # a first piece: query i sees i + 1 columns, fewer than k in
+        # the first tile and part of the second
+        kv_len = np.asarray([s, s + 32])
+        q_pos = kv_len[:, None] - s + np.arange(s)[None, :]
+    col = jnp.arange(t)
+    valid = np.asarray(xing._seen(col, jnp.asarray(q_pos),
+                                  jnp.asarray(kv_len)))
+    buf = sparse_select.sort_keys(jnp.asarray(scores), jnp.asarray(valid))
+    n_blocks = jnp.int32(-(-int(kv_len.max()) // xing.KV_BLOCK))
+
+    def route(kernel):
+        monkeypatch.setattr(latent_prefill_attention, "serves",
+                            lambda block: kernel)
+        fn = jax.jit(lambda buf, q_pos, kv_len, n_blocks:
+                     xing.piece_threshold(buf, q_pos, kv_len, n_blocks, k))
+        args = (buf, jnp.asarray(q_pos), jnp.asarray(kv_len), n_blocks)
+        assert ("select_threshold" in str(jax.make_jaxpr(fn)(*args))) \
+            == kernel
+        return fn(*args)
+
+    (thr_x, cut_x), (thr, cut) = route(False), route(True)
+    assert np.array_equal(thr, thr_x) and np.array_equal(cut, cut_x)
+    if case in ("ties", "zeros", "unequal-rows"):
+        assert (np.asarray(cut) < t).any()       # the ties' branch ran
+    got = np.asarray(sparse_select.chosen(buf, col, thr[..., None],
+                                          cut[..., None]))
+    for r in range(n):
+        for i in range(s):
+            kk = min(k, int(valid[r, i].sum()))
+            # (-0.0 and 0.0 are one score: + 0.0 makes them one float)
+            _, top = jax.lax.top_k(jnp.where(valid[r, i],
+                                             scores[r, i] + 0.0, -jnp.inf),
+                                   kk)
+            assert set(np.flatnonzero(got[r, i])) == set(
+                np.asarray(top).tolist()), (case, r, i)
+
+
+@pytest.mark.parametrize("kernel", [False, True], ids=["xla", "kernel"])
+def test_the_keys_a_threshold_reads_follow_its_route(kernel, monkeypatch):
+    from copilot_for_consensus_tpu.ops import latent_prefill_attention
+
+    monkeypatch.setattr(latent_prefill_attention, "serves",
+                        lambda block: kernel)
+    # blocks of 16: rows of 3 and 1 live blocks, pieces of 32 queries
+    got = xing.threshold_keys_read([40, 9], 32, 128)
+    assert got == (32 * 16 * (3 + 1) if kernel
+                   else sparse_select.PASSES * 2 * 32 * 16 * 3)
+
+
 # ---------------------------------------------------------------------------
 # (d) index_topk >= the length is dense latent attention
 # ---------------------------------------------------------------------------
@@ -453,7 +539,14 @@ def test_the_engine_records_what_the_selection_read(engine):
                 assert r.selected_tokens == sum(
                     min(t + 1, TOPK) for n in (40, 12) for t in range(n))
             assert r.index_tokens_read == 0
+            # off a TPU the threshold's XLA rounds walk the wave's live
+            # blocks (of 16 here) of every row, padded ones too
+            walked, rest = divmod(
+                r.select_keys_read,
+                sparse_select.PASSES * r.padded_tokens * xing.KV_BLOCK)
+            assert 1 <= walked <= MAX_LEN // xing.KV_BLOCK and rest == 0
             continue
+        assert r.select_keys_read == 0
         assert r.index_tokens_read == STEPS * 4 * MAX_LEN
         assert r.state_tokens_read == STEPS * 4 * MAX_LEN
         assert 0 < r.selected_tokens <= r.live_tokens
@@ -574,3 +667,51 @@ def test_an_admission_of_several_pieces_through_the_kernel_is_the_xla_routes(
     for name in cache_x:
         assert np.abs(np.asarray(cache_k[name][:, 1, :, :100])
                       - np.asarray(cache_x[name][:, 1, :, :100])).max() < TOL
+
+
+def test_an_admission_through_the_threshold_kernel_is_the_xla_rounds_exactly(
+        params, monkeypatch):
+    """A prompt of 100 tokens admitted in pieces of 32 and then, in ONE
+    wave of two rows of unequal extents, its last piece (7 live blocks)
+    beside another prompt's first (2), with the threshold on the
+    kernel's route (ops/select_threshold.py) and on the XLA rounds',
+    attention through the admission kernel both times: the threshold's
+    integers are the same, so every logit and both caches are the same
+    bit for bit."""
+    from unittest import mock
+
+    from copilot_for_consensus_tpu.ops import latent_prefill_attention
+
+    monkeypatch.setattr(latent_prefill_attention, "serves",
+                        lambda block: True)
+    seq, other = tokens(100, seed=9), tokens(32, seed=10)
+    real = xing.piece_threshold
+
+    def xla_rounds(*args):
+        with mock.patch.object(latent_prefill_attention, "serves",
+                               lambda block: False):
+            return real(*args)
+
+    def admitted(kernel):
+        monkeypatch.setattr(xing, "piece_threshold",
+                            real if kernel else xla_rounds)
+        fn = piece_fn(CFG)
+        cache = xing.init_cache(CFG, 2, MAX_LEN, jnp.float32)
+        toks = np.zeros((2, 32), np.int32)
+        toks[0], toks[1, :4] = other, seq[96:]
+        args = (params, jnp.asarray(toks), jnp.asarray([32, 4]),
+                jnp.asarray([0, 96]), jnp.asarray([0, 1]))
+        text = str(jax.make_jaxpr(fn)(*args, cache))
+        assert "mla_prefill_attention" in text
+        assert ("select_threshold" in text) == kernel
+        first, cache = prefill(fn, params, cache, 1, seq[:96])
+        last, cache, _ = fn(*args, cache)
+        return first, np.asarray(last), cache
+
+    (first_x, last_x, cache_x), (first_k, last_k, cache_k) = (
+        admitted(False), admitted(True))
+    assert np.array_equal(first_k, first_x)
+    assert np.array_equal(last_k, last_x)
+    for name in cache_x:
+        assert np.array_equal(np.asarray(cache_k[name]),
+                              np.asarray(cache_x[name]))

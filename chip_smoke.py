@@ -143,29 +143,22 @@ def _bring_up(rehearse: bool) -> dict:
 # ---------------------------------------------------------------------------
 
 
-def admission_attention_rates(rehearse: bool, compare) -> dict:
-    """The admission kernel (``ops/latent_prefill_attention.py``)
-    against ``models/xing.py:piece_attention``'s XLA rounds at both
-    served cells' shapes: a wave of 2 x 2,048 queries (32 heads of 192
-    / 128; 64 heads of 256 / 256 under a mask that keeps 2,048 columns
-    a query) whose piece ends 4, 8 and 15 / 23 live rounds into its
-    slots' latent caches, the expansion of each round included on both
-    routes. → a cell and a number of rounds: agreement (``compare``),
-    milliseconds a round on either route, and the share of the MXU's
-    peak that the kernel route's time stands for, counted over the
-    (query, column) pairs attention needs: the seen ones, under a mask
-    or not, since the program scores them all."""
-    from unittest import mock
-
+def _latent_waves(rehearse: bool):
+    """An admission wave of 2 x 2,048 queries at each served latent
+    cell's widths (32 heads of 192 / 128; 64 heads of 256 / 256 under a
+    mask that keeps 2,048 columns a query), tiny when rehearsing: →
+    (cell, config, the numbers of live rounds to time, a function
+    ``piece(q, cache_a, chance, pos0, fold)`` that hands a piece
+    starting at ``pos0`` to ``fold(q, cache_a, slots, q_pos, kv_len,
+    n_blocks, layer, cfg, keep)``, ``piece_attention``'s arguments
+    after the layer index, and its arrays ``(q, cache_a, chance)``)."""
     import jax
     import jax.numpy as jnp
 
     from copilot_for_consensus_tpu.models import xing
     from copilot_for_consensus_tpu.models.configs import decoder_config
-    from copilot_for_consensus_tpu.ops import latent_prefill_attention
 
-    on_tpu = jax.default_backend() == "tpu"
-    dtype = jnp.bfloat16 if on_tpu else jnp.float32
+    dtype = jnp.bfloat16 if jax.default_backend() == "tpu" else jnp.float32
     blk = xing.KV_BLOCK
     cells = {
         "xing": (decoder_config("tiny-xing"), dict(
@@ -175,8 +168,6 @@ def admission_attention_rates(rehearse: bool, compare) -> dict:
             kv_lora_rank=512, qk_nope_head_dim=192, qk_rope_head_dim=64,
             v_head_dim=256, n_heads=64), 32768, (4, 8, 23), 2048)}
     n, s = (2, 64) if rehearse else (2, 2048)
-    rates: dict = {"tiles": [latent_prefill_attention.TQ,
-                             latent_prefill_attention.TK]}
     for cell, (cfg, widths, extent, rounds, topk) in cells.items():
         if rehearse:
             extent, rounds, topk = 4 * blk, (2, 4), 24 * bool(topk)
@@ -195,8 +186,8 @@ def admission_attention_rates(rehearse: bool, compare) -> dict:
         slots = jnp.asarray([2, 0], jnp.int32)
         chance = jax.random.uniform(keys[3], (n, s, extent))
 
-        def piece(q, cache_a, chance, pos0, kernel, topk=topk, cfg=cfg,
-                  layer=layer, slots=slots):
+        def piece(q, cache_a, chance, pos0, fold, topk=topk, cfg=cfg,
+                  layer=layer, slots=slots, extent=extent):
             q_pos = pos0 + jnp.arange(s)[None, :] + jnp.zeros((n, 1),
                                                               jnp.int32)
             kv_len = jnp.full((n,), pos0 + s, jnp.int32)
@@ -207,27 +198,69 @@ def admission_attention_rates(rehearse: bool, compare) -> dict:
                     & (chance * (q_pos[..., None] + 1) < topk)
                 keep = lambda j: jax.lax.dynamic_slice(  # noqa: E731
                     kept, (0, 0, j * blk), (n, s, blk))
-            # the route is read when the program is traced
-            with mock.patch.object(latent_prefill_attention, "serves",
-                                   lambda block: kernel):
-                return xing.piece_attention(
-                    q, cache_a, jnp.int32(0), slots, q_pos, kv_len,
-                    (pos0 + s + blk - 1) // blk, layer, cfg, keep)
+            return fold(q, cache_a, slots, q_pos, kv_len,
+                        (pos0 + s + blk - 1) // blk, layer, cfg, keep)
 
-        routes = {name: jax.jit(functools.partial(piece, kernel=kernel))
-                  for name, kernel in (("xla", False), ("kernel", True))}
+        yield cell, cfg, rounds, piece, (q, cache_a, chance)
+
+
+def _best_ms(fn, *args, rehearse: bool):
+    """(``fn``'s result, the best of five timed calls after the first,
+    in milliseconds)."""
+    import jax
+
+    out = jax.block_until_ready(fn(*args))
+    best = float("inf")
+    for _ in range(1 if rehearse else 5):
+        t = time.perf_counter()
+        jax.block_until_ready(fn(*args))
+        best = min(best, time.perf_counter() - t)
+    return out, best * 1e3
+
+
+def admission_attention_rates(rehearse: bool, compare) -> dict:
+    """The admission kernel (``ops/latent_prefill_attention.py``)
+    against ``models/xing.py:piece_attention``'s XLA rounds at both
+    served cells' shapes (``_latent_waves``): a wave whose piece ends
+    4, 8 and 15 / 23 live rounds into its slots' latent caches, the
+    expansion of each round included on both routes. → a cell and a
+    number of rounds: agreement (``compare``), milliseconds a round on
+    either route, and the share of the MXU's peak that the kernel
+    route's time stands for, counted over the (query, column) pairs
+    attention needs: the seen ones, under a mask or not, since the
+    program scores them all."""
+    from unittest import mock
+
+    import jax
+    import jax.numpy as jnp
+
+    from copilot_for_consensus_tpu.models import xing
+    from copilot_for_consensus_tpu.ops import latent_prefill_attention
+
+    blk = xing.KV_BLOCK
+    rates: dict = {"tiles": [latent_prefill_attention.TQ,
+                             latent_prefill_attention.TK]}
+    for cell, cfg, rounds, piece, arrays in _latent_waves(rehearse):
+        n, s, h, dk = arrays[0].shape
+        dv = cfg.v_head_dim
+
+        def route(kernel):
+            def fold(q, cache_a, *rest):
+                # the route is read when the program is traced
+                with mock.patch.object(latent_prefill_attention, "serves",
+                                       lambda block: kernel):
+                    return xing.piece_attention(q, cache_a, jnp.int32(0),
+                                                *rest)
+            return jax.jit(functools.partial(piece, fold=fold))
+
+        routes = {"xla": route(False), "kernel": route(True)}
         rates[cell] = {}
         for live in rounds:
             pos0 = jnp.int32(live * blk - s)
             ms = {}
             for name, fn in routes.items():
-                out = jax.block_until_ready(fn(q, cache_a, chance, pos0))
-                best = float("inf")
-                for _ in range(1 if rehearse else 5):
-                    t = time.perf_counter()
-                    jax.block_until_ready(fn(q, cache_a, chance, pos0))
-                    best = min(best, time.perf_counter() - t)
-                ms[name] = best * 1e3
+                out, ms[name] = _best_ms(fn, *arrays, pos0,
+                                         rehearse=rehearse)
                 if name == "xla":
                     want = out
             compare(f"mla_prefill_attention/{cell}/rounds={live}", out, want)
@@ -238,7 +271,84 @@ def admission_attention_rates(rehearse: bool, compare) -> dict:
                 "kernel_mxu_share": round(
                     2 * pairs * h * (dk + dv) / (ms["kernel"] * 1e-3)
                     / 197e12, 4)}
-        del cache_a, chance
+    return rates
+
+
+def latent_expand_rates(rehearse: bool) -> dict:
+    """A round of the kernel route's admission attention
+    (``xing._piece_attention_kernel``) at both served cells' shapes
+    (``_latent_waves``, the deepest piece: 15 / 23 live rounds), split
+    in two: the whole round (a round's latents read, expanded on the
+    route the widths take, folded by the kernel) and the fold alone
+    (the same walk over ONE round's operands, expanded once before
+    it). → a cell: which way its widths take
+    (``xing._rotary_in_weight``: ``expand_heads``, or ``expand``'s
+    turned over), milliseconds a round whole, the fold's, their
+    difference (what the expansion costs beside the kernel), and the
+    rate at which that time writes and reads the kernel's operands
+    (``xing.expand_bytes_moved``'s bytes a round and layer, twice:
+    written, then read); and whether ``expand_heads``' keys and values
+    of a round are ``xing.expand``'s transposed bit for bit on this
+    backend (a fact, not a check: both are float32 sums of the same
+    products, in the order the backend's dot takes them)."""
+    import jax
+    import jax.numpy as jnp
+
+    from copilot_for_consensus_tpu.models import xing
+    from copilot_for_consensus_tpu.ops import latent_prefill_attention as lpa
+
+    blk = xing.KV_BLOCK
+    rates: dict = {}
+    for cell, cfg, rounds, piece, arrays in _latent_waves(rehearse):
+        live = rounds[-1]
+        n, s, h, _ = arrays[0].shape
+
+        def whole(q, cache_a, *rest):
+            return xing._piece_attention_kernel(q, cache_a, jnp.int32(0),
+                                                *rest)
+
+        def fold_alone(q, cache_a, slots, q_pos, kv_len, n_blocks, layer,
+                       cfg, keep):
+            k, v = xing.expand_heads(
+                xing._block_rows(cache_a, jnp.int32(0), slots, 0, blk),
+                *xing.expansion_weights(layer, cfg), q.dtype)
+            plan = lpa.plan_queries(q_pos, kv_len)
+            q = q.transpose(0, 2, 1, 3)
+            carry = jax.lax.fori_loop(
+                0, n_blocks, lambda j, carry: lpa.fold_round(
+                    q, k, v, carry, j * blk, plan,
+                    None if keep is None else keep(j)),
+                lpa.empty_carry(n, h, s, cfg.v_head_dim))
+            return lpa.finish(carry, q.dtype)
+
+        def operands(q, cache_a, slots, q_pos, kv_len, n_blocks, layer, cfg,
+                     keep):
+            latent = xing._block_rows(cache_a, jnp.int32(0), slots, 0, blk)
+            got = xing.expand_heads(
+                latent, *xing.expansion_weights(layer, cfg), q.dtype)
+            want = xing.expand(latent, layer, cfg)
+            return jnp.stack([jnp.all(g == w.astype(q.dtype).transpose(
+                0, 2, 1, 3)) for g, w in zip(got, want)])
+
+        pos0 = jnp.int32(live * blk - s)
+        same = jax.jit(functools.partial(piece, fold=operands))(
+            *arrays, pos0)
+        ms = {name: _best_ms(jax.jit(functools.partial(piece, fold=fold)),
+                             *arrays, pos0, rehearse=rehearse)[1] / live
+              for name, fold in (("round", whole), ("fold", fold_alone))}
+        moved = xing.expand_bytes_moved(
+            [blk] * n, blk, dataclasses.replace(cfg, n_layers=1),
+            arrays[0].dtype.itemsize)
+        rates[cell] = {
+            "rotary_in_weight": xing._rotary_in_weight(cfg),
+            "operands_are_expands_bit_for_bit": bool(same.all()),
+            "rounds": live,
+            "round_ms": round(ms["round"], 4),
+            "fold_ms": round(ms["fold"], 4),
+            "expand_ms": round(ms["round"] - ms["fold"], 4),
+            "operand_mb_a_round": round(moved / 1e6, 1),
+            "expand_gb_s": round(2 * moved / max(
+                ms["round"] - ms["fold"], 1e-6) / 1e6, 1)}
     return rates
 
 
@@ -323,13 +433,7 @@ def select_threshold_rates(rehearse: bool) -> dict:
     # modulo a prime over the extent), so the ties' branch stays out;
     # then the tied scores of (1), where it runs on both routes
     def timed(fn, *args) -> float:
-        jax.block_until_ready(fn(*args))
-        best = float("inf")
-        for _ in range(1 if rehearse else 5):
-            t = time.perf_counter()
-            jax.block_until_ready(fn(*args))
-            best = min(best, time.perf_counter() - t)
-        return best * 1e3
+        return _best_ms(fn, *args, rehearse=rehearse)[1]
 
     prime = 32771
     distinct = ((col[None, None, :] * 7919
@@ -992,6 +1096,11 @@ def phase_kernels(rehearse: bool) -> int:
     say(f"admission threshold, a wave of 2 x 2,048 queries: "
         f"{threshold_rates}")
 
+    # -- what a round's expansion costs beside the fold kernel ---------
+    expand_rates = latent_expand_rates(rehearse)
+    say(f"latent admission attention, a round's expansion beside its "
+        f"fold: {expand_rates}")
+
     # -- admission attention over latents (attention="mla" on a TPU,
     # ops/latent_prefill_attention.py): a round's scores stay in VMEM --
     prefill_rates = admission_attention_rates(rehearse, compare)
@@ -1049,6 +1158,7 @@ def phase_kernels(rehearse: bool) -> int:
          mla_decode_attention=latent_rates,
          selected_latent_attention=kept_rates,
          select_threshold=threshold_rates,
+         latent_expand=expand_rates,
          mla_prefill_attention=prefill_rates,
          flash_attention=flash_rates,
          grouped_qmatmul=grouped_rates,

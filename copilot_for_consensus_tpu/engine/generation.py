@@ -2720,6 +2720,11 @@ class GenerationEngine:
                 extra["attn_pairs"] = sum(
                     n * int(pos0[r]) + n * (n + 1) // 2
                     for r, (_slot, n) in enumerate(rows))
+                if self._mla:
+                    # (padded rows are counted: their rounds are expanded)
+                    extra["expand_bytes_moved"] = xing.expand_bytes_moved(
+                        (pos0 + lens).tolist(), self.max_len, self.cfg,
+                        self.params["tok_emb"].dtype.itemsize)
                 if self._mixed:
                     # a window layer's queries read at most a window
                     extra["window_attn_pairs"] = sum(

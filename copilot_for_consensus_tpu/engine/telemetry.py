@@ -369,6 +369,12 @@ class StepRecord:
     attn_pairs: int = 0         # admission waves: query-key pairs the
     #                             wave's real tokens attend to, each
     #                             over its sequence's whole prefix
+    expand_bytes_moved: int = 0  # admission waves: bytes of expanded
+    #                             keys and values its rounds hand to
+    #                             their folds, over all layers, padded
+    #                             rows included (on a TPU the operands
+    #                             of ops/latent_prefill_attention.py,
+    #                             written once and read once)
     # attention="mla" engines whose attention is SELECTED
     # (cfg.index_topk; models/xing.py); 0 elsewhere. Host arithmetic,
     # the same in every layer: in a decode dispatch over the decoding
@@ -664,6 +670,7 @@ class EngineTelemetry:
                     attn_pairs: int = 0, index_tokens_read: int = 0,
                     selected_tokens: int = 0,
                     live_tokens: int = 0, select_keys_read: int = 0,
+                    expand_bytes_moved: int = 0,
                     window_live_tokens: int = 0,
                     window_tokens_read: int = 0,
                     window_attn_pairs: int = 0,
@@ -691,6 +698,7 @@ class EngineTelemetry:
             index_tokens_read=index_tokens_read,
             selected_tokens=selected_tokens, live_tokens=live_tokens,
             select_keys_read=select_keys_read,
+            expand_bytes_moved=expand_bytes_moved,
             window_live_tokens=window_live_tokens,
             window_tokens_read=window_tokens_read,
             window_attn_pairs=window_attn_pairs,

@@ -504,8 +504,10 @@ def piece_attention(q: jax.Array, cache_a: jax.Array, li: jax.Array,
 
     Two routes to the same fold. On a TPU a round's scores stay on the
     chip: ``ops/latent_prefill_attention.py`` folds the round into the
-    carry a tile at a time and makes the causal mask itself. Elsewhere
-    the XLA rounds below, which the tests hold the kernel to."""
+    carry a tile at a time and makes the causal mask itself, and the
+    round is expanded into the layout its blocks read
+    (``expand_heads``). Elsewhere the XLA rounds below over ``expand``,
+    which the tests hold the kernel's route to."""
     n, s, h, _ = q.shape
     blk = min(KV_BLOCK, cache_a.shape[3])
     dv = cfg.v_head_dim
@@ -547,25 +549,88 @@ def piece_attention(q: jax.Array, cache_a: jax.Array, li: jax.Array,
         return o.transpose(0, 2, 1, 3).reshape(n, s, h * dv).astype(q.dtype)
 
 
+#: lanes of a vreg and columns of an MXU pass on every TPU so far: what
+#: an einsum's output columns are multiplied and stored in tiles of
+LANE_TILE = 128
+
+
+def _rotary_in_weight(cfg: DecoderConfig) -> bool:
+    """Does the kernel's route expand a round heads first, the shared
+    rotary key taken through the keys' weight (``expand_heads``)? Where
+    a head's whole key fills no more column tiles than its no-position
+    part alone (192 + 64 in two tiles of 128, as 192): the MXU then
+    writes the rotary columns in a tile it multiplies anyway, for a
+    ninth more rows of weight, and a round's copies go (2.81 → 2.40 ms
+    a round at GLM's widths). Where the no-position part fills its
+    tiles (128 + 64) the augmented weight adds a tile of columns a head
+    and loses (0.93 → 0.99 ms at Xing's widths), and appending the key
+    instead gains a fiftieth of a round, which that cell cannot tell
+    from its two levels: those widths keep ``expand`` (all timed on
+    the chip: ``PERF.md`` section 6, PR 48)."""
+    dn, dk = cfg.qk_nope_head_dim, cfg.qk_nope_head_dim \
+        + cfg.qk_rope_head_dim
+    return -(-dk // LANE_TILE) == -(-dn // LANE_TILE)
+
+
+def expansion_weights(layer: Params, cfg: DecoderConfig
+                      ) -> tuple[jax.Array, jax.Array]:
+    """``wkv_b`` as ``expand_heads`` reads it, its two halves apart,
+    made once a layer call outside the rounds: the values' ``[r, H,
+    dv]`` and the keys' ``[r + dr, H, dn + dr]``, which is the key half
+    in one corner, the identity in the rotary corner and zeros
+    elsewhere, so that ONE einsum over a whole latent row writes a
+    head's key whole; its rotary columns are the row's shared key times
+    1 summed with zeros in float32, which is exact."""
+    r, h = cfg.kv_lora_rank, cfg.n_heads
+    dn, dr = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim
+    w = _wkv_b(layer, cfg)
+    eye = jnp.eye(dr, dtype=w.dtype)[:, None, :]
+    w_k = jnp.zeros((r + dr, h, dn + dr), w.dtype) \
+        .at[:r, :, :dn].set(w[..., :dn]).at[r:, :, dn:].set(eye)
+    return w_k, w[..., dn:]
+
+
+@scope("latent_expand")
+def expand_heads(latent: jax.Array, w_k: jax.Array, w_v: jax.Array, dtype
+                 ) -> tuple[jax.Array, jax.Array]:
+    """``expand`` as the fold kernel reads it: latent rows ``[n, R,
+    T]`` and ``expansion_weights`` → every head's keys ``[n, H, T, dn +
+    dr]`` and values ``[n, H, T, dv]`` in ``dtype``, heads before
+    positions and each at its whole width: two einsums, and nothing
+    between them and the kernel."""
+    latent = latent.astype(w_k.dtype)
+    k = jnp.einsum("nrt,rhd->nhtd", latent, w_k)
+    v = jnp.einsum("nrt,rhd->nhtd", latent[:, :w_v.shape[0]], w_v)
+    return k.astype(dtype), v.astype(dtype)
+
+
 def _piece_attention_kernel(q, cache_a, li, slots, q_pos, kv_len, n_blocks,
                             layer, cfg, keep):
     """``piece_attention`` with each round folded by the kernel: the
-    same walk of the live rounds, the same expansion a round, heads
-    before positions as the kernel's tiles want them."""
+    same walk of the live rounds; a round's keys and values heads
+    before positions as the kernel's tiles want them, written so by
+    ``expand_heads`` where ``_rotary_in_weight``, elsewhere ``expand``'s
+    turned over."""
     n, s, h, _ = q.shape
     blk = min(KV_BLOCK, cache_a.shape[3])
     heads_first = lambda a: a.transpose(0, 2, 1, 3)  # noqa: E731
     with scope("attn"):
         plan = latent_prefill_attention.plan_queries(q_pos, kv_len)
         q = heads_first(q)
+    if _rotary_in_weight(cfg):
+        with scope("kv_prefix"), scope("latent_expand"):
+            w_k, w_v = expansion_weights(layer, cfg)
 
     def fold(j, carry):
         with scope("kv_prefix"):
-            k, v = expand(_block_rows(cache_a, li, slots, j, blk), layer,
-                          cfg)
-            with scope("latent_expand"):
-                k, v = heads_first(k.astype(q.dtype)), \
-                    heads_first(v.astype(q.dtype))
+            latent = _block_rows(cache_a, li, slots, j, blk)
+            if _rotary_in_weight(cfg):
+                k, v = expand_heads(latent, w_k, w_v, q.dtype)
+            else:
+                k, v = expand(latent, layer, cfg)
+                with scope("latent_expand"):
+                    k, v = heads_first(k.astype(q.dtype)), \
+                        heads_first(v.astype(q.dtype))
         with scope("attn"):
             seen = None
             if keep is not None:
@@ -835,6 +900,21 @@ def threshold_keys_read(kv_len: list[int], s: int, extent: int) -> int:
     if latent_prefill_attention.serves(blk):
         return s * blk * sum(live)
     return sparse_select.PASSES * len(live) * s * blk * max(live)
+
+
+def expand_bytes_moved(kv_len: list[int], extent: int, cfg: DecoderConfig,
+                       itemsize: int) -> int:
+    """Bytes of expanded keys and values that a wave's rounds hand to
+    their folds (host numbers): its live rounds (the longest row's) x
+    the layers x every row's and head's ``KV_BLOCK`` columns of a key
+    ``dn + dr`` and a value ``dv`` wide, ``itemsize`` bytes each. On
+    the kernel's route these are ``fold_round``'s operands, written
+    once by ``expand_heads`` and read once by the kernel."""
+    blk = min(KV_BLOCK, extent)
+    rounds = max(-(-max(kv_len) // blk), 0)
+    width = cfg.qk_nope_head_dim + cfg.qk_rope_head_dim + cfg.v_head_dim
+    return rounds * cfg.n_layers * len(kv_len) * cfg.n_heads * blk \
+        * width * itemsize
 
 
 def select_step(q_i: jax.Array, w_i: jax.Array, k_cur: jax.Array,

@@ -863,6 +863,65 @@ def test_mla_guard_trips_when_a_round_is_folded_in_xla(
                    for f in faults)
 
 
+def round_operand_ops(text, cfg):
+    """The ops of a compiled admission program, inside its loop over
+    the live rounds and under ``latent_expand``, whose result is as
+    large as a round's values ``[rows, heads, round, dv]`` or larger,
+    by the last part of their ``op_name``: what is written to make the
+    fold kernel's operands."""
+    least = ADMIT[0] * cfg.n_heads * min(xing.KV_BLOCK, MLA_MAX) \
+        * cfg.v_head_dim
+    made, fused = [], False
+    for line in text.splitlines():
+        if line and not line[0].isspace():
+            # (a fusion's body: its ops are the fusion's, counted there)
+            fused = line.startswith("%fused_computation")
+        m = re.match(r"\s*(?:ROOT )?%?[\w.\-]+ = \w+\[([\d,]+)\]\S* "
+                     r"([\w\-]+)\(", line)
+        name = re.search(r'op_name="([^"]*)"', line)
+        if fused or not m or not name or m.group(2) in (
+                "parameter", "get-tuple-element", "bitcast"):
+            continue
+        parts = name.group(1).split("/")
+        if "latent_expand" in parts and parts[parts.index(
+                "kv_prefix") - 2:parts.index("kv_prefix")] \
+                == ["while", "body"] \
+                and math.prod(int(x) for x in m.group(1).split(",")) \
+                >= least:
+            made.append(parts[-1])
+    return sorted(made)
+
+
+def test_a_rounds_expansion_writes_the_kernels_operands_and_no_more(
+        one_chip, on_tpu):
+    """Where the rotary key rides in the keys' weight (these widths, 32
+    + 16 in a tile of 128, as the served 192 + 64): two einsums a round
+    (in each of the two scanned stacks of layers) write the keys and
+    values where the fold kernel reads them; no slice of a wider
+    activation, no transpose, no key repeated a head beside them."""
+    assert xing._rotary_in_weight(GLM_CFG)
+    faults, _kernels, text = mla_program_faults(one_chip, "admit", GLM_CFG)
+    assert faults == []
+    assert round_operand_ops(text, GLM_CFG) == ["dot_general"] * 4
+
+
+@pytest.mark.parametrize("cfg", [MLA_CFG, GLM_CFG], ids=["xing", "glm"])
+def test_the_expansion_guard_trips_on_the_turned_over_form(
+        one_chip, on_tpu, monkeypatch, cfg):
+    """The formulation this replaced, and the route of widths whose
+    no-position part fills its tiles (a lane tile of 32 stands in for
+    128 at these widths, as the served 128 + 64): ``expand``'s one
+    einsum a round, its output cut at the keys' width, the rotary key
+    repeated a head, keys and values turned heads-first for the
+    kernel."""
+    monkeypatch.setattr(xing, "LANE_TILE", 32)
+    assert not xing._rotary_in_weight(cfg)
+    faults, _kernels, text = mla_program_faults(one_chip, "admit", cfg)
+    assert faults == []
+    made = round_operand_ops(text, cfg)
+    assert len(made) > 4 and set(made) - {"dot_general"}
+
+
 def test_mla_guard_trips_when_the_empty_carry_is_a_literal(
         one_chip, on_tpu, monkeypatch):
     """Zeros with a row set to ``-inf`` are folded into a literal of the
@@ -1006,7 +1065,9 @@ OTHER_PROGRAMS = ("jit__decode", "jit__admit_fused", "jit__admit_eva",
 
 
 @pytest.fixture(scope="module")
-def lowered_programs():
+def lowered_others():
+    """The other configurations' programs at the tiny sizes, lowered
+    (not yet compiled)."""
     key = jax.random.PRNGKey(0)
     i32 = jnp.zeros((4,), jnp.int32)
     rows = (jnp.zeros((2, 32), jnp.int32), jnp.ones((2,), jnp.int32))
@@ -1029,7 +1090,12 @@ def lowered_programs():
     for may_close in (False, True):
         texts[f"jit__decode_eva[{may_close}]"] = evb._decode_eva_fn.lower(
             evb.params, i32, i32, evb._cache, key, may_close=may_close)
-    return {k: v.compile().as_text() for k, v in texts.items()}
+    return texts
+
+
+@pytest.fixture(scope="module")
+def lowered_programs(lowered_others):
+    return {k: v.compile().as_text() for k, v in lowered_others.items()}
 
 
 @pytest.mark.parametrize("program", OTHER_PROGRAMS)
@@ -1048,3 +1114,73 @@ def test_the_other_configurations_programs_hold_nothing_of_this_one(
     assert not any("grouped_qmatmul" in name
                    or "mla_decode_attention" in name
                    or "mla_prefill_attention" in name for name in names)
+
+
+# ---------------------------------------------------------------------------
+# and a change to one route leaves the other programs' text alone. PR 48
+# rewrote the expansion on the KERNEL's route of the latent admission
+# (a TPU's); the decode programs (the absorbed form, which expands
+# nothing), the other architectures' admission programs and, on the
+# CPU, the latent admission programs themselves (the XLA rounds: the
+# oracle) were to stay the parent's, hash for hash. The hashes below are
+# the parent's (commit 6099b46), taken from its checkout by this code.
+# A PR that means to change one of these programs pins its new hash and
+# says so; one that does not has a finding.
+# ---------------------------------------------------------------------------
+
+PINNED_PROGRAMS = {
+    "tiny/admit":
+        "741174c67cfb469e778631676b87640b9bca70958b616e21eb6afa044e1b3efe",
+    "tiny-eva/admit":
+        "9474a1bdb9aa2fea06042ff9da7c8f5ac805802b127fee1d9146090859bd9024",
+    "tiny-mixed/admit":
+        "9c85553b1f30e32f0c282c07d3ff4a47fe3e6b4f9b2f5074a112fcf2c4f9d4f5",
+    "tiny-xing/admit":
+        "db9b6dd88d732f50ff5663af5bf26bf60d63e2422c3ae226796e5cfa16bc040b",
+    "tiny-xing/decode":
+        "f33456e7740a6f129139516ceefbdacb5c1d91cb571e0ffd7ef38088ba1d0b74",
+    "tiny-glm/admit":
+        "d159d4c04f659da6c562ef36c7e549537eb0c6f38ee37d404285301e510d0783",
+    "tiny-glm/decode":
+        "ea76bdcb01d6d8f5971adf577b3ce6a7f7c54fabd75959455de1b44c3a6a36f4",
+}
+
+
+@pytest.fixture(scope="module")
+def lowered_text_hashes(lowered_others):
+    """sha256 of ``.lower().as_text()`` (StableHLO, no source lines) of
+    the tiny engines' programs on the CPU."""
+    import hashlib
+
+    key = jax.random.PRNGKey(0)
+    i32 = jnp.zeros((4,), jnp.int32)
+    two = jnp.zeros((2,), jnp.int32)
+
+    def rows(s):
+        return jnp.zeros((2, s), jnp.int32), jnp.ones((2,), jnp.int32)
+
+    def engine(name, buckets):
+        return GenerationEngine(
+            decoder_config(name), None, num_slots=4, max_len=256,
+            prefill_buckets=buckets, dtype=jnp.bfloat16, eos_id=-1,
+            quantize="int8")
+
+    low = {"tiny/admit": lowered_others["jit__admit_fused"],
+           "tiny-eva/admit": lowered_others["jit__admit_eva"]}
+    eng = engine("tiny-mixed", (8, 16))
+    low["tiny-mixed/admit"] = eng._admit_mixed_fn.lower(
+        eng.params, *rows(16), two, two, eng._cache, key)
+    for name in ("tiny-xing", "tiny-glm"):
+        eng = engine(name, (32,))
+        low[name + "/admit"] = eng._admit_mla_fn.lower(
+            eng.params, *rows(32), two, two, eng._cache, key)
+        low[name + "/decode"] = eng._decode_mla_fn.lower(
+            eng.params, i32, i32, eng._cache, key)
+    return {k: hashlib.sha256(v.as_text().encode()).hexdigest()
+            for k, v in low.items()}
+
+
+@pytest.mark.parametrize("program", list(PINNED_PROGRAMS))
+def test_the_lowered_text_of_the_untouched_programs_is_the_parents(
+        lowered_text_hashes, program):
+    assert lowered_text_hashes[program] == PINNED_PROGRAMS[program]

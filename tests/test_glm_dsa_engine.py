@@ -545,8 +545,11 @@ def test_the_engine_records_what_the_selection_read(engine):
                 r.select_keys_read,
                 sparse_select.PASSES * r.padded_tokens * xing.KV_BLOCK)
             assert 1 <= walked <= MAX_LEN // xing.KV_BLOCK and rest == 0
+            # and as many rounds of keys and values are expanded
+            assert r.expand_bytes_moved == walked * xing.expand_bytes_moved(
+                [1] * r.batch, MAX_LEN, CFG, 4)
             continue
-        assert r.select_keys_read == 0
+        assert r.select_keys_read == 0 and r.expand_bytes_moved == 0
         assert r.index_tokens_read == STEPS * 4 * MAX_LEN
         assert r.state_tokens_read == STEPS * 4 * MAX_LEN
         assert 0 < r.selected_tokens <= r.live_tokens

@@ -2,8 +2,8 @@
 # attention without its scores leaving the chip
 # (ops/latent_prefill_attention.py), through the Pallas interpreter,
 # against `models/xing.py:piece_attention`'s XLA rounds: the same walk
-# of the live rounds, the same expansion, the same arithmetic in
-# another order of sums.
+# of the live rounds, the same keys and values (expanded heads first on
+# the kernel's route), the same arithmetic in another order of sums.
 #
 # Sizes: the two tiny configurations, whose keys and values stand in
 # the served ones' ratios (tiny-xing 24 / 16 as 192 / 128, tiny-glm
@@ -29,6 +29,13 @@ from copilot_for_consensus_tpu.models import xing
 from copilot_for_consensus_tpu.models.configs import decoder_config
 from copilot_for_consensus_tpu.ops import latent_prefill_attention as lpa
 
+# The kernel's route has a round's keys and values heads first one of
+# two ways by the widths (`xing._rotary_in_weight`): written so by
+# `xing.expand_heads`, the shared rotary key taken through the keys'
+# weight, or `xing.expand`'s turned over. The `expansion` fixture runs
+# a test both ways at the tiny widths (16 + 8), where a lane tile of
+# 128 takes the first as the served 192 + 64 do, and a tile of 16 the
+# second as the served 128 + 64 do.
 BLK, EXTENT, SLOTS, N_L = 128, 6 * 128, 5, 2
 F32, BF16 = 2e-6, 8e-3
 CFGS = {"xing": decoder_config("tiny-xing"),
@@ -39,6 +46,15 @@ CFGS = {"xing": decoder_config("tiny-xing"),
 def small_tiles(monkeypatch):
     monkeypatch.setattr(xing, "KV_BLOCK", BLK)
     monkeypatch.setattr(lpa, "TQ", 64)
+
+
+@pytest.fixture(params=["heads_first", "turned_over"])
+def expansion(request, monkeypatch):
+    if request.param == "turned_over":
+        monkeypatch.setattr(xing, "LANE_TILE", 16)
+    assert all(xing._rotary_in_weight(c) == (request.param == "heads_first")
+               for c in CFGS.values())
+    return request.param
 
 
 def test_the_sizes_here_stand_in_the_served_ratios():
@@ -135,17 +151,41 @@ def test_kernel_route_equals_the_xla_rounds(cfg, n, s):
     assert np.abs(got - want).max() < F32
 
 
-def test_rows_at_different_places_in_one_call():
+@pytest.mark.parametrize("masked", [False, True], ids=["causal", "kept"])
+@pytest.mark.parametrize("cfg", CFGS.values(), ids=CFGS.keys())
+def test_rows_at_different_places_in_one_call(cfg, masked, expansion):
     """A first piece, a piece deep in its prompt, a short last piece:
-    each row's rounds past its own length are not its business, and a
-    query tile that lies wholly before a round is skipped."""
-    cfg = CFGS["xing"]
+    each row's rounds past its own length are not its business (they
+    are expanded with the wave's and folded by nobody), and a query
+    tile that lies wholly before a round is skipped; with and without
+    a selection's mask."""
     cache_a, layer, q = state(cfg, 1, 4, 128)
-    want, got = both_routes(cfg, cache_a, layer, q,
-                            [0, 4 * BLK, BLK, 2 * BLK + 128],
-                            [128, 128, 37, 1], slots=[3, 0, 4, 1])
+    pos0, lens = [0, 4 * BLK, BLK, 2 * BLK + 128], [128, 128, 37, 1]
+    keep = None
+    if masked:
+        keep = seen_of(pos0, lens, 128) & (
+            np.random.default_rng(3).random((4, 128, EXTENT)) < 0.3)
+    want, got = both_routes(cfg, cache_a, layer, q, pos0, lens, keep,
+                            slots=[3, 0, 4, 1])
     assert np.isfinite(got).all()
     assert np.abs(got - want).max() < F32
+
+
+@pytest.mark.parametrize("masked", [False, True], ids=["causal", "kept"])
+@pytest.mark.parametrize("cfg", CFGS.values(), ids=CFGS.keys())
+def test_a_piece_shorter_than_a_query_tile(cfg, masked, expansion):
+    """Pieces of 32 queries under tiles of 64: one tile a row, the
+    rounds' operands as wide as ever."""
+    cache_a, layer, q = state(cfg, 29, 2, 32, jnp.bfloat16)
+    pos0, lens = [BLK + 96, 3 * BLK], [32, 20]
+    keep = None
+    if masked:
+        keep = seen_of(pos0, lens, 32) & (
+            np.random.default_rng(5).random((2, 32, EXTENT)) < 0.4)
+        keep[:, :, 0] = True
+    want, got = both_routes(cfg, cache_a, layer, q, pos0, lens, keep)
+    assert np.isfinite(got).all()
+    assert np.abs(got - want).max() < BF16
 
 
 @pytest.mark.parametrize("pos0", [0, BLK - 64, BLK, 3 * BLK + 64],
@@ -299,6 +339,79 @@ def test_the_mask_is_shared_by_all_heads_and_read_a_layer_at_a_time():
     assert np.abs(got - want).max() < F32
     _, other = both_routes(cfg, cache_a, layer, q, pos0, lens, keep, li=1)
     assert np.abs(got - other).max() > 1e-2
+
+
+# ---------------------------------------------------------------------------
+# the kernel route's expansion: `expand`'s keys and values, heads first
+# ---------------------------------------------------------------------------
+
+
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32],
+                         ids=["bf16", "f32"])
+@pytest.mark.parametrize("cfg", CFGS.values(), ids=CFGS.keys())
+def test_the_kernels_operands_are_expands_transposed(cfg, dtype):
+    """`expand_heads` against `expand` (the XLA rounds' and the
+    oracle's) on a round's latents of three rows: the same keys and
+    values, heads before positions. The rotary columns bit for bit in
+    either type: the shared key times 1 summed with zeros. The rest are
+    float32 sums of the same products, which the CPU's dot blocks by
+    the width of what it writes (448 columns a position there, a
+    head's here): equal to the last bit or two in float32, and in
+    bfloat16, the served type, equal but where that bit decides a
+    rounding (a value in a few hundred, one step apart). Whether the
+    chip's are bit for bit is `chip_smoke.py`'s to say
+    (`latent_expand_rates`)."""
+    cache_a, layer, _q = state(cfg, 31, 1, 64, dtype)
+    latent = cache_a[1, :3, :, BLK:2 * BLK]
+    want_k, want_v = (np.asarray(a.transpose(0, 2, 1, 3), np.float32)
+                      for a in xing.expand(latent, layer, cfg))
+    w_k, w_v = xing.expansion_weights(layer, cfg)
+    r, dn, dr = cfg.kv_lora_rank, cfg.qk_nope_head_dim, \
+        cfg.qk_rope_head_dim
+    assert w_v.shape == (r, cfg.n_heads, cfg.v_head_dim)
+    assert w_k.shape == (r + dr, cfg.n_heads, dn + dr)
+    k, v = jax.jit(lambda lat: xing.expand_heads(lat, w_k, w_v, dtype))(
+        latent)
+    assert k.dtype == v.dtype == dtype
+    assert k.shape == (3, cfg.n_heads, BLK, dn + dr)
+    assert v.shape == (3, cfg.n_heads, BLK, cfg.v_head_dim)
+    k, v = np.asarray(k, np.float32), np.asarray(v, np.float32)
+    assert np.array_equal(k[..., dn:], want_k[..., dn:])
+    for got, want in ((k, want_k), (v, want_v)):
+        off = np.abs(got - want)
+        if dtype == jnp.bfloat16:
+            assert (off <= np.abs(want) * 2.0 ** -7).all()
+            assert (off > 0).mean() < 0.01
+        else:
+            assert off.max() <= 4 * np.finfo(np.float32).eps \
+                * np.abs(want).max()
+
+
+@pytest.mark.parametrize("widths,in_weight", [
+    (dict(qk_nope_head_dim=192, qk_rope_head_dim=64), True),
+    (dict(qk_nope_head_dim=128, qk_rope_head_dim=64), False),
+    (dict(qk_nope_head_dim=128, qk_rope_head_dim=128), False),
+    (dict(qk_nope_head_dim=64, qk_rope_head_dim=64), True)],
+    ids=["glm", "xing", "whole-tiles", "half-tile"])
+def test_the_rotary_key_rides_in_the_weight_where_it_adds_no_tile(
+        widths, in_weight):
+    """By the widths alone: a round is expanded heads first, the rotary
+    key through the keys' weight, when a head's whole key fills no more
+    tiles of 128 columns than its no-position part (the served 192 +
+    64); where that part fills its tiles (the served 128 + 64) the
+    route keeps `expand`."""
+    cfg = dataclasses.replace(CFGS["glm"], **widths)
+    assert xing._rotary_in_weight(cfg) == in_weight
+
+
+@pytest.mark.parametrize("cfg", CFGS.values(), ids=CFGS.keys())
+def test_the_bytes_a_waves_rounds_expand(cfg):
+    """Rows of 3 and 1 live blocks (of 128 here) in a wave: the longest
+    row's rounds, every row's columns, all the layers."""
+    got = xing.expand_bytes_moved([2 * BLK + 5, 9], EXTENT, cfg, 2)
+    width = cfg.qk_nope_head_dim + cfg.qk_rope_head_dim + cfg.v_head_dim
+    assert got == 3 * cfg.n_layers * 2 * cfg.n_heads * BLK * width * 2
+    assert xing.expand_bytes_moved([0, 0], EXTENT, cfg, 2) == 0
 
 
 # ---------------------------------------------------------------------------

@@ -459,7 +459,13 @@ def test_the_engine_records_the_counts_with_its_dispatches(engine):
         if r.kind == "prefill":
             assert r.expert_rows == r.tokens * K * n_moe
             assert r.attn_pairs > 0
+            # whole rounds of expanded keys and values, for every layer
+            # and (padded) row of the wave
+            rounds, rest = divmod(r.expand_bytes_moved, xing.expand_bytes_moved(
+                [1] * r.batch, MAX_LEN, CFG, 4))
+            assert 1 <= rounds <= MAX_LEN // xing.KV_BLOCK and rest == 0
         else:
+            assert r.expand_bytes_moved == 0
             assert r.expert_rows == r.rows * STEPS * K * n_moe
             assert r.state_tokens_read == STEPS * 4 * MAX_LEN
         assert 0 < r.experts_touched <= r.expert_rows
